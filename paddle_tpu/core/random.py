@@ -33,11 +33,10 @@ def _ensure_prng_impl():
     if _prng_impl_chosen:
         return
     _prng_impl_chosen = True
-    try:
-        if jax.default_backend() == "tpu":
-            jax.config.update("jax_default_prng_impl", "rbg")
-    except Exception:
-        pass
+    if jax.default_backend() == "tpu":
+        # raw key data is 4 words under rbg (2 under threefry): nothing may
+        # hard-code a key width
+        jax.config.update("jax_default_prng_impl", "rbg")
 
 
 def rng_state_tensor():

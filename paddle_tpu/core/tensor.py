@@ -21,17 +21,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import dtype as dtypes
-from .lazy import LazyArray
-
-def _complex_transfer_ok(arr) -> bool:
-    """TPU runtimes in this fleet cannot transfer complex buffers host-ward
-    (and a failed attempt wedges the device queue, so no try/except probe);
-    CPU always can."""
-    try:
-        return next(iter(arr.devices())).platform == "cpu"
-    except Exception:
-        return True
 from .device import Place, get_default_place
+from .lazy import LazyArray
 
 
 class Tensor:
@@ -87,13 +78,6 @@ class Tensor:
 
     def numpy(self) -> np.ndarray:
         self.value()  # force + cache any pending lazy computation
-        if jnp.iscomplexobj(self._data) and \
-                not _complex_transfer_ok(self._data):
-            # this TPU runtime can't transfer complex buffers host-ward;
-            # split on device, recombine on host
-            re = np.asarray(jnp.real(self._data))
-            im = np.asarray(jnp.imag(self._data))
-            return re + 1j * im
         return np.asarray(self._data)
 
     def item(self, *args):

@@ -20,7 +20,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-__all__ = ["CostModel", "OpCost", "DeviceSpec", "TPU_V4", "HOST_CPU"]
+__all__ = ["CostModel", "OpCost", "DeviceSpec", "TPU_V4", "TPU_V5E",
+           "HOST_CPU"]
 
 
 @dataclass
@@ -34,7 +35,26 @@ class DeviceSpec:
 
 # one v4 chip: ~275 TFLOP/s bf16, ~1.2 TB/s HBM
 TPU_V4 = DeviceSpec("tpu-v4", peak_flops=275e12, hbm_bandwidth=1.2e12)
+# one v5e chip: 197 TFLOP/s bf16, 819 GB/s HBM (Google Cloud docs, "TPU v5e")
+TPU_V5E = DeviceSpec("tpu-v5e", peak_flops=197e12, hbm_bandwidth=819e9)
 HOST_CPU = DeviceSpec("cpu", peak_flops=1e11, hbm_bandwidth=5e10)
+
+# TPU specs by jax ``device_kind`` prefix. A kind that is not here is an
+# error, never costed as some other chip.
+_TPU_SPECS = {"TPU v4": TPU_V4, "TPU v5 lite": TPU_V5E}
+
+
+def device_spec(device) -> DeviceSpec:
+    """The roofline peaks for one jax device."""
+    if device.platform != "tpu":
+        return HOST_CPU
+    for prefix, spec in _TPU_SPECS.items():
+        if device.device_kind.startswith(prefix):
+            return spec
+    raise ValueError(
+        f"no DeviceSpec for TPU device_kind {device.device_kind!r} "
+        f"(known: {sorted(_TPU_SPECS)}); add its published peaks to "
+        f"cost_model._TPU_SPECS or pass CostModel(device=DeviceSpec(...))")
 
 
 @dataclass
@@ -84,7 +104,7 @@ class CostModel:
     @staticmethod
     def _detect() -> DeviceSpec:
         import jax
-        return TPU_V4 if jax.default_backend() == "tpu" else HOST_CPU
+        return device_spec(jax.devices()[0])
 
     # -------------------------------------------------------------- static
 

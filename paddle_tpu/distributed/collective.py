@@ -205,7 +205,6 @@ class _MPBackend:
             return None
         import jax.numpy as _jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         local = _jnp.asarray(local)
         sh = NamedSharding(mesh, P("r"))
         garr = jax.make_array_from_single_device_arrays(
@@ -215,8 +214,8 @@ class _MPBackend:
         fns = self.__dict__.setdefault("_dev_fns", {})
         fn = fns.get(key)
         if fn is None:
-            fn = jax.jit(shard_map(body, mesh=mesh,
-                                   in_specs=P("r"), out_specs=P("r")))
+            fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("r"),
+                                       out_specs=P("r")))
             fns[key] = fn
         out = fn(garr)
         return out.addressable_shards[0].data[0]

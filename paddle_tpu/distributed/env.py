@@ -69,10 +69,7 @@ def _maybe_init_multihost():
     nproc = int(os.environ.get(ENV_WORLD_SIZE, "1"))
     # NB: must not call jax.process_count() here — it would initialize the XLA
     # backend, after which jax.distributed.initialize refuses to run
-    is_init = getattr(jax.distributed, "is_initialized", None)
-    already = (is_init() if is_init is not None
-               else jax._src.distributed.global_state.client is not None)
-    if master and nproc > 1 and not already:
+    if master and nproc > 1 and not jax.distributed.is_initialized():
         rank = int(os.environ.get(ENV_RANK, "0"))
         jax.distributed.initialize(coordinator_address=master,
                                    num_processes=nproc, process_id=rank)

@@ -28,8 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ._shard_compat import pvary, shard_map
-
 __all__ = ["pipeline_apply", "CompiledPipeline"]
 
 
@@ -42,8 +40,8 @@ def _ring_body(w_local, xs, stage_fn, S: int, M: int, V: int, axis: str):
     outputs = jnp.zeros((M,) + xs.shape[1:], xs.dtype)
     # the carry holds per-DEVICE state (each stage's inbox differs), so mark it
     # varying over the pipe axis for the typed shard_map carry check
-    buf = pvary(buf, (axis,))
-    outputs = pvary(outputs, (axis,))
+    buf = jax.lax.pcast(buf, (axis,), to="varying")
+    outputs = jax.lax.pcast(outputs, (axis,), to="varying")
 
     def tick(carry, t):
         buf, outputs = carry
@@ -103,7 +101,7 @@ def pipeline_apply(stage_params: Any, xs: jnp.ndarray,
     w = jax.tree_util.tree_map(split_vs, stage_params)
     w_specs = jax.tree_util.tree_map(
         lambda l: P(axis, *([None] * (l.ndim - 1))), w)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_ring_body, stage_fn=stage_fn, S=S, M=M, V=V, axis=axis),
         mesh=mesh, in_specs=(w_specs, P(*([None] * xs.ndim))), out_specs=P())
     return fn(w, xs)

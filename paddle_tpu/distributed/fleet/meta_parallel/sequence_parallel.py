@@ -29,7 +29,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ...env import get_mesh
-from ._shard_compat import pvary, shard_map
 
 __all__ = ["ring_attention", "shard_sequence", "gather_sequence"]
 
@@ -76,8 +75,10 @@ def _ring_attn_local(q, k, v, sm_scale: float, S: int, axis: str,
     # the carry varies over every axis the inputs are split on (sep + any
     # batch/head shardings that pass through), per typed-shard_map rules
     vary_all = tuple(dict.fromkeys((axis,) + tuple(vary)))
-    acc0 = pvary(jnp.zeros((B, H, L, D), jnp.float32), vary_all)
-    lse0 = pvary(jnp.full((B, H, L), -jnp.inf, jnp.float32), vary_all)
+    acc0 = jax.lax.pcast(jnp.zeros((B, H, L, D), jnp.float32), vary_all,
+                         to="varying")
+    lse0 = jax.lax.pcast(jnp.full((B, H, L), -jnp.inf, jnp.float32),
+                         vary_all, to="varying")
     (k_f, v_f, acc, lse), _ = jax.lax.scan(
         step, (k, v, acc0, lse0), jnp.arange(S))
     out = jnp.swapaxes(acc, 1, 2)                    # [B,L,H,D]
@@ -111,9 +112,9 @@ def ring_attention(q, k, v, mesh: Optional[Mesh] = None, axis: str = "sep",
     vary = tuple({a for sp in (sq, sk, sv) for dim in tuple(sp)
                   for a in ((dim,) if isinstance(dim, str) else (dim or ()))
                   if a != axis})
-    fn = shard_map(partial(_ring_attn_local, sm_scale=sm_scale, S=S, axis=axis,
-                           vary=vary),
-                   mesh=mesh, in_specs=(sq, sk, sv), out_specs=sq)
+    fn = jax.shard_map(partial(_ring_attn_local, sm_scale=sm_scale, S=S,
+                               axis=axis, vary=vary),
+                       mesh=mesh, in_specs=(sq, sk, sv), out_specs=sq)
     return fn(q, k, v)
 
 

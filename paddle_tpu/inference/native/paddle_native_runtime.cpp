@@ -10,7 +10,7 @@
 //
 // Exposes the same PD_* C ABI subset as paddle_inference_c.cpp, so the same
 // pure-C consumer program runs against either library; the CPython-embedding
-// library remains the fallback for pass pipelines / TPU tunneling.
+// library remains the fallback for pass pipelines and TPU execution.
 //
 // Artifact format (jit/api.py _save_native_artifact):
 //   PDNATIVE1
@@ -277,14 +277,12 @@ bool Model::run() {
     args.push_back(it->second.get());
   }
   xla::ExecuteOptions opts;
-  // ExecuteSharded on the explicit device, fill_future=false: the plain
-  // Execute path walks the compile-time device assignment (not set by our
-  // default CompileOptions) and crashed inside the CPU client
-  std::optional<xla::PjRtFuture<>> future;
+  // ExecuteSharded on the explicit device, the overload that fills no
+  // future: the plain Execute path walks the compile-time device assignment
+  // (not set by our default CompileOptions) and crashed inside the CPU client
   auto r = exe->ExecuteSharded(
       absl::Span<xla::PjRtBuffer* const>(args),
-      client()->addressable_devices()[0], opts, future,
-      /*fill_future=*/false);
+      client()->addressable_devices()[0], opts);
   if (!r.ok()) {
     std::fprintf(stderr, "paddle_native: execute failed: %s\n",
                  std::string(r.status().message()).c_str());

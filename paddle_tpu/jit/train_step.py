@@ -471,8 +471,18 @@ class TrainStep:
             return x if sh is None else \
                 jax.lax.with_sharding_constraint(x, sh)
 
+        # the mesh this program is laid over, read off its own arrays (ZeRO/
+        # TP-placed params, a DeviceLoader-sharded batch) — never assumed
+        # from a global mesh some earlier job may have left behind
+        mesh = next((a.sharding.mesh
+                     for a in [p.value() for p in params]
+                     + list(example_inputs)
+                     if isinstance(getattr(a, "sharding", None),
+                                   NamedSharding)
+                     and a.sharding.mesh.size > 1), None)
+
         def run_model(param_arrays, buffer_arrays, input_arrays):
-            ctx = dispatch.TraceContext()
+            ctx = dispatch.TraceContext(mesh=mesh)
             saved_p = [p._data for p in params]
             saved_b = [b._data for b in buffers]
             dispatch.push_trace(ctx)

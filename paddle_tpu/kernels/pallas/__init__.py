@@ -1,2 +1,1 @@
-from .util import _install_compiler_params_alias  # noqa: F401 (side effect)
 from . import flash_attention  # noqa: F401

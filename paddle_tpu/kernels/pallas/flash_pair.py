@@ -2,8 +2,8 @@
 
 Why this exists: at head_dim 64 (GPT-medium, BERT-base, most 64-dim-head
 models) the flat [B*H, L, D] kernels read half-empty 128-lane tiles AND the
-[B,L,H,D] <-> [B*H,L,D] relayout around them costs ~4 ms/layer of pure HBM
-transposes at BERT-base shapes (measured, BASELINE.md r4). This path instead
+[B,L,H,D] <-> [B*H,L,D] relayout around them is pure HBM transposes (their
+cost on the chip: not measured on the current stack). This path instead
 reads 128-wide column blocks straight out of the fused projection output
 [B, L, 3*H*D] — TWO adjacent 64-wide heads per block — and writes the
 context back pre-packed [B, L, H*D]. Zero layout copies, full lanes.

@@ -16,16 +16,3 @@ def tpu_placement(x) -> bool:
         except Exception:
             pass
     return jax.default_backend() == "tpu"
-
-
-def _install_compiler_params_alias():
-    """jax renamed pltpu.TPUCompilerParams -> pltpu.CompilerParams; the
-    kernels are written against the current name. On 0.4.x, alias it so the
-    same kernel source drives both."""
-    from jax.experimental.pallas import tpu as pltpu
-    if not hasattr(pltpu, "CompilerParams") and \
-            hasattr(pltpu, "TPUCompilerParams"):
-        pltpu.CompilerParams = pltpu.TPUCompilerParams
-
-
-_install_compiler_params_alias()

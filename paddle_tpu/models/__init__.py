@@ -1,4 +1,4 @@
-"""Flagship model families for the benchmark configs (BASELINE.md).
+"""Flagship model families for the benchmark configs (BASELINE.json).
 
 The reference ships transformers in python/paddle/nn/layer/transformer.py and fused
 variants in incubate; full LM architectures (GPT/BERT/ERNIE) live in PaddleNLP built on

@@ -1,4 +1,4 @@
-"""BERT encoder LM — the to_static benchmark config (BASELINE.md config 2).
+"""BERT encoder LM — the to_static benchmark config (BASELINE.json config 2).
 
 Post-LN transformer encoder per the original BERT recipe, with MLM + NSP pretraining
 heads. Built on paddle_tpu.nn (reference surface: nn.TransformerEncoder,

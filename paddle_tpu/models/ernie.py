@@ -1,4 +1,4 @@
-"""ERNIE encoder family (BASELINE.md config 5 names ERNIE-3.0).
+"""ERNIE encoder family (BASELINE.json config 5 names ERNIE-3.0).
 
 ERNIE's architecture is the BERT post-LN encoder plus a task-type embedding
 stream (multi-task pretraining); its signature knowledge-masking lives in the

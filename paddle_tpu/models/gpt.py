@@ -1,4 +1,4 @@
-"""GPT decoder-only LM — the flagship / north-star model (BASELINE.md config 4).
+"""GPT decoder-only LM — the flagship / north-star model (BASELINE.json config 4).
 
 Architecture follows the GPT-3 recipe (pre-LN transformer decoder, learned position
 embeddings, GELU MLP with 4x width, tied LM head). Built on paddle_tpu.nn layers so the
@@ -75,7 +75,9 @@ def _gpt_scan_blocks_fwd(x, l1w, l1b, qw, qb, pw, pb, l2w, l2b, f1w, f1b, f2w,
     b, s, h = x.shape
     hd = h // num_heads
     n_layers = l1w.shape[0]
-    keys = rest[0] if rest else jnp.zeros((n_layers, 2), jnp.uint32)
+    # no dropout -> no keys: a one-word placeholder whose [0] seeds the
+    # rate-0 kernels (real keys are as wide as the PRNG impl makes them)
+    keys = rest[0] if rest else jnp.zeros((n_layers, 1), jnp.uint32)
 
     def ln(z, w, bias):
         zf = z.astype(jnp.float32)
@@ -97,23 +99,15 @@ def _gpt_scan_blocks_fwd(x, l1w, l1b, qw, qb, pw, pb, l2w, l2b, f1w, f1b, f2w,
         y = ln(carry, l1w_, l1b_)
         qkv = tag_array(y @ qw_ + qb_, ATTN_QKV)     # [B,S,3H]
         from ..kernels.pallas.flash_attention import (
-            flash_attention_blhd, flash_attention_qkv_packed,
-            packed_layout_supported)
-        from ..kernels.pallas.flash_pair import (flash_pair_packed,
-                                                 pair_layout_supported)
-        if use_flash and pair_layout_supported(hd, num_heads, s):
-            # single-tile head-block kernels: zero relayouts + fused
-            # single-pass dqkv backward (kernels/pallas/flash_pair.py)
-            att = tag_array(flash_pair_packed(qkv, num_heads, True,
-                                              dropout_rate=attn_dropout,
-                                              seed=kd[0].astype(jnp.int32)),
-                            ATTN_CONTEXT)
-        elif use_flash and packed_layout_supported(hd):
-            # fused-projection kernel for longer sequences: no head
+            flash_attention_blhd, packed_layout_supported)
+        from ..kernels.pallas.flash_pair import pair_layout_supported
+        from ..nn.functional.attention import packed_flash
+        if use_flash and (pair_layout_supported(hd, num_heads, s)
+                          or packed_layout_supported(hd)):
+            # packed kernels straight off the fused projection: no head
             # split/merge inside the scan
-            att = tag_array(flash_attention_qkv_packed(
-                qkv, num_heads, causal=True, dropout_rate=attn_dropout,
-                seed=kd[0].astype(jnp.int32)), ATTN_CONTEXT)
+            att = tag_array(packed_flash(qkv, num_heads, True, attn_dropout,
+                                         kd[0]), ATTN_CONTEXT)
         elif use_flash:
             q, k, v = (t.reshape(b, s, num_heads, hd)
                        for t in jnp.split(qkv, 3, axis=-1))
@@ -784,9 +778,9 @@ def _generate_with_cache(lm, backbone, num_layers: int, n_kv_heads: int,
         else jnp.asarray(input_ids)
     b, s0 = ids_arr.shape
     # cache buffers sized to the DECODE, not the model's position table:
-    # every step streams the whole [B, M, nh, hd] K/V pair per layer, and at
-    # GPT-medium M=1024 that 0.54 GB/step read was 2.6 of the 4.9 ms step
-    # (BASELINE.md round-4 decode table) — tight M more than doubled tok/s
+    # every step streams the whole [B, M, nh, hd] K/V pair per layer — at
+    # GPT-medium M=1024 that is 0.54 GB read per step whatever the decode
+    # length (its share of the step on the chip: not measured on this stack)
     m, seed = _resolve_decode_horizon(s0, max_new_tokens, max_length,
                                       max_pos, seed, do_sample)
     if max_new_tokens == 0:

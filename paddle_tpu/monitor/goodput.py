@@ -90,8 +90,7 @@ _FOLD_AT = 512
 def executable_cost_stats(compiled) -> Optional[dict]:
     """``{"flops", "bytes"}`` from one compiled executable's
     ``cost_analysis()`` (None when the backend does not expose it, or the
-    analysis carries no flop count). Tolerates both the list-of-dicts
-    (jax 0.4.x) and plain-dict shapes."""
+    analysis carries no flop count)."""
     analyze = getattr(compiled, "cost_analysis", None)
     if analyze is None:
         return None
@@ -99,8 +98,6 @@ def executable_cost_stats(compiled) -> Optional[dict]:
         ca = analyze()
     except Exception:
         return None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
     if not isinstance(ca, dict):
         return None
     flops = ca.get("flops")

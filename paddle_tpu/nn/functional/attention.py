@@ -11,9 +11,10 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ...core import random as rng
-from ...core.dispatch import register_op
+from ...core.dispatch import program_mesh, register_op
 from ...core.remat import ATTN_CONTEXT, tag_array
 from ...core.tensor import Tensor
 from ...ops._helpers import _op
@@ -77,22 +78,58 @@ def _flash_attn_pallas_fwd(q, k, v, *rest, causal=False, dropout_rate=0.0):
 register_op("flash_attn_pallas", _flash_attn_pallas_fwd, nondiff_inputs=(3,))
 
 
-def _flash_attn_packed_fwd(qkv, *rest, num_heads, causal=True,
-                           dropout_rate=0.0):
+def packed_flash(qkv, num_heads, causal, dropout_rate, seed):
+    """The packed-qkv flash kernel for this head geometry, [B, L, 3*H*D] ->
+    [B, L, H*D], callable from inside a multi-device program.
+
+    The SPMD partitioner cannot split a Mosaic kernel ("Mosaic kernels
+    cannot be automatically partitioned. Please wrap the call in a
+    shard_map" — what the first ZeRO train step on four real chips died
+    of). So when the tracer says the program is laid over a mesh
+    (``dispatch.program_mesh()``), the call is mapped over it: the batch
+    splits over the data-like axes that divide it (``io.batch_sharding``'s
+    convention), everything else is replicated, and each shard runs the
+    kernel unchanged."""
     from ...kernels.pallas.flash_attention import flash_attention_qkv_packed
     from ...kernels.pallas.flash_pair import (flash_pair_packed,
                                               pair_layout_supported)
-    seed = rest[0] if rest else 0
     d = qkv.shape[-1] // (3 * num_heads)
     if pair_layout_supported(d, num_heads, qkv.shape[1]):
         # single-tile fast path (head-blocks fill the 128-lane quantum;
         # fused single-pass dqkv backward) — kernels/pallas/flash_pair.py
-        return tag_array(flash_pair_packed(qkv, num_heads, causal,
-                                           dropout_rate=dropout_rate,
-                                           seed=seed), ATTN_CONTEXT)
-    return tag_array(flash_attention_qkv_packed(qkv, num_heads, causal=causal,
-                                                dropout_rate=dropout_rate,
-                                                seed=seed), ATTN_CONTEXT)
+        def kernel(x, s):
+            return flash_pair_packed(x, num_heads, causal,
+                                     dropout_rate=dropout_rate, seed=s)
+    else:
+        def kernel(x, s):
+            return flash_attention_qkv_packed(x, num_heads, causal=causal,
+                                              dropout_rate=dropout_rate,
+                                              seed=s)
+    seed = jnp.asarray(seed, jnp.int32)
+    mesh = program_mesh()
+    if mesh is None:
+        return kernel(qkv, seed)
+    axes = tuple(a for a in ("data", "sharding") if mesh.shape.get(a, 1) > 1)
+    if not axes or qkv.shape[0] % math.prod(mesh.shape[a] for a in axes):
+        batch = P()      # nothing divides the batch: every device runs it all
+    elif dropout_rate > 0.0:
+        # the keep mask is seeded per (LOCAL batch row, head, tile): every
+        # shard of a split batch would draw the same masks
+        raise NotImplementedError(
+            "in-kernel attention dropout is not supported when the batch is "
+            "sharded over devices (the hardware-PRNG mask is seeded by the "
+            "local batch index); use attention_dropout_prob=0.0 on a "
+            "multi-chip mesh")
+    else:
+        batch = P(axes)
+    return jax.shard_map(kernel, mesh=mesh, in_specs=(batch, P()),
+                         out_specs=batch, check_vma=False)(qkv, seed)
+
+
+def _flash_attn_packed_fwd(qkv, *rest, num_heads, causal=True,
+                           dropout_rate=0.0):
+    return tag_array(packed_flash(qkv, num_heads, causal, dropout_rate,
+                                  rest[0] if rest else 0), ATTN_CONTEXT)
 
 
 register_op("flash_attn_qkv_packed", _flash_attn_packed_fwd,

@@ -9,13 +9,9 @@ import os
 import sys
 
 os.environ.pop("XLA_FLAGS", None)
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
-
-jax.config.update("jax_platforms", "cpu")
-jax.config.update("jax_compilation_cache_dir",
-                  os.environ.get("PADDLE_TEST_CACHE", "/tmp/paddle_tpu_test_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 1)
 
 import numpy as np
 import paddle_tpu as paddle

@@ -1,22 +1,15 @@
 """Pallas hardware-PRNG dropout (kernels/pallas/dropout.py) — TPU-only
 (the hardware PRNG has no interpret lowering; CPU runs keep the XLA path).
+On the chip: ``chiprun -- tools/run_tpu_tests.sh`` from the repo root.
 """
 import numpy as np
-import jax
 import pytest
 
 import paddle_tpu as paddle
 import paddle_tpu.nn.functional as F
 
 
-def _on_tpu():
-    return jax.default_backend() == "tpu"
-
-
-tpu_only = pytest.mark.skipif(not _on_tpu(), reason="pallas dropout needs TPU")
-
-
-@tpu_only
+@pytest.mark.tpu
 def test_dropout_tpu_statistics_and_determinism():
     from paddle_tpu.kernels.pallas.dropout import dropout_tpu
     import jax.numpy as jnp
@@ -32,7 +25,7 @@ def test_dropout_tpu_statistics_and_determinism():
     np.testing.assert_allclose(vals[vals != 0], 1.0 / 0.7, rtol=1e-5)
 
 
-@tpu_only
+@pytest.mark.tpu
 def test_dropout_functional_backward_mask_consistent():
     x = paddle.ones([256, 128], "float32")
     x.stop_gradient = False
@@ -44,7 +37,7 @@ def test_dropout_functional_backward_mask_consistent():
                                np.asarray(y.numpy()), rtol=1e-6)
 
 
-@tpu_only
+@pytest.mark.tpu
 def test_dropout_eval_identity():
     x = paddle.ones([128, 128], "float32")
     y = F.dropout(x, p=0.4, training=False)

@@ -168,7 +168,7 @@ def test_ledger_flop_accounting_recompute_split():
     token fraction scales model FLOPs only (serving dead slots)."""
     class FakeExe:
         def cost_analysis(self):
-            return [{"flops": 1000.0, "bytes accessed": 64.0}]
+            return {"flops": 1000.0, "bytes accessed": 64.0}
 
     reg = Registry()
     led = GoodputLedger(reg, peak=1e6)
@@ -210,7 +210,7 @@ def test_serve_flops_per_token_is_decode_only(tmp_path):
             self._f = flops
 
         def cost_analysis(self):
-            return [{"flops": self._f, "bytes accessed": 0.0}]
+            return {"flops": self._f, "bytes accessed": 0.0}
 
     monitor.enable(str(tmp_path / "run.jsonl"))
     mon = monitor.get()
@@ -223,9 +223,9 @@ def test_serve_flops_per_token_is_decode_only(tmp_path):
 
 
 def test_executable_cost_stats_shapes():
-    class ListShape:
+    class Full:
         def cost_analysis(self):
-            return [{"flops": 5.0, "bytes accessed": 7.0}]
+            return {"flops": 5.0, "bytes accessed": 7.0}
 
     class DictShape:
         def cost_analysis(self):
@@ -235,7 +235,7 @@ def test_executable_cost_stats_shapes():
         def cost_analysis(self):
             raise RuntimeError("no analysis")
 
-    assert executable_cost_stats(ListShape()) == {"flops": 5.0, "bytes": 7.0}
+    assert executable_cost_stats(Full()) == {"flops": 5.0, "bytes": 7.0}
     assert executable_cost_stats(DictShape()) == {"flops": 5.0, "bytes": 0.0}
     assert executable_cost_stats(Broken()) is None
     assert executable_cost_stats(object()) is None

@@ -404,12 +404,19 @@ def test_device_loader_stacking_rejects_unshiftable_sharding():
     """Sharding types whose axis semantics can't shift past the stacking
     axis fail loudly instead of silently sharding the K axis."""
     import jax
-    from jax.sharding import PositionalSharding
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    # jax 0.9 dropped PositionalSharding; what is left of the position-only
+    # family is GSPMDSharding (devices + an HLO tile assignment, no axis
+    # names) — the form a NamedSharding degrades to, and just as unshiftable
+    from jax._src.sharding_impls import GSPMDSharding
 
+    named = NamedSharding(Mesh(np.array(jax.devices()[:8]), ("data",)),
+                          P("data", None))
+    positional = GSPMDSharding(named._device_assignment,
+                               named._to_xla_hlo_sharding(2))
     rng = np.random.RandomState(0)
     batches = [(rng.randn(8, 4).astype("float32"),) for _ in range(4)]
-    dl = DeviceLoader(batches, stack_batches=2,
-                      sharding=PositionalSharding(jax.devices()).reshape(8, 1))
+    dl = DeviceLoader(batches, stack_batches=2, sharding=positional)
     with pytest.raises(ValueError, match="NamedSharding"):
         list(dl)
 

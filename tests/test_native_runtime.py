@@ -99,6 +99,13 @@ def native_lib():
     return build_native_library()
 
 
+# the three tests that need the library pay a ~2 min C++ build against
+# TensorFlow's XLA headers in a fresh checkout: slow lane (ROADMAP queue 3
+# item 12), run with ``-m slow tests/test_native_runtime.py``
+_needs_native_build = pytest.mark.slow
+
+
+@_needs_native_build
 def test_native_lib_links_no_python(native_lib):
     out = subprocess.run(["ldd", native_lib], capture_output=True, text=True)
     assert "libpython" not in out.stdout, out.stdout
@@ -152,6 +159,7 @@ def test_dynamic_batch_save_with_fused_epilogue(tmp_path):
 # from a pure C program below (output shape/dtype accessors included).
 
 
+@_needs_native_build
 def test_native_runtime_from_pure_c_program(saved_fixed_model, native_lib,
                                             tmp_path):
     """The whole story: a C program with NO Python linkage, against a library
@@ -175,6 +183,7 @@ def test_native_runtime_from_pure_c_program(saved_fixed_model, native_lib,
     np.testing.assert_allclose(got, ref, rtol=1e-4, atol=1e-5)
 
 
+@_needs_native_build
 def test_native_runtime_rejects_corrupt_header_cleanly(saved_fixed_model,
                                                        native_lib, tmp_path):
     """A corrupt .pdnative header (absurd ndim / negative dims / truncation)

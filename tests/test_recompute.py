@@ -180,8 +180,18 @@ def _init_sharding_mesh(degree=8):
 @pytest.mark.parametrize("k", [1, 2])
 def test_recompute_x_accum_x_zero_parity(k):
     """Remat inside the accumulation scan body must not perturb the ZeRO
-    machinery: loss/weights bitwise vs the no-remat sharded path, compile
-    count still 1/bucket, fp32 accumulators still shard-sized."""
+    machinery: same trajectory as the no-remat sharded path, compile count
+    still 1/bucket, fp32 accumulators still shard-sized.
+
+    "Same trajectory" is a tolerance, not bitwise: XLA:CPU in jaxlib 0.9.0
+    fuses the REPLAYED forward into the backward differently from the
+    saved-residual backward (other FMA contraction), so grads agree to the
+    last ulp only, and Adam's g/sqrt(v) turns a 1-ulp difference on a
+    near-zero grad element into a visible fraction of one lr step. Measured
+    on 0.9.0 after 2 steps at lr=1e-3: losses still identical, weights off
+    by <= 2.6e-8 (k=1) and <= 1.4e-5 (k=2). The gate is 5% of ONE lr step —
+    a dropped or doubled microbatch, or a remat region that changed the
+    math, moves weights by whole lr steps."""
     _init_sharding_mesh()
     out = {}
     for gran in ("none", "selective"):
@@ -201,10 +211,11 @@ def test_recompute_x_accum_x_zero_parity(k):
         if k > 1 and step._accum_plan is not None:
             ideal = step._accum_plan.ideal_bytes()
             assert step._accum_plan.accum_bytes() <= 1.15 * ideal
-    assert out["none"][0] == out["selective"][0]
+    np.testing.assert_allclose(out["none"][0], out["selective"][0],
+                               rtol=1e-6)
     for n in out["none"][1]:
-        np.testing.assert_array_equal(out["none"][1][n],
-                                      out["selective"][1][n], err_msg=n)
+        np.testing.assert_allclose(out["none"][1][n], out["selective"][1][n],
+                                   rtol=0, atol=0.05 * 1e-3, err_msg=n)
 
 
 # ----------------------------------------------------------------- wiring

@@ -445,7 +445,7 @@ def test_spec_goodput_counts_accepted_tokens_only(tmp_path):
     ACCEPTED token, so rejected drafts can never inflate utilization."""
     class FakeExe:
         def cost_analysis(self):
-            return [{"flops": 1000.0, "bytes accessed": 0.0}]
+            return {"flops": 1000.0, "bytes accessed": 0.0}
 
     monitor.enable(str(tmp_path / "run.jsonl"))
     try:

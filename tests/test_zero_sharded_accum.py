@@ -22,6 +22,8 @@ On the virtual 8-device CPU mesh:
   ``DeviceLoader(stack_batches=K)`` must not let the stacking axis absorb
   the batch-sharding axis.
 """
+import re
+
 import jax
 import numpy as np
 import pytest
@@ -115,40 +117,57 @@ def test_zero_accum_parity_with_unsharded(k):
 # ------------------------------------------------------- shard-sized memory
 
 
-def test_accumulator_shard_sized_measured():
-    """THE acceptance gate: with stage-2 + accumulate_steps=4 the measured
-    fp32 accumulator residency (temp-bytes delta of the accumulated
-    executable over the K=1 one) is <= 1.15x the 1/world_size ideal, while
-    the unsharded path pays the full-size accumulator."""
-    from paddle_tpu.monitor.memory import executable_memory_stats
+def _scan_accumulated_f32_bytes(compiled) -> int:
+    """Per-device bytes of the fp32 arrays the compiled scan UPDATES across
+    microbatches, read from the post-partitioning HLO: the while body's ROOT
+    tuple hands loop invariants (params, the input stack) through as
+    get-tuple-element and produces what it accumulates with an op."""
+    txt = compiled.as_text()
+    body = re.search(r" while\(.*?body=(%[\w.\-]+)", txt).group(1)
+    block = txt[txt.index("\n" + body + " ("):]
+    root = next(l for l in block.splitlines()
+                if l.lstrip().startswith("ROOT"))
+    types, operands = root.split(") tuple(")
+    shapes = re.findall(r"(\w+)\[([\d,]*)\]", types)
+    operands = re.sub(r"/\*.*?\*/", "", operands).rstrip(")").split(",")
+    assert len(shapes) == len(operands), root
+    return sum(4 * int(np.prod([int(d) for d in dims.split(",") if d]))
+               for (dt, dims), op in zip(shapes, operands)
+               if dt == "f32" and "get-tuple-element" not in op)
 
+
+def test_accumulator_shard_sized_measured():
+    """THE acceptance gate: with stage-2 + accumulate_steps=4 the fp32
+    accumulators XLA actually carries through the scan are <= 1.15x the
+    1/world_size ideal per device, while the unsharded path carries them
+    full-size.
+
+    Measured from the scan's carried state in the compiled executable, not
+    from ``memory_analysis()`` temp-bytes deltas (K=4 minus K=1) as before:
+    XLA:CPU in jaxlib 0.9.0 reuses the K=1 gradient temporaries for the K>1
+    microbatch gradients, so that delta is no longer the accumulator — it
+    reads 77,332 B for a 132,352 B full-size accumulator, and 49,052 B where
+    the carried accumulators are exactly the 16,544 B ideal."""
     _init_sharding_mesh()
     DIN, HID, K = 64, 256, 4
 
-    def run(level, acc):
+    def run(level):
         m, m2, opt2 = _make(level, din=DIN, hid=HID)
-        step = paddle.jit.TrainStep(m2, opt2, accumulate_steps=acc)
-        step(_inputs(acc, din=DIN))
-        stats = executable_memory_stats(next(iter(step._fast.values())))
-        return step, stats
+        step = paddle.jit.TrainStep(m2, opt2, accumulate_steps=K)
+        step(_inputs(K, din=DIN))
+        return step, _scan_accumulated_f32_bytes(
+            next(iter(step._fast.values())))
 
-    step1, base_s = run("os_g", 1)
-    if base_s is None:
-        pytest.skip("backend exposes no memory_analysis()")
-    stepK, accK_s = run("os_g", K)
-    _, base_u = run(None, 1)
-    _, accK_u = run(None, K)
+    stepK, carried_sharded = run("os_g")
+    _, carried_unsharded = run(None)
 
     full = stepK._full_grad_bytes()
     ideal = -(-full // 8)  # ceil: per-param sharding rounds up
-    delta_sharded = accK_s["temp_bytes"] - base_s["temp_bytes"]
-    delta_unsharded = accK_u["temp_bytes"] - base_u["temp_bytes"]
-
     # the unsharded accumulator really is full-size (sanity: the comparison
     # below means something)
-    assert delta_unsharded >= 0.9 * full, (delta_unsharded, full)
+    assert carried_unsharded >= 0.9 * full, (carried_unsharded, full)
     # ...and the sharded one is genuinely 1/world-sized
-    assert delta_sharded <= 1.15 * ideal, (delta_sharded, ideal, full)
+    assert carried_sharded <= 1.15 * ideal, (carried_sharded, ideal, full)
     # analytic accounting agrees with the plan
     assert stepK._grad_acc_bytes() == ideal
 
