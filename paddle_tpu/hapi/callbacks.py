@@ -275,15 +275,13 @@ class AutoCheckpoint(Callback):
             # background write itself reports separately as hidden ckpt
             # time through ckpt_saved(mode="async")
             mon.ckpt_blocked(t0, time.perf_counter())
-        tracer = _trace._active
-        if tracer is not None:
-            # host time the fit loop spent inside save() (the async host
-            # snapshot, or the whole write when block=True) — lands as a
-            # floating span on the next step's trace, where a periodic
-            # save explains a step-time spike
-            tracer.floating("ckpt/save", t0, time.perf_counter(),
-                            step=self._global_step, block=bool(block),
-                            mode=mode or ("sync" if block else "async"))
+        # host time the fit loop spent inside save() (the async host
+        # snapshot, or the whole write when block=True) — adopted by the
+        # next step's trace, where a periodic save explains a step-time
+        # spike
+        _trace.record("ckpt/save", t0, time.perf_counter(), "step",
+                      step=self._global_step, block=bool(block),
+                      mode=mode or ("sync" if block else "async"))
         self._last_saved = self._global_step
         self._t_last = time.monotonic()
 

@@ -15,17 +15,17 @@ and the loader materializes correctly-placed global arrays off the critical
 path, exactly the placement ``jit``/``TrainStep`` would otherwise have to
 force at dispatch time.
 
-Profiler attribution: when a ``paddle.profiler.Profiler`` is recording, the
-loader emits ``stage`` events — ``device_loader/wait`` (consumer stall: feed
-time that was NOT hidden), ``device_loader/fetch`` and ``device_loader/h2d``
-(producer-side work that WAS hidden) — so host-feed vs device-compute overlap
-is directly observable in the summary/Chrome trace.
+Attribution: the loader makes one ``monitor.trace`` span call per phase —
+``loader/wait`` (consumer stall: feed time that was NOT hidden),
+``loader/fetch`` and ``loader/h2d`` (producer-side work that WAS hidden).
+They lie in any profile being taken (``paddle/loader/*``), in the span ring,
+and as ``stage`` events in a recording ``paddle.profiler.Profiler``, so
+host-feed vs device-compute overlap is directly observable.
 """
 from __future__ import annotations
 
 import queue
 import threading
-import time
 import weakref
 from typing import Callable, Optional, Union
 
@@ -112,13 +112,6 @@ def batch_sharding(mesh, axis_name=None):
     return leaf_sharding
 
 
-def _emit_stage(name: str, start: float, end: float):
-    # lazy import: profiler is optional on this path and must cost nothing
-    # when not recording
-    from ..profiler import record_stage
-    record_stage(name, start, end)
-
-
 _END = object()
 
 
@@ -129,23 +122,16 @@ def _produce(inner, put_fn, q, stop, state):
     only holds the pieces it needs; the iterator stays collectable."""
     try:
         while not stop.is_set():
-            t0 = time.perf_counter()
-            try:
-                batch = next(inner)
-            except StopIteration:
-                break
-            t1 = time.perf_counter()
-            on_device = put_fn(batch)
-            t2 = time.perf_counter()
-            _emit_stage("device_loader/fetch", t0, t1)
-            _emit_stage("device_loader/h2d", t1, t2)
-            tracer = _trace._active
-            if tracer is not None:
-                # producer-side work, recorded as floating spans the NEXT
-                # step trace adopts: the waterfall shows fetch/H2D that ran
-                # (hidden or not) ahead of that step's dispatch
-                tracer.floating("loader/fetch", t0, t1)
-                tracer.floating("loader/h2d", t1, t2)
+            # producer-side work, as spans the NEXT step trace adopts: the
+            # waterfall shows fetch/H2D that ran (hidden or not) ahead of
+            # that step's dispatch
+            with _trace.span("loader/fetch", "step"):
+                try:
+                    batch = next(inner)
+                except StopIteration:
+                    break
+            with _trace.span("loader/h2d", "step"):
+                on_device = put_fn(batch)
             # bounded put that notices abandonment (same pattern as
             # DataLoader._PrefetchIterator): a consumer that stopped
             # iterating must not leave this thread blocked forever
@@ -203,10 +189,12 @@ class _DeviceIterator:
     def __next__(self):
         if self._done:
             raise StopIteration
-        t0 = time.perf_counter()
-        item = self._q.get()
-        t1 = time.perf_counter()
-        _emit_stage("device_loader/wait", t0, t1)
+        # consumer stall ahead of the next step: adopted by that step's
+        # trace, so "slow step" splits into waited-on-feed vs dispatch
+        with _trace.span("loader/wait", "step") as wait:
+            item = self._q.get()
+            qsize = self._q.qsize()
+            wait.set(qsize=qsize)
         if item is _END:
             self._done = True
             err = self._state["err"]
@@ -219,12 +207,7 @@ class _DeviceIterator:
             # feed-health telemetry: queue depth gauge + stall counter (a
             # blocking get means the producer lost the race this step; the
             # terminal END wait above is epoch teardown, not a stall)
-            mon.loader_wait(t1 - t0, self._q.qsize(), span=(t0, t1))
-        tracer = _trace._active
-        if tracer is not None:
-            # consumer stall ahead of the next step: adopted by that step's
-            # trace, so "slow step" splits into waited-on-feed vs dispatch
-            tracer.floating("loader/wait", t0, t1, qsize=self._q.qsize())
+            mon.loader_wait(wait.dur_s, qsize, span=(wait.t0, wait.t1))
         return item
 
     def close(self):

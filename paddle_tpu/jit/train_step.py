@@ -33,7 +33,6 @@ from __future__ import annotations
 
 import itertools
 import math
-import time
 from typing import Callable, List, Optional, Sequence
 
 import jax
@@ -48,7 +47,6 @@ from ..core import random as _random
 from ..core import remat as _remat
 from ..core.tensor import Parameter, Tensor
 from ..nn.layer import Layer
-from ..profiler import _recorder as _prof_recorder, record_stage
 
 __all__ = ["TrainStep"]
 
@@ -330,9 +328,11 @@ class TrainStep:
         self._buffers.append(_random.rng_state_tensor())
         self._compiled = None
         # fast path: AOT executables keyed by input signature + a reusable
-        # flat argument state (see _fast_call)
+        # flat argument state (see _fast_dispatch); _fast_bucket numbers
+        # the executables (by id) in the order they were minted
         self._fast_path = fast_path
         self._fast = {}
+        self._fast_bucket = {}
         self._fast_state = None
         self._fast_meta = None
         # recompile-sentinel state: the previous step's input signature, so a
@@ -343,8 +343,8 @@ class TrainStep:
         self._mon_prev_sig = None
         self._mon_sig_bucket = {}
         self._gp_id = next(TrainStep._ids)
-        # span-tracer state: the open per-step trace (monitor/trace.py) and
-        # a step counter for its attrs — None/0 while tracing is off
+        # span state: the trace of the call in flight (monitor/trace.py) and
+        # the step counter that is its trace id
         self._cur_trace = None
         self._trace_n = 0
         # health-plane state: the CompiledHealth spec captured at build time
@@ -659,9 +659,11 @@ class TrainStep:
                       for a, m, um, t in zip(param_arrays, masters, use_master,
                                              trainables) if t]
             diff_states = [s for s, t in zip(states, trainables) if t]
-            new_upd, new_states_diff = opt_cls._update_rule(
-                upd_in, [g.astype(u.dtype) for g, u in zip(grads, upd_in)],
-                diff_states, scalars, **static)
+            with jax.named_scope("optimizer"):
+                new_upd, new_states_diff = opt_cls._update_rule(
+                    upd_in,
+                    [g.astype(u.dtype) for g, u in zip(grads, upd_in)],
+                    diff_states, scalars, **static)
             if scaler_on:
                 # overflow anywhere in the window: the whole K-step update is
                 # discarded on device (params/state bit-identical), exactly
@@ -717,9 +719,11 @@ class TrainStep:
                       for a, m, um, t in zip(param_arrays, masters, use_master,
                                              trainables) if t]
             diff_states = [s for s, t in zip(states, trainables) if t]
-            new_upd, new_states_diff = opt_cls._update_rule(
-                upd_in, [g.astype(u.dtype) for g, u in zip(grads, upd_in)],
-                diff_states, scalars, **static)
+            with jax.named_scope("optimizer"):
+                new_upd, new_states_diff = opt_cls._update_rule(
+                    upd_in,
+                    [g.astype(u.dtype) for g, u in zip(grads, upd_in)],
+                    diff_states, scalars, **static)
             new_params, new_masters, new_states = repack(
                 param_arrays, masters, states, new_upd, new_states_diff)
             loss_out = loss
@@ -763,89 +767,85 @@ class TrainStep:
             # without a recompile) before this call dispatches
             mon.health.fault.maybe_fire(
                 list(zip(self._param_names, self._params)), emit=mon.emit)
-        tracer = _trace._active
-        t = None
-        if tracer is not None:
-            # one head-sampled trace per step; floating spans the loader
-            # recorded since the previous step (wait/fetch/H2D, checkpoint
-            # saves) are adopted as children, so the waterfall shows what
-            # the step waited on before it dispatched
-            self._trace_n += 1
-            t = tracer.start_trace("train_step", kind="step",
-                                   step=self._trace_n)
+        # one trace per call (trace id = step number; head-sampled where a
+        # sink is on); spans the loader recorded since the previous step
+        # (wait/fetch/H2D, checkpoint saves) are adopted as children, so
+        # the waterfall shows what the step waited on before it dispatched
+        self._trace_n += 1
+        with _trace.start_trace("train_step", self._trace_n, "step",
+                                step=self._trace_n) as t:
             self._cur_trace = t
-        try:
-            return self._call_impl(inputs)
-        except BaseException as e:
-            # flight-recorder post-mortem: dump the recent-event ring before
-            # the exception unwinds out of the training loop
-            if t is not None:
+            try:
+                return self._call_impl(inputs)
+            except BaseException as e:
+                # flight-recorder post-mortem: dump the recent-event ring
+                # before the exception unwinds out of the training loop
                 t.event("crash", exc=type(e).__name__)
                 t.escalate("crash")
-            _monitor.on_crash(e)
-            raise
-        finally:
-            if t is not None:
+                _monitor.on_crash(e)
+                raise
+            finally:
                 self._cur_trace = None
-                t.end()
 
     def _call_impl(self, inputs):
-        input_arrays = tuple(t.value() if isinstance(t, Tensor) else jnp.asarray(t)
-                             for t in inputs)
-        if self._acc_steps > 1:
-            # the scan takes K from the traced shape — an unstacked batch
-            # would silently run shape[0] SINGLE-SAMPLE microbatches (wrong
-            # batch semantics, K× the intended update count), so enforce the
-            # stacking contract loudly
-            for i, a in enumerate(input_arrays):
-                if getattr(a, "ndim", 0) == 0 \
-                        or a.shape[0] != self._acc_steps:
-                    raise ValueError(
-                        f"TrainStep(accumulate_steps={self._acc_steps}) "
-                        f"expects every input stacked with leading axis "
-                        f"{self._acc_steps} (K microbatches per call); "
-                        f"input[{i}] has shape "
-                        f"{tuple(getattr(a, 'shape', ()))} — stack with "
-                        f"io.stack_microbatches or "
-                        f"DeviceLoader(stack_batches={self._acc_steps})")
-        if self._fast_path:
-            return self._fast_call(input_arrays)
-        if self._compiled is None:
-            self._build(input_arrays)
         mon = _monitor._active
-        step_trace = self._cur_trace
-        # jit trace-cache size before the call: a growth across the call IS a
-        # recompile (the slow path compiles lazily inside __call__)
-        n0 = self._compiled._cache_size() if mon is not None else 0
-        param_arrays, masters, states, buffer_arrays, scalars = \
-            self._gather_args()
-
-        if mon is not None:
-            _remat.reset_trace_stats()  # a cache miss traces inside the call
-        t0 = time.perf_counter() if (mon is not None
-                                     or step_trace is not None) else 0.0
-        loss_out, new_params, new_masters, new_states, new_buffers = \
-            self._compiled(param_arrays, masters, states, buffer_arrays,
-                           scalars, input_arrays)
-        t1 = time.perf_counter() if t0 else 0.0
-        if step_trace is not None:
-            step_trace.record("dispatch", t0, t1, path="jit",
-                              microbatches=self._microbatches(input_arrays))
+        with _trace.span("train_step/prepare") as prep:
+            input_arrays = tuple(
+                t.value() if isinstance(t, Tensor) else jnp.asarray(t)
+                for t in inputs)
+            if self._acc_steps > 1:
+                # the scan takes K from the traced shape — an unstacked
+                # batch would silently run shape[0] SINGLE-SAMPLE
+                # microbatches (wrong batch semantics, K× the intended
+                # update count), so enforce the stacking contract loudly
+                for i, a in enumerate(input_arrays):
+                    if getattr(a, "ndim", 0) == 0 \
+                            or a.shape[0] != self._acc_steps:
+                        raise ValueError(
+                            f"TrainStep(accumulate_steps={self._acc_steps}) "
+                            f"expects every input stacked with leading axis "
+                            f"{self._acc_steps} (K microbatches per call); "
+                            f"input[{i}] has shape "
+                            f"{tuple(getattr(a, 'shape', ()))} — stack with "
+                            f"io.stack_microbatches or "
+                            f"DeviceLoader(stack_batches={self._acc_steps})")
+            if self._fast_path:
+                exe, scalars, sig = self._fast_prepare(input_arrays)
+            else:
+                if self._compiled is None:
+                    self._build(input_arrays)
+                # jit trace-cache size before the call: a growth across the
+                # call IS a recompile (the slow path compiles lazily inside
+                # __call__)
+                n0 = self._compiled._cache_size() if mon is not None else 0
+                param_arrays, masters, states, buffer_arrays, scalars = \
+                    self._gather_args()
+                if mon is not None:
+                    _remat.reset_trace_stats()  # a cache miss traces inside
+        if self._fast_path:
+            # the step's entry instant books the pre-dispatch host work
+            # (state refresh, scalars, arg handling) as goodput overhead
+            return self._fast_dispatch(exe, scalars, sig, input_arrays,
+                                       prep.t0)
+        with _trace.span("train_step/dispatch", path="jit",
+                         microbatches=self._microbatches(input_arrays)) as d:
+            loss_out, new_params, new_masters, new_states, new_buffers = \
+                self._compiled(param_arrays, masters, states, buffer_arrays,
+                               scalars, input_arrays)
 
         if mon is not None:
             sig = self._input_sig(input_arrays)
             n1 = self._compiled._cache_size()
             if n1 > n0:
-                if step_trace is not None:
-                    # the dispatch above WAS a compile; link the sentinel
-                    step_trace.event("recompile", count=n1, path="jit")
+                # the dispatch above WAS a compile; link the sentinel
+                self._cur_trace.event("recompile", count=n1, path="jit")
                 # the jit path compiles INSIDE the dispatch call — no
                 # separate compile wall exists, so the dispatch span itself
                 # classifies as compile time in the goodput ledger
                 self._mon_sig_bucket[sig] = n1
                 mon.train_step_compiled(
                     sig, self._mon_prev_sig, compile_s=None, count=n1,
-                    path="jit", span=(t0, t1), **self._flop_kwargs(
+                    path="jit", span=(d.t0, d.t1), **self._flop_kwargs(
                         input_arrays))
                 if self._acc_steps > 1:
                     mon.accum_config(self._acc_steps, self._grad_acc_bytes())
@@ -855,10 +855,10 @@ class TrainStep:
                 # steady-state dispatch latency; a cache-miss call is compile
                 # time, not dispatch, and is already covered by the recompile
                 # event
-                mon.step_event(t1 - t0,
+                mon.step_event(d.dur_s,
                                microbatches=self._microbatches(input_arrays),
                                bucket=self._mon_sig_bucket.get(sig),
-                               span=(t0, t1), step_id=self._gp_id)
+                               span=(d.t0, d.t1), step_id=self._gp_id)
             self._mon_prev_sig = sig
 
         opt = self._opt
@@ -1063,12 +1063,11 @@ class TrainStep:
                 # counter so bias correction replays this step number,
                 # exactly as the eager path where optimizer.step() never ran
                 self._opt._rollback_step()
-                if self._cur_trace is not None:
-                    # a skipped update is exactly the kind of step a
-                    # post-mortem wants whole: force it past head sampling
-                    self._cur_trace.event("skip_update",
-                                          microbatches=self._acc_steps)
-                    self._cur_trace.escalate("skip_update")
+                # a skipped update is exactly the kind of step a
+                # post-mortem wants whole: force it past head sampling
+                self._cur_trace.event("skip_update",
+                                      microbatches=self._acc_steps)
+                self._cur_trace.escalate("skip_update")
                 mon = _monitor._active
                 if mon is not None:
                     mon.update_skipped(self._acc_steps)
@@ -1188,17 +1187,16 @@ class TrainStep:
             args = (*self._fast_state, self._step_scalars())
         else:
             args = self._gather_args()
-        t_c = time.perf_counter()
         _remat.reset_trace_stats()
-        exe = self._compiled.lower(*args, input_arrays).compile()
-        compile_s = time.perf_counter() - t_c
+        # the step that paid the compile carries it as its own span, linked
+        # to the recompile-sentinel payload by bucket count
+        with _trace.span("train_step/compile", path="aot",
+                         bucket=len(self._fast) + 1) as comp:
+            exe = self._compiled.lower(*args, input_arrays).compile()
+        compile_s = comp.dur_s
         sig = self._input_sig(input_arrays)
         self._fast[sig] = exe
-        if self._cur_trace is not None:
-            # the step that paid the compile carries it as its own span,
-            # linked to the recompile-sentinel payload by bucket count
-            self._cur_trace.record("compile", t_c, time.perf_counter(),
-                                   path="aot", bucket=len(self._fast))
+        self._fast_bucket[id(exe)] = len(self._fast)
         mon = _monitor._active
         if mon is not None:
             # recompile sentinel: new AOT shape bucket — event carries the
@@ -1253,6 +1251,7 @@ class TrainStep:
         re-lowers against the live placements (recompile sentinel fires)."""
         n = len(self._fast)
         self._fast.clear()
+        self._fast_bucket.clear()
         self._fast_state = None
         self._compiled = None
         mon = _monitor._active
@@ -1314,12 +1313,8 @@ class TrainStep:
             st[3] = tuple(buffers_t)
         return True
 
-    def _fast_call(self, input_arrays):
-        opt = self._opt
-        mon = _monitor._active
-        # step-entry instant: the goodput ledger books the pre-dispatch
-        # host work (state refresh, scalars, arg handling) as overhead
-        tc0 = time.perf_counter() if mon is not None else None
+    def _fast_prepare(self, input_arrays):
+        """(executable, step scalars, signature) for these inputs."""
         sig = self._input_sig(input_arrays)
         exe = self._fast.get(sig)
         if exe is None:
@@ -1335,31 +1330,24 @@ class TrainStep:
             exe, scalars = self._build_fast(input_arrays)
         else:
             scalars = self._step_scalars()
+        return exe, scalars, sig
+
+    def _fast_dispatch(self, exe, scalars, sig, input_arrays, host_t0):
+        opt = self._opt
+        mon = _monitor._active
         if mon is not None:
             self._mon_prev_sig = sig
         st = self._fast_state
-
-        step_trace = self._cur_trace
-        t0 = time.perf_counter() if (_prof_recorder.enabled
-                                     or mon is not None
-                                     or step_trace is not None) else 0.0
-        loss_out, new_params, new_masters, new_states, new_buffers = exe(
-            st[0], st[1], st[2], st[3], scalars, input_arrays)
-        if t0:
-            t1 = time.perf_counter()
-            if _prof_recorder.enabled:
-                record_stage("train_step/dispatch", t0, t1)
-            if mon is not None or step_trace is not None:
-                bucket = list(self._fast).index(sig) + 1
-            if mon is not None:
-                mon.step_event(t1 - t0,
-                               microbatches=self._microbatches(input_arrays),
-                               bucket=bucket, span=(t0, t1), host_t0=tc0,
-                               step_id=self._gp_id)
-            if step_trace is not None:
-                step_trace.record(
-                    "dispatch", t0, t1, path="aot", bucket=bucket,
-                    microbatches=self._microbatches(input_arrays))
+        bucket = self._fast_bucket[id(exe)]
+        with _trace.span("train_step/dispatch", path="aot",
+                         bucket=bucket) as d:
+            loss_out, new_params, new_masters, new_states, new_buffers = exe(
+                st[0], st[1], st[2], st[3], scalars, input_arrays)
+        if mon is not None:
+            mon.step_event(d.dur_s,
+                           microbatches=self._microbatches(input_arrays),
+                           bucket=bucket, span=(d.t0, d.t1),
+                           host_t0=host_t0, step_id=self._gp_id)
 
         # outputs become next step's inputs verbatim (donation-friendly: the
         # just-invalidated input buffers are replaced wholesale)

@@ -96,6 +96,16 @@ def _gpt_scan_blocks_fwd(x, l1w, l1b, qw, qb, pw, pb, l2w, l2b, f1w, f1b, f2w,
     def body(carry, per):
         (l1w_, l1b_, qw_, qb_, pw_, pb_, l2w_, l2b_, f1w_, f1b_, f2w_, f2b_,
          kd) = per
+        with jax.named_scope("attention"):
+            carry = carry + drop(attention(carry, l1w_, l1b_, qw_, qb_, pw_,
+                                           pb_, kd), kd, 1)
+        with jax.named_scope("mlp"):
+            y = ln(carry, l2w_, l2b_)
+            y = jax.nn.gelu(tag_array(y @ f1w_ + f1b_, MLP_HIDDEN),
+                            approximate=True) @ f2w_ + f2b_
+            return carry + drop(y, kd, 2), None
+
+    def attention(carry, l1w_, l1b_, qw_, qb_, pw_, pb_, kd):
         y = ln(carry, l1w_, l1b_)
         qkv = tag_array(y @ qw_ + qb_, ATTN_QKV)     # [B,S,3H]
         from ..kernels.pallas.flash_attention import (
@@ -131,12 +141,7 @@ def _gpt_scan_blocks_fwd(x, l1w, l1b, qw, qb, pw, pb, l2w, l2b, f1w, f1b, f2w,
             att = tag_array(
                 jnp.swapaxes(jnp.einsum("bhqk,bhkd->bhqd", probs, vt), 1, 2),
                 ATTN_CONTEXT)
-        att = tag_array(att.reshape(b, s, h) @ pw_ + pb_, ATTN_OUT)
-        carry = carry + drop(att, kd, 1)
-        y = ln(carry, l2w_, l2b_)
-        y = jax.nn.gelu(tag_array(y @ f1w_ + f1b_, MLP_HIDDEN),
-                        approximate=True) @ f2w_ + f2b_
-        return carry + drop(y, kd, 2), None
+        return tag_array(att.reshape(b, s, h) @ pw_ + pb_, ATTN_OUT)
 
     if remat != "none":
         # "full" | "dots" | "selective" on the scan BODY: one jax.checkpoint
@@ -261,22 +266,26 @@ def _paged_kv_update(kv_cache, k, v):
         wpos = (pos + jnp.arange(s, dtype=jnp.int32))[None, :]
         wpos = jnp.broadcast_to(wpos, (b, s))
         end = jnp.broadcast_to(jnp.asarray(write_end)[None, None], (b, 1))
-    lidx = wpos // bs_blk                                     # [B, S]
-    phys = jnp.take_along_axis(table, jnp.minimum(lidx, mbs - 1), axis=1)
-    phys = jnp.where((wpos < end) & (lidx < mbs), phys, 0)    # -> trash
-    off = wpos % bs_blk
-    pool_k = pool_k.at[phys, off].set(k.astype(pool_k.dtype))
-    pool_v = pool_v.at[phys, off].set(v.astype(pool_v.dtype))
     shard = _PAGED_KV_SHARD["sharding"]
-    if shard is not None:
-        pool_k = jax.lax.with_sharding_constraint(pool_k, shard)
-        pool_v = jax.lax.with_sharding_constraint(pool_v, shard)
-    nkv, hd = pool_k.shape[2], pool_k.shape[3]
-    k_view = jnp.take(pool_k, table, axis=0).reshape(b, mbs * bs_blk, nkv, hd)
-    v_view = jnp.take(pool_v, table, axis=0).reshape(b, mbs * bs_blk, nkv, hd)
-    if shard is not None and _PAGED_KV_SHARD["constrain_view"]:
-        k_view = jax.lax.with_sharding_constraint(k_view, shard)
-        v_view = jax.lax.with_sharding_constraint(v_view, shard)
+    with jax.named_scope("kv_write"):
+        lidx = wpos // bs_blk                                 # [B, S]
+        phys = jnp.take_along_axis(table, jnp.minimum(lidx, mbs - 1), axis=1)
+        phys = jnp.where((wpos < end) & (lidx < mbs), phys, 0)  # -> trash
+        off = wpos % bs_blk
+        pool_k = pool_k.at[phys, off].set(k.astype(pool_k.dtype))
+        pool_v = pool_v.at[phys, off].set(v.astype(pool_v.dtype))
+        if shard is not None:
+            pool_k = jax.lax.with_sharding_constraint(pool_k, shard)
+            pool_v = jax.lax.with_sharding_constraint(pool_v, shard)
+    with jax.named_scope("kv_gather"):
+        nkv, hd = pool_k.shape[2], pool_k.shape[3]
+        k_view = jnp.take(pool_k, table, axis=0).reshape(
+            b, mbs * bs_blk, nkv, hd)
+        v_view = jnp.take(pool_v, table, axis=0).reshape(
+            b, mbs * bs_blk, nkv, hd)
+        if shard is not None and _PAGED_KV_SHARD["constrain_view"]:
+            k_view = jax.lax.with_sharding_constraint(k_view, shard)
+            v_view = jax.lax.with_sharding_constraint(v_view, shard)
     return k_view, v_view, (pool_k, pool_v)
 
 
@@ -407,13 +416,19 @@ class GPTBlock(nn.Layer):
         self.dropout = nn.Dropout(config.hidden_dropout_prob)
 
     def forward(self, x, attn_mask=None, kv_cache=None):
+        # named scopes are metadata on the ops (the profile an operator
+        # opens shows them); the programs stay the same programs
         if kv_cache is not None:
-            a, new_cache = self.attn(self.ln_1(x), kv_cache=kv_cache)
-            x = x + a
-            x = x + self.mlp(self.ln_2(x))
+            with jax.named_scope("attention"):
+                a, new_cache = self.attn(self.ln_1(x), kv_cache=kv_cache)
+                x = x + a
+            with jax.named_scope("mlp"):
+                x = x + self.mlp(self.ln_2(x))
             return x, new_cache
-        x = x + self.dropout(self.attn(self.ln_1(x), attn_mask))
-        x = x + self.dropout(self.mlp(self.ln_2(x)))
+        with jax.named_scope("attention"):
+            x = x + self.dropout(self.attn(self.ln_1(x), attn_mask))
+        with jax.named_scope("mlp"):
+            x = x + self.dropout(self.mlp(self.ln_2(x)))
         return x
 
 
@@ -619,8 +634,9 @@ class GPTForCausalLM(nn.Layer):
             # (not [B,S,V]) and the head matmul + CE fuse into one executable
             tied = self.lm_head is None
             w = self.gpt.wte.weight if tied else self.lm_head.weight
-            loss = _op("lm_head_ce", hidden[:, :-1, :], w, labels[:, 1:],
-                       transpose_w=tied)
+            with jax.named_scope("lm_head_loss"):
+                loss = _op("lm_head_ce", hidden[:, :-1, :], w, labels[:, 1:],
+                           transpose_w=tied)
             # the logits are NOT materialized on the loss path — in eager that
             # second [B,S,V] projection would really execute each step. Output
             # structure is mode-independent: labels => (None, loss), always.

@@ -71,6 +71,13 @@ _TRACED_KINDS = frozenset((
     "crash", "health_nan", "health_overflow", "health_spike"))
 
 
+def _ending_now(dur_s: float):
+    """(t0, t1) on ``perf_counter`` for an interval of ``dur_s`` that a
+    caller timed itself and reports as it ends (no span to hand over)."""
+    t1 = time.perf_counter()
+    return t1 - dur_s, t1
+
+
 def _sig_json(sig):
     """Input signature tuple -> JSON-ready list (shapes/dtypes/shardings)."""
     out = []
@@ -271,10 +278,8 @@ class Monitor:
         # goodput: the dispatch is productive time attributed to its shape
         # bucket's FLOP entry; host_t0 (the step's entry instant) books the
         # pre-dispatch host work as overhead
-        if span is None:
-            t1 = time.perf_counter()
-            span = (t1 - dur_s, t1)
-        self.goodput.dispatch("train", (step_id, bucket), span[0], span[1],
+        t0, t1 = span or _ending_now(dur_s)
+        self.goodput.dispatch("train", (step_id, bucket), t0, t1,
                               host_t0=host_t0)
         self.emit("step", dur_s=dur_s)
 
@@ -365,10 +370,7 @@ class Monitor:
         # goodput: consumer-visible feed wait is data_wait — the producer's
         # hidden fetch/H2D never reaches the ledger (hidden work is not
         # lost time)
-        if span is None:
-            t1 = time.perf_counter()
-            span = (t1 - wait_s, t1)
-        self.goodput.add("data_wait", span[0], span[1])
+        self.goodput.add("data_wait", *(span or _ending_now(wait_s)))
         if wait_s > _STALL_S:
             self.registry.counter("loader/stalls").inc()
             self.emit("loader_stall", wait_s=wait_s, qsize=qsize)
@@ -651,9 +653,10 @@ class Monitor:
                   prefill_s=prefill_s)
 
     def serve_step(self, dur_s: float, live: int, queue_depth: int,
-                   engine_id=None):
+                   engine_id=None, span=None):
         """One decode step over all live slots: per-token latency is
-        dur_s (the whole batch advances one token per step)."""
+        dur_s (the whole batch advances one token per step). ``span``:
+        the ``engine/decode_call`` interval it was timed by."""
         self.registry.counter("serve/decode_steps").inc()
         self.registry.counter("serve/tokens").inc(live)
         self.registry.gauge("serve/live_slots").set(live)
@@ -663,16 +666,15 @@ class Monitor:
         # (HFU) while only `live` of them carried requests (MFU) — the
         # ledger scales model FLOPs by the live fraction; decode tokens are
         # GENERATED tokens, the serving-throughput figure
-        now = time.perf_counter()
+        t0, t1 = span or _ending_now(dur_s)
         self.goodput.dispatch("serve", (engine_id, "decode", None),
-                              now - dur_s, now, tokens=live,
-                              generated=True)
+                              t0, t1, tokens=live, generated=True)
 
     def serve_spec_step(self, dur_s: float, drafted: int, accepted: int,
                         emitted: int, width: int, drafter: str,
                         live: int = 0, queue_depth: int = 0,
                         accepted_per_step=None, hit_rate=None,
-                        engine_id=None):
+                        engine_id=None, span=None):
         """One speculative verify dispatch for one slot: ``drafted`` tokens
         proposed, ``accepted`` of them agreed with the verifier, and
         ``emitted`` tokens actually advanced the request (accepted + the
@@ -701,10 +703,9 @@ class Monitor:
         if hit_rate is not None:
             g("serve/spec_draft_hit_rate").set(hit_rate)
         self.registry.histogram("serve/spec_step_s").observe(dur_s)
-        now = time.perf_counter()
+        t0, t1 = span or _ending_now(dur_s)
         self.goodput.dispatch("serve", (engine_id, "verify", width),
-                              now - dur_s, now, tokens=emitted,
-                              generated=True)
+                              t0, t1, tokens=emitted, generated=True)
 
     def serve_spec(self, drafter: str, drafted: int, accepted: int,
                    emitted: int, trace_id=None):
@@ -717,14 +718,14 @@ class Monitor:
         self.emit("serve_spec", **fields)
 
     def serve_prefill_step(self, dur_s: float, bucket, tokens: int,
-                           engine_id=None):
+                           engine_id=None, span=None):
         """One prefill execution (a chunk iteration, or a monolithic
         bucketed prefill): productive time + FLOPs for the goodput ledger;
         ``tokens`` is the VALID token count this call carried (a padded
         chunk tail is hardware work but not model work)."""
-        now = time.perf_counter()
+        t0, t1 = span or _ending_now(dur_s)
         self.goodput.dispatch("serve", (engine_id, "prefill", bucket),
-                              now - dur_s, now, tokens=tokens)
+                              t0, t1, tokens=tokens)
 
     def serve_sched(self, t0: float, t1: float):
         """One whole scheduler iteration (``DecodeEngine.step()``) as a

@@ -1,37 +1,50 @@
-"""Span tracer — request- and step-scoped causal telemetry.
+"""Span layer — the program's one record of what ran when, and why.
 
-The monitor's registry answers "what are the aggregates doing" and the
-profiler answers "where did this traced window's time go"; this module
-answers the question neither can: *which request or step was slow, and
-which phase ate the time*. It is a Dapper-style tracer scaled down to one
-process: a **trace** is one causal unit (a serving request from ``submit()``
-to finish, one training step), a **span** is one phase of it (queue wait, a
-chunked-prefill iteration, the AOT dispatch), and spans carry parent links
-plus point **events** (a COW copy batch, a preemption, a recompile) so a
-TTFT or step-time outlier decomposes exactly.
+The monitor's registry answers "what are the aggregates doing" and a
+device profile answers "what did the chip run"; this module answers what
+neither can: *which request, engine step or train step was slow, and which
+phase ate the time*. A **span** is one interval of host work: a name,
+``t0``/``t1`` on ``time.perf_counter()``, its own id, the id of the span
+that caused it (``parent_id``) and the id its request or train step shares
+(``trace_id``: the request id, the step number). Sites make ONE call:
 
-Clocks: spans are timed on ``time.perf_counter()`` (monotonic — a phase
-duration can never go negative on an NTP step) and exported against a
-wall-clock anchor taken once at tracer start, so trace records line up with
-the monitor's ``ts`` fields and the profiler's Chrome export.
+* ``with span(name, **attrs):`` — a scoped phase of the calling thread.
+  It is ALSO a ``jax.profiler.TraceAnnotation("paddle/<name>")`` entered
+  and left with it, so whenever anyone takes a profile
+  (``jax.profiler.start_trace``, ``paddle.profiler.Profiler``, the
+  benchmark) the program's phases lie in the ``.xplane.pb`` beside the
+  device ops, on one clock. With no profile being taken an annotation is
+  a flag check: that is its off switch, and there is no other.
+* ``record(name, t0, t1, **attrs)`` — an interval already timed.
+* ``start_trace(name, key=...)`` — one causal unit (a serving request from
+  ``submit()`` to finish, one ``TrainStep`` call) whose phases open and
+  close across calls and threads: ``tr.span("queue")`` ... ``.end()``.
+  Such spans overlap each other, so they cannot be annotations (a
+  ``TraceAnnotation`` nests per thread); used as a context manager the
+  trace's ROOT span is scoped, and is one.
 
-Sampling is head-based: the keep/drop decision is made when the trace
-STARTS (``PADDLE_TRACE_SAMPLE``, a probability in [0, 1], default 1.0 —
-a deterministic credit accumulator, not a PRNG, so a 0.1 sample really
+Every finished span goes to ONE process-wide bounded ring (the flight
+recorder: ``RING_CAPACITY`` spans, on by default, no knob) that
+``spans(t0, t1, prefix)`` reads; to the profiler's recorder while a
+``Profiler`` records; and — for spans of a trace — to the JSONL sink when
+``enable()`` turned one on. ``ring(False)`` exists so the ring's cost can
+be measured, not as a mode. In the ring and as an annotation a span of a
+trace is ``<trace>/<span>`` (``request/queue``, ``train_step/dispatch``);
+inside its trace, and so in the sink, it keeps the short name.
+
+The sink (opt-in: ``enable()`` / ``PADDLE_TRACE``) is the Dapper-style
+part, scaled down to one process. Sampling is head-based and governs the
+sink only: the keep/drop decision is made when the trace STARTS
+(``PADDLE_TRACE_SAMPLE``, a probability in [0, 1], default 1.0 — a
+deterministic credit accumulator, not a PRNG, so a 0.1 sample really
 keeps every 10th trace). Unsampled traces still buffer their spans in
 memory (bounded) so a WARN fired mid-trace can **escalate** them to
 sampled — the trace you need post-mortem is by construction the one the
-sampler would have dropped.
-
-Sink: schema-v1 ``run.trace.jsonl`` through the same buffered
-:class:`~paddle_tpu.monitor.sink.JsonlSink` (per-process ``.procN``
-suffix under the launcher env contract). A bounded in-memory ring of
-finished spans feeds the profiler's Chrome export and flight dumps.
-
-Cost contract: every integration point guards on ONE module-global
-``trace._active is None`` check (the ``monitor._active`` pattern); with the
-tracer enabled, an unsampled trace costs object construction and list
-appends only — no serialization, no I/O.
+sampler would have dropped. Records are schema-v1 ``run.trace.jsonl``
+through the same buffered :class:`~paddle_tpu.monitor.sink.JsonlSink`
+(per-process ``.procN`` suffix under the launcher env contract), timed on
+``perf_counter`` and exported against a wall-clock anchor taken once at
+tracer start, so they line up with the monitor's ``ts`` fields.
 """
 from __future__ import annotations
 
@@ -40,52 +53,114 @@ import itertools
 import os
 import threading
 import time
-from collections import deque
+from collections import deque, namedtuple
 from typing import Dict, Optional
+
+from jax.profiler import TraceAnnotation
 
 from .sink import JsonlSink
 
-__all__ = ["TRACE_SCHEMA_VERSION", "Span", "Tracer", "enable", "disable",
-           "enabled", "get", "current_trace_id", "escalate"]
+__all__ = ["TRACE_SCHEMA_VERSION", "RING_CAPACITY", "Span", "SpanRecord",
+           "Tracer", "span", "record", "spans", "ring", "start_trace",
+           "enable", "disable", "enabled", "get", "current_trace_id",
+           "escalate"]
 
 TRACE_SCHEMA_VERSION = 1
+RING_CAPACITY = 32768
+ANNOTATION_PREFIX = "paddle/"
 
-# THE hot-path flag: integration points read this one module global and do
-# nothing when it is None.
+# the sink session, when one is enabled (sampling, JSONL, escalation)
 _active: Optional["Tracer"] = None
 
 _lock = threading.Lock()
 
+# ---- the flight recorder: every finished span of the process
+_ring: deque = deque(maxlen=RING_CAPACITY)
+_ring_on = True
+_span_ids = itertools.count(1)
+_tls = threading.local()          # .stack: this thread's open scoped spans
+# set by paddle_tpu.profiler while a Profiler records: (name, t0, t1) -> None
+_profiler_emit = None
+
+SpanRecord = namedtuple("SpanRecord", ["name", "t0", "t1", "span_id",
+                                       "parent_id", "trace_id", "attrs"])
+
+
+def _stack() -> list:
+    st = getattr(_tls, "stack", None)
+    if st is None:
+        st = _tls.stack = []
+    return st
+
 
 class Span:
-    """One phase of a trace. ``end()`` seals it into the owning trace's
-    buffer; ``event()`` attaches a point annotation (bounded — a runaway
+    """One interval of host work. Scoped (``with span(...)``): stamped,
+    annotated and linked to the enclosing scoped span of its thread on
+    entry, sealed on exit. Unscoped (``trace.span(...)``): opened by its
+    owner and sealed by ``end()``, possibly steps later and on another
+    thread. ``event()`` attaches a point annotation (bounded — a runaway
     event stream degrades to a drop counter, never unbounded memory)."""
 
     MAX_EVENTS = 256
 
-    __slots__ = ("trace", "span_id", "parent_id", "name", "kind", "t0",
-                 "t1", "attrs", "events", "events_dropped")
+    __slots__ = ("name", "t0", "t1", "span_id", "parent_id", "key",
+                 "attrs", "trace", "kind", "adopt_kind", "events",
+                 "events_dropped", "_ann")
 
-    def __init__(self, trace: "_Trace", span_id: int, parent_id, name: str,
-                 kind: str, t0: float, attrs: dict):
-        self.trace = trace
-        self.span_id = span_id
-        self.parent_id = parent_id
+    def __init__(self, name: str, adopt_kind: Optional[str] = None,
+                 **attrs):
         self.name = name
-        self.kind = kind
-        self.t0 = t0
-        self.t1 = None
         self.attrs = attrs
-        self.events = []
+        self.t0 = self.t1 = None
+        self.span_id = next(_span_ids)
+        self.parent_id = None
+        self.key = None               # what readers of the ring call trace_id
+        self.trace = None             # the trace it is a child of, if any
+        self.kind = "phase"
+        self.adopt_kind = adopt_kind
+        self.events = None
         self.events_dropped = 0
+
+    def _under(self, up: "Span"):
+        """Link to the span that caused this one."""
+        self.parent_id = up.span_id
+        self.key = up.key
+        self.trace = up if isinstance(up, _Trace) else up.trace
+
+    # ------------------------------------------------------------- scoped
+
+    def __enter__(self):
+        try:
+            st = _tls.stack
+        except AttributeError:
+            st = _tls.stack = []
+        if st:
+            self._under(st[-1])
+        st.append(self)
+        self._ann = ann = TraceAnnotation(ANNOTATION_PREFIX + self.name)
+        ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self._ann.__exit__(None, None, None)
+        st = _tls.stack
+        if st and st[-1] is self:
+            st.pop()
+        self.end(t1)
+        return False
+
+    # ------------------------------------------------------------ content
 
     def set(self, **attrs):
         self.attrs.update(attrs)
         return self
 
     def event(self, name: str, t: Optional[float] = None, **fields):
-        if len(self.events) >= self.MAX_EVENTS:
+        if self.events is None:
+            self.events = []
+        elif len(self.events) >= self.MAX_EVENTS:
             self.events_dropped += 1
             return
         ev = {"name": name, "t": time.perf_counter() if t is None else t}
@@ -97,7 +172,26 @@ class Span:
         if self.t1 is not None:
             return  # idempotent: a double end keeps the first boundary
         self.t1 = time.perf_counter() if t1 is None else t1
-        self.trace._seal(self)
+        if _ring_on:
+            # plain tuples of plain values: the collector stops tracking
+            # them, so a full ring costs later collections nothing
+            _ring.append((self.name, self.t0, self.t1, self.span_id,
+                          self.parent_id, self.key, self.attrs))
+        emit = _profiler_emit
+        if emit is not None:
+            emit(self.name, self.t0, self.t1)
+        tr = self.trace
+        if tr is not None:
+            if tr.tracer is not None:
+                tr._seal(self)
+        elif self.adopt_kind is not None and _active is not None:
+            # observed OUTSIDE any trace (the DeviceLoader's wait/fetch/H2D
+            # run before the step trace opens; a checkpoint save lands
+            # between steps): buffered (bounded, cross-thread) until the
+            # next trace of that kind starts and adopts it — the step
+            # waterfall then shows the feed work that preceded the dispatch,
+            # and an unrelated request trace in between cannot steal it
+            _active._floating.append(self)
 
     @property
     def dur_s(self) -> float:
@@ -105,38 +199,46 @@ class Span:
                 else time.perf_counter()) - self.t0
 
 
-class _Trace:
-    """One causal unit: a root span plus its children, buffered until
-    ``end()`` decides (sampling) whether the spans reach the sink."""
+class _Trace(Span):
+    """One causal unit, which IS its root span (``<name>/<root>``) plus
+    what a trace adds: children opened by name, and — with a sink session
+    (``tracer``) — the buffer that holds them until ``end()`` decides
+    (sampling) whether they reach the sink. Without a session the trace
+    only names its spans and hands them its ``key``. As a context manager
+    the root is a scoped span of the calling thread."""
 
     MAX_SPANS = 512
 
-    __slots__ = ("tracer", "trace_id", "name", "kind", "sampled",
-                 "escalated", "root", "_sealed", "_dropped", "_next_span",
-                 "_ended")
+    __slots__ = ("tracer", "trace_id", "trace_name", "sampled", "escalated",
+                 "_sealed", "_dropped")
 
-    def __init__(self, tracer: "Tracer", trace_id: str, name: str,
-                 kind: str, sampled: bool, t0: float, attrs: dict):
-        self.tracer = tracer
-        self.trace_id = trace_id
-        self.name = name
+    def __init__(self, tracer: Optional["Tracer"], trace_id: Optional[str],
+                 name: str, kind: str, sampled: bool, attrs: dict,
+                 key=None, root: str = "call"):
+        Span.__init__(self, f"{name}/{root}", **attrs)
+        self.t0 = time.perf_counter()
+        self.key = key
         self.kind = kind
+        self.tracer = tracer
+        self.trace_id = trace_id       # the sink's id; None without a sink
+        self.trace_name = name
         self.sampled = sampled
         self.escalated = None
-        self._sealed = []          # finished spans, root excluded until end
+        self._sealed = []              # finished children, for the sink
         self._dropped = 0
-        self._next_span = itertools.count(1)
-        self._ended = False
-        self.root = Span(self, 0, None, name, kind, t0, attrs)
 
     # -------------------------------------------------------------- building
 
     def span(self, name: str, kind: str = "phase", parent: Optional[Span]
              = None, t0: Optional[float] = None, **attrs) -> Span:
         """Open a child span (default parent: the root)."""
-        return Span(self, next(self._next_span),
-                    (parent or self.root).span_id, name, kind,
-                    time.perf_counter() if t0 is None else t0, attrs)
+        sp = Span(f"{self.trace_name}/{name}", **attrs)
+        sp._under(self)
+        if parent is not None:
+            sp.parent_id = parent.span_id
+        sp.kind = kind
+        sp.t0 = time.perf_counter() if t0 is None else t0
+        return sp
 
     def record(self, name: str, t0: float, t1: float, kind: str = "phase",
                parent: Optional[Span] = None, **attrs) -> Span:
@@ -145,24 +247,40 @@ class _Trace:
         sp.end(t1)
         return sp
 
-    def event(self, name: str, **fields):
-        """Point annotation on the ROOT span."""
-        self.root.event(name, **fields)
-
     def _seal(self, span: Span):
-        if span.span_id == 0:
-            return  # the root exports via end(), not the child buffer
         if len(self._sealed) >= self.MAX_SPANS:
             self._dropped += 1
             return
         self._sealed.append(span)
 
+    def _adopt(self, span: Span):
+        """Take a finished span recorded outside any trace (a loader wait
+        before the step opened) as a child of the root."""
+        span.trace, span.parent_id = self, self.span_id
+        self._seal(span)
+
     # ------------------------------------------------------------- lifecycle
+
+    def __enter__(self):
+        st = _stack()
+        if not (st and st[-1] is self):
+            st.append(self)
+        self._ann = ann = TraceAnnotation(ANNOTATION_PREFIX + self.name)
+        ann.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self._ann.__exit__(None, None, None)
+        self.end(t1)
+        return False
 
     def escalate(self, reason: str = "warn"):
         """Force-sample this trace (always-sample-on-WARN): the spans are
         already buffered, so escalation any time before ``end()`` loses
-        nothing."""
+        nothing. Without a sink session there is nothing to keep."""
+        if self.tracer is None:
+            return
         if not self.sampled:
             self.sampled = True
             self.tracer._escalated += 1
@@ -174,21 +292,23 @@ class _Trace:
             self.escalated = reason
 
     def end(self, t1: Optional[float] = None, **attrs):
-        if self._ended:
+        if self.t1 is not None:
             return
-        self._ended = True
         if attrs:
-            self.root.attrs.update(attrs)
-        self.root.end(t1)  # seals the root last — it sorts first on export
-        self.tracer._finish_trace(self)
+            self.attrs.update(attrs)
+        st = getattr(_tls, "stack", None)
+        if st and st[-1] is self:
+            st.pop()
+        Span.end(self, t1)
+        if self.tracer is not None:
+            self.tracer._finish_trace(self)
 
 
 class Tracer:
-    """One enabled tracing session (sink + ring + sampling state)."""
+    """One enabled sink session (JSONL + sampling + escalation state)."""
 
     def __init__(self, path: Optional[str] = None, *,
-                 sample: Optional[float] = None, ring: int = 1024,
-                 flush_every: int = 32):
+                 sample: Optional[float] = None, flush_every: int = 32):
         if sample is None:
             try:
                 sample = float(os.environ.get("PADDLE_TRACE_SAMPLE", "")
@@ -198,9 +318,6 @@ class Tracer:
         self.sample = min(max(float(sample), 0.0), 1.0)
         self.sink = JsonlSink(path, flush_every) if path else None
         self.path = self.sink.path if self.sink else None
-        # finished spans of SAMPLED traces, monotonic times kept — the
-        # profiler's Chrome export and flight dumps read this
-        self.ring = deque(maxlen=max(int(ring), 1))
         self._wall0 = time.time()
         self._mono0 = time.perf_counter()
         self._ids = itertools.count(1)
@@ -210,8 +327,8 @@ class Tracer:
         # sample=0.0 means "escalations only" and keeps nothing up front
         self._credit = 1.0 if self.sample > 0 else 0.0
         self._open: dict = {}          # id(trace) -> trace
-        self._tls = threading.local()  # per-thread current-trace stack
         self._floating = deque(maxlen=64)
+        self._recent = deque(maxlen=8)   # newest sampled trace ids
         self._last_trace_id: Optional[str] = None
         self.traces_started = 0
         self.traces_sampled = 0
@@ -234,9 +351,10 @@ class Tracer:
     # --------------------------------------------------------------- traces
 
     def start_trace(self, name: str, kind: str = "trace",
-                    current: bool = True, **attrs) -> _Trace:
-        """Open a trace. ``current=True`` pushes it on this thread's
-        current-trace stack (step traces; WARN tagging reads the top);
+                    current: bool = True, key=None, root: str = "call",
+                    **attrs) -> _Trace:
+        """Open a trace. ``current=True`` makes its root the innermost
+        scoped span of this thread (step traces; WARN tagging reads it);
         serving request traces pass False — many are open at once and none
         is "the" current one. Pending floating spans (loader waits recorded
         before any trace existed) are adopted as children of the new root.
@@ -248,8 +366,7 @@ class Tracer:
                 self._credit -= 1.0
             n = next(self._ids)
         tid = f"{os.getpid():x}-{n:x}"
-        tr = _Trace(self, tid, name, kind, sampled, time.perf_counter(),
-                    attrs)
+        tr = _Trace(self, tid, name, kind, sampled, attrs, key, root)
         with self._slock:
             # the open-trace map is read by OTHER threads (escalate from
             # the aggregator's WARN path, snapshot_info from dump) — every
@@ -258,40 +375,48 @@ class Tracer:
         self._last_trace_id = tid
         self.traces_started += 1
         if current:
-            stack = getattr(self._tls, "stack", None)
-            if stack is None:
-                stack = self._tls.stack = []
-            stack.append(tr)
+            _stack().append(tr)
         if self._floating:
             # adopt only the floats addressed to this trace KIND: loader/
             # ckpt spans are step-trace context — a serving request trace
             # starting in between must not steal them
             with self._slock:
                 keep, mine = deque(maxlen=self._floating.maxlen), []
-                for entry in self._floating:
-                    (mine if entry[0] == kind else keep).append(entry)
+                for sp in self._floating:
+                    (mine if sp.adopt_kind == kind else keep).append(sp)
                 self._floating = keep
-            for _, name_f, t0, t1, a in mine:
-                tr.record(name_f, t0, t1, **a)
+            for sp in mine:
+                tr._adopt(sp)
         return tr
 
     def _finish_trace(self, tr: _Trace):
         with self._slock:
             self._open.pop(id(tr), None)
-        stack = getattr(self._tls, "stack", None)
-        if stack and stack[-1] is tr:
-            stack.pop()
+        spans = [tr] + tr._sealed
+        tr._sealed = []        # the children point back at their trace
         if not tr.sampled:
             return
         self.traces_sampled += 1
-        spans = [tr.root] + tr._sealed
+        self._recent.append(tr.trace_id)
+        # the sink numbers a trace's spans from its root (0) in creation
+        # order, whatever ids the process-wide counter handed them
+        local = {tr.span_id: 0}
+        for i, g in enumerate(sorted(s.span_id for s in spans[1:]), 1):
+            local[g] = i
+        prefix = tr.trace_name + "/"
         # children sealed before an escalation/late root-end keep insertion
         # order; export sorts by start so waterfalls render stably
-        spans.sort(key=lambda s: (s.t0, s.span_id))
+        spans.sort(key=lambda s: (s.t0, local[s.span_id]))
         for sp in spans:
+            if sp is tr:
+                parent, name = None, tr.trace_name
+            else:
+                parent = local.get(sp.parent_id, 0)
+                name = sp.name[len(prefix):] if sp.name.startswith(prefix) \
+                    else sp.name
             rec = {"v": TRACE_SCHEMA_VERSION, "kind": "span",
-                   "trace": tr.trace_id, "span": sp.span_id,
-                   "parent": sp.parent_id, "name": sp.name,
+                   "trace": tr.trace_id, "span": local[sp.span_id],
+                   "parent": parent, "name": name,
                    "span_kind": sp.kind, "ts": self.wall(sp.t0),
                    "dur_s": round((sp.t1 if sp.t1 is not None else sp.t0)
                                   - sp.t0, 9)}
@@ -302,22 +427,20 @@ class Tracer:
                     dict(e, t=self.wall(e["t"])) for e in sp.events]
             if sp.events_dropped:
                 rec["events_dropped"] = sp.events_dropped
-            self.ring.append({**rec, "_t0": sp.t0,
-                              "_t1": sp.t1 if sp.t1 is not None else sp.t0})
             if self.sink is not None:
                 self.sink.write(rec)
                 self.spans_written += 1
         summary = {"v": TRACE_SCHEMA_VERSION, "kind": "trace",
-                   "trace": tr.trace_id, "name": tr.name,
-                   "trace_kind": tr.kind, "ts": self.wall(tr.root.t0),
-                   "dur_s": round(tr.root.dur_s, 9),
+                   "trace": tr.trace_id, "name": tr.trace_name,
+                   "trace_kind": tr.kind, "ts": self.wall(tr.t0),
+                   "dur_s": round(tr.dur_s, 9),
                    "spans": len(spans)}
         if tr.escalated:
             summary["escalated"] = tr.escalated
         if tr._dropped:
             summary["spans_dropped"] = tr._dropped
-        if tr.root.attrs:
-            summary["attrs"] = tr.root.attrs
+        if tr.attrs:
+            summary["attrs"] = tr.attrs
         if self.sink is not None:
             self.sink.write(summary)
 
@@ -325,24 +448,20 @@ class Tracer:
 
     def floating(self, name: str, t0: float, t1: float,
                  adopt_kind: str = "step", **attrs):
-        """A completed span observed OUTSIDE any trace (the DeviceLoader's
-        wait/fetch/H2D run before the step trace opens; a checkpoint save
-        lands between steps). Buffered (bounded, cross-thread) and adopted
-        as children of the next trace of ``adopt_kind`` to start — the
-        step waterfall then shows the feed work that preceded the
-        dispatch, and an unrelated request trace starting in between
-        cannot steal it."""
-        self._floating.append((adopt_kind, name, float(t0), float(t1),
-                               attrs))
+        """A completed span observed OUTSIDE any trace, for the next trace
+        of ``adopt_kind`` to adopt: ``record()`` under its older name."""
+        record(name, t0, t1, adopt_kind=adopt_kind, **attrs)
 
     # ------------------------------------------------------------ WARN hooks
 
     def current_trace_id(self) -> Optional[str]:
-        """This thread's open trace id (top of stack), else the most
-        recently started trace anywhere — what a WARN record embeds."""
-        stack = getattr(self._tls, "stack", None)
-        if stack:
-            return stack[-1].trace_id
+        """This thread's open trace id (innermost scoped span that belongs
+        to one), else the most recently started trace anywhere — what a
+        WARN record embeds."""
+        for sp in reversed(getattr(_tls, "stack", None) or ()):
+            tr = sp if isinstance(sp, _Trace) else sp.trace
+            if tr is not None and tr.trace_id is not None:
+                return tr.trace_id
         return self._last_trace_id
 
     def escalate(self, trace: Optional[_Trace] = None,
@@ -365,20 +484,11 @@ class Tracer:
         """Flight-dump payload: where the trace stream lives and which
         traces were recently active (the crash report names the trace to
         open, not just the metrics at death)."""
-        recent = []
-        seen = set()
-        for rec in reversed(self.ring):
-            t = rec.get("trace")
-            if t and t not in seen:
-                seen.add(t)
-                recent.append(t)
-            if len(recent) >= 8:
-                break
         with self._slock:
             open_ids = [tr.trace_id for tr in self._open.values()]
         return {"path": self.path, "current": self.current_trace_id(),
                 "open": open_ids,
-                "recent": recent, "sample": self.sample,
+                "recent": list(reversed(self._recent)), "sample": self.sample,
                 "started": self.traces_started,
                 "sampled": self.traces_sampled,
                 "escalated": self._escalated,
@@ -402,21 +512,84 @@ class Tracer:
             self.sink.close()
 
 
-# ------------------------------------------------------------------ module API
+# ---------------------------------------------------------- the one span call
+
+
+# ``with span("engine/admit") as sp: ...`` — a scoped span. Its parent, trace
+# id and trace are those of the enclosing scoped span of this thread.
+# ``adopt_kind``: where it runs outside any trace, the kind of trace that
+# should adopt it when the sink is on ("step" for the input pipeline's spans).
+span = Span
+
+
+def record(name: str, t0: float, t1: float,
+           adopt_kind: Optional[str] = None, **attrs) -> Span:
+    """An interval already timed (it cannot become an annotation after the
+    fact). Inside a scoped span it is that span's child."""
+    sp = Span(name, adopt_kind, **attrs)
+    st = getattr(_tls, "stack", None)
+    if st:
+        sp._under(st[-1])
+    sp.t0 = t0
+    sp.end(t1)
+    return sp
+
+
+def start_trace(name: str, key=None, kind: str = "trace",
+                current: bool = True, root: str = "call", **attrs) -> _Trace:
+    """Open the trace of one request or step: through the sink session
+    when one is enabled, else a bare one whose spans reach the ring only.
+    ``key`` is the id its spans carry as ``trace_id``; the root span is
+    ``<name>/<root>``."""
+    tracer = _active
+    if tracer is not None:
+        return tracer.start_trace(name, kind, current, key, root, **attrs)
+    tr = _Trace(None, None, name, kind, False, attrs, key, root)
+    if current:
+        _stack().append(tr)
+    return tr
+
+
+def _snapshot() -> list:
+    while True:
+        try:
+            return list(_ring)
+        except RuntimeError:     # another thread appended mid-copy
+            continue
+
+
+def spans(t0: float, t1: float, prefix: Optional[str] = None) -> list:
+    """The finished spans wholly inside ``[t0, t1]`` (``perf_counter``
+    seconds), oldest first, as :class:`SpanRecord`; ``prefix`` keeps the
+    names that start with it. The ring holds the newest ``RING_CAPACITY``
+    spans of the process."""
+    return [SpanRecord(*s) for s in _snapshot()
+            if s[1] >= t0 and s[2] <= t1
+            and (prefix is None or s[0].startswith(prefix))]
+
+
+def ring(on: bool):
+    """Switch the ring's recording off or on again. It is on by default;
+    this exists so that its cost can be measured, not as a mode."""
+    global _ring_on
+    _ring_on = bool(on)
+
+
+# ------------------------------------------------------------- the sink session
 
 
 def enable(path: Optional[str] = None, *, sample: Optional[float] = None,
-           ring: int = 1024, flush_every: int = 32) -> Tracer:
-    """Turn the tracer on. ``path`` is the trace JSONL file (None: in-memory
-    ring only); multi-process runs write ``path.procN`` per the sink
-    contract. ``sample``: head-sampling probability (default: env
-    ``PADDLE_TRACE_SAMPLE``, else 1.0). Idempotent-safe."""
+           flush_every: int = 32) -> Tracer:
+    """Turn the sink session on. ``path`` is the trace JSONL file (None:
+    sampling and escalation state only); multi-process runs write
+    ``path.procN`` per the sink contract. ``sample``: head-sampling
+    probability (default: env ``PADDLE_TRACE_SAMPLE``, else 1.0).
+    Idempotent-safe."""
     global _active
     with _lock:
         if _active is not None:
             _teardown_locked()
-        _active = Tracer(path, sample=sample, ring=ring,
-                         flush_every=flush_every)
+        _active = Tracer(path, sample=sample, flush_every=flush_every)
     return _active
 
 

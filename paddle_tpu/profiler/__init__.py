@@ -24,6 +24,7 @@ from typing import Callable, List, Optional, Sequence, Tuple
 
 from .. import monitor as _monitor
 from ..core import dispatch
+from ..monitor import trace as _trace
 
 __all__ = ["Profiler", "ProfilerTarget", "ProfilerState", "RecordEvent",
            "make_scheduler", "export_chrome_tracing", "load_profiler_result",
@@ -83,11 +84,14 @@ def _dispatch_hook(name: str, start: float, end: float):
 
 
 def record_stage(name: str, start: float, end: float):
-    """Emit a pipeline-stage event (``io.DeviceLoader`` and the TrainStep
-    fast path use this to attribute wall time to host-feed vs device-compute).
-    Recorded into the Profiler when one is recording, and mirrored as a
-    ``stage`` record into an enabled ``paddle_tpu.monitor`` sink — it is only
-    a no-op when BOTH are off."""
+    """Emit a pipeline-stage event. Recorded into the Profiler when one is
+    recording, and mirrored as a ``stage`` record into an enabled
+    ``paddle_tpu.monitor`` sink — it is only a no-op when BOTH are off.
+    The program's own phases (``io.DeviceLoader``, ``jit.TrainStep``, the
+    serving engine) do not call this: they make one ``monitor.trace`` span
+    call, and while a Profiler records every finished span arrives here
+    under its span name (``loader/wait``, ``train_step/dispatch``,
+    ``engine/decode_call``, ...)."""
     _recorder.emit(name, start, end, "stage")
 
 
@@ -255,6 +259,8 @@ class Profiler:
     def _set_recording(self, on: bool):
         _recorder.enabled = on
         dispatch.set_profiler_hook(_dispatch_hook if on else None)
+        # the span layer fans its finished spans out to this recorder
+        _trace._profiler_emit = record_stage if on else None
 
     # ------------------------------------------------------------- reporting
 
@@ -323,9 +329,9 @@ class Profiler:
             ends = [e.end for e in _recorder.events]
             wall = (max(ends) - min(starts)) if starts else 0.0
         return {
-            "feed_stall_s": agg.get("device_loader/wait", 0.0),
-            "feed_fetch_s": agg.get("device_loader/fetch", 0.0),
-            "feed_h2d_s": agg.get("device_loader/h2d", 0.0),
+            "feed_stall_s": agg.get("loader/wait", 0.0),
+            "feed_fetch_s": agg.get("loader/fetch", 0.0),
+            "feed_h2d_s": agg.get("loader/h2d", 0.0),
             "dispatch_s": agg.get("train_step/dispatch", 0.0),
             "steps": len(self._step_times),
             "wall_s": wall,
@@ -339,22 +345,10 @@ class Profiler:
                 f"over {len(self._step_times)} steps")
 
     def _export_chrome(self, path: str):
-        # span-tracer merge: finished spans from the monitor tracer's ring
-        # are timed on the SAME perf_counter clock as host events, so both
-        # land on one timeline — a profiler window around a slow step shows
-        # the step's trace spans (queue/prefill/dispatch) in place
-        trace_spans = []
-        try:
-            from ..monitor import trace as _trace_mod
-            tracer = _trace_mod._active
-            if tracer is not None:
-                trace_spans = list(tracer.ring)
-        except Exception:
-            pass
+        # the program's spans (monitor/trace.py) that finished while this
+        # profiler recorded are among the stage events already: the span
+        # layer fans them out to the recorder, on the same perf_counter
         t0 = min((e.start for e in _recorder.events), default=0.0)
-        if trace_spans:
-            t0 = min([t0] + [s["_t0"] for s in trace_spans]) \
-                if _recorder.events else min(s["_t0"] for s in trace_spans)
         pid = os.getpid()
         # real thread ids, compacted to stable small ints in order of first
         # appearance, with thread_name metadata rows — the DeviceLoader
@@ -373,20 +367,6 @@ class Profiler:
             events.append({"name": e.name, "ph": "X", "pid": pid, "tid": tid,
                            "ts": (e.start - t0) * 1e6,
                            "dur": (e.end - e.start) * 1e6, "cat": e.kind})
-        for s in trace_spans:
-            key = f"trace:{s.get('trace')}"
-            tid = tid_map.get(key)
-            if tid is None:
-                tid = tid_map[key] = len(tid_map)
-                meta.append({"name": "thread_name", "ph": "M", "pid": pid,
-                             "tid": tid, "ts": 0.0, "dur": 0.0,
-                             "args": {"name": key}})
-            events.append({"name": s.get("name", "?"), "ph": "X",
-                           "pid": pid, "tid": tid,
-                           "ts": (s["_t0"] - t0) * 1e6,
-                           "dur": (s["_t1"] - s["_t0"]) * 1e6,
-                           "cat": "trace",
-                           "args": s.get("attrs") or {}})
         with open(path, "w") as f:
             json.dump({"traceEvents": meta + events,
                        "displayTimeUnit": "ms"}, f)

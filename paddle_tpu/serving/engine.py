@@ -91,6 +91,10 @@ __all__ = ["DecodeEngine", "Request", "generate_via_engine",
            "quantize_for_serving", "EngineHangError", "TERMINAL_STATUSES"]
 
 
+# the (host phase, executable call) spans of the two kinds of dispatch
+_PREFILL_SPANS = ("engine/prefill_host", "engine/prefill_call")
+_DECODE_SPANS = ("engine/decode_prepare", "engine/decode_call")
+
 # terminal caller-supplied request ids remembered per engine for dedup
 # (a requeue retry arriving AFTER completion still returns the original)
 DEDUP_WINDOW = 1024
@@ -551,6 +555,20 @@ class DecodeEngine:
         self.cancelled = 0
         self.drains = 0
         self.nan_logits = 0
+        # ---- why the queue waits. Two clocks run while the head of a
+        # non-empty queue finds no free slot / no KV blocks; a request's
+        # own waits are their advance between its enqueue and its
+        # admission, so a step books them in O(1) whatever the queue holds
+        self._slot_wait_clock = 0.0
+        self._block_wait_clock = 0.0
+        self._wait_cause: Optional[str] = None   # booked since _wait_mark
+        self._wait_mark = 0.0
+        # cumulative, for an operator's dashboard (stats())
+        self.queue_waits = 0
+        self.queue_wait_s_sum = 0.0
+        self.block_waits = 0
+        self.block_wait_s_sum = 0.0
+        self.page_rejects = 0
         mon = _monitor._active
         if mon is not None:
             mon.serve_engine(self.max_slots, self.max_len,
@@ -687,12 +705,14 @@ class DecodeEngine:
                     hidden, new_pools = spec.backbone(
                         Tensor(tok[:, None]), kv_caches=caches,
                         start_pos=pos)
-                    logits = self._head(hidden.value()[:, -1])
-                    nxt = self._pick(logits, key).astype(jnp.int32)
-                    # per-slot finite-logits flag: data, not shape — NaN
-                    # detection never retraces, and a clean step pays one
-                    # row-reduce fused into the head matmul's epilogue
-                    ok = jnp.all(jnp.isfinite(logits), axis=-1)
+                    with jax.named_scope("lm_head_sample"):
+                        logits = self._head(hidden.value()[:, -1])
+                        nxt = self._pick(logits, key).astype(jnp.int32)
+                        # per-slot finite-logits flag: data, not shape —
+                        # NaN detection never retraces, and a clean step
+                        # pays one row-reduce fused into the head matmul's
+                        # epilogue
+                        ok = jnp.all(jnp.isfinite(logits), axis=-1)
                     return new_pools, nxt, ok
                 return self._traced(leaves, body)
 
@@ -706,9 +726,10 @@ class DecodeEngine:
                     hidden, new_caches = spec.backbone(
                         Tensor(tok[:, None]), kv_caches=caches,
                         start_pos=pos)
-                    logits = self._head(hidden.value()[:, -1])
-                    nxt = self._pick(logits, key).astype(jnp.int32)
-                    ok = jnp.all(jnp.isfinite(logits), axis=-1)
+                    with jax.named_scope("lm_head_sample"):
+                        logits = self._head(hidden.value()[:, -1])
+                        nxt = self._pick(logits, key).astype(jnp.int32)
+                        ok = jnp.all(jnp.isfinite(logits), axis=-1)
                     return new_caches, nxt, ok
                 return self._traced(leaves, body)
 
@@ -746,11 +767,12 @@ class DecodeEngine:
                 hidden, new_pools = spec.backbone(
                     Tensor(ids), kv_caches=caches, start_pos=p0,
                     write_end=end)
-                h_last = jax.lax.dynamic_slice_in_dim(
-                    hidden.value(), end - p0 - 1, 1, axis=1)[:, 0]
-                logits = self._head(h_last)
-                tok0 = self._pick(logits, key).astype(jnp.int32)
-                ok = jnp.all(jnp.isfinite(logits))
+                with jax.named_scope("lm_head_sample"):
+                    h_last = jax.lax.dynamic_slice_in_dim(
+                        hidden.value(), end - p0 - 1, 1, axis=1)[:, 0]
+                    logits = self._head(h_last)
+                    tok0 = self._pick(logits, key).astype(jnp.int32)
+                    ok = jnp.all(jnp.isfinite(logits))
                 return new_pools, tok0[0], ok
             return self._traced(leaves, body)
 
@@ -933,15 +955,14 @@ class DecodeEngine:
             req = Request([], max_new_tokens=1, request_id=request_id)
             self._reject(req, f"invalid request: {e}")
             return req
-        trc = _trace._active
-        if trc is not None:
-            # one trace per request, head-sampled at the door; phases open
-            # and close across step() iterations so a TTFT decomposes as
-            # queue + prefill (+ requeue episodes) with no gaps
-            req._trace = trc.start_trace(
-                "request", kind="request", current=False, request=req.id,
-                engine=self.engine_id, prompt=len(req.prompt),
-                max_new=req.max_new_tokens)
+        # one trace per request (trace id = request id; head-sampled at the
+        # door where a sink is on); phases open and close across step()
+        # iterations so a TTFT decomposes as queue + prefill (+ requeue
+        # episodes) with no gaps
+        req._trace = _trace.start_trace(
+            "request", req.id, "request", current=False, root="total",
+            request=req.id, engine=self.engine_id, prompt=len(req.prompt),
+            max_new=req.max_new_tokens)
         n = len(req.prompt)
         if n == 0:
             self._reject(req, "empty prompt")
@@ -974,8 +995,7 @@ class DecodeEngine:
             if mon is not None:
                 mon.serve_request(queued=False, error=req.error,
                                   draining=True)
-            if req._trace is not None:
-                req._trace.end(status="rejected_draining", error=req.error)
+            req._trace.end(status="rejected_draining", error=req.error)
         elif not self._queue.push(req):
             req.status, req.error = "rejected_overload", \
                 f"admission queue full ({self._queue.max_queue})"
@@ -984,8 +1004,7 @@ class DecodeEngine:
             if mon is not None:
                 mon.serve_request(queued=False, error=req.error,
                                   overload=True)
-            if req._trace is not None:
-                req._trace.end(status="rejected_overload", error=req.error)
+            req._trace.end(status="rejected_overload", error=req.error)
         else:
             if req.ttft_deadline_s is not None or req.deadline_s is not None:
                 self._deadline_reqs.add(req)
@@ -994,8 +1013,7 @@ class DecodeEngine:
             mon = _monitor._active
             if mon is not None:
                 mon.serve_request(queued=True)
-            if req._trace is not None:
-                req._phase = req._trace.span("queue")
+            self._open_queue_phase(req)
         return req
 
     def _reject(self, req: Request, why: str):
@@ -1003,8 +1021,57 @@ class DecodeEngine:
         mon = _monitor._active
         if mon is not None:
             mon.serve_request(queued=False, error=why)
-        if req._trace is not None:
+        if req._trace is not None:     # the malformed-request stand-in has none
             req._trace.end(status="failed", error=why)
+
+    # ------------------------------------------------------- queue waits
+
+    def _book_wait(self, now: float, cause: Optional[str]):
+        """Book the time since the last look to what the queue was waiting
+        for then, and note what it waits for from now on (``"slot"``,
+        ``"blocks"`` or nothing)."""
+        if self._wait_cause == "slot":
+            self._slot_wait_clock += now - self._wait_mark
+        elif self._wait_cause == "blocks":
+            self._block_wait_clock += now - self._wait_mark
+        self._wait_cause, self._wait_mark = cause, now
+
+    def _open_queue_phase(self, req: Request, **attrs):
+        """``req`` entered the queue (at submit, or again after a
+        preemption): open its ``queue`` span and note where the two wait
+        clocks stand."""
+        now = time.perf_counter()
+        self._book_wait(now, self._wait_cause)
+        req._wait0 = (self._slot_wait_clock, self._block_wait_clock)
+        req._page_rejects = 0
+        req._trace_phase("queue", t0=now, **attrs)
+
+    def _close_queue_phase(self, req: Request, slot: int) -> float:
+        """``req`` got ``slot``: fill in its ``queue`` span with what it
+        waited for (whichever cause held it longest; ``"none"`` = only for
+        the step in flight). Returns the instant, at which the caller opens
+        the next phase: that closes the span."""
+        now = time.perf_counter()
+        self._book_wait(now, self._wait_cause)
+        slot_s = self._slot_wait_clock - req._wait0[0]
+        block_s = self._block_wait_clock - req._wait0[1]
+        cause = "none" if slot_s <= 0.0 and block_s <= 0.0 \
+            else "blocks" if block_s >= slot_s else "slot"
+        wait_s = now - req._phase.t0
+        self.queue_waits += 1
+        self.queue_wait_s_sum += wait_s
+        if block_s > 0.0:
+            self.block_waits += 1
+            self.block_wait_s_sum += block_s
+        req._phase.set(slot=slot, cause=cause, slot_wait_s=round(slot_s, 6),
+                       block_wait_s=round(block_s, 6),
+                       page_rejects=req._page_rejects)
+        mon = _monitor._active
+        if mon is not None:
+            # measured from the LAST enqueue (a preemption re-queue opens a
+            # new span), so the histogram and the span agree by construction
+            mon.serve_queue_wait(wait_s)
+        return now
 
     # ---------------------------------------------------------- scheduling
 
@@ -1030,27 +1097,39 @@ class DecodeEngine:
         reached a TERMINAL status since the last step (done / failed /
         expired / cancelled / rejected_draining — one list, one contract).
         """
+        with _trace.span("engine/step") as whole:
+            finished = self._step()
         mon = _monitor._active
-        # goodput bracket: the whole scheduler iteration; the executable
-        # calls inside classify as productive/compile, the remainder is
-        # engine host overhead — the serving timeline stays gap-free
-        sched_t0 = time.perf_counter() if mon is not None else None
+        if mon is not None:
+            # goodput bracket: the whole scheduler iteration; the executable
+            # calls inside classify as productive/compile, the remainder is
+            # engine host overhead — the serving timeline stays gap-free
+            mon.serve_sched(whole.t0, whole.t1)
+        return finished
+
+    def _step(self) -> List[Request]:
+        """The phases of one iteration, each one span; together they tile
+        ``engine/step``."""
         finished: List[Request] = []
-        if self._terminal_buf:
-            # cancel()/engine-failure terminalizations since the last step
-            finished.extend(self._terminal_buf)
-            self._terminal_buf.clear()
-        # SIGTERM wiring: the watcher recorded a signal -> begin draining
-        # at THIS step boundary (never mid-executable-call)
-        if not self._draining and self._pw is not None \
-                and self._pw.requested():
-            self.begin_drain(self._pw_grace_s)
-        now = self._clock()
-        self._expire_sweep(now, finished)
-        if self._draining:
-            self._drain_step(now, finished)
-        else:
-            self._admit_queued(finished)
+        with _trace.span("engine/sweep"):
+            if self._terminal_buf:
+                # cancel()/engine-failure terminalizations since the last
+                # step
+                finished.extend(self._terminal_buf)
+                self._terminal_buf.clear()
+            # SIGTERM wiring: the watcher recorded a signal -> begin
+            # draining at THIS step boundary (never mid-executable-call)
+            if not self._draining and self._pw is not None \
+                    and self._pw.requested():
+                self.begin_drain(self._pw_grace_s)
+            now = self._clock()
+            self._expire_sweep(now, finished)
+            if self._draining:
+                self._drain_step(now, finished)
+        if not self._draining:
+            with _trace.span("engine/admit") as adm:
+                admitted, refused = self._admit_queued(finished)
+                adm.set(admitted=admitted, refused=refused)
         if self._prefilling:
             for slot in sorted(self._prefilling,
                                key=lambda s: self._slot_seq[s]):
@@ -1062,19 +1141,17 @@ class DecodeEngine:
             # serialize freshly parked registered blocks OUT to the pool at
             # the end of the iteration — never inside the admission/decode
             # hot path — bounded per step so exports cannot stall decode
-            self._drain_pool_exports()
-            mon3 = _monitor._active
-            if mon3 is not None:
-                mon3.serve_pool(self.pool_stats(),
-                                engine_id=self.engine_id)
+            with _trace.span("engine/pool_export"):
+                self._drain_pool_exports()
+            mon = _monitor._active
+            if mon is not None:
+                mon.serve_pool(self.pool_stats(), engine_id=self.engine_id)
         if self._draining and self.drained and not self._drain_reported:
             self._drain_reported = True
             self.drains += 1
-            mon2 = _monitor._active
-            if mon2 is not None:
-                mon2.serve_drain_end(self._clock() - (self._drain_t0 or now))
-        if sched_t0 is not None and mon is _monitor._active:
-            mon.serve_sched(sched_t0, time.perf_counter())
+            mon = _monitor._active
+            if mon is not None:
+                mon.serve_drain_end(self._clock() - (self._drain_t0 or now))
         return finished
 
     def run(self, max_steps: Optional[int] = None) -> List[Request]:
@@ -1097,7 +1174,9 @@ class DecodeEngine:
         """Fold queued prompts into free slots (the admission half of
         step()). The "admit" fault site counts ATTEMPTS — a blocked
         head-of-line request retrying every step keeps counting — and an
-        injected raise fails just that request, cleanly."""
+        injected raise fails just that request, cleanly. Returns (requests
+        admitted, requests refused for want of KV blocks)."""
+        admitted = refused = 0
         while self._queue and self._slots.n_free:
             head = self._queue.peek()
             if self._faults is not None:
@@ -1109,10 +1188,17 @@ class DecodeEngine:
                     continue
             if self.paged:
                 if not self._try_admit_paged(head):
+                    refused = 1
                     break          # head-of-line waits for blocks, FIFO kept
                 self._queue.pop()
             else:
                 self._admit(self._queue.pop(), self._slots.alloc(), finished)
+            admitted += 1
+        # whoever is still queued waits, from here to the next look, for
+        # blocks (the head was refused) or for a slot (none was free)
+        self._book_wait(time.perf_counter(), None if not self._queue
+                        else "blocks" if refused else "slot")
+        return admitted, refused
 
     # ----------------------------------------------------------- guardrails
 
@@ -1139,9 +1225,7 @@ class DecodeEngine:
         self.nan_logits += 1
         mon = _monitor._active
         if mon is not None:
-            mon.serve_nan_logits(where,
-                                 trace_id=req._trace.trace_id
-                                 if req._trace is not None else None)
+            mon.serve_nan_logits(where, trace_id=req._trace.trace_id)
 
     def _retire_id(self, req: Request):
         """Dedup bookkeeping at terminalization: a tracked id moves from
@@ -1170,7 +1254,7 @@ class DecodeEngine:
         req.t_done = time.time()
         (self._terminal_buf if finished is None else finished).append(req)
         mon = _monitor._active
-        trace_id = req._trace.trace_id if req._trace is not None else None
+        trace_id = req._trace.trace_id
         if mon is not None:
             # dedicated counters, not serve/completions — the summary's
             # "completed" stays stop-condition completions, and requests
@@ -1183,12 +1267,10 @@ class DecodeEngine:
                 mon.serve_cancelled(where or "?", trace_id=trace_id)
             elif status == "rejected_draining":
                 mon.serve_request(queued=False, error=why, draining=True)
-        if req._trace is not None:
-            mono = time.perf_counter()
-            req._trace_phase(None, t0=mono)
-            req._trace.end(t1=mono, status=status, error=why,
-                           tokens=len(req.tokens),
-                           preemptions=req.preemptions)
+        mono = time.perf_counter()
+        req._trace_phase(None, t0=mono)
+        req._trace.end(t1=mono, status=status, error=why,
+                       tokens=len(req.tokens), preemptions=req.preemptions)
         if status == "expired":
             self.expired += 1
         elif status == "cancelled":
@@ -1394,7 +1476,7 @@ class DecodeEngine:
                 tr.escalate("serve_hang")
             except Exception:
                 pass
-        trace_ids = [tr.trace_id for tr in traces]
+        trace_ids = [tr.trace_id for tr in traces if tr.trace_id]
         mon = _monitor._active
         dump_path = None
         if mon is not None:
@@ -1414,28 +1496,35 @@ class DecodeEngine:
             + (f"; flight dump {dump_path}" if dump_path else ""),
             RuntimeWarning)
 
-    def _dispatch_guarded(self, kind: str, bucket, call):
+    def _dispatch_guarded(self, kind: str, bucket, spans, upload, call):
         """Run one decode/chunk dispatch under the guardrails: the chaos
         seam fires first (a ``slow`` lands inside the armed window — that
-        is how the watchdog is tested), the watchdog brackets the call +
-        host sync, and any exception or detected hang routes through
-        ``_fail_engine`` so the engine fails loudly with consistent
-        state. ``call`` must COMMIT the donated pools/caches to the engine
-        itself before returning — on the hang path the dispatch completed
-        (the old buffers are donated away), so the commit must not depend
-        on this function returning normally."""
+        is how the watchdog is tested), the watchdog brackets the uploads,
+        the call + host sync, and any exception or detected hang routes
+        through ``_fail_engine`` so the engine fails loudly with consistent
+        state. ``spans`` names two: ``upload()`` makes the executable's
+        device arguments under one more span of the host phase, and
+        ``call(*args)`` dispatches, waits and reads back under the call's
+        own, which is therefore dispatch, device run and read-back alone.
+        ``call`` must COMMIT the donated
+        pools/caches to the engine itself before returning — on the hang
+        path the dispatch completed (the old buffers are donated away), so
+        the commit must not depend on this function returning normally.
+        Returns (what ``call`` returned, the call's span)."""
         wd = self._watchdog
         if wd is not None:
-            traces = [r._trace for r in self._slot_req if r is not None
-                      and r._trace is not None]
-            traces += [st.req._trace for st in self._prefilling.values()
-                       if st.req._trace is not None]
+            traces = [r._trace for r in self._slot_req if r is not None]
+            traces += [st.req._trace for st in self._prefilling.values()]
             wd.arm(kind=kind, bucket=bucket, engine=self.engine_id,
                    traces=traces)
         try:
             if self._faults is not None:
                 self._faults.fire(kind)
-            out = call()
+            with _trace.span(spans[0]):
+                args = upload()
+            with _trace.span(spans[1]) as call_span:
+                out = call(*args)
+                del args           # released inside the span that used them
         except Exception as e:
             if wd is not None:
                 # a hang that then RAISED: the raise is the failure that
@@ -1453,7 +1542,7 @@ class DecodeEngine:
                 f"{fired.get('elapsed_s', 0):.2f}s "
                 f"(> {HANG_ENV}={wd.hang_s}s); WARN + flight dump emitted "
                 f"while it hung"))
-        return out
+        return out, call_span
 
     # ------------------------------------------------- paged scheduling
 
@@ -1634,52 +1723,43 @@ class DecodeEngine:
             self._pager.release_slot(slot)
             self._pager.restore_sharing_counters(ctrs)
             self._slots.release(slot)
+            self.page_rejects += 1
+            req._page_rejects += 1
+            pool_blocks = pool_meta["blocks"] if pool_meta else 0
             mon = _monitor._active
             if mon is not None:
-                mon.serve_page_reject(
-                    free, needed,
-                    trace_id=req._trace.trace_id
-                    if req._trace is not None else None,
-                    pool_blocks=pool_meta["blocks"] if pool_meta else 0)
-            if req._trace is not None:
-                req._trace.event("page_reject", free=int(free),
-                                 needed=int(needed),
-                                 pool_blocks=pool_meta["blocks"]
-                                 if pool_meta else 0)
-                if free >= needed:
-                    # refusal WITHOUT real pressure is the allocator-bug
-                    # signature — this trace must survive head sampling
-                    req._trace.escalate("page_reject")
+                mon.serve_page_reject(free, needed,
+                                      trace_id=req._trace.trace_id,
+                                      pool_blocks=pool_blocks)
+            req._trace.event("page_reject", free=int(free),
+                             needed=int(needed), pool_blocks=pool_blocks)
+            if free >= needed:
+                # refusal WITHOUT real pressure is the allocator-bug
+                # signature — this trace must survive head sampling
+                req._trace.escalate("page_reject")
             return False
         self._slot_seq[slot] = next(self._admit_seq)
         self._prefilling[slot] = _PrefillState(req, cov, copies)
         req.slot, req.status = slot, "prefilling"
-        mon = _monitor._active
-        if mon is not None:
-            # measured from the LAST enqueue (a preemption re-queue resets
-            # it), so the histogram and the trace's queue phase agree
-            mon.serve_queue_wait(max(time.time() - req.t_enqueue, 0.0))
-        if req._trace is not None:
-            if req._phase is not None:
-                req._phase.set(slot=slot)
-            ph = req._trace_phase("prefill", slot=slot, shared=int(cov))
-            if self._pager.last_adopt_parked:
-                # blocks revived from the persistent prefix cache: this
-                # admission's prefill compute shrank by lru_hit_tokens
-                ph.set(lru_hit_blocks=self._pager.last_adopt_parked,
-                       lru_hit_tokens=self._pager.last_adopt_parked_tokens)
-            if pool_meta is not None:
-                # TTFT attribution: the pool fetch is ITS OWN slice of the
-                # prefill phase, so a TTFT regression decomposes into
-                # fetch-bytes time vs prefill-compute time downstream
-                ph.set(pool_hit_blocks=int(pool_meta["blocks"]),
-                       pool_hit_tokens=int(pool_meta["tokens"]),
-                       pool_fetch_s=round(pool_meta["fetch_s"], 6))
-                ph.event("pool_fetch", blocks=int(pool_meta["blocks"]),
-                         tokens=int(pool_meta["tokens"]),
-                         dur_s=round(pool_meta["fetch_s"], 6))
-            if copies:
-                ph.event("cow", n=len(copies))
+        ph = req._trace_phase("prefill", t0=self._close_queue_phase(req, slot),
+                              slot=slot, prefix_hit_tokens=int(cov))
+        if self._pager.last_adopt_parked:
+            # blocks revived from the persistent prefix cache: this
+            # admission's prefill compute shrank by lru_hit_tokens
+            ph.set(lru_hit_blocks=self._pager.last_adopt_parked,
+                   lru_hit_tokens=self._pager.last_adopt_parked_tokens)
+        if pool_meta is not None:
+            # TTFT attribution: the pool fetch is ITS OWN slice of the
+            # prefill phase, so a TTFT regression decomposes into
+            # fetch-bytes time vs prefill-compute time downstream
+            ph.set(pool_hit_blocks=int(pool_meta["blocks"]),
+                   pool_hit_tokens=int(pool_meta["tokens"]),
+                   pool_fetch_s=round(pool_meta["fetch_s"], 6))
+            ph.event("pool_fetch", blocks=int(pool_meta["blocks"]),
+                     tokens=int(pool_meta["tokens"]),
+                     dur_s=round(pool_meta["fetch_s"], 6))
+        if copies:
+            ph.event("cow", n=len(copies))
         return True
 
     def _advance_prefill(self, slot: int, finished: List[Request]):
@@ -1691,41 +1771,54 @@ class DecodeEngine:
         p0 = st.done
         sc = self._chunk_len(st.n)
         end = min(p0 + sc, st.n)
-        copies, st.pending_copies = st.pending_copies, []
-        more = self._ensure_or_evict(slot, p0, end)
-        if more is None or slot not in self._prefilling:
-            return                         # this very slot was preempted
-        copies += more
-        exe = self._prefill_exes.get(sc)
-        if exe is None:
-            exe = self._build_chunk(sc)
-        ids = np.zeros((1, sc), np.int32)
-        ids[0, :end - p0] = st.prompt[p0:end]
-        src, dst = self._cow_args(copies)
-        t0 = time.time()
+        host = dict(slot=slot, tokens=end - p0)
+        with _trace.span("engine/prefill_host", **host):
+            copies, st.pending_copies = st.pending_copies, []
+            more = self._ensure_or_evict(slot, p0, end)
+            if more is None or slot not in self._prefilling:
+                return                     # this very slot was preempted
+            copies += more
+            exe = self._prefill_exes.get(sc)
+            if exe is None:
+                exe = self._build_chunk(sc)
+            ids = np.zeros((1, sc), np.int32)
+            ids[0, :end - p0] = st.prompt[p0:end]
+            src, dst = self._cow_args(copies)
 
-        def _call():
-            self._pools, picked, ok = exe(
-                self._leaf_values(), self._pools,
-                self._dev(self._pager.tables), self._dev(ids),
-                self._dev(jnp.int32(slot)), self._dev(jnp.int32(p0)),
-                self._dev(jnp.int32(end)), src, dst, self._next_key())
-            return picked, ok
+        def upload():
+            return (self._dev(self._pager.tables), self._dev(ids),
+                    self._dev(jnp.int32(slot)), self._dev(jnp.int32(p0)),
+                    self._dev(jnp.int32(end)), src, dst, self._next_key())
 
-        tok0, l_ok = self._dispatch_guarded("chunk", sc, _call)
-        chunk_s = time.time() - t0
+        def run(*args):
+            self._pools, picked, ok = exe(self._leaf_values(), self._pools,
+                                          *args)
+            # host readback inside the armed window (see _decode)
+            return picked, bool(np.asarray(ok))
+
+        (tok0, l_ok), call = self._dispatch_guarded(
+            "chunk", sc, _PREFILL_SPANS, upload, run)
+        with _trace.span("engine/prefill_host", **host):
+            self._chunk_done(st, slot, sc, end, len(copies), tok0, l_ok,
+                             call, finished)
+
+    def _chunk_done(self, st: _PrefillState, slot: int, sc: int, end: int,
+                    n_cow: int, tok0, l_ok: bool, call, finished):
+        """Host bookkeeping after one chunk's executable has returned; on
+        the final chunk, the first token and the promotion to decode."""
+        p0 = st.done
+        chunk_s = call.dur_s
         st.prefill_s += chunk_s
         mon = _monitor._active
         if mon is not None:
             mon.serve_prefill_step(chunk_s, sc, tokens=end - p0,
-                                   engine_id=self.engine_id)
+                                   engine_id=self.engine_id,
+                                   span=(call.t0, call.t1))
         st.done = end
         st.chunks += 1
-        if st.req._phase is not None:
-            st.req._phase.event("chunk", p0=int(p0), end=int(end),
-                                dur_s=round(chunk_s, 6),
-                                cow=len(copies))
-        if not bool(np.asarray(l_ok)):
+        st.req._phase.event("chunk", p0=int(p0), end=int(end),
+                            dur_s=round(chunk_s, 6), cow=n_cow)
+        if not l_ok:
             # non-finite logits: this chunk's cached K/V are garbage —
             # terminalize now instead of prefilling further (or streaming)
             req = st.req
@@ -1756,13 +1849,9 @@ class DecodeEngine:
         if mon is not None:
             mon.serve_admitted(req.t_first_token - req.t_submit, sc,
                                st.prefill_s)
-        if req._trace is not None:
-            if req._phase is not None:
-                req._phase.set(chunks=st.chunks,
-                               exe_s=round(st.prefill_s, 6))
-            req._trace_phase("decode")
-            req._trace.root.set(
-                ttft_s=round(req.t_first_token - req.t_submit, 6))
+        req._phase.set(chunks=st.chunks, exe_s=round(st.prefill_s, 6))
+        req._trace_phase("decode")
+        req._trace.set(ttft_s=round(req.t_first_token - req.t_submit, 6))
         if req._stop_hit():
             self._finish(req, finished)
 
@@ -1788,19 +1877,16 @@ class DecodeEngine:
         req.tokens = []
         req.t_first_token = None
         req.preemptions += 1
-        req.t_enqueue = time.time()
         self._queue.push_front(req)
         self.preemptions += 1
-        if req._trace is not None:
-            # requeue episode: whatever phase was running ends and a fresh
-            # queue phase opens at the same instant
-            req._trace.event("preempt", nth=req.preemptions)
-            req._trace_phase("queue", requeue=req.preemptions)
+        # requeue episode: whatever phase was running ends and a fresh
+        # queue phase opens at the same instant
+        req._trace.event("preempt", nth=req.preemptions)
+        self._open_queue_phase(req, requeue=True, nth=req.preemptions)
         mon = _monitor._active
         if mon is not None:
             mon.serve_preempted(req.preemptions,
-                                trace_id=req._trace.trace_id
-                                if req._trace is not None else None)
+                                trace_id=req._trace.trace_id)
 
     def _ensure_or_evict(self, slot: int, start: int, end: int):
         """ensure_writable with pool-pressure eviction: preempt youngest
@@ -1825,25 +1911,25 @@ class DecodeEngine:
         exe = self._prefill_exes.get(sb)
         if exe is None:
             exe = self._build_prefill(sb)
-        t0 = time.time()
-        mono0 = time.perf_counter()
         # queue wait measured DIRECTLY at slot assignment (was derived as
         # t_first_token - t_submit - dt, which charges host bookkeeping to
         # the queue and can go negative when the clocks disagree with the
-        # subtraction); clamped because t_enqueue and t0 are wall-clock
-        wait_s = max(t0 - req.t_enqueue, 0.0)
-        if req._trace is not None:
-            if req._phase is not None:
-                req._phase.set(slot=slot)
-            req._trace_phase("prefill", t0=mono0, slot=slot, bucket=sb)
-        def _call():
-            self._caches, picked, ok = exe(
-                self._leaf_values(), self._caches, jnp.asarray(ids),
-                jnp.int32(slot), jnp.int32(n), self._next_key())
-            return picked, ok
+        # subtraction)
+        req._trace_phase("prefill", t0=self._close_queue_phase(req, slot),
+                         slot=slot, bucket=sb)
+
+        def upload():
+            return (jnp.asarray(ids), jnp.int32(slot), jnp.int32(n),
+                    self._next_key())
+
+        def run(*args):
+            self._caches, picked, ok = exe(self._leaf_values(),
+                                           self._caches, *args)
+            return int(picked), bool(np.asarray(ok))
 
         try:
-            tok0, l_ok = self._dispatch_guarded("chunk", sb, _call)
+            (t, l_ok), call = self._dispatch_guarded(
+                "chunk", sb, _PREFILL_SPANS, upload, run)
         except BaseException as e:
             # the half-admitted slot is in neither _prefilling nor
             # _slot_req yet, so _fail_engine could not release it — and
@@ -1853,7 +1939,7 @@ class DecodeEngine:
                 self._terminalize(req, "failed", f"engine failed: {e}",
                                   None)
             raise
-        if not bool(np.asarray(l_ok)):
+        if not l_ok:
             # the slot never joined the decode batch; release it and fail
             # the request instead of streaming from NaN logits
             self._nan_logits(req, "prefill")
@@ -1861,8 +1947,7 @@ class DecodeEngine:
             self._terminalize(req, "failed", "non-finite logits (nan)",
                               finished, where="prefill")
             return
-        t = int(tok0)
-        dt = time.time() - t0
+        dt = call.dur_s
         req.slot, req.status = slot, "running"
         req.t_first_token = time.time()
         req.tokens.append(t)
@@ -1873,108 +1958,128 @@ class DecodeEngine:
         self._slot_req[slot] = req
         mon = _monitor._active
         if mon is not None:
-            mon.serve_queue_wait(wait_s)
             mon.serve_prefill_step(dt, sb, tokens=n,
-                                   engine_id=self.engine_id)
+                                   engine_id=self.engine_id,
+                                   span=(call.t0, call.t1))
             mon.serve_admitted(req.t_first_token - req.t_submit, sb, dt)
-        if req._trace is not None:
-            if req._phase is not None:
-                req._phase.set(exe_s=round(dt, 6))
-            req._trace_phase("decode")
-            req._trace.root.set(
-                ttft_s=round(req.t_first_token - req.t_submit, 6))
+        req._phase.set(chunks=1, exe_s=round(dt, 6))
+        req._trace_phase("decode")
+        req._trace.set(ttft_s=round(req.t_first_token - req.t_submit, 6))
         if req._stop_hit():
             self._finish(req, finished)
+
+    def _decode_tables(self):
+        """The block tables the decode executable may write through: a slot
+        that is not live (mid-prefill: it sits at pos 0 with its real row)
+        gets the trash row, or every decode step run while its prompt is
+        still being chunked would write a stale token's K/V at position 0
+        of its first — possibly shared — block."""
+        if not self._prefilling:
+            return self._pager.tables      # dead rows are trash already
+        return np.where(self._live[:, None], self._pager.tables,
+                        np.int32(TRASH_BLOCK))
 
     def _decode(self, finished: List[Request]):
         if self.drafter is not None:
             return self._decode_spec(finished)
-        exe = self._decode_exe
-        if exe is None:
-            exe = self._build_decode()
-        if self.paged:
-            # make every live slot's write target private + present. A
-            # preempted victim's pending copies are DROPPED with it — its
-            # freed blocks may be re-handed to the very slot being ensured
-            copies_by_slot = {}
-            slot = 0
-            while slot < self.max_slots:
-                if not self._live[slot]:
-                    slot += 1
-                    continue
-                p = int(self._pos[slot])
-                c = self._pager.ensure_writable(slot, p, p + 1)
-                if c is None:
-                    victim = self._youngest_victim(slot)
-                    self._preempt(victim)
-                    copies_by_slot.pop(victim, None)
-                    if victim == slot:     # self-preempted: skip this row
+        with _trace.span("engine/decode_prepare") as prep:
+            exe = self._decode_exe
+            if exe is None:
+                exe = self._build_decode()
+            n_cow = preempted = 0
+            if self.paged:
+                # make every live slot's write target private + present. A
+                # preempted victim's pending copies are DROPPED with it —
+                # its freed blocks may be re-handed to the very slot being
+                # ensured
+                copies_by_slot = {}
+                slot = 0
+                while slot < self.max_slots:
+                    if not self._live[slot]:
                         slot += 1
-                    continue               # else retry the same slot
-                copies_by_slot[slot] = c
-                slot += 1
-            if not self._live.any():       # everyone self-preempted
-                return
-            if _trace._active is not None:
+                        continue
+                    p = int(self._pos[slot])
+                    c = self._pager.ensure_writable(slot, p, p + 1)
+                    if c is None:
+                        victim = self._youngest_victim(slot)
+                        self._preempt(victim)
+                        preempted += 1
+                        copies_by_slot.pop(victim, None)
+                        if victim == slot:  # self-preempted: skip this row
+                            slot += 1
+                        continue            # else retry the same slot
+                    copies_by_slot[slot] = c
+                    slot += 1
+                if not self._live.any():    # everyone self-preempted
+                    prep.set(live=0, cow=0, preempted=preempted)
+                    return
                 for s, c in copies_by_slot.items():
-                    r2 = self._slot_req[s]
-                    if c and r2 is not None and r2._phase is not None:
-                        r2._phase.event("cow", n=len(c))
-            src, dst = self._cow_args(
-                [p for c in copies_by_slot.values() for p in c])
-            t0 = time.time()
+                    if c:
+                        n_cow += len(c)
+                        self._slot_req[s]._phase.event("cow", n=len(c))
+                src, dst = self._cow_args(
+                    [p for c in copies_by_slot.values() for p in c])
+            prep.set(live=self.live_count, cow=n_cow, preempted=preempted)
+        if self.paged:
+            def upload():
+                return (self._dev(self._decode_tables()),
+                        self._dev(self._tok), self._dev(self._pos), src,
+                        dst, self._next_key())
 
-            def _call():
-                self._pools, picked, ok = exe(
-                    self._leaf_values(), self._pools,
-                    self._dev(self._pager.tables), self._dev(self._tok),
-                    self._dev(self._pos), src, dst, self._next_key())
+            def run(*args):
+                self._pools, picked, ok = exe(self._leaf_values(),
+                                              self._pools, *args)
                 # host readback inside the armed window: a hang in the
                 # device sync is a hang in the dispatch
                 return np.asarray(picked), np.asarray(ok)
         else:
-            t0 = time.time()
+            def upload():
+                return (jnp.asarray(self._tok), jnp.asarray(self._pos),
+                        self._next_key())
 
-            def _call():
-                self._caches, picked, ok = exe(
-                    self._leaf_values(), self._caches,
-                    jnp.asarray(self._tok), jnp.asarray(self._pos),
-                    self._next_key())
+            def run(*args):
+                self._caches, picked, ok = exe(self._leaf_values(),
+                                               self._caches, *args)
                 return np.asarray(picked), np.asarray(ok)
 
-        nxt, l_ok = self._dispatch_guarded("decode", None, _call)
-        dt = time.time() - t0
-        live = 0
-        for slot in range(self.max_slots):
-            req = self._slot_req[slot]
-            if req is None:
-                continue
-            live += 1
-            if not bool(l_ok[slot]):
-                # this slot's logits went non-finite: fail ITS request and
-                # free the slot; the rest of the batch streams on untouched
-                self._nan_logits(req, "decode")
-                self._release_slot_state(slot)
-                self._terminalize(req, "failed", "non-finite logits (nan)",
-                                  finished, where="decode")
-                continue
-            t = int(nxt[slot])
-            req.tokens.append(t)
-            self.tokens_generated += 1
-            self._pos[slot] += 1
-            self._tok[slot] = t
-            if req._phase is not None:
-                req._phase.event("decode_step", dur_s=round(dt, 6))
-            if req._stop_hit():
-                self._finish(req, finished)
-        self.decode_steps += 1
-        mon = _monitor._active
-        if mon is not None:
-            mon.serve_step(dt, live, len(self._queue),
-                           engine_id=self.engine_id)
-            if self.paged:
-                mon.serve_paged(self._pager.stats(), self.kv_util(),
-                                engine_id=self.engine_id)
+        (nxt, l_ok), call = self._dispatch_guarded(
+            "decode", None, _DECODE_SPANS, upload, run)
+        with _trace.span("engine/decode_finish") as fin:
+            live = n_tok = n_done = 0
+            for slot in range(self.max_slots):
+                req = self._slot_req[slot]
+                if req is None:
+                    continue
+                live += 1
+                if not bool(l_ok[slot]):
+                    # this slot's logits went non-finite: fail ITS request
+                    # and free the slot; the rest of the batch streams on
+                    # untouched
+                    self._nan_logits(req, "decode")
+                    self._release_slot_state(slot)
+                    self._terminalize(req, "failed",
+                                      "non-finite logits (nan)", finished,
+                                      where="decode")
+                    continue
+                t = int(nxt[slot])
+                req.tokens.append(t)
+                self.tokens_generated += 1
+                n_tok += 1
+                self._pos[slot] += 1
+                self._tok[slot] = t
+                if req._stop_hit():
+                    self._finish(req, finished)
+                    n_done += 1
+            self.decode_steps += 1
+            fin.set(tokens=n_tok, finished=n_done)
+            mon = _monitor._active
+            if mon is not None:
+                mon.serve_step(call.dur_s, live, len(self._queue),
+                               engine_id=self.engine_id,
+                               span=(call.t0, call.t1))
+                if self.paged:
+                    mon.serve_paged(self._pager.stats(), self.kv_util(),
+                                    engine_id=self.engine_id)
 
     def _decode_spec(self, finished: List[Request]):
         """Speculative decode step: per live slot, draft up to
@@ -2005,91 +2110,96 @@ class DecodeEngine:
                 continue
             req = self._slot_req[slot]
             p = int(self._pos[slot])
-            copies = self._ensure_or_evict(slot, p, p + 1)
-            if copies is None or not self._live[slot]:
-                continue                   # self-preempted: skip this slot
-            stepped = True
-            remaining = req.max_new_tokens - len(req.tokens)
-            k_cap = max(0, min(vw - 1, remaining - 1,
-                               self.max_len - 1 - p))
-            drafts = []
-            if k_cap > 0:
-                drafts = [int(t) for t in drafter.propose(req, k_cap)]
-                drafts = drafts[:k_cap]
-            reservation = []
-            if drafts:
-                cov_end, rcopies, reservation = \
-                    self._pager.reserve_speculative(slot, p + 1,
-                                                    p + 1 + len(drafts))
-                drafts = drafts[:max(0, cov_end - (p + 1))]
-                copies = copies + rcopies
-            k = len(drafts)
-            ids = np.zeros((1, vw), np.int32)
-            ids[0, 0] = self._tok[slot]
-            if k:
-                ids[0, 1:1 + k] = drafts
-            end = p + 1 + k
-            src, dst = self._cow_args(copies)
-            t0 = time.time()
+            with _trace.span("engine/decode_prepare", slot=slot) as prep:
+                copies = self._ensure_or_evict(slot, p, p + 1)
+                if copies is None or not self._live[slot]:
+                    continue               # self-preempted: skip this slot
+                stepped = True
+                remaining = req.max_new_tokens - len(req.tokens)
+                k_cap = max(0, min(vw - 1, remaining - 1,
+                                   self.max_len - 1 - p))
+                drafts = []
+                if k_cap > 0:
+                    drafts = [int(t) for t in drafter.propose(req, k_cap)]
+                    drafts = drafts[:k_cap]
+                reservation = []
+                if drafts:
+                    cov_end, rcopies, reservation = \
+                        self._pager.reserve_speculative(slot, p + 1,
+                                                        p + 1 + len(drafts))
+                    drafts = drafts[:max(0, cov_end - (p + 1))]
+                    copies = copies + rcopies
+                k = len(drafts)
+                ids = np.zeros((1, vw), np.int32)
+                ids[0, 0] = self._tok[slot]
+                if k:
+                    ids[0, 1:1 + k] = drafts
+                end = p + 1 + k
+                src, dst = self._cow_args(copies)
+                prep.set(drafted=k, cow=len(copies))
 
-            def _call():
-                self._pools, picked, ok = exe(
-                    self._leaf_values(), self._pools,
-                    self._dev(self._pager.tables), self._dev(ids),
-                    self._dev(jnp.int32(slot)), self._dev(jnp.int32(p)),
-                    self._dev(jnp.int32(end)), src, dst, self._next_key())
+            def upload():
+                return (self._dev(self._pager.tables), self._dev(ids),
+                        self._dev(jnp.int32(slot)), self._dev(jnp.int32(p)),
+                        self._dev(jnp.int32(end)), src, dst,
+                        self._next_key())
+
+            def run(*args):
+                self._pools, picked, ok = exe(self._leaf_values(),
+                                              self._pools, *args)
                 # host readback inside the armed window (see _decode)
                 return np.asarray(picked), np.asarray(ok)
 
             # on dispatch failure _fail_engine terminalizes every tenant
             # and releases the pager state — the reservation dies with it
-            out, l_ok = self._dispatch_guarded("verify", vw, _call)
-            dt = time.time() - t0
-            if not bool(l_ok):
-                # a NaN anywhere in the verify window poisons the accept
-                # test: fail the request (release_slot frees the
-                # speculative reservation with the rest of its blocks)
-                self._nan_logits(req, "verify")
-                self._release_slot_state(slot)
-                self._terminalize(req, "failed", "non-finite logits (nan)",
-                                  finished, where="verify")
-                continue
-            a = 0
-            while a < k and int(out[a]) == drafts[a]:
-                a += 1
-            n_emit = 0
-            for t in drafts[:a] + [int(out[a])]:
-                req.tokens.append(int(t))
-                self.tokens_generated += 1
-                n_emit += 1
+            (out, l_ok), call = self._dispatch_guarded(
+                "verify", vw, _DECODE_SPANS, upload, run)
+            with _trace.span("engine/decode_finish", slot=slot) as fin:
+                if not bool(l_ok):
+                    # a NaN anywhere in the verify window poisons the
+                    # accept test: fail the request (release_slot frees the
+                    # speculative reservation with the rest of its blocks)
+                    self._nan_logits(req, "verify")
+                    self._release_slot_state(slot)
+                    self._terminalize(req, "failed",
+                                      "non-finite logits (nan)", finished,
+                                      where="verify")
+                    continue
+                a = 0
+                while a < k and int(out[a]) == drafts[a]:
+                    a += 1
+                n_emit = 0
+                for t in drafts[:a] + [int(out[a])]:
+                    req.tokens.append(int(t))
+                    self.tokens_generated += 1
+                    n_emit += 1
+                    if req._stop_hit():
+                        break
+                self._pos[slot] = p + n_emit
+                self._tok[slot] = req.tokens[-1]
+                if reservation:
+                    self._pager.rollback_speculative(slot, p + n_emit,
+                                                     reservation)
+                req.spec_drafted += k
+                req.spec_accepted += a
+                self.spec_steps += 1
+                self.spec_drafted += k
+                self.spec_accepted += a
+                self.spec_emitted += n_emit
+                drafter.observe(req, a, k)
+                fin.set(tokens=n_emit, accepted=a)
+                mon = _monitor._active
+                if mon is not None:
+                    mon.serve_spec_step(
+                        call.dur_s, k, a, n_emit, vw, drafter.name,
+                        live=self.live_count, queue_depth=len(self._queue),
+                        accepted_per_step=self.spec_emitted
+                        / self.spec_steps,
+                        hit_rate=(self.spec_accepted / self.spec_drafted
+                                  if self.spec_drafted else 0.0),
+                        engine_id=self.engine_id, span=(call.t0, call.t1))
                 if req._stop_hit():
-                    break
-            self._pos[slot] = p + n_emit
-            self._tok[slot] = req.tokens[-1]
-            if reservation:
-                self._pager.rollback_speculative(slot, p + n_emit,
-                                                 reservation)
-            req.spec_drafted += k
-            req.spec_accepted += a
-            self.spec_steps += 1
-            self.spec_drafted += k
-            self.spec_accepted += a
-            self.spec_emitted += n_emit
-            drafter.observe(req, a, k)
-            if req._phase is not None:
-                req._phase.event("spec_step", drafted=k, accepted=a,
-                                 emitted=n_emit, dur_s=round(dt, 6))
-            mon = _monitor._active
-            if mon is not None:
-                mon.serve_spec_step(
-                    dt, k, a, n_emit, vw, drafter.name,
-                    live=self.live_count, queue_depth=len(self._queue),
-                    accepted_per_step=self.spec_emitted / self.spec_steps,
-                    hit_rate=(self.spec_accepted / self.spec_drafted
-                              if self.spec_drafted else 0.0),
-                    engine_id=self.engine_id)
-            if req._stop_hit():
-                self._finish(req, finished)
+                    self._finish(req, finished)
         if not stepped:
             return
         self.decode_steps += 1
@@ -2111,15 +2221,17 @@ class DecodeEngine:
             if self.drafter is not None and req.spec_drafted:
                 mon.serve_spec(self.drafter.name, req.spec_drafted,
                                req.spec_accepted, len(req.tokens),
-                               trace_id=req._trace.trace_id
-                               if req._trace is not None else None)
-        if req._trace is not None:
-            mono = time.perf_counter()
-            if req._phase is not None:
-                req._phase.set(tokens=len(req.tokens))
-            req._trace_phase(None, t0=mono)
-            req._trace.end(t1=mono, status="done", tokens=len(req.tokens),
-                           preemptions=req.preemptions)
+                               trace_id=req._trace.trace_id)
+        # the decode span runs from the first token to the last: both
+        # instants (seconds since submit) and the count, once
+        mono = time.perf_counter()
+        born = req._trace.t0
+        req._phase.set(tokens=len(req.tokens),
+                       first_token_s=round(req._phase.t0 - born, 6),
+                       last_token_s=round(mono - born, 6))
+        req._trace_phase(None, t0=mono)
+        req._trace.end(t1=mono, status="done", tokens=len(req.tokens),
+                       preemptions=req.preemptions)
 
     # ------------------------------------------------------------- insight
 
@@ -2201,6 +2313,14 @@ class DecodeEngine:
             "live_slots": self.live_count,
             "queue_depth": self.queue_depth,
             "kv_util": round(self.kv_util(), 4),
+            # admissions and the seconds they queued; of those, the ones
+            # that waited for KV blocks (the pool, not the slots, was
+            # short) and for how long; refusals of the head for blocks
+            "queue_waits": self.queue_waits,
+            "queue_wait_s_sum": round(self.queue_wait_s_sum, 6),
+            "block_waits": self.block_waits,
+            "block_wait_s_sum": round(self.block_wait_s_sum, 6),
+            "page_rejects": self.page_rejects,
             "guardrails": {
                 "expired": self.expired,
                 "cancelled": self.cancelled,

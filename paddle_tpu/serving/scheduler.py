@@ -86,16 +86,16 @@ class Request:
         # served from parked blocks prefills only the uncovered remainder
         self.prefill_chunks = 0
         self.t_submit = time.time()
-        # when the request last entered the queue: t_submit at first, reset
-        # on a preemption re-queue — serve/queue_wait_s measures from HERE,
-        # so a preempted request's second wait doesn't absorb its first run
-        self.t_enqueue = self.t_submit
         self.t_first_token: Optional[float] = None
         self.t_done: Optional[float] = None
-        # span-tracer state (monitor/trace.py): the request's trace and its
-        # currently open phase span; None when tracing is off
+        # span state (monitor/trace.py): the request's trace and its open
+        # phase span (the engine opens both at submit); where the engine's
+        # two queue-wait clocks stood when it was last enqueued, and how
+        # often it was refused for KV blocks since
         self._trace = None
         self._phase = None
+        self._wait0 = (0.0, 0.0)
+        self._page_rejects = 0
 
     def _trace_phase(self, name: Optional[str], t0: Optional[float] = None,
                      **attrs):

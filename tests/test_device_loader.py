@@ -162,9 +162,9 @@ def test_profiler_attributes_feed_stages():
         for _ in DeviceLoader(dl, prefetch_depth=2):
             pass
     kinds = {(e.kind, e.name) for e in p.events}
-    assert ("stage", "device_loader/wait") in kinds
-    assert ("stage", "device_loader/h2d") in kinds
-    assert ("stage", "device_loader/fetch") in kinds
+    assert ("stage", "loader/wait") in kinds
+    assert ("stage", "loader/h2d") in kinds
+    assert ("stage", "loader/fetch") in kinds
 
 
 def test_namedtuple_batches_preserved():

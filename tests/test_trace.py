@@ -87,8 +87,9 @@ def _spans_by_trace(path):
 
 
 def test_span_schema_parents_and_ring(tmp_path):
-    t = trace.enable(str(tmp_path / "t.jsonl"), sample=1.0, ring=4)
-    tr = t.start_trace("unit", kind="step", step=7)
+    t = trace.enable(str(tmp_path / "t.jsonl"), sample=1.0)
+    t_before = time.perf_counter()
+    tr = t.start_trace("unit", kind="step", step=7, key=7)
     child = tr.span("phase_a")
     child.event("tick", n=1)
     child.end()
@@ -108,9 +109,147 @@ def test_span_schema_parents_and_ring(tmp_path):
     assert all(s["dur_s"] >= 0 for s in spans)
     summary = [r for r in recs if r["kind"] == "trace"]
     assert summary and summary[0]["spans"] == 3
-    # ring is bounded and keeps monotonic times for the profiler merge
-    assert len(t.ring) <= 4
-    assert all("_t0" in s and "_t1" in s for s in t.ring)
+    # the same spans lie in the process-wide ring under their full names,
+    # each with its parent's id and the id the whole trace shares
+    got = trace.spans(t_before, time.perf_counter() + 1.0, prefix="unit/")
+    assert [g.name for g in got] == ["unit/phase_a", "unit/phase_b",
+                                     "unit/call"]
+    root_rec = got[-1]
+    assert root_rec.parent_id is None and root_rec.attrs["step"] == 7
+    assert all(g.parent_id == root_rec.span_id for g in got[:-1])
+    assert all(g.trace_id == 7 for g in got)
+    assert all(g.t1 >= g.t0 for g in got)
+
+
+def test_ring_is_bounded_and_spans_selects_the_window():
+    """The flight recorder: on with nothing enabled, bounded, and
+    ``spans(t0, t1)`` returns exactly the spans wholly inside."""
+    assert not trace.enabled()
+    with trace.span("unit/before"):
+        pass
+    t0 = time.perf_counter()
+    with trace.span("unit/outer", n=1) as outer:
+        with trace.span("unit/inner") as inner:
+            pass
+        trace.record("unit/timed", inner.t0, inner.t1, k="v")
+    t1 = time.perf_counter()
+    straddles = trace.span("unit/straddles").__enter__()
+    t1 = min(t1, straddles.t0)
+    straddles.__exit__(None, None, None)
+    got = trace.spans(t0, t1, prefix="unit/")
+    assert [g.name for g in got] == ["unit/inner", "unit/timed",
+                                     "unit/outer"]
+    by = {g.name: g for g in got}
+    assert by["unit/outer"].attrs == {"n": 1}
+    assert by["unit/outer"].parent_id is None
+    assert by["unit/inner"].parent_id == by["unit/outer"].span_id
+    assert by["unit/timed"].parent_id == by["unit/outer"].span_id
+    assert by["unit/timed"].attrs == {"k": "v"}
+    assert (by["unit/inner"].t0, by["unit/inner"].t1) == (inner.t0, inner.t1)
+    assert trace.spans(t0, t1, prefix="engine/") == []
+    # bounded: flooding it keeps the newest RING_CAPACITY spans
+    for _ in range(trace.RING_CAPACITY + 10):
+        trace.record("unit/flood", 0.0, 0.0)
+    everything = trace.spans(float("-inf"), float("inf"))
+    assert len(everything) == trace.RING_CAPACITY
+    assert {g.name for g in everything} == {"unit/flood"}
+
+
+def test_ring_and_parent_links_hold_under_threads():
+    """The ring is the one thing threads share (the loader's producer
+    records beside the training loop): every thread's spans arrive, each
+    inner span under ITS thread's outer one, while a reader copies the
+    ring all along."""
+    import sys
+    n_threads, n_each = 8, 300
+    t0 = time.perf_counter()
+    stop = threading.Event()
+    seen_by_reader = []
+
+    def reader():
+        while not stop.is_set():
+            seen_by_reader.append(len(trace.spans(t0, float("inf"),
+                                                  "stress/")))
+
+    def worker(k):
+        for i in range(n_each):
+            with trace.span(f"stress/outer{k}", i=i):
+                with trace.span(f"stress/inner{k}", i=i):
+                    pass
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        rd = threading.Thread(target=reader, daemon=True)
+        rd.start()
+        ws = [threading.Thread(target=worker, args=(k,), daemon=True)
+              for k in range(n_threads)]
+        for w in ws:
+            w.start()
+        for w in ws:
+            w.join(timeout=60)
+        stop.set()
+        rd.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not rd.is_alive() and not any(w.is_alive() for w in ws)
+    got = trace.spans(t0, time.perf_counter(), "stress/")
+    assert len(got) == 2 * n_threads * n_each
+    assert len({g.span_id for g in got}) == len(got)
+    outer = {g.span_id: g for g in got if g.name.startswith("stress/outer")}
+    for g in got:
+        if g.name.startswith("stress/inner"):
+            up = outer[g.parent_id]
+            assert up.name == "stress/outer" + g.name[len("stress/inner"):]
+            assert up.attrs["i"] == g.attrs["i"]
+            assert up.t0 <= g.t0 and g.t1 <= up.t1
+    assert seen_by_reader and seen_by_reader[-1] <= len(got)
+
+
+def test_ring_off_makes_a_site_record_nothing():
+    t0 = time.perf_counter()
+    trace.ring(False)
+    try:
+        with trace.span("unit/dark") as sp:
+            pass
+        trace.record("unit/dark_timed", sp.t0, sp.t1)
+    finally:
+        trace.ring(True)
+    with trace.span("unit/lit"):
+        pass
+    assert sp.t1 >= sp.t0          # the span is still timed for its caller
+    assert [g.name for g in trace.spans(t0, time.perf_counter(), "unit/")] \
+        == ["unit/lit"]
+
+
+def test_spans_lie_in_a_plain_jax_profile(engine, tmp_path):
+    """Every scoped span is also a TraceAnnotation: a profile taken with
+    plain jax.profiler.start_trace round engine.step() holds the engine's
+    phases in its host plane."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    r = engine.submit([4, 5, 6, 7], max_new_tokens=3)
+    jax.profiler.start_trace(str(tmp_path / "prof"))
+    try:
+        engine.run(max_steps=50)
+    finally:
+        jax.profiler.stop_trace()
+    assert r.status == "done"
+    paths = glob.glob(str(tmp_path / "prof" / "**" / "*.xplane.pb"),
+                      recursive=True)
+    assert paths, "the profiler wrote no .xplane.pb"
+    names = set()
+    for plane in ProfileData.from_file(paths[0]).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                names.update(e.name for e in line.events
+                             if e.name.startswith("paddle/"))
+    assert "paddle/engine/decode_call" in names
+    assert {"paddle/engine/step", "paddle/engine/admit",
+            "paddle/engine/prefill_call",
+            "paddle/engine/decode_finish"} <= names
 
 
 def test_head_sampling_deterministic_and_escalation(tmp_path):
@@ -237,7 +376,7 @@ def test_request_reject_and_overload_traces(engine, tmp_path):
     monitor.disable()
 
 
-def test_serving_decode_span_carries_steps_and_cow(engine, tmp_path):
+def test_serving_decode_span_carries_tokens_and_cow(engine, tmp_path):
     monitor.enable(str(tmp_path / "d.jsonl"), trace=True)
     shared = list(range(2, 15))
     a = engine.submit(shared, max_new_tokens=3)
@@ -249,12 +388,19 @@ def test_serving_decode_span_carries_steps_and_cow(engine, tmp_path):
     t.flush()
     spans = _spans_by_trace(t.path)
     dec = [s for s in spans[a._trace.trace_id] if s["name"] == "decode"][0]
+    # the decode span says once what a per-token event said 256 times at
+    # most: how many tokens, and when the first and the last one came
+    # (seconds since submit), i.e. it runs from the one to the other
     assert dec["attrs"]["tokens"] == 3
-    assert sum(1 for e in dec["events"]
-               if e["name"] == "decode_step") >= 2
+    first, last = dec["attrs"]["first_token_s"], dec["attrs"]["last_token_s"]
+    assert 0.0 < first < last
+    assert abs((last - first) - dec["dur_s"]) < 1e-4
+    assert abs(first - (a.t_first_token - a.t_submit)) < 0.02
+    assert not any(e["name"] == "decode_step"
+                   for e in dec.get("events") or [])
     b_spans = spans[b._trace.trace_id]
     pre = [s for s in b_spans if s["name"] == "prefill"][0]
-    assert pre["attrs"]["shared"] > 0
+    assert pre["attrs"]["prefix_hit_tokens"] > 0
     has_cow = any(e["name"] == "cow"
                   for s in b_spans for e in s.get("events") or [])
     assert has_cow, "COW batch never landed as a span event"
@@ -301,7 +447,7 @@ def test_train_step_trace_spans_and_zero_recompile(tmp_path):
     for tid, s in steps.items():
         if tid != first:
             assert [p["name"] for p in s if p["parent"] is not None] \
-                == ["dispatch"]
+                == ["prepare", "dispatch"]
             d = [p for p in s if p["name"] == "dispatch"][0]
             assert d["attrs"]["path"] == "aot" and d["attrs"]["bucket"] == 1
     # the recompile sentinel event carries the step's trace id
