@@ -9,7 +9,8 @@ positions, bf16 params with fp32 masters), on random weights from a seed:
   1. device gate   versions + device; anything but a TPU exits non-zero
   2. trainer       Dataset -> DataLoader -> io.DeviceLoader -> jit.TrainStep
                    (AdamW, multi_precision) at B=16, S=1024
-  3. kernel        flash_pair_packed compiled by Mosaic vs plain jax.numpy
+  3. kernel        flash_pair_packed and paged_decode_attention compiled by
+                   Mosaic vs plain jax.numpy
                    causal attention, forward and d(qkv), at the bench shape
   4. server        serving.DecodeEngine(paged, chunked prefill) answering 8
                    staggered requests that share a 64-token prefix
@@ -48,7 +49,9 @@ FULL = dict(
     prefix=64,
     prompt_lens=(80, 128, 192, 256, 320, 384, 448, 512),
     new_tokens=(32, 48, 64, 80, 96, 112, 128, 40),
-    kernel_shape=(2, 1024, 8, 128))
+    kernel_shape=(2, 1024, 8, 128),
+    # GPT-3 XL's decode step: slots, heads, head width, block, table width
+    paged_shape=(32, 16, 128, 16, 128))
 # same code, toy sizes: what --rehearse-cpu runs (widths keep head_dim 128
 # and S >= 128 so the model still takes the packed-qkv Pallas branch)
 REHEARSAL = dict(
@@ -59,7 +62,8 @@ REHEARSAL = dict(
     prefix=32,
     prompt_lens=(40, 56, 72, 96),
     new_tokens=(8, 12, 16, 10),
-    kernel_shape=(1, 256, 2, 128))
+    kernel_shape=(1, 256, 2, 128),
+    paged_shape=(4, 2, 128, 16, 8))
 
 BF16_ULP = 2.0 ** -8        # relative spacing of bfloat16
 
@@ -250,7 +254,58 @@ def phase_kernel(jax, size, on_tpu):
             f"ulp of max|ref| {scale:.3g})")
         assert math.isfinite(err) and err <= tol, (name, err, tol)
         obs[name] = {"max_abs_err": err, "tol": tol}
+    obs["paged_decode"] = check_paged_decode(jax, size, on_tpu)
     return obs
+
+
+def check_paged_decode(jax, size, on_tpu):
+    """The paged decode kernel (Mosaic on the chip) against plain jax.numpy
+    over the same pools: every slot's whole table gathered, float32 scores
+    masked past the slot's length, softmax, context."""
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.kernels.pallas.paged_decode import paged_decode_attention
+
+    b, nh, hd, bs, mbs = size["paged_shape"]
+    nb = b * mbs // 4
+    keys = jax.random.split(jax.random.PRNGKey(SEED + 4), 3)
+    q = jax.random.normal(keys[0], (b, 1, nh, hd),
+                          jnp.float32).astype(jnp.bfloat16)
+    pool_k, pool_v = (jax.random.normal(k, (nb, bs, nh, hd), jnp.float32)
+                      .astype(jnp.bfloat16) for k in keys[1:])
+    rng = np.random.RandomState(SEED + 4)
+    table = jnp.asarray(rng.randint(1, nb, (b, mbs)), jnp.int32)
+    top = mbs * bs
+    lengths = rng.randint(1, top + 1, b)
+    lengths[:4] = (1, bs - 1, bs + 1, top)      # the edges, always
+    lengths = jnp.asarray(lengths, jnp.int32)
+
+    def reference(q, pool_k, pool_v):
+        with jax.default_matmul_precision("highest"):
+            k, v = (jnp.take(p, table, axis=0).reshape(b, top, nh, hd)
+                    .astype(jnp.float32) for p in (pool_k, pool_v))
+            s = jnp.einsum("bqnd,bknd->bnqk", q.astype(jnp.float32),
+                           k) / math.sqrt(hd)
+            live = jnp.arange(top)[None, None, None, :] \
+                < lengths[:, None, None, None]
+            p = jax.nn.softmax(jnp.where(live, s, -1e30), axis=-1)
+            return jnp.einsum("bnqk,bknd->bqnd", p, v)
+
+    def kernel(q, pool_k, pool_v):
+        return paged_decode_attention(q, pool_k, pool_v, table, lengths,
+                                      interpret=not on_tpu)
+
+    got = jax.jit(kernel)(q, pool_k, pool_v).astype(jnp.float32)
+    want = jax.jit(reference)(q, pool_k, pool_v)
+    scale = float(jnp.max(jnp.abs(want)))
+    err = float(jnp.max(jnp.abs(got - want)))
+    # a bf16 output of float32 accumulation: its own rounding, half an ulp
+    # of the largest magnitude; a wrong block, mask or head is O(scale)
+    tol = 2 * BF16_ULP * scale
+    say(f"kernel: paged_decode {tuple(size['paged_shape'])} max|err| "
+        f"{err:.4g} (tol {tol:.4g} = 2 bf16 ulp of max|ref| {scale:.3g})")
+    assert math.isfinite(err) and err <= tol, ("paged_decode", err, tol)
+    return {"max_abs_err": err, "tol": tol}
 
 
 def phase_server(jax, paddle, model, size, n_dev, on_tpu):
@@ -325,6 +380,12 @@ def phase_server(jax, paddle, model, size, n_dev, on_tpu):
             f"request {r.id}: first token {r.tokens[0]} scores {deficit:.4f}" \
             f" below the reference max {top:.4f} (margin {margin:.4f})"
     assert engine.nan_logits == 0, engine.nan_logits
+    # what the decode executable attended with: the Pallas kernel on one
+    # chip, the gathered view under tensor parallelism (pools sharded)
+    attention = engine.stats()["decode_attention"]
+    say(f"server: decode_attention {attention}")
+    assert attention == ("paged_kernel" if n_dev == 1 else "gather"), \
+        attention
     assert pager.prefix_hits >= 1, pager.prefix_hits
     assert engine.compile_count == warm_mints, \
         f"steady-state recompiles: {engine.compile_count - warm_mints}"
@@ -351,6 +412,7 @@ def phase_server(jax, paddle, model, size, n_dev, on_tpu):
            "warm_mints": warm_mints, "warmup_s": warm_s, "serve_s": serve_s,
            "prefix_hits": int(pager.prefix_hits),
            "shared_hits": int(pager.shared_hits),
+           "decode_attention": attention,
            "steady_state_recompiles": 0, "first_token_worst_margin": worst,
            "hbm_in_use_peak": mem, "shards": evidence}
     engine.close()
@@ -423,6 +485,10 @@ def main(argv=None):
             seq_len >= 128 and head_dim >= 64
         flash_pair.flash_pair_packed = functools.partial(
             flash_pair.flash_pair_packed, interpret=True)
+        # and the decode step takes the paged kernel as it does on one chip
+        from paddle_tpu.kernels.pallas import paged_decode
+        seam = paged_decode.force_interpret()    # held to the end of main
+        seam.__enter__()
 
     phases = {}
     say(f"--- trainer ({n_dev} chip(s))")

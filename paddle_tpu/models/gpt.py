@@ -213,47 +213,51 @@ def gpt_tiny(**overrides) -> "GPTConfig":
 
 
 # Tensor-parallel serving context (serving/engine.py sets it around its
-# executable traces): a NamedSharding pinning the KV pools — and, when
-# ``constrain_view`` is on, the gathered per-row views (same rank-4 axis
-# order) — to the device mesh, usually head-sharded P(None, None, "model",
-# None). The constraint keeps the block-axis scatter/gather SHARD-LOCAL on
-# the head axis: block indices are replicated data, so each device
-# scatters and gathers only its own n_kv (or hd) shard and no resharding
-# ever lands inside the decode step. The view constraint is only applied
-# for HEAD-axis sharding: per-head attention consumes it layout-unchanged
-# there, while pinning an hd-sharded view fights GQA attention's preferred
-# layout and forces XLA into full rematerializations.
-_PAGED_KV_SHARD = {"sharding": None, "constrain_view": True}
+# executable traces): the NamedSharding the KV pools live under on the
+# device mesh and, with ``constrain``, a pin of it mid-graph on the updated
+# pools and on the gathered per-row views (same rank-4 axis order). The
+# constraint keeps the block-axis scatter/gather SHARD-LOCAL on the head
+# axis: block indices are replicated data, so each device scatters and
+# gathers only its own n_kv shard and no resharding ever lands inside the
+# decode step. The engine asks for it only under HEAD-axis sharding
+# P(None, None, "model", None): per-head attention consumes that layout
+# unchanged, while pinning an hd-sharded pool or view fights GQA attention's
+# preferred layout and forces XLA into full rematerializations. Any
+# sharding at all keeps the decode step off the single-chip Pallas kernel
+# (``_paged_decode_attend``).
+_PAGED_KV_SHARD = {"sharding": None, "constrain": True}
 
 
-def set_paged_kv_sharding(sharding, constrain_view=True):
-    """Install (or clear, with None) the paged-pool sharding constraint.
-    Returns the previous (sharding, constrain_view) pair so callers can
-    restore it (try/finally)."""
-    prev = (_PAGED_KV_SHARD["sharding"], _PAGED_KV_SHARD["constrain_view"])
+def set_paged_kv_sharding(sharding, constrain=True):
+    """Install (or clear, with None) the paged-pool sharding context.
+    Returns the previous (sharding, constrain) pair so callers can restore
+    it (try/finally)."""
+    prev = (_PAGED_KV_SHARD["sharding"], _PAGED_KV_SHARD["constrain"])
     _PAGED_KV_SHARD["sharding"] = sharding
-    _PAGED_KV_SHARD["constrain_view"] = bool(constrain_view)
+    _PAGED_KV_SHARD["constrain"] = bool(constrain)
     return prev
 
 
-def _paged_kv_update(kv_cache, k, v):
-    """Paged-cache write + gather, shared by GPT and LLaMA cached attention.
+def _paged_kv_pin():
+    """The sharding to pin pools and views to mid-graph, or None."""
+    return _PAGED_KV_SHARD["sharding"] if _PAGED_KV_SHARD["constrain"] \
+        else None
+
+
+def _paged_kv_write(kv_cache, k, v):
+    """Paged-cache write, shared by GPT and LLaMA cached attention.
 
     ``kv_cache`` is ``(pool_k, pool_v, table, pos, write_end)``: per-layer
     [NB, BS, n_kv, hd] pools, a [B, mbs] int32 block table, the write
     cursor(s) and the exclusive end of VALID new positions. ``k``/``v`` are
     this call's fresh projections, [B, S, n_kv, hd].
 
-    Writes scatter each position to ``(table[b, p // BS], p % BS)``;
-    positions >= write_end (padded chunk tails) or beyond the table width
-    redirect to trash block 0, so padding can never corrupt a live or
-    shared block. Reads gather every row's blocks back into a contiguous
-    [B, mbs*BS, n_kv, hd] view with ``jnp.take`` on the block axis — the
-    caller's causal mask (key position <= query position) hides the stale
-    tail exactly as it does for the contiguous layout. Under a tensor-
-    parallel mesh (``set_paged_kv_sharding``) both the updated pools and
-    the gathered views are constrained to the head-sharded placement, so
-    the scatter and the gather stay shard-local on the head axis.
+    Scatters each position to ``(table[b, p // BS], p % BS)``; positions >=
+    write_end (padded chunk tails) or beyond the table width redirect to
+    trash block 0, so padding can never corrupt a live or shared block.
+    Under a tensor-parallel mesh (``set_paged_kv_sharding``) the updated
+    pools are constrained to the head-sharded placement, so the scatter
+    stays shard-local on the head axis. Returns the updated pools.
     """
     pool_k, pool_v, table, pos, write_end = kv_cache
     b, s = k.shape[:2]
@@ -266,7 +270,7 @@ def _paged_kv_update(kv_cache, k, v):
         wpos = (pos + jnp.arange(s, dtype=jnp.int32))[None, :]
         wpos = jnp.broadcast_to(wpos, (b, s))
         end = jnp.broadcast_to(jnp.asarray(write_end)[None, None], (b, 1))
-    shard = _PAGED_KV_SHARD["sharding"]
+    shard = _paged_kv_pin()
     with jax.named_scope("kv_write"):
         lidx = wpos // bs_blk                                 # [B, S]
         phys = jnp.take_along_axis(table, jnp.minimum(lidx, mbs - 1), axis=1)
@@ -277,16 +281,51 @@ def _paged_kv_update(kv_cache, k, v):
         if shard is not None:
             pool_k = jax.lax.with_sharding_constraint(pool_k, shard)
             pool_v = jax.lax.with_sharding_constraint(pool_v, shard)
+    return pool_k, pool_v
+
+
+def _paged_kv_gather(pool_k, pool_v, table):
+    """Paged-cache read as a dense view: every row's blocks gathered back
+    into contiguous [B, mbs*BS, n_kv, hd] buffers with ``jnp.take`` on the
+    block axis — the caller's causal mask (key position <= query position)
+    hides the stale tail exactly as it does for the contiguous layout.
+    Under a tensor-parallel mesh the views are constrained like the pools,
+    so the gather stays shard-local on the head axis."""
+    shard = _paged_kv_pin()
     with jax.named_scope("kv_gather"):
-        nkv, hd = pool_k.shape[2], pool_k.shape[3]
+        b, mbs = table.shape
+        bs_blk, nkv, hd = pool_k.shape[1:]
         k_view = jnp.take(pool_k, table, axis=0).reshape(
             b, mbs * bs_blk, nkv, hd)
         v_view = jnp.take(pool_v, table, axis=0).reshape(
             b, mbs * bs_blk, nkv, hd)
-        if shard is not None and _PAGED_KV_SHARD["constrain_view"]:
+        if shard is not None:
             k_view = jax.lax.with_sharding_constraint(k_view, shard)
             v_view = jax.lax.with_sharding_constraint(v_view, shard)
-    return k_view, v_view, (pool_k, pool_v)
+    return k_view, v_view
+
+
+def _paged_decode_attend(kv_cache, q, pools):
+    """The paged DECODE step's attention, where the input says decode: one
+    query position a slot (``S == 1``), per-slot cursors, no pool sharding
+    installed, and arrays the Pallas kernel can run on
+    (``kernels/pallas/paged_decode.py``: a TPU, or its test seam). The
+    kernel reads each slot's live blocks straight from the just-written
+    pools; returns the context [B, 1, nh, hd], or None for every other
+    caller (prefill chunks, speculative verify, TP serving, the CPU), which
+    attend over ``_paged_kv_gather``'s dense view."""
+    table, pos = kv_cache[2], kv_cache[3]
+    if (q.shape[1] != 1 or jnp.ndim(pos) != 1
+            or _PAGED_KV_SHARD["sharding"] is not None):
+        return None
+    from ..kernels.pallas import paged_decode
+    mode = paged_decode.kernel_mode(q, pools[0])
+    if mode is None:
+        return None
+    with jax.named_scope("paged_decode"):
+        return paged_decode.paged_decode_attention(
+            q, pools[0], pools[1], table, pos + 1,
+            interpret=mode == "interpret")
 
 
 class GPTAttention(nn.Layer):
@@ -363,7 +402,11 @@ class GPTAttention(nn.Layer):
         q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
         if len(kv_cache) == 5:
             pos = kv_cache[3]
-            k_buf, v_buf, new_cache = _paged_kv_update(kv_cache, k, v)
+            new_cache = _paged_kv_write(kv_cache, k, v)
+            ctx = _paged_decode_attend(kv_cache, q, new_cache)
+            if ctx is not None:
+                return self.out_proj(Tensor(ctx.reshape(b, s, h))), new_cache
+            k_buf, v_buf = _paged_kv_gather(*new_cache, kv_cache[2])
         else:
             k_buf, v_buf, pos = kv_cache   # jnp arrays + int32 scalar/[B]
             if jnp.ndim(pos) == 1:

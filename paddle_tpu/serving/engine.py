@@ -365,8 +365,7 @@ class DecodeEngine:
                         f"(shard_gpt_tp / shard_llama_tp defaults)")
         self._repl = None
         self._pool_sh = None
-        self._kv_shard_ctx = None
-        self._kv_view_ctx = True
+        self._kv_pin = False
         if self._mesh is not None:
             if not self.paged:
                 raise NotImplementedError(
@@ -394,9 +393,7 @@ class DecodeEngine:
             # the pool mid-graph forces XLA full-remat copies — there the
             # committed input placement + pinned out_shardings alone keep
             # the storage hd-sharded and the layout stable across calls
-            if pool_spec == P(None, None, "model", None):
-                self._kv_shard_ctx = self._pool_sh
-            self._kv_view_ctx = pool_spec == P(None, None, "model", None)
+            self._kv_pin = pool_spec == P(None, None, "model", None)
             # commit every leaf that does not already live on THIS mesh to
             # a mesh-replicated placement: AOT executables refuse inputs
             # whose shardings drift from the compiled ones, and a single-
@@ -498,6 +495,7 @@ class DecodeEngine:
         self._slots = SlotAllocator(self.max_slots)
         self._queue = AdmissionQueue(max_queue)
         self._decode_exe = None
+        self._decode_attention = None
         self._verify_exe = None
         self._prefill_exes = {}
         # cumulative speculation counters (stats() + monitor mirrors)
@@ -634,19 +632,19 @@ class DecodeEngine:
         """Trace + AOT-compile with every layer in eval mode (serving
         semantics: dropout off), then restore each layer's OWN flag — an
         engine must not flip a training model's mode as a side effect.
-        Under a mesh the paged-pool sharding constraint is installed for
-        the duration of the trace (``_paged_kv_update`` pins its scatter/
-        gather shard-local on the head axis) and ``out_shardings`` pins the
-        donated pools back to their input placement — without the pin,
-        XLA's propagation could hand back differently-laid pools and the
-        NEXT call's input shardings would no longer match the compiled
+        Under a mesh the paged-pool sharding context is installed for the
+        duration of the trace (head-sharded, ``_paged_kv_write/_gather``
+        pin the scatter/gather shard-local on the head axis; sharded at
+        all, the decode step keeps the gather path) and ``out_shardings``
+        pins the donated pools back to their input placement — without the
+        pin, XLA's propagation could hand back differently-laid pools and
+        the NEXT call's input shardings would no longer match the compiled
         ones."""
         layers = self.model.sublayers(include_self=True)
         saved = [(l, l.training) for l in layers]
         for l in layers:
             l.training = False
-        prev_ctx = set_paged_kv_sharding(self._kv_shard_ctx,
-                                         self._kv_view_ctx) \
+        prev_ctx = set_paged_kv_sharding(self._pool_sh, self._kv_pin) \
             if self._mesh is not None else None
         try:
             kw = dict(donate_argnums=(1,))
@@ -737,10 +735,22 @@ class DecodeEngine:
                     jnp.asarray(self._tok), jnp.asarray(self._pos),
                     self._greedy_key)
         t0 = time.time()
+        if self.paged:
+            from ..kernels.pallas import paged_decode
+            traced = paged_decode.kernel_traces()
         exe = self._compile_in_eval(fn, args,
                                     out_shardings=self._pool_out_shardings()
                                     if self.paged else None)
         self._decode_exe = exe
+        # which attention the trace took (the model chose from its input:
+        # models/gpt.py::_paged_decode_attend); a silent fallback on the
+        # chip would otherwise look like "no gain"
+        if not self.paged:
+            self._decode_attention = "contiguous"
+        elif paged_decode.kernel_traces() > traced:
+            self._decode_attention = "paged_kernel"
+        else:
+            self._decode_attention = "gather"
         # the decode step advances one token per SLOT per call
         self._minted("decode", None, time.time() - t0, exe=exe,
                      tokens=self.max_slots)
@@ -1496,7 +1506,8 @@ class DecodeEngine:
             + (f"; flight dump {dump_path}" if dump_path else ""),
             RuntimeWarning)
 
-    def _dispatch_guarded(self, kind: str, bucket, spans, upload, call):
+    def _dispatch_guarded(self, kind: str, bucket, spans, upload, call,
+                          **call_attrs):
         """Run one decode/chunk dispatch under the guardrails: the chaos
         seam fires first (a ``slow`` lands inside the armed window — that
         is how the watchdog is tested), the watchdog brackets the uploads,
@@ -1505,7 +1516,8 @@ class DecodeEngine:
         state. ``spans`` names two: ``upload()`` makes the executable's
         device arguments under one more span of the host phase, and
         ``call(*args)`` dispatches, waits and reads back under the call's
-        own, which is therefore dispatch, device run and read-back alone.
+        own, which is therefore dispatch, device run and read-back alone
+        and carries ``call_attrs``.
         ``call`` must COMMIT the donated
         pools/caches to the engine itself before returning — on the hang
         path the dispatch completed (the old buffers are donated away), so
@@ -1522,7 +1534,7 @@ class DecodeEngine:
                 self._faults.fire(kind)
             with _trace.span(spans[0]):
                 args = upload()
-            with _trace.span(spans[1]) as call_span:
+            with _trace.span(spans[1], **call_attrs) as call_span:
                 out = call(*args)
                 del args           # released inside the span that used them
         except Exception as e:
@@ -2020,7 +2032,13 @@ class DecodeEngine:
                 src, dst = self._cow_args(
                     [p for c in copies_by_slot.values() for p in c])
             prep.set(live=self.live_count, cow=n_cow, preempted=preempted)
+        call_attrs = dict(path=self._decode_attention)
         if self.paged:
+            # the live KV blocks this step has to read; the gather path read
+            # max_slots * max_blocks_per_slot whatever this says
+            call_attrs["kv_blocks"] = int(
+                (self._pos[self._live] // self.block_size + 1).sum())
+
             def upload():
                 return (self._dev(self._decode_tables()),
                         self._dev(self._tok), self._dev(self._pos), src,
@@ -2043,7 +2061,7 @@ class DecodeEngine:
                 return np.asarray(picked), np.asarray(ok)
 
         (nxt, l_ok), call = self._dispatch_guarded(
-            "decode", None, _DECODE_SPANS, upload, run)
+            "decode", None, _DECODE_SPANS, upload, run, **call_attrs)
         with _trace.span("engine/decode_finish") as fin:
             live = n_tok = n_done = 0
             for slot in range(self.max_slots):
@@ -2309,6 +2327,10 @@ class DecodeEngine:
             "executables": 1 + len(self._prefill_exes)
             if self._decode_exe is not None else len(self._prefill_exes),
             "decode_steps": self.decode_steps,
+            # what the decode executable was traced with: "paged_kernel"
+            # (kernels/pallas/paged_decode.py), "gather" (the dense view),
+            # "contiguous" (row cache); None before its first trace
+            "decode_attention": self._decode_attention,
             "tokens_generated": self.tokens_generated,
             "live_slots": self.live_count,
             "queue_depth": self.queue_depth,

@@ -1,0 +1,197 @@
+"""Paged decode attention (kernels/pallas/paged_decode.py) against the gather
+path it replaces at decode (models/gpt.py::_paged_kv_gather + the masked
+float32 softmax of ``_forward_cached``), interpret mode on the CPU: the
+lengths round a block edge, mixed batches, tables whose rows share physical
+blocks, grouped queries, head widths 64 and 128, float32 and bfloat16
+pools; stale garbage past a slot's length and in unreferenced blocks must
+not reach the output. The Mosaic compiles at the real decode shapes are at
+the end (a described v5e; no chip needed)."""
+import math
+
+import numpy as np
+import jax
+import jax.numpy as jnp
+import pytest
+
+from paddle_tpu.kernels.pallas.paged_decode import (kernel_mode,
+                                                    paged_decode_attention)
+from paddle_tpu.models.gpt import _paged_kv_gather
+
+BS, MBS = 16, 8            # block size; table width: max_len 128
+MAX_LEN = BS * MBS
+
+
+def gather_path(q, pool_k, pool_v, table, lengths):
+    """What ``_forward_cached`` does with the gathered view at S == 1."""
+    b, _, nh, hd = q.shape
+    k_buf, v_buf = _paged_kv_gather(pool_k, pool_v, table)
+    n_kv = k_buf.shape[2]
+    qg = q.reshape(b, 1, n_kv, nh // n_kv, hd).astype(jnp.float32)
+    scores = jnp.einsum("bqkgd,bmkd->bkgqm", qg, k_buf.astype(jnp.float32),
+                        precision="highest") / math.sqrt(hd)
+    key_pos = jnp.arange(k_buf.shape[1])[None, None, None, None, :]
+    scores = jnp.where(key_pos < lengths[:, None, None, None, None], scores,
+                       -1e30)
+    probs = jax.nn.softmax(scores, axis=-1)
+    ctx = jnp.einsum("bkgqm,bmkd->bqkgd", probs, v_buf.astype(jnp.float32),
+                     precision="highest")
+    return ctx.reshape(b, 1, nh, hd).astype(q.dtype)
+
+
+def case(lengths, *, nh=4, n_kv=4, hd=64, dtype=jnp.float32, seed=0,
+         table=None, nb=40):
+    rng = np.random.RandomState(seed)
+    b = len(lengths)
+    q = jnp.asarray(rng.randn(b, 1, nh, hd), jnp.float32).astype(dtype)
+    pool_k, pool_v = (jnp.asarray(rng.randn(nb, BS, n_kv, hd) * 0.7,
+                                  jnp.float32).astype(dtype)
+                      for _ in range(2))
+    if table is None:       # block 0 is the engine's trash block
+        table = rng.randint(1, nb, (b, MBS))
+    return (q, pool_k, pool_v, jnp.asarray(table, jnp.int32),
+            jnp.asarray(lengths, jnp.int32))
+
+
+def check(args, **kw):
+    got = paged_decode_attention(*args, interpret=True, **kw)
+    want = gather_path(*args)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    # float32: the same products, another order of the float32 sums;
+    # bfloat16: at most the output's own rounding apart
+    tol = 2e-6 if args[0].dtype == jnp.float32 else 2.0 ** -8
+    scale = float(jnp.max(jnp.abs(want.astype(jnp.float32))))
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=0, atol=tol * max(scale, 1.0))
+    return got
+
+
+@pytest.mark.parametrize("length", [1, BS - 1, BS, BS + 1])
+def test_lengths_round_a_block_edge(length):
+    check(case([length, length]))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_mixed_batch_dead_slot_and_full_slot(dtype):
+    """One slot at pos 0 on the trash row (a dead or mid-prefill slot), one
+    at max_len - 1, the rest between; several chunks a slot."""
+    args = case([1, MAX_LEN, 37, 64, 100], dtype=dtype, seed=1)
+    table = np.array(args[3])
+    table[0] = 0
+    args = args[:3] + (jnp.asarray(table),) + args[4:]
+    check(args, pages_per_chunk=2)
+
+
+@pytest.mark.parametrize("pages", [1, 3, 8])
+def test_chunk_width_does_not_matter(pages):
+    check(case([5, 77, MAX_LEN, 16], seed=2), pages_per_chunk=pages)
+
+
+def test_rows_that_share_physical_blocks():
+    """Prefix sharing: three slots read the same first two blocks, then
+    their own."""
+    args = case([40, 33, 47], seed=3)
+    table = np.array(args[3])
+    table[:, :2] = table[0, :2]
+    check(args[:3] + (jnp.asarray(table),) + args[4:])
+
+
+@pytest.mark.parametrize("nh,n_kv", [(4, 4), (8, 2), (4, 1)])
+def test_query_heads_fold_onto_their_kv_head(nh, n_kv):
+    check(case([3, 50, 128], nh=nh, n_kv=n_kv, seed=4), pages_per_chunk=4)
+
+
+@pytest.mark.parametrize("hd", [64, 128])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_head_widths_and_pool_dtypes(hd, dtype):
+    check(case([17, 90, 1], nh=4, n_kv=2, hd=hd, dtype=dtype, seed=5))
+
+
+def test_float32_query_over_bfloat16_pools():
+    """A query that is not bfloat16-exact meets the stored bfloat16 blocks
+    with its float32 value (split in exact bfloat16 terms, not rounded)."""
+    q, pk, pv, table, lengths = case([30, 128], seed=6)
+    args = (q, pk.astype(jnp.bfloat16), pv.astype(jnp.bfloat16), table,
+            lengths)
+    got = paged_decode_attention(*args, interpret=True)
+    want = gather_path(*args)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
+                               atol=5e-6)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_stale_garbage_never_reaches_the_output(dtype):
+    """inf, nan and huge values past each slot's length (the live block's
+    tail, the table's dead entries) and in blocks no row references change
+    nothing: the output is bit-equal to the clean pools'."""
+    lengths = [1, BS - 1, BS + 1, 70]
+    q, pk, pv, table, L = case(lengths, dtype=dtype, seed=7)
+    table = np.array(table)
+    table[:] = np.arange(1, 1 + table.size).reshape(table.shape) % 39 + 1
+    table[0] = 0
+    clean = paged_decode_attention(q, pk, pv, jnp.asarray(table), L,
+                                   interpret=True, pages_per_chunk=2)
+    junk = np.array([np.inf, -np.inf, np.nan, 3e38], np.float32)
+    live = np.zeros((pk.shape[0], BS), bool)       # (block, offset) read
+    for row, n in zip(table, lengths):
+        for p in range(n):
+            live[row[p // BS], p % BS] = True
+    dirty_k, dirty_v = (np.array(x, np.float32) for x in (pk, pv))
+    fill = junk[np.arange((~live).sum()) % 4][:, None, None]
+    dirty_k[~live] = fill
+    dirty_v[~live] = fill[::-1]
+    dirty = paged_decode_attention(
+        q, jnp.asarray(dirty_k).astype(dtype),
+        jnp.asarray(dirty_v).astype(dtype), jnp.asarray(table), L,
+        interpret=True, pages_per_chunk=2)
+    assert np.isfinite(np.asarray(dirty, np.float32)).all()
+    np.testing.assert_array_equal(np.asarray(clean, np.float32),
+                                  np.asarray(dirty, np.float32))
+
+
+def test_off_the_tpu_the_model_keeps_the_gather_path():
+    q, pk = case([1])[:2]
+    assert kernel_mode(q, pk) is None
+
+
+# ------------------------------------------- Mosaic, for a described v5e
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("b,nh,n_kv,mbs,nb,q_dtype", [
+    (32, 16, 16, 128, 3000, jnp.bfloat16),   # GPT-3 XL's decode step
+    (16, 8, 8, 64, 1100, jnp.bfloat16),      # chip_smoke.py's server
+    (32, 32, 8, 128, 3000, jnp.bfloat16),    # grouped queries, 4 a KV head
+    (32, 16, 16, 128, 3000, jnp.float32),    # float32 query, bf16 pools
+])
+def test_mosaic_compiles_the_decode_shapes(one_chip, b, nh, n_kv, mbs, nb,
+                                           q_dtype):
+    hd = 128
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((nb, BS, n_kv, hd), jnp.bfloat16)
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        exe = jax.jit(paged_decode_attention).lower(
+            sds((b, 1, nh, hd), q_dtype), pool, pool,
+            sds((b, mbs), jnp.int32), sds((b,), jnp.int32)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    text = exe.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    # the pools reach the kernel as they lie: no pool-sized temporary
+    assert exe.memory_analysis().temp_size_in_bytes < (1 << 20)

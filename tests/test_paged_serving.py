@@ -291,6 +291,129 @@ def test_paged_parity_llama_with_sharing():
         np.testing.assert_array_equal(exp, r.output_tokens)
 
 
+# ------------------------------------- the Pallas paged-decode kernel path
+
+
+def _tiny_llama():
+    from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
+    paddle.seed(7)
+    lm = LlamaForCausalLM(llama_tiny(
+        vocab_size=64, hidden_size=32, num_layers=2, num_heads=4,
+        num_kv_heads=2, max_position_embeddings=64))
+    lm.eval()
+    return lm
+
+
+def _sharing_cow_preemption_workload(eng):
+    """An identical prompt admitted beside its running twin (its shared
+    tail block is copied on the first write), then same-prefix prompts that
+    a 8-block pool cannot hold at once (the youngest is preempted)."""
+    rng = np.random.RandomState(11)
+    twin = rng.randint(1, 64, 13).tolist()
+    reqs = [eng.submit(twin, max_new_tokens=10)]
+    while reqs[0].status != "running":
+        eng.step()
+    reqs.append(eng.submit(twin, max_new_tokens=10))
+    eng.step()
+    prefix = rng.randint(1, 64, 12).tolist()
+    for _ in range(3):
+        reqs.append(eng.submit(prefix + rng.randint(1, 64, 8).tolist(),
+                               max_new_tokens=18))
+        eng.step()
+    eng.run(max_steps=800)
+    return reqs
+
+
+@pytest.mark.parametrize("make", [_tiny_gpt, _tiny_llama],
+                         ids=["gpt", "llama"])
+def test_paged_kernel_serves_what_the_gather_path_serves(make):
+    """With the decode step traced onto kernels/pallas/paged_decode.py
+    (interpreted here; Mosaic on the chip), a paged engine serves token for
+    token what the gather path serves, across prefix sharing, copy-on-write
+    and preemption; the engine says which path it traced, in ``stats()``
+    and on every ``engine/decode_call`` span."""
+    import time
+    from paddle_tpu.kernels.pallas import paged_decode
+    from paddle_tpu.monitor import trace
+    model = make()
+    served = {}
+    for path in ("gather", "paged_kernel"):
+        eng = DecodeEngine(model, max_slots=4, max_len=48, block_size=8,
+                           kv_blocks=9, prefill_chunk=8)
+        assert eng.stats()["decode_attention"] is None    # nothing traced
+        t0 = time.perf_counter()
+        with paged_decode.force_interpret(path == "paged_kernel"):
+            reqs = _sharing_cow_preemption_workload(eng)
+        calls = trace.spans(t0, time.perf_counter(), "engine/decode_call")
+        st = eng.stats()
+        assert st["decode_attention"] == path
+        assert all(r.status == "done" for r in reqs)
+        assert st["paged"]["shared_hits"] >= 1, st["paged"]
+        assert st["paged"]["cow_copies"] >= 1, st["paged"]
+        assert eng.preemptions >= 1
+        assert eng.compile_count == 2             # one chunk, one decode
+        assert len(calls) == eng.decode_steps > 0
+        assert {c.attrs["path"] for c in calls} == {path}
+        # the twin's first decode step: one live slot at position 13 reads
+        # the 2 blocks of 8 that hold positions 0..13
+        assert calls[0].attrs["kv_blocks"] == 2
+        assert all(1 <= c.attrs["kv_blocks"] <= 4 * 6 for c in calls)
+        eng._pager.check_invariants()
+        served[path] = [list(r.output_tokens) for r in reqs]
+    assert served["paged_kernel"] == served["gather"]
+
+
+def test_paged_kernel_zero_recompile_under_block_churn(tiny):
+    """The block-churn gate on the kernel path: table and lengths are the
+    kernel's scalar-prefetch DATA, so allocation, sharing, COW and finish-
+    release mint nothing after the first two executables."""
+    from paddle_tpu.kernels.pallas import paged_decode
+    eng = DecodeEngine(tiny, max_slots=4, max_len=48, block_size=8,
+                       prefill_chunk=8)
+    rng = np.random.RandomState(0)
+    shared = rng.randint(1, 64, 12).tolist()
+    with paged_decode.force_interpret():
+        eng.submit([1, 2, 3], max_new_tokens=2)
+        eng.run()
+    assert eng.stats()["decode_attention"] == "paged_kernel"
+    base = eng.compile_count
+    reqs = []
+    for i in range(12):
+        p = shared + rng.randint(1, 64, rng.randint(1, 4)).tolist() \
+            if i % 3 == 0 else rng.randint(1, 64, rng.randint(2, 20)).tolist()
+        reqs.append(eng.submit(p, max_new_tokens=int(rng.randint(2, 8))))
+        eng.step()
+    eng.run()
+    assert all(r.status == "done" for r in reqs)
+    assert eng.compile_count == base
+    assert eng.stats()["paged"]["shared_hits"] > 0
+    for r in reqs[:4]:
+        np.testing.assert_array_equal(
+            _eager(tiny, r.prompt, len(r.output_tokens)), r.output_tokens)
+
+
+def test_decode_call_span_counts_the_live_blocks(tiny):
+    """``kv_blocks`` is what a decode step has to read: over the live slots,
+    the blocks that hold positions 0..pos (dead slots, at pos 0 on the trash
+    row, are not in it)."""
+    import time
+    from paddle_tpu.monitor import trace
+    eng = DecodeEngine(tiny, max_slots=4, max_len=48, block_size=8,
+                       prefill_chunk=8)
+    t0 = time.perf_counter()
+    eng.submit(list(range(1, 21)), max_new_tokens=6)     # 20 prompt tokens
+    eng.submit(list(range(1, 6)), max_new_tokens=6)      # 5
+    eng.run()
+    calls = trace.spans(t0, time.perf_counter(), "engine/decode_call")
+    assert eng.stats()["decode_attention"] == "gather"   # CPU, no seam
+    assert {c.attrs["path"] for c in calls} == {"gather"}
+    # the short prompt (one chunk) decodes alone at positions 5 and 6 while
+    # the long one is still chunked; then both: positions 20 + 7, 21 + 8,
+    # 22 + 9 (blocks of 8: 3 + 1, 3 + 2, 3 + 2); then the long one alone at
+    # 23 and 24
+    assert [c.attrs["kv_blocks"] for c in calls] == [1, 1, 4, 5, 5, 3, 4]
+
+
 # ----------------------------------------------------- satellite: pager unit
 
 
