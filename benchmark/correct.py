@@ -15,49 +15,55 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .reference import gpt as ref
 from . import sketch as SK
 from . import system
-from . import weights as W
+from .reference import common
 
 
 # ------------------------------------------------------------- training
 
-def follow_reference(model, job, seed, dtype, rows, steps, **kw) -> dict:
+def follow_reference(cell, seed, rows, steps, **kw) -> dict:
     """`reference_training`, arranged by the program's leaves."""
-    losses, g1, upd, sk = reference_training(model, job, seed, dtype, rows,
-                                             steps, **kw)
-    split = system.compare_map(model)
+    losses, g1, upd, sk = reference_training(cell, seed, rows, steps, **kw)
+    fam, model = cell.family, cell.config["model"]
+    split = system.compare_map(fam, model)
     return {"losses": losses, "grad": by_leaf(g1, split),
             "update": by_leaf(upd, split),
-            "sketch": by_leaf(sk, system.leaf_map(model))}
+            "sketch": by_leaf(sk, fam.leaf_map(model))}
 
 
-def reference_training(model: dict, job: dict, seed: int, dtype: str,
-                       rows, steps: int, dot=ref.hi_dot, batch_rows=None):
-    """Follow the first `steps` updates in float32 from the same weights
-    and rows. `batch_rows` (default: all) plants the half-batch fault:
-    only those rows of each batch are used, the mean taken over them.
+def reference_training(cell, seed: int, rows, steps: int,
+                       dot=common.hi_dot, batch_rows=None):
+    """Follow the first `steps` updates of the cell's job in float32,
+    through its family's plain reference, from the same weights and rows.
+    `batch_rows` (default: all) plants the half-batch fault: only those
+    rows of each batch are used, the mean taken over them.
     Returns (losses, first-gradient leaf norms, update leaf norms,
     first-gradient sketches): norms as {stacked key: array [L] or []},
     sketches as {stacked key: [L, K] or [K]}."""
+    fam, ref = cell.family, cell.reference
+    model, job = cell.config["model"], cell.mix
     o = job["optimizer"]
-    batch, seq = job["batch"], job["seq"]
+    batch = job["batch"]
     use = list(range(batch)) if batch_rows is None else list(batch_rows)
-    w0 = W.make(model, seed, dtype)
+    w0 = fam.make(model, seed, cell.config["dtype"])
     params = {k: v.astype(jnp.float32) for k, v in w0.items()}
     start = params
     m = {k: jnp.zeros_like(v) for k, v in params.items()}
     v2 = {k: jnp.zeros_like(v) for k, v in params.items()}
 
+    def leaf_norms(tree):
+        return common.leaf_norms(tree, ref.LAYER_KEYS, fam.FUSED)
+
     @jax.jit
     def one(params, m, v2, ids, t):
-        loss, g = ref.loss_and_grads(params, ids, model["num_heads"], dot)
-        p2, m, v2 = ref.adamw_step(params, m, v2, g, t, o["lr"], o["beta1"],
-                                   o["beta2"], o["eps"], o["weight_decay"])
+        loss, g = ref.loss_and_grads(params, ids, model, dot)
+        p2, m, v2 = common.adamw_step(
+            params, m, v2, g, t, o["lr"], o["beta1"], o["beta2"], o["eps"],
+            o["weight_decay"])
         sk = {k: SK.sketch_layers(a) if k in ref.LAYER_KEYS
               else SK.sketch(a) for k, a in g.items()}
-        return loss, (ref.leaf_norms(g), sk), p2, m, v2
+        return loss, (leaf_norms(g), sk), p2, m, v2
 
     losses, g1, sk1 = [], None, None
     for s in range(steps):
@@ -68,7 +74,7 @@ def reference_training(model: dict, job: dict, seed: int, dtype: str,
         if s == 0:
             g1 = {k: np.asarray(a) for k, a in gn[0].items()}
             sk1 = {k: np.asarray(a) for k, a in gn[1].items()}
-    delta = jax.jit(lambda a, b: ref.leaf_norms(
+    delta = jax.jit(lambda a, b: leaf_norms(
         {k: a[k] - b[k] for k in a}))(params, start)
     return losses, g1, {k: np.asarray(a) for k, a in delta.items()}, sk1
 
@@ -136,21 +142,22 @@ def pick_sample(finished: list, k: int, seed: int) -> list:
     return [order[0]] + [rest[i] for i in sorted(pick)]
 
 
-def served_token_gaps(model: dict, seed: int, dtype: str, sample: list,
-                      pad_to: int, control: bool = False):
-    """For each sampled request run the reference ONCE over its prompt
-    and served tokens, and read, at every served position, how far the
+def served_token_gaps(cell, seed: int, sample: list, pad_to: int,
+                      control: bool = False):
+    """For each sampled request run the family's reference ONCE over its
+    prompt and served tokens (padded to `pad_to`, the longest a served
+    request can be), and read, at every served position, how far the
     served token's logit lies below the reference's best. With `control`
     the token judged is the one the fp8 forward puts first instead.
     Returns (widest gap, mean gap over the tokens, tokens compared)."""
-    w = W.make(model, seed, dtype)
-    n_heads = model["num_heads"]
+    ref, model = cell.reference, cell.config["model"]
+    w = cell.family.make(model, seed, cell.config["dtype"])
 
     @jax.jit
     def gaps(w, ids, judged, first, count):
-        lg = ref.logits(w, ids[None], n_heads)[0]
+        lg = ref.logits(w, ids[None], model, common.hi_dot)[0]
         if control:
-            low = ref.logits(w, ids[None], n_heads, ref.fp8_dot)[0]
+            low = ref.logits(w, ids[None], model, common.fp8_dot)[0]
             judged = jnp.argmax(low, axis=-1).astype(jnp.int32)
         got = jnp.take_along_axis(lg, judged[:, None], axis=-1)[:, 0]
         pos = jnp.arange(ids.shape[0])

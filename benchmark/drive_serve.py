@@ -45,12 +45,11 @@ def percentile(values: list, q: float) -> float:
 def run(cell, seed: int, seconds: float, rec: Recorder, tracer, setup,
         faults=None) -> dict:
     cfg, mix = cell.config, cell.mix
-    model = cfg["model"]
-    vocab = model["vocab_size"]
+    vocab = cfg["model"]["vocab_size"]
     open_loop = mix["loop"] == "open"
 
     with setup.phase("build"):
-        srv = system.Server(cfg, seed, (faults or {}).get("model"))
+        srv = system.Server(cell, seed, (faults or {}).get("model"))
         kept_model = srv.model if faults and "keep" in faults else None
         if faults and "server" in faults:
             faults["server"](srv)
@@ -185,8 +184,7 @@ def run(cell, seed: int, seconds: float, rec: Recorder, tracer, setup,
     if sample:
         (numbers["served_logit_gap"], numbers["served_logit_gap_mean"],
          numbers["tokens_compared"]) = correct.served_token_gaps(
-                model, seed, cfg["dtype"], sample,
-                model["max_position_embeddings"])
+                cell, seed, sample, cfg["engine"]["max_len"])
     numbers["requests_not_done"] = len(bad) + len(missed)
     numbers["answers_of_wrong_length"] = short
     numbers["nan_logits"] = c1["nan_logits"] - c0["nan_logits"]
@@ -198,6 +196,13 @@ def run(cell, seed: int, seconds: float, rec: Recorder, tracer, setup,
              f"{len(steps)} engine steps; decode-only step (host clock) "
              f"median {quiet[len(quiet) // 2]:.1f} ms over {len(quiet)}"
              if quiet else f"{len(flights)} requests, no decode-only step"]
+    # a window that reads slow (PERF.md section 7 item 9) shows here whether
+    # a few steps froze or every step was slower
+    slow = sorted((1e3 * (s[1] - s[0]) for s in steps), reverse=True)[:3]
+    notes.append("longest engine steps (host clock) "
+                 + ", ".join(f"{x:.0f}" for x in slow)
+                 + f" ms; latest send {max(lateness, default=0.0):.0f} ms "
+                   f"after it was due")
     return {
         "notes": notes,
         "attempted": len(flights), "failed": len(bad) + len(missed),

@@ -14,13 +14,12 @@ from .trace import Recorder
 def run(cell, seed: int, seconds: float, rec: Recorder, tracer, setup,
         faults=None) -> dict:
     cfg, job = cell.config, cell.mix
-    model = cfg["model"]
-    vocab, seq, batch = model["vocab_size"], job["seq"], job["batch"]
+    vocab, seq, batch = cfg["model"]["vocab_size"], job["seq"], job["batch"]
     rows = lambda i: traffic.train_row(seed, i, vocab, seq)   # noqa: E731
     n_ref = int(job["reference_steps"])
 
     with setup.phase("build"):
-        tr = system.Trainer(cfg, job, seed, rows)
+        tr = system.Trainer(cell, seed, rows)
         if faults and "trainer" in faults:
             faults["trainer"](tr)
     feed = tr.batches()
@@ -31,7 +30,7 @@ def run(cell, seed: int, seconds: float, rec: Recorder, tracer, setup,
             if s == 0:
                 got["grad"] = tr.first_grad_norms()
                 got["sketch"] = tr.first_grad_sketches()
-        got["update"] = tr.update_norms(seed, cfg["dtype"])
+        got["update"] = tr.update_norms()
     compiles0 = tr.num_compiles()
     setup.close()
 
@@ -69,8 +68,7 @@ def run(cell, seed: int, seconds: float, rec: Recorder, tracer, setup,
 
     # ---- the reference, after the window
     t0 = time.perf_counter()
-    want = correct.follow_reference(model, job, seed, cfg["dtype"], rows,
-                                    n_ref)
+    want = correct.follow_reference(cell, seed, rows, n_ref)
     losses = want["losses"]
     numbers = correct.compare_training(got, want)
     numbers["recompiles_in_window"] = recompiles

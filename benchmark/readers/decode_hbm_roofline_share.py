@@ -1,11 +1,12 @@
 """Share of the HBM roofline the decode executable reaches: the bytes the
-MODEL needs for a step (weights once, the live context read once, one
-position's K and V written per live slot; from the shapes and the live
-lengths the benchmark itself tracks) over 819 GB/s, over the device time
-of the whole executable. Independent of what implements the step."""
+MODEL needs for a step (its family's count: weights once, the live
+context read once, one position's K and V written per live slot; from the
+shapes and the live lengths the benchmark itself tracks) over 819 GB/s,
+over the device time of the whole executable. Independent of what
+implements the step."""
 import sys
 
-from .. import counts, weights
+from .. import counts
 from ._common import mean, serve_module_runs, traced_steps
 
 
@@ -14,13 +15,12 @@ def read(ctx):
     steps = [s for s in traced_steps(ctx) if s[2] > 0]
     if not runs or not steps:
         return None
-    model = ctx["cell"].config["model"]
-    nbytes = mean([counts.decode_step_bytes(
-        model, weights.n_params(model), s[3], s[2]) for s in steps])
+    fam, model = ctx["cell"].family, ctx["cell"].config["model"]
+    nbytes = mean([fam.decode_step_bytes(model, s[3], s[2]) for s in steps])
     # decode is memory-bound by three decades here; the FLOP term is
     # there so the bound named is the one that applies
-    flops = counts.forward_flops(model, mean([s[2] for s in steps]),
-                                 mean([s[3] for s in steps]))
+    flops = fam.forward_flops(model, mean([s[2] for s in steps]),
+                              mean([s[3] for s in steps]))
     share, bound = counts.roofline_share(
         flops, nbytes, mean(runs), ctx["peaks"]["flops_bf16"],
         ctx["peaks"]["hbm_bytes_per_s"])

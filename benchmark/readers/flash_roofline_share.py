@@ -1,7 +1,7 @@
 """Share of its roofline the attention kernel reaches in the train step:
-causal attention's FLOPs and least bytes for one step, from the shapes,
-over the device time per step of the ops the selector matches
-(device_trace). The bound that applies is written to standard error."""
+causal attention's FLOPs and least bytes for one step, from the shapes
+(the family's count), over the device time per step of the ops the
+selector matches (device_trace). The bound that applies is written to standard error."""
 import sys
 
 from .. import counts
@@ -26,8 +26,8 @@ def read(ctx):
     model, job = cell.config["model"], cell.mix
     rows = job["batch"] // ctx["facts"]["chips"]
     share, bound = counts.roofline_share(
-        counts.attention_train_flops(model, rows, job["seq"]),
-        counts.attention_train_bytes(model, rows, job["seq"]),
+        cell.family.attention_train_flops(model, rows, job["seq"]),
+        cell.family.attention_train_bytes(model, rows, job["seq"]),
         secs / len(runs), ctx["peaks"]["flops_bf16"],
         ctx["peaks"]["hbm_bytes_per_s"])
     print(f"[bench] flash_roofline_share: {bound}-bound, {n} kernel runs "

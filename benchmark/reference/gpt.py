@@ -11,8 +11,10 @@ Parameters are a flat dict of STACKED arrays (leading axis = layer):
   ln1_w ln1_b ln2_w ln2_b proj_b fc2_b [L,H]   qkv_w [L,H,3H]  qkv_b [L,3H]
   proj_w [L,H,H]  fc1_w [L,H,4H]  fc1_b [L,4H]  fc2_w [L,4H,H]
 
-`dot` is the matmul every product goes through, so the lower-precision
-control (`fp8_dot`) is the same code with the products' operands rounded.
+`model` is the configuration's whole `model` group; `dot` is the matmul
+every product goes through (`common.hi_dot`), so the lower-precision
+control (`common.fp8_dot`) is the same code with the products' operands
+rounded.
 """
 from __future__ import annotations
 
@@ -21,29 +23,11 @@ import math
 import jax
 import jax.numpy as jnp
 
+from .common import hi_dot
+
 LAYER_KEYS = ("ln1_w", "ln1_b", "qkv_w", "qkv_b", "proj_w", "proj_b",
               "ln2_w", "ln2_b", "fc1_w", "fc1_b", "fc2_w", "fc2_b")
 TOP_KEYS = ("wte", "wpe", "lnf_w", "lnf_b")
-F8_MAX = 448.0            # largest finite float8_e4m3fn
-
-
-def hi_dot(spec, a, b):
-    return jnp.einsum(spec, a, b, precision="highest",
-                      preferred_element_type=jnp.float32)
-
-
-def _round_fp8(x):
-    """Round to float8_e4m3fn under a per-tensor scale (amax -> 448), the
-    usual fp8 recipe; gradients pass straight through the rounding."""
-    scale = F8_MAX / jnp.maximum(jnp.max(jnp.abs(x)), 1e-30)
-    q = (x * scale).astype(jnp.float8_e4m3fn).astype(jnp.float32) / scale
-    return x + jax.lax.stop_gradient(q - x)
-
-
-def fp8_dot(spec, a, b):
-    """The control's matmul: both operands rounded to fp8, product and
-    accumulation as the reference's."""
-    return hi_dot(spec, _round_fp8(a), _round_fp8(b))
 
 
 def layer_norm(x, w, b, eps=1e-5):
@@ -72,11 +56,11 @@ def block(x, p, n_heads, dot):
     return x + dot("bsk,kh->bsh", y, p["fc2_w"]) + p["fc2_b"]
 
 
-def hidden_states(params, ids, n_heads, dot=hi_dot, remat=False):
+def hidden_states(params, ids, model, dot=hi_dot, remat=False):
     """ids [B,S] int32 -> final-LayerNorm hidden states [B,S,H] float32."""
     p = {k: v.astype(jnp.float32) for k, v in params.items()
          if k in TOP_KEYS}
-    s = ids.shape[1]
+    s, n_heads = ids.shape[1], model["num_heads"]
     x = p["wte"][ids] + p["wpe"][:s][None]
 
     def body(x, layer):
@@ -89,22 +73,22 @@ def hidden_states(params, ids, n_heads, dot=hi_dot, remat=False):
     return layer_norm(x, p["lnf_w"], p["lnf_b"])
 
 
-def logits(params, ids, n_heads, dot=hi_dot):
+def logits(params, ids, model, dot=hi_dot):
     """Full forward: [B,S] -> [B,S,V] through the tied head."""
-    hid = hidden_states(params, ids, n_heads, dot)
+    hid = hidden_states(params, ids, model, dot)
     return dot("bsh,vh->bsv", hid, params["wte"].astype(jnp.float32))
 
 
-def nll_sum(params, ids, n_heads, dot=hi_dot):
+def nll_sum(params, ids, model, dot=hi_dot):
     """Sum over the S-1 shifted positions of every row of -log p(next)."""
-    hid = hidden_states(params, ids, n_heads, dot, remat=True)[:, :-1]
+    hid = hidden_states(params, ids, model, dot, remat=True)[:, :-1]
     lg = dot("bsh,vh->bsv", hid, params["wte"].astype(jnp.float32))
     lse = jax.scipy.special.logsumexp(lg, axis=-1)
     gold = jnp.take_along_axis(lg, ids[:, 1:, None], axis=-1)[..., 0]
     return jnp.sum(lse - gold)
 
 
-def loss_and_grads(params, ids, n_heads, dot=hi_dot, rows_per_block=1):
+def loss_and_grads(params, ids, model, dot=hi_dot, rows_per_block=1):
     """Mean next-token loss over ids [B,S] and its gradients, computed in
     blocks of rows so the [rows,heads,S,S] scores fit the device."""
     b, s = ids.shape
@@ -113,50 +97,10 @@ def loss_and_grads(params, ids, n_heads, dot=hi_dot, rows_per_block=1):
 
     def body(carry, rows):
         tot, acc = carry
-        v, g = jax.value_and_grad(nll_sum)(params, rows, n_heads, dot)
+        v, g = jax.value_and_grad(nll_sum)(params, rows, model, dot)
         return (tot + v, jax.tree_util.tree_map(jnp.add, acc, g)), None
 
     zero = jax.tree_util.tree_map(
         lambda a: jnp.zeros(a.shape, jnp.float32), params)
     (tot, acc), _ = jax.lax.scan(body, (jnp.float32(0.0), zero), blocks)
     return tot / n_tok, jax.tree_util.tree_map(lambda g: g / n_tok, acc)
-
-
-def adamw_step(params, m, v, grads, t, lr, beta1, beta2, eps, wd):
-    """Decoupled-weight-decay Adam (Loshchilov & Hutter), every leaf
-    decayed, bias-corrected moments; t counts from 1."""
-    bc1 = 1.0 - beta1 ** t
-    bc2 = 1.0 - beta2 ** t
-    out_p, out_m, out_v = {}, {}, {}
-    for k in params:
-        g = grads[k]
-        m1 = beta1 * m[k] + (1.0 - beta1) * g
-        m2 = beta2 * v[k] + (1.0 - beta2) * jnp.square(g)
-        upd = lr * (m1 / bc1) / (jnp.sqrt(m2 / bc2) + eps) \
-            + lr * wd * params[k]
-        out_p[k], out_m[k], out_v[k] = params[k] - upd, m1, m2
-    return out_p, out_m, out_v
-
-
-def split_qkv_bias(tree):
-    """The fused qkv bias as three leaves: the key third has no gradient
-    under softmax (a constant added to every score of a row), so it must
-    be judged apart from the query and value thirds."""
-    out = dict(tree)
-    b = out.pop("qkv_b")
-    h = b.shape[-1] // 3
-    for i, part in enumerate("qkv"):
-        out[f"qkv_b.{part}"] = b[..., i * h:(i + 1) * h]
-    return out
-
-
-def leaf_norms(tree):
-    """Per-leaf L2 norms, one per LAYER for the stacked keys: {key: [L] or
-    []} float32, the qkv bias split in three."""
-    out = {}
-    for k, a in split_qkv_bias(tree).items():
-        a = a.astype(jnp.float32)
-        axes = tuple(range(1, a.ndim)) if k.split(".")[0] in LAYER_KEYS \
-            else None
-        out[k] = jnp.sqrt(jnp.sum(jnp.square(a), axis=axes))
-    return out

@@ -1,9 +1,10 @@
 """What a run is made of, found by NAME: the cell and its metrics from
 `BENCHMARK.json`, the configuration from its `file`, the traffic mix from
 `traffic/<mix>.json`, the limits of `correct` from `limits/<cell>.json`,
-and each per-layer metric's reader from `readers/<base name>.py`. The
-harness holds no table of cells, mixes or metrics: a later PR adds files
-and list entries.
+each per-layer metric's reader from `readers/<base name>.py`, and the
+model family the configuration names from `families/<family>.py` with its
+plain reference `reference/<family>.py`. The harness holds no table of
+cells, mixes, metrics or families: a later PR adds files and list entries.
 """
 from __future__ import annotations
 
@@ -29,12 +30,19 @@ class Cell:
             raise SystemExit(f"unknown workload {name!r}; BENCHMARK.json "
                              f"has {sorted(cells)}")
         self.here = here
+        self._loaded = {}       # a tree's own files, loaded once a cell
         self.name = name
         self.entry = cells[name]
         self.chips = int(self.entry["chips"])
         cfg_entry = next(c for c in self.bench["configs"]
                          if c["name"] == self.entry["config"])
         self.config = _load(os.path.join(root, cfg_entry["file"]))
+        if "family" not in self.config:
+            raise SystemExit(
+                f"{cfg_entry['file']} names no \"family\": say which "
+                f"benchmark/families/<family>.py and benchmark/reference/"
+                f"<family>.py hold its weights, program builder, leaf map, "
+                f"counts and plain reference")
         self.mix = _load(os.path.join(here, "traffic",
                                       self.entry["traffic"] + ".json"))
         lim = os.path.join(here, "limits", name + ".json")
@@ -65,14 +73,26 @@ class Cell:
             raise SystemExit(f"benchmark/{'/'.join(parts)} is not there: "
                              f"add the file, the harness finds it by name")
         name = "benchmark." + ".".join(parts)[:-len(".py")]
-        mod = sys.modules.get(name)
+        mod = self._loaded.get(path) or sys.modules.get(name)
         if mod is None or getattr(mod, "__file__", None) != path:
             spec = importlib.util.spec_from_file_location(name, path)
             mod = importlib.util.module_from_spec(spec)
             if path.startswith(HERE + os.sep):
                 sys.modules[name] = mod     # the stock file IS that module
             spec.loader.exec_module(mod)
+        self._loaded[path] = mod
         return mod
+
+    @property
+    def family(self):
+        """`families/<family>.py`: the weights, the program's model and
+        its leaves, the counts of operations and bytes."""
+        return self.module("families", self.config["family"] + ".py")
+
+    @property
+    def reference(self):
+        """`reference/<family>.py`: the family's plain reference."""
+        return self.module("reference", self.config["family"] + ".py")
 
     def driver(self):
         """`drive_<kind>.py::run` for the mix's `kind`."""

@@ -1,8 +1,10 @@
 """The one file that touches the program: builds the system under test
-(`paddle_tpu`'s GPT, `jit.TrainStep`, `io.DeviceLoader`, `serving.
-DecodeEngine`) from a configuration file and the benchmark's own weights,
-and reads its public counters. Everything measured or compared lives in
-the benchmark's other files.
+(`jit.TrainStep`, `io.DeviceLoader`, `serving.DecodeEngine` round the model
+the cell's family builds) from a configuration file and the benchmark's
+own weights, and reads its public counters. What knows a model's leaves,
+shapes or arithmetic is the family's (`families/<family>.py`, reached
+through `cell.family`); everything measured or compared lives in the
+benchmark's other files.
 """
 from __future__ import annotations
 
@@ -14,63 +16,42 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import sketch as SK
-from . import weights as W
-
-# stacked reference key -> the program's leaf name inside one block
-_BLOCK_LEAVES = {
-    "ln1_w": "ln_1.weight", "ln1_b": "ln_1.bias",
-    "qkv_w": "attn.qkv_proj.weight", "qkv_b": "attn.qkv_proj.bias",
-    "proj_w": "attn.out_proj.weight", "proj_b": "attn.out_proj.bias",
-    "ln2_w": "ln_2.weight", "ln2_b": "ln_2.bias",
-    "fc1_w": "mlp.fc_in.weight", "fc1_b": "mlp.fc_in.bias",
-    "fc2_w": "mlp.fc_out.weight", "fc2_b": "mlp.fc_out.bias",
-}
-_TOP_LEAVES = {"wte": "gpt.wte.weight", "wpe": "gpt.wpe.weight",
-               "lnf_w": "gpt.ln_f.weight", "lnf_b": "gpt.ln_f.bias"}
 
 
-def leaf_map(model: dict) -> dict:
-    """{program leaf name: (stacked key, layer index or None)}."""
-    out = {name: (key, None) for key, name in _TOP_LEAVES.items()}
-    for i in range(model["num_layers"]):
-        for key, name in _BLOCK_LEAVES.items():
-            out[f"gpt.h.{i}.{name}"] = (key, i)
-    return out
-
-
-def compare_map(model: dict) -> dict:
+def compare_map(fam, model: dict) -> dict:
     """{comparison leaf: (reference norm key, layer)}: the program's
-    leaves, the fused qkv bias split into its q, k and v thirds."""
+    leaves, a fused one (`fam.FUSED`) split into its parts."""
     out = {}
-    for name, (key, layer) in leaf_map(model).items():
-        if key == "qkv_b":
-            for part in "qkv":
-                out[f"{name}.{part}"] = (f"qkv_b.{part}", layer)
+    for name, (key, layer) in fam.leaf_map(model).items():
+        if key in fam.FUSED:
+            for part in fam.FUSED[key]:
+                out[f"{name}.{part}"] = (f"{key}.{part}", layer)
         else:
             out[name] = (key, layer)
     return out
 
 
-def _leaf_norms(names, arrays) -> dict:
-    """L2 norm of each program leaf, the qkv bias by thirds; one jitted
-    call, scalars fetched once."""
+def _leaf_norms(parts_of: dict, arrays) -> dict:
+    """L2 norm of each program leaf, a fused one by its parts (equal
+    slices of the last axis); `parts_of` is {leaf name: part names or
+    ()}, in the arrays' order. One jitted call, scalars fetched once."""
     def sq(x):
         return jnp.sqrt(jnp.sum(jnp.square(x.astype(jnp.float32))))
 
     def norms(xs):
         out = []
-        for n, x in zip(names, xs):
-            if n.endswith("qkv_proj.bias"):
-                h = x.shape[-1] // 3
-                out += [sq(x[i * h:(i + 1) * h]) for i in range(3)]
+        for parts, x in zip(parts_of.values(), xs):
+            if parts:
+                h = x.shape[-1] // len(parts)
+                out += [sq(x[..., i * h:(i + 1) * h])
+                        for i in range(len(parts))]
             else:
                 out.append(sq(x))
         return out
 
     keys = []
-    for n in names:
-        keys += [f"{n}.{p}" for p in "qkv"] \
-            if n.endswith("qkv_proj.bias") else [n]
+    for n, parts in parts_of.items():
+        keys += [f"{n}.{p}" for p in parts] if parts else [n]
     return dict(zip(keys, map(float, jax.jit(norms)(arrays))))
 
 
@@ -100,21 +81,18 @@ def _cheap_init():
         api._host_sample = orig
 
 
-def build_model(cfg: dict, arrays: dict):
-    """`GPTForCausalLM` at the configuration's sizes, every leaf replaced
-    by the benchmark's array for it (so the program's own initialiser
-    decides nothing)."""
-    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+def build_model(fam, cfg: dict, arrays: dict):
+    """The family's model at the configuration's sizes, every leaf
+    replaced by the benchmark's array for it (so the program's own
+    initialiser decides nothing)."""
     with _cheap_init():
-        model = GPTForCausalLM(GPTConfig(hidden_dropout_prob=0.0,
-                                         attention_dropout_prob=0.0,
-                                         **cfg["model"]))
-    assign(model, cfg["model"], arrays)
+        model = fam.build(cfg)
+    assign(fam, model, cfg["model"], arrays)
     return model
 
 
-def assign(model, model_cfg: dict, arrays: dict):
-    lm = leaf_map(model_cfg)
+def assign(fam, model, model_cfg: dict, arrays: dict):
+    lm = fam.leaf_map(model_cfg)
     seen = set()
     for name, p in model.named_parameters():
         key, layer = lm[name]
@@ -134,14 +112,15 @@ class Trainer:
     as the job file states it. `batches()` yields device batches; `step`
     dispatches one optimizer update and returns the loss handle."""
 
-    def __init__(self, cfg: dict, job: dict, seed: int, rows):
+    def __init__(self, cell, seed: int, rows):
         import paddle_tpu as paddle
         from paddle_tpu.io import DataLoader, Dataset, DeviceLoader
-        self.model_cfg = cfg["model"]
-        self.job = job
+        cfg, job = cell.config, cell.mix
+        self.fam, self.model_cfg, self.job = cell.family, cfg["model"], job
+        self.seed, self.dtype = seed, cfg["dtype"]
         opt_cfg = job["optimizer"]
-        arrays = W.make(self.model_cfg, seed, cfg["dtype"])
-        self.model = build_model(cfg, arrays)
+        arrays = self.fam.make(self.model_cfg, seed, self.dtype)
+        self.model = build_model(self.fam, cfg, arrays)
         del arrays
         if job.get("recompute", "none") != "none":
             self.model.enable_recompute(job["recompute"])
@@ -186,6 +165,11 @@ class Trainer:
             raise ValueError("optimizer state does not cover every leaf")
         return sd, dict(zip(names, keys))
 
+    def _norms(self, names, arrays) -> dict:
+        lm = self.fam.leaf_map(self.model_cfg)
+        return _leaf_norms({n: self.fam.FUSED.get(lm[n][0], ())
+                            for n in names}, arrays)
+
     def _masters(self, sd, key_of) -> list:
         """The fp32 value the optimizer updates: its master copy, or the
         parameter itself where that is already float32."""
@@ -202,7 +186,7 @@ class Trainer:
         b1 = self.job["optimizer"]["beta1"]
         m = [sd[f"{key_of[n]}_moment1"].value() for n in key_of]
         return {n: v / (1.0 - b1)
-                for n, v in _leaf_norms(list(key_of), m).items()}
+                for n, v in self._norms(key_of, m).items()}
 
     def first_grad_sketches(self) -> dict:
         """{leaf: [K] sketch of its first gradient}, from the same first
@@ -219,18 +203,18 @@ class Trainer:
         out = jax.device_get(jax.jit(all_of)(m))
         return {n: np.asarray(v) / (1.0 - b1) for n, v in zip(key_of, out)}
 
-    def update_norms(self, seed: int, dtype: str) -> dict:
+    def update_norms(self) -> dict:
         """Per-leaf norm of (fp32 master now - the weights it started
         from); the start is made again from the seed."""
         sd, key_of = self._state()
-        lm = leaf_map(self.model_cfg)
-        w0 = W.make(self.model_cfg, seed, dtype)
+        lm = self.fam.leaf_map(self.model_cfg)
+        w0 = self.fam.make(self.model_cfg, self.seed, self.dtype)
         diffs = []
         for n, mst in zip(key_of, self._masters(sd, key_of)):
             key, layer = lm[n]
             a = w0[key] if layer is None else w0[key][layer]
             diffs.append(mst - a.astype(jnp.float32))
-        return _leaf_norms(list(key_of), diffs)
+        return self._norms(key_of, diffs)
 
     def num_compiles(self) -> int:
         return int(self.step_fn.num_compiles)
@@ -253,17 +237,18 @@ class Trainer:
 class Server:
     """`DecodeEngine` over the configuration's deployment."""
 
-    def __init__(self, cfg: dict, seed: int, model=None):
+    def __init__(self, cell, seed: int, model=None):
         """`model`: a model object kept from an earlier Server of the same
         configuration (the tools' several seeds in one process); it gets
         this seed's weights and a new engine."""
         from paddle_tpu.serving import DecodeEngine
-        arrays = W.make(cfg["model"], seed, cfg["dtype"])
+        cfg, fam = cell.config, cell.family
+        arrays = fam.make(cfg["model"], seed, cfg["dtype"])
         if model is None:
-            self.model = build_model(cfg, arrays)
+            self.model = build_model(fam, cfg, arrays)
         else:
             self.model = model
-            assign(model, cfg["model"], arrays)
+            assign(fam, model, cfg["model"], arrays)
         del arrays
         self.model.eval()
         self.geometry = dict(cfg["engine"])

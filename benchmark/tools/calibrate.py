@@ -27,7 +27,7 @@ import numpy as np
 
 def training(cell, seeds, n_control, _seconds):
     from .. import correct, system, traffic
-    from ..reference import gpt as ref
+    from ..reference import common
     cfg, job = cell.config, cell.mix
     model = cfg["model"]
     n_ref = int(job["reference_steps"])
@@ -36,7 +36,7 @@ def training(cell, seeds, n_control, _seconds):
         rows = lambda j: traffic.train_row(          # noqa: E731
             seed, j, model["vocab_size"], job["seq"])
         t0 = time.time()
-        tr = system.Trainer(cfg, job, seed, rows)
+        tr = system.Trainer(cell, seed, rows)
         feed = tr.batches()
         got = {"losses": []}
         for s in range(n_ref):
@@ -44,13 +44,12 @@ def training(cell, seeds, n_control, _seconds):
             if s == 0:
                 got["grad"] = tr.first_grad_norms()
                 got["sketch"] = tr.first_grad_sketches()
-        got["update"] = tr.update_norms(seed, cfg["dtype"])
+        got["update"] = tr.update_norms()
         tr.close()
         t1 = time.time()
 
         def follow(**kw):
-            return correct.follow_reference(
-                model, job, seed, cfg["dtype"], rows, n_ref, **kw)
+            return correct.follow_reference(cell, seed, rows, n_ref, **kw)
 
         want = follow()
         t2 = time.time()
@@ -70,7 +69,7 @@ def training(cell, seeds, n_control, _seconds):
                "losses": got["losses"], "ref_losses": want["losses"]}
         if i < n_control:
             rec["control_fp8"] = held(correct.compare_training(
-                follow(dot=ref.fp8_dot), want))
+                follow(dot=common.fp8_dot), want))
             rec["fault_half_batch"] = held(correct.compare_training(
                 follow(batch_rows=range(job["batch"] // 2)), want))
         print(json.dumps(rec), flush=True)
@@ -128,7 +127,7 @@ def serving(cell, seeds, n_control, seconds, masked=False):
     from ..trace import Recorder
     cfg = cell.config
     vocab = cfg["model"]["vocab_size"]
-    pad = cfg["model"]["max_position_embeddings"]
+    pad = cfg["engine"]["max_len"]
     model, out = None, []
     for i, seed in enumerate(seeds):
         rec = Recorder()
@@ -153,8 +152,7 @@ def serving(cell, seeds, n_control, seconds, masked=False):
                     ("fault_altered_token",
                      altered_last_token(res["sample"], vocab, seed), False)):
                 gap, mean, n = correct.served_token_gaps(
-                    cfg["model"], seed, cfg["dtype"], sample, pad,
-                    control=control)
+                    cell, seed, sample, pad, control=control)
                 read = dict(res["numbers"], served_logit_gap=gap,
                             served_logit_gap_mean=mean, tokens_compared=n)
                 rows, ok = correct.verdict(read, cell.limits)

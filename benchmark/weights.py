@@ -1,36 +1,15 @@
-"""The benchmark's own weights: every array of a GPT configuration made on
-the device from `--seed` in ONE jitted call, in the type they are served or
+"""The benchmark's own weights: every array of a configuration made on the
+device from `--seed` in ONE jitted call, in the type they are served or
 trained in. The program's model and the plain reference are both handed
-these arrays; neither makes any of its own.
-
-GPT-3 recipe: N(0, 0.02) matrices and embeddings, residual-out projections
-scaled by 1/sqrt(2L). Biases and LayerNorm parameters are given small
-random values too (the program initialises them to 0 and 1): a check on
-all-zero biases would not see a bias that is dropped.
+these arrays; neither makes any of its own. Which arrays a configuration
+has, and how each is scaled, is its family's to say
+(`families/<family>.py::shapes`, `make`); the seed's key and the drawing
+are here, once.
 """
 from __future__ import annotations
 
-import math
-
 import jax
 import jax.numpy as jnp
-
-
-def shapes(model: dict) -> dict:
-    """{stacked key: shape} for a configuration's `model` group."""
-    v, h, n_l = model["vocab_size"], model["hidden_size"], model["num_layers"]
-    p, i = model["max_position_embeddings"], 4 * model["hidden_size"]
-    return {
-        "wte": (v, h), "wpe": (p, h), "lnf_w": (h,), "lnf_b": (h,),
-        "ln1_w": (n_l, h), "ln1_b": (n_l, h), "qkv_w": (n_l, h, 3 * h),
-        "qkv_b": (n_l, 3 * h), "proj_w": (n_l, h, h), "proj_b": (n_l, h),
-        "ln2_w": (n_l, h), "ln2_b": (n_l, h), "fc1_w": (n_l, h, i),
-        "fc1_b": (n_l, i), "fc2_w": (n_l, i, h), "fc2_b": (n_l, h),
-    }
-
-
-def n_params(model: dict) -> int:
-    return sum(math.prod(s) for s in shapes(model).values())
 
 
 def seed_key(seed: int):
@@ -43,24 +22,18 @@ def seed_key(seed: int):
         jax.random.key(seed & 0x7FFFFFFF, impl="threefry2x32"), seed >> 31)
 
 
-def make(model: dict, seed: int, dtype="bfloat16"):
-    """All arrays of the configuration, on the device, from the seed."""
-    shp = shapes(model)
-    std = 0.02
-    resid = std / math.sqrt(2.0 * model["num_layers"])
-
+def draw(shapes: dict, recipe, seed: int, dtype):
+    """{key: array} on the device: array `key` is mean + std x a standard
+    normal drawn under `fold_in(seed's key, n)`, n the key's place in
+    sorted order; `recipe(key)` gives (mean, std)."""
     def build(key):
         out = {}
-        for n, (name, shape) in enumerate(sorted(shp.items())):
-            z = jax.random.normal(jax.random.fold_in(key, n), shape,
-                                  jnp.float32)
-            if name in ("proj_w", "fc2_w"):
-                a = resid * z
-            elif name.endswith("_w") and name.startswith("ln"):
-                a = 1.0 + std * z
-            else:
-                a = std * z
-            out[name] = a.astype(dtype)
+        for n, (name, shape) in enumerate(sorted(shapes.items())):
+            mean, std = recipe(name)
+            a = std * jax.random.normal(jax.random.fold_in(key, n), shape,
+                                        jnp.float32)
+            # no `0.0 +` where the mean is 0: tests pin the arrays' bits
+            out[name] = (mean + a if mean else a).astype(dtype)
         return out
 
     return jax.jit(build)(seed_key(seed))

@@ -117,9 +117,8 @@ def test_fp8_control_is_not_correct(seed):
                           Setup(time.time()))
     assert res["numbers"]["served_logit_gap"] <= \
         cell.limits["served_logit_gap"]
-    gap, mean, n = correct.served_token_gaps(
-        cell.config["model"], seed, cell.config["dtype"], res["sample"],
-        128, control=True)
+    gap, mean, n = correct.served_token_gaps(cell, seed, res["sample"], 128,
+                                             control=True)
     assert 0 < mean <= gap
     assert n == res["numbers"]["tokens_compared"] > 50
     # the control has to fail ONE of the cell's numbers, through the same
@@ -170,8 +169,7 @@ def test_the_least_altered_token_fails_the_widest_gap():
     assert len(bad) == 1 and bad[0]["tokens"][:-1] == \
         res["sample"][0]["tokens"][:-1]
     assert bad[0]["tokens"][-1] != res["sample"][0]["tokens"][-1]
-    gap, mean, n = correct.served_token_gaps(
-        cell.config["model"], 3, cell.config["dtype"], bad, 128)
+    gap, mean, n = correct.served_token_gaps(cell, 3, bad, 128)
     rows, ok = correct.verdict(dict(res["numbers"], served_logit_gap=gap),
                                cell.limits)
     assert not ok and "served_logit_gap" in failed(rows)

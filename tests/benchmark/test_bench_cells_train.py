@@ -91,17 +91,15 @@ def test_fp8_control_is_not_correct(seed):
     """The reference put in the program's place, its products in fp8."""
     from _tiny import tiny_cell
     from benchmark import correct, traffic
-    from benchmark.reference import gpt as ref
+    from benchmark.reference import common
     cell = tiny_cell(CELL)
-    model, job = cell.config["model"], cell.mix
-    rows = lambda i: traffic.train_row(seed, i, 256, job["seq"])  # noqa
+    rows = lambda i: traffic.train_row(seed, i, 256, cell.mix["seq"])  # noqa
 
     def follow(**kw):
-        return correct.follow_reference(model, job, seed, "float32", rows,
-                                        3, **kw)
+        return correct.follow_reference(cell, seed, rows, 3, **kw)
 
     want = follow()
-    numbers = correct.compare_training(follow(dot=ref.fp8_dot), want)
+    numbers = correct.compare_training(follow(dot=common.fp8_dot), want)
     rows_, ok = correct.verdict(numbers, {k: v for k, v in cell.limits.items()
                                           if k in numbers})
     assert ok is False

@@ -234,10 +234,10 @@ def test_every_new_metric_is_listed_for_a_cell_that_reports_its_target():
         assert m["source"] == ("device_trace"
                                if m["name"].startswith("idle_in_")
                                else "program_counter")
-    # appended: everything the accepted benchmark had comes first
-    names = [m["name"] for m in stock["per_layer"]]
-    assert all(n.split(".")[0] in NEW for n in names[-15:])
-    assert not any(n.split(".")[0] in NEW for n in names[:-15])
+        # listed for a cell that reports the end-to-end metric it moves
+        reporters = next(e for e in stock["end_to_end"]
+                         if e["name"] == m["moves"])["workloads"]
+        assert set(m["workloads"]) <= set(reporters)
 
 
 def test_serving_readers_on_a_real_open_loop_run(tmp_path):
