@@ -28,7 +28,8 @@ from .....nn import initializer
 from .....nn.layer import Layer
 from .....ops._helpers import _op
 
-__all__ = ["MoELayer", "switch_gate", "gshard_gate", "naive_gate"]
+__all__ = ["MoELayer", "switch_gate", "gshard_gate", "naive_gate",
+           "HeldExpertsMoE", "route_topk", "collect_counters"]
 
 
 def _one_hot_dispatch(gates, capacity):
@@ -164,3 +165,6 @@ class MoELayer(Layer):
                      **self._gate_cfg)
         self.aux_loss = aux
         return y
+
+
+from .held import HeldExpertsMoE, collect_counters, route_topk  # noqa: E402,F401

@@ -1,0 +1,153 @@
+"""An expert layer that is TOLD which experts it holds: one chip's share of
+an expert-parallel deployment, without its exchange.
+
+``HeldExpertsMoE`` is built with the router's full width (``n_routed``),
+``top_k``, ``norm_topk_prob`` and the range of experts that live here
+(``offset``, ``count``). A call scores every token against ALL routed
+experts in float32, keeps the published top-k and their weights, and returns
+
+    the shared expert's output  +  sum over assignments to HELD experts
+
+so an assignment to an absent expert is left out: its owner adds it, on
+another chip, and the partial sum is what goes on to the next layer. Summed
+over the shares of a deployment, the shared expert counted once, the parts
+are the uncut layer (``tests/test_qwen3_next.py`` holds them to it). No
+capacity and no dropped token: the held experts' groups are multiplied by
+``kernels/pallas/moe_grouped.py``. Nothing here stands in for the other
+chips or their traffic; the all-to-all over an ``"expert"`` mesh axis is
+the next step (ROADMAP).
+
+Inference-only raw-array math, like the cached attention paths: the router's
+auxiliary loss and a backward through the grouped matmul are not here yet.
+
+``collect_counters()`` is how a serving executable reads the step's routing:
+inside it every call adds three traced integers (assignments of valid
+tokens, those that fell on held experts, held experts with at least one).
+"""
+from __future__ import annotations
+
+import contextlib
+
+import jax
+import jax.numpy as jnp
+
+from .....nn import initializer
+from .....nn.layer import Layer
+from .....kernels.pallas import moe_grouped
+
+__all__ = ["HeldExpertsMoE", "route_topk", "collect_counters"]
+
+_COUNTERS = []                # innermost collector last
+
+
+class _Counters:
+    def __init__(self):
+        self.items = []
+
+    def total(self):
+        """int32 [3] summed over the calls recorded, or None for none."""
+        if not self.items:
+            return None
+        return jnp.sum(jnp.stack(self.items), axis=0).astype(jnp.int32)
+
+
+@contextlib.contextmanager
+def collect_counters():
+    c = _Counters()
+    _COUNTERS.append(c)
+    try:
+        yield c
+    finally:
+        _COUNTERS.pop()
+
+
+def route_topk(x, router_w, top_k: int, norm_topk_prob: bool):
+    """Float32 router: softmax over ALL routed experts, top-k, weights
+    renormalised to sum 1 when the model says so. ``x [T, H]``, ``router_w
+    [H, n_routed]``. Returns (ids [T, k] int32, weights [T, k] float32)."""
+    logits = jnp.einsum("th,he->te", x.astype(jnp.float32),
+                        router_w.astype(jnp.float32), precision="highest",
+                        preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, ids = jax.lax.top_k(probs, top_k)
+    if norm_topk_prob:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return ids.astype(jnp.int32), weights
+
+
+def _swiglu(x, gate_w, up_w, down_w):
+    prec = "highest" if x.dtype == jnp.float32 else None
+    hid = jax.nn.silu(jnp.dot(x, gate_w, precision=prec)) \
+        * jnp.dot(x, up_w, precision=prec)
+    return jnp.dot(hid, down_w, precision=prec)
+
+
+class HeldExpertsMoE(Layer):
+    """Router over ``n_routed`` experts + the ``count`` of them held here
+    (global ids ``offset .. offset + count - 1``) + an optional shared
+    expert gated by ``sigmoid(x w_s)``."""
+
+    def __init__(self, hidden_size: int, expert_width: int, n_routed: int,
+                 top_k: int, *, offset: int = 0, count: int = None,
+                 norm_topk_prob: bool = True, shared_width: int = 0,
+                 std: float = 0.02, dtype=None):
+        super().__init__()
+        count = n_routed if count is None else count
+        if not (0 <= offset and offset + count <= n_routed and count >= 1):
+            raise ValueError(f"held experts [{offset}, {offset + count}) "
+                             f"do not lie inside the router's {n_routed}")
+        self.n_routed, self.top_k = int(n_routed), int(top_k)
+        self.offset, self.count = int(offset), int(count)
+        self.norm_topk_prob = bool(norm_topk_prob)
+        normal = initializer.Normal(0.0, std)
+
+        def mat(*shape):
+            return self.create_parameter(shape, dtype=dtype,
+                                         default_initializer=normal)
+
+        h, i = hidden_size, expert_width
+        self.gate = mat(h, n_routed)                    # the router
+        self.experts_gate_proj = mat(count, h, i)
+        self.experts_up_proj = mat(count, h, i)
+        self.experts_down_proj = mat(count, i, h)
+        self.shared_width = int(shared_width)
+        if shared_width:
+            self.shared_gate_proj = mat(h, shared_width)
+            self.shared_up_proj = mat(h, shared_width)
+            self.shared_down_proj = mat(shared_width, h)
+            self.shared_expert_gate = mat(h, 1)
+
+    def apply(self, x, valid=None):
+        """``x [T, H]`` raw array in the model's dtype, ``valid [T]`` bool
+        (default: all). Returns [T, H] in ``x``'s dtype."""
+        t = x.shape[0]
+        valid = jnp.ones((t,), bool) if valid is None else valid
+        with jax.named_scope("moe_route"):
+            ids, weights = route_topk(x, self.gate.value(), self.top_k,
+                                      self.norm_topk_prob)
+        with jax.named_scope("moe_experts"):
+            out, counts = moe_grouped.moe_grouped(
+                x, ids, weights, valid, self.experts_gate_proj.value(),
+                self.experts_up_proj.value(), self.experts_down_proj.value(),
+                self.offset)
+        if _COUNTERS:
+            _COUNTERS[-1].items.append(jnp.stack([
+                jnp.sum(valid.astype(jnp.int32)) * self.top_k,
+                jnp.sum(counts), jnp.sum((counts > 0).astype(jnp.int32))]))
+        if self.shared_width:
+            with jax.named_scope("shared_expert"):
+                prec = "highest" if x.dtype == jnp.float32 else None
+                sh = _swiglu(x, self.shared_gate_proj.value(),
+                             self.shared_up_proj.value(),
+                             self.shared_down_proj.value())
+                g = jax.nn.sigmoid(jnp.dot(
+                    x, self.shared_expert_gate.value(),
+                    precision=prec).astype(jnp.float32))
+                out = out + sh.astype(jnp.float32) * g
+        return out.astype(x.dtype)
+
+    def forward(self, x):
+        from .....core.tensor import Tensor
+        a = x.value()
+        return Tensor(self.apply(a.reshape(-1, a.shape[-1]))
+                      .reshape(a.shape))

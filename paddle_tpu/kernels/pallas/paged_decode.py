@@ -74,15 +74,19 @@ def kernel_traces() -> int:
     return _TRACES["n"]
 
 
-def kernel_mode(q, pool_k):
+def kernel_mode(q, pool_k, n_kv=None):
     """How the paged decode step should attend: ``"mosaic"`` on a TPU whose
     tiling the shapes fit, ``"interpret"`` inside ``force_interpret``, None
-    for the gather path. Decided from the input's shapes and placement."""
+    for the gather path. Decided from the input's shapes and placement.
+    ``n_kv`` says a 3-D pool's rows are (position, KV head) pairs."""
     if _FORCE["interpret"]:
         return "interpret"
     if not tpu_placement(q):
         return None
-    bs, n_kv, hd = pool_k.shape[1:]
+    if n_kv is None:
+        bs, n_kv, hd = pool_k.shape[1:]
+    else:
+        bs, hd = pool_k.shape[1] // n_kv, pool_k.shape[2]
     sublanes = 32 // jnp.dtype(pool_k.dtype).itemsize    # rows of one tile
     if hd % 128 or (bs * n_kv) % sublanes or q.shape[-2] % n_kv:
         return None
@@ -211,30 +215,39 @@ def _kernel(len_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 
 def paged_decode_attention(q, pool_k, pool_v, table, lengths, *,
-                           interpret=False, pages_per_chunk=None):
+                           interpret=False, pages_per_chunk=None,
+                           n_kv=None):
     """Attention of one query position per slot over that slot's live KV.
 
     ``q`` [B, 1, nh, hd]; ``pool_k`` / ``pool_v`` [NB, BS, n_kv, hd] in
     their stored dtype (``nh`` a multiple of ``n_kv``: query head ``i``
-    reads KV head ``i // (nh // n_kv)``); ``table`` [B, mbs] int32 block
-    ids; ``lengths`` [B] int32, the live positions of each slot (cursor +
-    1, at least 1). Returns the context [B, 1, nh, hd] in ``q``'s dtype.
+    reads KV head ``i // (nh // n_kv)``), or with ``n_kv`` given already
+    [NB, BS * n_kv, hd] (``cache_spec.kv_layer(merged_rows=True)``: the
+    matrix a block the kernel works on; for few KV heads the 4-D form is
+    tiled so that viewing it this way copies the pool); ``table`` [B, mbs]
+    int32 block ids; ``lengths`` [B] int32, the live positions of each slot
+    (cursor + 1, at least 1). Returns the context [B, 1, nh, hd] in ``q``'s
+    dtype.
     """
-    assert q.shape[1] == 1 and q.shape[2] % pool_k.shape[2] == 0 \
-        and pool_v.shape == pool_k.shape
+    if n_kv is None:
+        nb, block, n_kv, hd = pool_k.shape
+        pool_k = pool_k.reshape(nb, block * n_kv, hd)
+        pool_v = pool_v.reshape(nb, block * n_kv, hd)
+    assert q.shape[1] == 1 and q.shape[2] % n_kv == 0 \
+        and pool_v.shape == pool_k.shape and pool_k.shape[1] % n_kv == 0
     _TRACES["n"] += 1
     return _attend(q, pool_k, pool_v, table, lengths, interpret=interpret,
                    pages=min(pages_per_chunk or PAGES_PER_CHUNK,
-                             table.shape[1]))
+                             table.shape[1]), n_kv=n_kv)
 
 
 # jitted so that a model's layers share ONE trace and ONE Mosaic lowering of
 # the kernel (it is the same program in each; lowered once a layer, it was
 # most of a minute of every start on the chip's host, cache hit or not)
-@functools.partial(jax.jit, static_argnames=("interpret", "pages"))
-def _attend(q, pool_k, pool_v, table, lengths, *, interpret, pages):
+@functools.partial(jax.jit, static_argnames=("interpret", "pages", "n_kv"))
+def _attend(q, pool_k, pool_v, table, lengths, *, interpret, pages, n_kv):
     b, _, nh, hd = q.shape
-    nb, block, n_kv, _ = pool_k.shape
+    nb, block = pool_k.shape[0], pool_k.shape[1] // n_kv
     mbs = table.shape[1]
     kernel = functools.partial(
         _kernel, pages=pages, block=block, n_kv=n_kv, group=nh // n_kv,
@@ -258,7 +271,5 @@ def _attend(q, pool_k, pool_v, table, lengths, *, interpret, pages):
         interpret=interpret,
         name="paged_decode",
     )(lengths.astype(jnp.int32), table.reshape(-1).astype(jnp.int32),
-      q.reshape(b, nh, hd),
-      pool_k.reshape(nb, block * n_kv, hd),
-      pool_v.reshape(nb, block * n_kv, hd))
+      q.reshape(b, nh, hd), pool_k, pool_v)
     return out.reshape(b, 1, nh, hd)

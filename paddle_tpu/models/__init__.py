@@ -15,3 +15,5 @@ from .t5 import (T5Config, T5Model,  # noqa: F401
                  T5ForConditionalGeneration, t5_tiny)
 from .llama import (LlamaConfig, LlamaModel, LlamaForCausalLM, llama_tiny,  # noqa: F401
                     llama_7b, shard_llama_tp)
+from .qwen3_next import (Qwen3NextConfig, Qwen3NextModel,  # noqa: F401
+                         Qwen3NextForCausalLM, qwen3_next_tiny)

@@ -690,6 +690,22 @@ class GPTForCausalLM(nn.Layer):
 
     # ------------------------------------------------------------ generation
 
+    def decode_spec(self):
+        """What the serving engine drives (``models/cache_spec.py``): every
+        layer caches K/V, one head a query head."""
+        from .cache_spec import ModelSpec, kv_layer
+        cfg = self.config
+        if getattr(cfg, "scan_layers", False):
+            raise NotImplementedError(
+                "DecodeEngine requires scan_layers=False (the KV cache "
+                "threads through discrete blocks)")
+        tied = self.lm_head is None
+        return ModelSpec(
+            self.gpt, [kv_layer(cfg.num_heads,
+                                cfg.hidden_size // cfg.num_heads)]
+            * cfg.num_layers, cfg.max_position_embeddings,
+            self.gpt.wte.weight if tied else self.lm_head.weight, tied)
+
     def generate(self, input_ids, max_new_tokens: int = 32,
                  temperature: float = 1.0, do_sample: bool = False,
                  top_k: int = 0, eos_token_id=None, seed=None,

@@ -349,6 +349,19 @@ class LlamaForCausalLM(nn.Layer):
                               transpose_y=True)
         return self.lm_head(hidden)
 
+    def decode_spec(self):
+        """What the serving engine drives (``models/cache_spec.py``): every
+        layer caches K/V at the grouped KV-head count."""
+        from .cache_spec import ModelSpec, kv_layer
+        cfg = self.config
+        tied = self.lm_head is None
+        return ModelSpec(
+            self.model, [kv_layer(cfg.num_kv_heads,
+                                  cfg.hidden_size // cfg.num_heads)]
+            * cfg.num_layers, cfg.max_position_embeddings,
+            self.model.embed_tokens.weight if tied else self.lm_head.weight,
+            tied)
+
     def generate(self, input_ids, max_new_tokens: int = 32,
                  temperature: float = 1.0, do_sample: bool = False,
                  top_k: int = 0, eos_token_id=None, seed=None,

@@ -66,7 +66,7 @@ from __future__ import annotations
 import itertools
 import os
 import time
-from collections import OrderedDict, namedtuple
+from collections import OrderedDict
 from typing import List, Optional, Sequence
 
 import jax
@@ -78,6 +78,7 @@ from .. import monitor as _monitor
 from ..monitor import trace as _trace
 from ..core.tensor import Tensor
 from ..distributed.env import get_mesh
+from ..models.cache_spec import ModelSpec
 from ..models.gpt import (_lm_head_logits, _pick_token,
                           _resolve_decode_horizon, set_paged_kv_sharding)
 from ..distributed.reshard import snapshot as _snapshot
@@ -98,10 +99,6 @@ _DECODE_SPANS = ("engine/decode_prepare", "engine/decode_call")
 # terminal caller-supplied request ids remembered per engine for dedup
 # (a requeue retry arriving AFTER completion still returns the original)
 DEDUP_WINDOW = 1024
-
-ModelSpec = namedtuple("ModelSpec", [
-    "backbone", "num_layers", "n_kv_heads", "head_dim", "max_pos",
-    "head_weight", "head_transpose"])
 
 
 def _rides_model_axis(arr) -> bool:
@@ -134,34 +131,17 @@ def serving_mesh(leaves):
 
 
 def _model_spec(model) -> ModelSpec:
-    """Resolve the causal-LM surface the engine drives: the cached-forward
-    backbone, KV-cache geometry, and the LM head weight. Duck-typed over
-    GPTForCausalLM / LlamaForCausalLM (both expose ``backbone(ids,
-    kv_caches=..., start_pos=...) -> (hidden, new_caches)``)."""
-    cfg = getattr(model, "config", None)
-    if cfg is None:
-        raise TypeError(f"{type(model).__name__} has no .config — the "
-                        f"engine serves GPT/LLaMA-style causal LMs")
-    if hasattr(model, "gpt"):                       # GPTForCausalLM
-        if getattr(cfg, "scan_layers", False):
-            raise NotImplementedError(
-                "DecodeEngine requires scan_layers=False (the KV cache "
-                "threads through discrete blocks)")
-        return ModelSpec(
-            model.gpt, cfg.num_layers, cfg.num_heads,
-            cfg.hidden_size // cfg.num_heads, cfg.max_position_embeddings,
-            model.gpt.wte.weight if model.lm_head is None
-            else model.lm_head.weight,
-            model.lm_head is None)
-    if hasattr(model, "model"):                     # LlamaForCausalLM
-        return ModelSpec(
-            model.model, cfg.num_layers, cfg.num_kv_heads,
-            cfg.hidden_size // cfg.num_heads, cfg.max_position_embeddings,
-            model.model.embed_tokens.weight if model.lm_head is None
-            else model.lm_head.weight,
-            model.lm_head is None)
-    raise TypeError(f"cannot resolve a decode backbone on "
-                    f"{type(model).__name__}")
+    """What the model says of itself (``models/cache_spec.py``): the
+    cached-forward backbone, the LM head, and per layer what it caches
+    (``kv`` with heads and width, or ``state`` with its arrays). The engine
+    asks nothing else about the architecture."""
+    describe = getattr(model, "decode_spec", None)
+    if describe is None:
+        raise TypeError(
+            f"{type(model).__name__} has no decode_spec() - the engine "
+            f"serves causal LMs that describe their backbone and per-layer "
+            f"caches (models/cache_spec.py)")
+    return describe()
 
 
 def quantize_for_serving(model, skip: Sequence = ()):
@@ -299,6 +279,10 @@ class DecodeEngine:
             quantize_for_serving(model)
         self.model = model
         self.spec = spec
+        # layers that keep a fixed-size recurrent state per slot (beside or
+        # instead of K/V): what they cannot do yet is refused by name
+        self._has_state = bool(spec.state_layers)
+        self._state_bytes = spec.state_bytes_per_slot
         self.quantize = quantize
         self.max_slots = int(max_slots)
         self.max_len = int(max_len)
@@ -313,6 +297,11 @@ class DecodeEngine:
         # what sequential decode would produce.
         self.drafter = drafter
         if drafter is not None:
+            if self._has_state:
+                raise NotImplementedError(
+                    "speculative decoding with recurrent-state layers needs "
+                    "a state snapshot to roll rejected drafts back to: the "
+                    "verify executable is not built for such a model")
             if not self.paged:
                 raise NotImplementedError(
                     "speculative decoding requires paged=True (speculative "
@@ -346,6 +335,13 @@ class DecodeEngine:
         # RowParallel placements, and the block table / cursors / COW index
         # arguments stay replicated host data (the BlockPager is untouched)
         self._mesh, self._tp = serving_mesh(self._leaves)
+        if self._mesh is not None and (self._has_state or any(
+                c.merged_rows for c in spec.layers)):
+            raise NotImplementedError(
+                "tensor-parallel serving of recurrent-state layers needs a "
+                "sharding rule for the state arrays and for merged-row K/V "
+                "pools (and an \"expert\" axis for held experts): not "
+                "implemented")
         if self._mesh is None:
             # loud refusal beats a deep jit crash: a model sharded over a
             # mesh the engine cannot drive (no "model" axis installed in
@@ -422,16 +418,19 @@ class DecodeEngine:
                                  f"{self.max_len}], got {prefill_chunk}")
             self.prefill_chunk = None if prefill_chunk is None \
                 else int(prefill_chunk)
-            def _pool():
-                z = jnp.zeros((self.kv_blocks, self.block_size,
-                               spec.n_kv_heads, spec.head_dim),
+            def _pool(c):
+                rows = (self.block_size * c.n_kv_heads,) if c.merged_rows \
+                    else (self.block_size, c.n_kv_heads)
+                z = jnp.zeros((self.kv_blocks,) + rows + (c.head_dim,),
                               self._cache_dtype)
                 return z if self._pool_sh is None \
                     else jax.device_put(z, self._pool_sh)
-            self._pools = [(_pool(), _pool())
-                           for _ in range(spec.num_layers)]
+            self._pools = [(_pool(c), _pool(c)) if c.kind == "kv"
+                           else self._state_rows(c) for c in spec.layers]
+            # a prefix hit would skip tokens a recurrent state has to see
             self._pager = BlockPager(self.kv_blocks, self.block_size,
-                                     self.max_slots, self._mbs)
+                                     self.max_slots, self._mbs,
+                                     prefix_cache=not self._has_state)
             self._caches = None
             # in-flight chunked prefills: slot -> _PrefillState
             self._prefilling: dict = {}
@@ -447,17 +446,23 @@ class DecodeEngine:
             self._prefilling = {}
             self.preemptions = 0
             self._caches = [
-                (jnp.zeros((self.max_slots, self.max_len, spec.n_kv_heads,
-                            spec.head_dim), self._cache_dtype),
-                 jnp.zeros((self.max_slots, self.max_len, spec.n_kv_heads,
-                            spec.head_dim), self._cache_dtype))
-                for _ in range(spec.num_layers)]
+                (jnp.zeros((self.max_slots, self.max_len, c.n_kv_heads,
+                            c.head_dim), self._cache_dtype),
+                 jnp.zeros((self.max_slots, self.max_len, c.n_kv_heads,
+                            c.head_dim), self._cache_dtype))
+                if c.kind == "kv" else self._state_rows(c)
+                for c in spec.layers]
         # ---- cross-process prefix-cache tier (serving/kvpool.py): parked
         # registered blocks export to the pool, registry-miss admissions
         # fetch + adopt. All host state; zero effect when kv_pool is None.
         if kv_pool is not None and not self.paged:
             raise ValueError("kv_pool requires paged=True (the pool moves "
                              "page-table blocks)")
+        if kv_pool is not None and self._has_state:
+            raise NotImplementedError(
+                "pool export/adopt moves K/V blocks only: a model with "
+                "recurrent-state layers would need its state at the block "
+                "boundary exported with them")
         self._kv_pool = kv_pool
         self._pool_gen = 0
         self._exported: set = set()     # digests already in the pool (gen)
@@ -512,6 +517,10 @@ class DecodeEngine:
         self.compile_count = 0
         self.decode_steps = 0
         self.tokens_generated = 0
+        # running sums of what the decode executable reports of its routed
+        # layers (assignments, those to held experts, held experts touched);
+        # None until a model with expert layers has decoded
+        self.moe_counts = None
         self.engine_id = next(DecodeEngine._ids)
         # ---- guardrail plane (all host state; zero effect until used)
         # injectable clock: deadlines and drain grace read THIS, so tests
@@ -680,38 +689,93 @@ class DecodeEngine:
                                 if tokens else None),
                 devices=self._tp)
 
+    # ------------------------------------------------ per-layer caches
+
+    def _state_rows(self, layer):
+        """A state layer's arrays, one row a slot: ``[max_slots, *shape]``.
+        A row's content only matters between its tenant's first chunk,
+        which starts it from zero, and that tenant's last token."""
+        return tuple(jnp.zeros((self.max_slots,) + shape, jnp.dtype(dtype))
+                     for shape, dtype in layer.arrays)
+
+    def _layer_caches(self, pools, table=None, slot=None):
+        """What each layer's cached forward is handed: a kv layer its pools
+        and ``table`` (the rows of the call's slots), a state layer its
+        arrays' rows, ``slot``'s alone for a one-slot call."""
+        out = []
+        for layer, cache in zip(self.spec.layers, pools):
+            if layer.kind == "kv":
+                out.append(tuple(cache) + ((table,) if table is not None
+                                           else ()))
+            elif slot is None:
+                out.append(tuple(cache))
+            else:
+                out.append(tuple(jax.lax.dynamic_slice_in_dim(a, slot, 1, 0)
+                                 for a in cache))
+        return out
+
+    def _layer_results(self, pools, new, slot=None):
+        """The caches to keep after a call: what the backbone returned, a
+        one-slot call's state rows written back at ``slot``."""
+        if slot is None or not self._has_state:
+            return [tuple(n) for n in new]
+        return [tuple(n) if layer.kind == "kv" else tuple(
+                    jax.lax.dynamic_update_slice_in_dim(
+                        a, r.astype(a.dtype), slot, 0)
+                    for a, r in zip(cache, n))
+                for layer, cache, n in zip(self.spec.layers, pools, new)]
+
+    def _backbone(self, ids, caches, **kw):
+        """The cached forward, with whatever its routed layers count of
+        the call (int32 [3], or None for a model without any)."""
+        from ..incubate.distributed.models.moe.held import collect_counters
+        with collect_counters() as counted:
+            hidden, new = self.spec.backbone(Tensor(ids), kv_caches=caches,
+                                             **kw)
+        return hidden, new, counted.total()
+
     # --------------------------------------------------------- executables
 
-    @staticmethod
-    def _apply_cow(pools, src, dst):
+    def _apply_cow(self, pools, src, dst):
         """Fold the pager's pending copy-on-write block copies into the
         executable: ``pools[l][dst[i]] = pools[l][src[i]]`` before anything
         reads or writes. Padded entries are (0, 0) trash-to-trash no-ops,
-        so the shape is always [max_slots] and COW never retraces."""
-        return [(pk.at[dst].set(jnp.take(pk, src, axis=0)),
-                 pv.at[dst].set(jnp.take(pv, src, axis=0)))
-                for pk, pv in pools]
+        so the shape is always [max_slots] and COW never retraces. State
+        layers have no blocks to copy."""
+        return [(c[0].at[dst].set(jnp.take(c[0], src, axis=0)),
+                 c[1].at[dst].set(jnp.take(c[1], src, axis=0)))
+                if layer.kind == "kv" else c
+                for layer, c in zip(self.spec.layers, pools)]
+
+    def _sample(self, hidden_last, key, moe=None):
+        """LM head + pick over ``hidden_last [B, H]``: (token ids int32 [B],
+        per-row finite-logits flag). With ``moe`` (the routed layers' three
+        counts) the ids carry them as a tail, so one fetch brings both."""
+        with jax.named_scope("lm_head_sample"):
+            logits = self._head(hidden_last)
+            nxt = self._pick(logits, key).astype(jnp.int32)
+            # per-slot finite-logits flag: data, not shape - NaN detection
+            # never retraces, and a clean step pays one row-reduce fused
+            # into the head matmul's epilogue
+            ok = jnp.all(jnp.isfinite(logits), axis=-1)
+        if moe is not None:
+            nxt = jnp.concatenate([nxt, moe])
+        return nxt, ok
 
     def _build_decode(self):
-        spec = self.spec
-
+        # a model with state layers is told which slots are live: write_end
+        # = pos + 1 for them, pos for the rest, whose state (and K/V) the
+        # step must leave alone
         if self.paged:
-            def fn(leaves, pools, table, tok, pos, cow_src, cow_dst, key):
+            def fn(leaves, pools, table, tok, pos, cow_src, cow_dst, key,
+                   *end):
                 def body():
                     pools2 = self._apply_cow(pools, cow_src, cow_dst)
-                    caches = [(pk, pv, table) for pk, pv in pools2]
-                    hidden, new_pools = spec.backbone(
-                        Tensor(tok[:, None]), kv_caches=caches,
-                        start_pos=pos)
-                    with jax.named_scope("lm_head_sample"):
-                        logits = self._head(hidden.value()[:, -1])
-                        nxt = self._pick(logits, key).astype(jnp.int32)
-                        # per-slot finite-logits flag: data, not shape —
-                        # NaN detection never retraces, and a clean step
-                        # pays one row-reduce fused into the head matmul's
-                        # epilogue
-                        ok = jnp.all(jnp.isfinite(logits), axis=-1)
-                    return new_pools, nxt, ok
+                    hidden, new, moe = self._backbone(
+                        tok[:, None], self._layer_caches(pools2, table),
+                        start_pos=pos, **dict(zip(("write_end",), end)))
+                    nxt, ok = self._sample(hidden.value()[:, -1], key, moe)
+                    return self._layer_results(pools2, new), nxt, ok
                 return self._traced(leaves, body)
 
             pad = self._dev(jnp.zeros(self.max_slots, jnp.int32))
@@ -719,21 +783,20 @@ class DecodeEngine:
                     self._dev(self._pager.tables), self._dev(self._tok),
                     self._dev(self._pos), pad, pad, self._greedy_key)
         else:
-            def fn(leaves, caches, tok, pos, key):
+            def fn(leaves, caches, tok, pos, key, *end):
                 def body():
-                    hidden, new_caches = spec.backbone(
-                        Tensor(tok[:, None]), kv_caches=caches,
-                        start_pos=pos)
-                    with jax.named_scope("lm_head_sample"):
-                        logits = self._head(hidden.value()[:, -1])
-                        nxt = self._pick(logits, key).astype(jnp.int32)
-                        ok = jnp.all(jnp.isfinite(logits), axis=-1)
-                    return new_caches, nxt, ok
+                    hidden, new, moe = self._backbone(
+                        tok[:, None], self._layer_caches(caches),
+                        start_pos=pos, **dict(zip(("write_end",), end)))
+                    nxt, ok = self._sample(hidden.value()[:, -1], key, moe)
+                    return self._layer_results(caches, new), nxt, ok
                 return self._traced(leaves, body)
 
             args = (self._leaf_values(), self._caches,
                     jnp.asarray(self._tok), jnp.asarray(self._pos),
                     self._greedy_key)
+        if self._has_state:
+            args += (self._dev(self._pos),)
         t0 = time.time()
         if self.paged:
             from ..kernels.pallas import paged_decode
@@ -764,7 +827,6 @@ class DecodeEngine:
         ``end`` is the absolute end of VALID tokens in this call: the write
         path trashes the padded tail, and the returned token is picked from
         the true last position (only the final chunk's pick is used)."""
-        spec = self.spec
         mbs = self._mbs
 
         def fn(leaves, pools, table, ids, slot, p0, end, cow_src, cow_dst,
@@ -773,17 +835,16 @@ class DecodeEngine:
                 pools2 = self._apply_cow(pools, cow_src, cow_dst)
                 row = jax.lax.dynamic_slice(table, (slot, jnp.int32(0)),
                                             (1, mbs))
-                caches = [(pk, pv, row) for pk, pv in pools2]
-                hidden, new_pools = spec.backbone(
-                    Tensor(ids), kv_caches=caches, start_pos=p0,
-                    write_end=end)
+                hidden, new, _ = self._backbone(
+                    ids, self._layer_caches(pools2, row, slot),
+                    start_pos=p0, write_end=end)
                 with jax.named_scope("lm_head_sample"):
                     h_last = jax.lax.dynamic_slice_in_dim(
                         hidden.value(), end - p0 - 1, 1, axis=1)[:, 0]
                     logits = self._head(h_last)
                     tok0 = self._pick(logits, key).astype(jnp.int32)
                     ok = jnp.all(jnp.isfinite(logits))
-                return new_pools, tok0[0], ok
+                return self._layer_results(pools2, new, slot), tok0[0], ok
             return self._traced(leaves, body)
 
         pad = self._dev(jnp.zeros(self.max_slots, jnp.int32))
@@ -882,14 +943,19 @@ class DecodeEngine:
 
         def fn(leaves, caches, ids, slot, true_len, key):
             def body():
+                # a state layer starts from its own zero row and, unlike
+                # K/V, has to be told where the bucket's padding begins
                 small = [
-                    (jnp.zeros((1, sb, spec.n_kv_heads, spec.head_dim),
+                    (jnp.zeros((1, sb, c.n_kv_heads, c.head_dim),
                                self._cache_dtype),
-                     jnp.zeros((1, sb, spec.n_kv_heads, spec.head_dim),
+                     jnp.zeros((1, sb, c.n_kv_heads, c.head_dim),
                                self._cache_dtype))
-                    for _ in range(spec.num_layers)]
-                hidden, small_new = spec.backbone(
-                    Tensor(ids), kv_caches=small, start_pos=jnp.int32(0))
+                    if c.kind == "kv" else tuple(
+                        jnp.zeros((1,) + a.shape[1:], a.dtype) for a in big)
+                    for c, big in zip(spec.layers, caches)]
+                hidden, small_new, _ = self._backbone(
+                    ids, small, start_pos=jnp.int32(0),
+                    **({"write_end": true_len} if self._has_state else {}))
                 # logits from the TRUE last prompt token; the bucket's
                 # padding tail is causally invisible to it
                 h_last = jax.lax.dynamic_slice_in_dim(
@@ -898,11 +964,11 @@ class DecodeEngine:
                 tok0 = self._pick(logits, key).astype(jnp.int32)
                 ok = jnp.all(jnp.isfinite(logits))
                 new_caches = [
-                    (jax.lax.dynamic_update_slice(
-                        big_k, sk.astype(big_k.dtype), (slot, 0, 0, 0)),
-                     jax.lax.dynamic_update_slice(
-                        big_v, sv.astype(big_v.dtype), (slot, 0, 0, 0)))
-                    for (big_k, big_v), (sk, sv) in zip(caches, small_new)]
+                    tuple(jax.lax.dynamic_update_slice(
+                        big, sm.astype(big.dtype),
+                        (slot,) + (0,) * (big.ndim - 1))
+                        for big, sm in zip(bigs, smalls))
+                    for bigs, smalls in zip(caches, small_new)]
                 return new_caches, tok0[0], ok
             return self._traced(leaves, body)
 
@@ -1558,6 +1624,14 @@ class DecodeEngine:
 
     # ------------------------------------------------- paged scheduling
 
+    def _state_attrs(self, slots: int) -> dict:
+        """What a call span of a model with state layers carries: the slots
+        whose recurrent state the call reads and writes, and its bytes."""
+        if not self._has_state:
+            return {}
+        return dict(state_slots=int(slots),
+                    state_bytes=int(slots) * self._state_bytes)
+
     def _chunk_len(self, n: int) -> int:
         """Shape of the chunk executable serving a length-n prompt: the
         fixed ``prefill_chunk``, else the monolithic bucket for n (sized as
@@ -1809,7 +1883,7 @@ class DecodeEngine:
             return picked, bool(np.asarray(ok))
 
         (tok0, l_ok), call = self._dispatch_guarded(
-            "chunk", sc, _PREFILL_SPANS, upload, run)
+            "chunk", sc, _PREFILL_SPANS, upload, run, **self._state_attrs(1))
         with _trace.span("engine/prefill_host", **host):
             self._chunk_done(st, slot, sc, end, len(copies), tok0, l_ok,
                              call, finished)
@@ -2032,7 +2106,10 @@ class DecodeEngine:
                 src, dst = self._cow_args(
                     [p for c in copies_by_slot.values() for p in c])
             prep.set(live=self.live_count, cow=n_cow, preempted=preempted)
-        call_attrs = dict(path=self._decode_attention)
+        call_attrs = dict(path=self._decode_attention,
+                          **self._state_attrs(self.live_count))
+        # which slots the step may advance (see _build_decode)
+        end = (self._pos + self._live,) if self._has_state else ()
         if self.paged:
             # the live KV blocks this step has to read; the gather path read
             # max_slots * max_blocks_per_slot whatever this says
@@ -2042,7 +2119,7 @@ class DecodeEngine:
             def upload():
                 return (self._dev(self._decode_tables()),
                         self._dev(self._tok), self._dev(self._pos), src,
-                        dst, self._next_key())
+                        dst, self._next_key(), *map(self._dev, end))
 
             def run(*args):
                 self._pools, picked, ok = exe(self._leaf_values(),
@@ -2053,7 +2130,7 @@ class DecodeEngine:
         else:
             def upload():
                 return (jnp.asarray(self._tok), jnp.asarray(self._pos),
-                        self._next_key())
+                        self._next_key(), *map(jnp.asarray, end))
 
             def run(*args):
                 self._caches, picked, ok = exe(self._leaf_values(),
@@ -2063,6 +2140,13 @@ class DecodeEngine:
         (nxt, l_ok), call = self._dispatch_guarded(
             "decode", None, _DECODE_SPANS, upload, run, **call_attrs)
         with _trace.span("engine/decode_finish") as fin:
+            if len(nxt) > self.max_slots:
+                # the routed layers' counts ride behind the tokens
+                moe = nxt[self.max_slots:].astype(np.int64)
+                self.moe_counts = moe if self.moe_counts is None \
+                    else self.moe_counts + moe
+                fin.set(moe_assignments=int(moe[0]), moe_local=int(moe[1]),
+                        moe_touched=int(moe[2]))
             live = n_tok = n_done = 0
             for slot in range(self.max_slots):
                 req = self._slot_req[slot]
@@ -2353,6 +2437,13 @@ class DecodeEngine:
                 if self._watchdog is not None else 0,
             },
         }
+        if self._has_state:
+            out["state"] = {"layers": len(self.spec.state_layers),
+                            "bytes_per_slot": self._state_bytes,
+                            "slots": self.max_slots}
+        if self.moe_counts is not None:
+            out["moe"] = dict(zip(("assignments", "local", "touched"),
+                                  map(int, self.moe_counts)))
         if self.paged:
             out["paged"] = dict(self._pager.stats().as_dict(),
                                 block_size=self.block_size,

@@ -114,7 +114,8 @@ class BlockPager:
     """
 
     def __init__(self, num_blocks: int, block_size: int, max_slots: int,
-                 blocks_per_slot: int, persistent_prefixes: bool = True):
+                 blocks_per_slot: int, persistent_prefixes: bool = True,
+                 prefix_cache: bool = True):
         if num_blocks < 2:
             raise ValueError(f"kv_blocks must be >= 2 (block 0 is the trash "
                              f"block), got {num_blocks}")
@@ -123,6 +124,10 @@ class BlockPager:
         self.max_slots = int(max_slots)
         self.blocks_per_slot = int(blocks_per_slot)
         self.persistent_prefixes = bool(persistent_prefixes)
+        # off for a model whose layers keep a recurrent state beside K/V:
+        # adopting cached K/V blocks would skip tokens that state has to
+        # see, so no prompt is registered and no prefix is ever shared
+        self.prefix_cache = bool(prefix_cache)
         self.tables = np.zeros((max_slots, blocks_per_slot), np.int32)
         # LIFO free list: recently freed blocks are re-handed first
         self._free: List[int] = list(range(num_blocks - 1, 0, -1))
@@ -457,6 +462,10 @@ class BlockPager:
         (its hidden state feeds the first generated token and only K/V is
         cached). ``last_adopt_parked``/``last_adopt_parked_tokens`` report
         this call's LRU revivals (the engine reads them for telemetry)."""
+        if not self.prefix_cache:
+            self.last_adopt_parked = self.last_adopt_parked_tokens = 0
+            self.last_adopt_pool = self.last_adopt_pool_tokens = 0
+            return 0
         toks = tuple(int(t) for t in tokens)
         n = len(toks)
         bs = self.block_size
@@ -569,6 +578,8 @@ class BlockPager:
         sharing. Called when the prefill COMPLETES — a half-written block
         must never be adoptable. First registration wins; a block carries
         at most one key."""
+        if not self.prefix_cache:
+            return
         toks = tuple(int(t) for t in tokens)
         n = len(toks)
         bs = self.block_size

@@ -5,7 +5,9 @@ lengths round a block edge, mixed batches, tables whose rows share physical
 blocks, grouped queries, head widths 64 and 128, float32 and bfloat16
 pools; stale garbage past a slot's length and in unreferenced blocks must
 not reach the output. The Mosaic compiles at the real decode shapes are at
-the end (a described v5e; no chip needed)."""
+the end (a described v5e; no chip needed): this kernel's, and those of the
+other serving kernels (`gdn.py`, `moe_grouped.py`), kept in this ONE file
+because only one test process may load the TPU's compiler."""
 import math
 
 import numpy as np
@@ -194,4 +196,71 @@ def test_mosaic_compiles_the_decode_shapes(one_chip, b, nh, n_kv, mbs, nb,
     text = exe.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     # the pools reach the kernel as they lie: no pool-sized temporary
+    assert exe.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+def compile_for(one_chip, fn, *shapes):
+    """`fn` compiled for the described chip from (shape, dtype) pairs, the
+    persistent cache off (a described chip's entries cannot be read back)."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        return jax.jit(fn).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+
+
+def test_merged_row_pools_reach_the_kernel_without_a_copy(one_chip):
+    """2 KV heads of width 256 under 16 query heads, 128 slots, the hybrid
+    decoder's full layers: the pools are kept as [blocks, block x heads,
+    width] (`cache_spec.kv_layer(merged_rows=True)`) because the 4-D form
+    with 2 heads gets small tiles and a pool-sized relayout a call, which
+    the second compile shows."""
+    b, nh, n_kv, hd, mbs, nb = 128, 16, 2, 256, 256, 20480
+    small = [((b, 1, nh, hd), jnp.bfloat16)]
+    tail = [((b, mbs), jnp.int32), ((b,), jnp.int32)]
+    merged = [((nb, BS * n_kv, hd), jnp.bfloat16)] * 2
+    exe = compile_for(
+        one_chip, lambda q, k, v, t, n: paged_decode_attention(
+            q, k, v, t, n, n_kv=n_kv), *small, *merged, *tail)
+    assert exe.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    assert exe.memory_analysis().temp_size_in_bytes < (1 << 20)
+    four_d = [((nb, BS, n_kv, hd), jnp.bfloat16)] * 2
+    exe = compile_for(one_chip, paged_decode_attention, *small, *four_d,
+                      *tail)
+    assert exe.memory_analysis().temp_size_in_bytes > nb * BS * n_kv * hd * 2
+
+
+def test_mosaic_compiles_the_delta_rule_decode_step(one_chip):
+    """128 slots x 32 value heads of [128, 128] float32 state: one kernel,
+    the state updated in place (aliased, no second copy)."""
+    from paddle_tpu.kernels.pallas import gdn
+    b, h, d = 128, 32, 128
+    row = ((b, h, d), jnp.float32)
+    exe = compile_for(
+        one_chip, lambda q, k, v, dec, beta, live, s: gdn._decode_call(
+            q, k, v, dec, beta, live, s, interpret=False),
+        row, row, row, ((b, h), jnp.float32), ((b, h), jnp.float32),
+        ((b,), jnp.bool_), ((b, h, d, d), jnp.float32))
+    assert exe.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    assert "gdn_decode" in exe.as_text()
+    assert exe.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+@pytest.mark.parametrize("tokens", [128, 512])
+def test_mosaic_compiles_the_grouped_expert_matmul(one_chip, tokens):
+    """128 held experts of 2048 x 512, top-10: a decode step's 128 tokens
+    and a prefill chunk's 512, each expert's three matrices one block."""
+    from paddle_tpu.kernels.pallas import moe_grouped as M
+    k, e, h, i = 10, 128, 2048, 512
+    tiles = -(-tokens * k // M.TILE) + e
+    exe = compile_for(
+        one_chip, lambda xs, ex, used, wg, wu, wd: M._grouped_ffn(
+            xs, ex, used, wg, wu, wd, tile=M.TILE, interpret=False),
+        ((tiles * M.TILE, h), jnp.bfloat16), ((tiles,), jnp.int32),
+        ((), jnp.int32), ((e, h, i), jnp.bfloat16), ((e, h, i), jnp.bfloat16),
+        ((e, i, h), jnp.bfloat16))
+    assert exe.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    assert "moe_grouped" in exe.as_text()
     assert exe.memory_analysis().temp_size_in_bytes < (1 << 20)
