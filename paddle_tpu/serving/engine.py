@@ -172,10 +172,12 @@ def quantize_for_serving(model, skip: Sequence = ()):
 
 class _PrefillState:
     """One slot's in-flight chunked prefill: which prompt positions are
-    cached so far (shared-prefix coverage counts) and the pending COW
-    copies the next chunk call must apply."""
+    cached so far (shared-prefix coverage counts), which are covered by
+    the chunks launched so far (one more chunk than ``done`` while a step
+    is on the device) and the pending COW copies the next chunk call must
+    apply."""
 
-    __slots__ = ("req", "prompt", "n", "done", "pending_copies",
+    __slots__ = ("req", "prompt", "n", "done", "sent", "pending_copies",
                  "prefill_s", "chunks")
 
     def __init__(self, req: Request, start: int,
@@ -184,9 +186,53 @@ class _PrefillState:
         self.prompt = np.asarray(req.prompt, np.int32)
         self.n = len(req.prompt)
         self.done = int(start)            # positions already cached
+        self.sent = int(start)            # ... once what is launched lands
         self.pending_copies = list(pending_copies)
         self.prefill_s = 0.0
         self.chunks = 0
+
+
+class _ChunkCall:
+    """One chunk-executable call of a plan: its slot and range, its device
+    arguments (made when the plan was), and, once launched, its outputs
+    still on the device and its ``engine/prefill_call`` span."""
+
+    __slots__ = ("slot", "st", "sc", "p0", "end", "final", "n_cow", "args",
+                 "tok0", "ok", "span")
+
+    def __init__(self, slot, st, sc, p0, end, n_cow, args):
+        self.slot, self.st, self.sc = slot, st, sc
+        self.p0, self.end, self.final = p0, end, end >= st.n
+        self.n_cow, self.args = n_cow, args
+        self.tok0 = self.ok = self.span = None
+
+
+class _DecodeCall:
+    """The decode-executable call of a plan. ``rows``: {slot: its request}
+    for the slots the step advances. ``tok``: the uploaded host tokens, or
+    None where the step in flight when the plan was made decodes: its
+    picked tokens, still on the device, are this call's. ``firsts``:
+    (slot index on the device, chunk call) for the rows whose token is a
+    chunk's first one, merged in on the device at launch."""
+
+    __slots__ = ("rows", "tok", "firsts", "args", "attrs", "picked", "ok",
+                 "span")
+
+    def __init__(self, rows, tok, firsts, args, attrs):
+        self.rows, self.tok, self.firsts = rows, tok, firsts
+        self.args, self.attrs = args, attrs
+        self.picked = self.ok = self.span = None
+
+
+class _Plan:
+    """One engine step, prepared: the chunk calls in launch order, then
+    the decode call (or None), and the sampling keys drawn for them, which
+    a plan that is thrown away hands to the one built in its place."""
+
+    __slots__ = ("chunks", "decode", "keys")
+
+    def __init__(self, chunks, decode, keys):
+        self.chunks, self.decode, self.keys = chunks, decode, keys
 
 
 class DecodeEngine:
@@ -229,7 +275,10 @@ class DecodeEngine:
 
     ``submit()`` validates and queues; ``step()`` runs ONE scheduler
     iteration (admit into free slots, advance pending prefill chunks, then
-    one decode step over all live slots); ``run()`` drains. Telemetry lands
+    one decode step over all live slots); ``run()`` drains. The paged
+    engine without a drafter launches a step's calls back to back and
+    prepares the NEXT step's (admission, chunk and decode set-up, argument
+    uploads) while the device runs them (``_step_planned``). Telemetry lands
     under ``serve/*`` when the monitor is enabled, and every minted
     executable bumps ``compile_count`` (the serving recompile sentinel —
     flat in steady state).
@@ -503,6 +552,32 @@ class DecodeEngine:
         self._decode_attention = None
         self._verify_exe = None
         self._prefill_exes = {}
+        # ---- the prepared step (paged, no drafter; _step_planned). The
+        # plan made for the next step while the last one ran, and why one
+        # was thrown away since (or could not be made); the last decode's
+        # picked tokens, still on the device, which the next decode takes
+        # as its own; the COW copies of decode rows made writable but not
+        # yet launched; sampling keys drawn for a plan that was discarded
+        self._plan: Optional[_Plan] = None
+        self._discarded: Optional[str] = None
+        self._picked = None
+        self._decode_cow: dict = {}
+        self._spare_keys: list = []
+        self._drawn: Optional[list] = None
+        self._note_exe = None
+        self._armed: dict = {}             # the watchdog's current window
+        # length of the decode executable's token vector: max_slots, plus
+        # the routed layers' three counts where the model has such layers
+        # (what the trace really returned decides: _build_decode)
+        from ..incubate.distributed.models.moe.held import HeldExpertsMoE
+        self._tok_len = self.max_slots + 3 * any(
+            isinstance(l, HeldExpertsMoE)
+            for l in model.sublayers(include_self=True))
+        self._slot_dev: dict = {}
+        # how each step that ran an executable came by its plan, and why
+        # the rebuilt ones lost theirs (stats()["plan"])
+        self.plan_counts = {"prepared": 0, "rebuilt": 0, "sync": 0}
+        self.plan_causes: dict = {}
         # cumulative speculation counters (stats() + monitor mirrors)
         self.spec_steps = 0        # verify dispatches
         self.spec_drafted = 0      # tokens proposed by the drafter
@@ -629,16 +704,42 @@ class DecodeEngine:
         return a if self._repl is None else jax.device_put(a, self._repl)
 
     def _next_key(self):
+        """The next call's sampling key. Keys drawn for a plan that was
+        thrown away come first, so a seed gives the same stream whichever
+        way its steps were built."""
         if not self._do_sample:
             return self._greedy_key
-        self._key, sub = jax.random.split(self._key)
-        if self._repl is not None:
-            self._key = jax.device_put(self._key, self._repl)
-            sub = jax.device_put(sub, self._repl)
+        if self._spare_keys:
+            sub = self._spare_keys.pop(0)
+        else:
+            self._key, sub = jax.random.split(self._key)
+            if self._repl is not None:
+                self._key = jax.device_put(self._key, self._repl)
+                sub = jax.device_put(sub, self._repl)
+        if self._drawn is not None:
+            self._drawn.append(sub)
         return sub
 
+    def _slot_index(self, slot: int):
+        """``slot`` as a device scalar (made once a slot)."""
+        dev = self._slot_dev.get(slot)
+        if dev is None:
+            dev = self._slot_dev[slot] = self._dev(np.int32(slot))
+        return dev
+
+    def _host_tok(self):
+        """The host's last token of every slot, as long as the decode
+        executable's token vector (a fresh array: uploads are not waited
+        for, so what is uploaded is never written again)."""
+        tok = np.zeros(self._tok_len, np.int32)
+        tok[:self.max_slots] = self._tok
+        return tok
+
     def _compile_in_eval(self, fn, args, out_shardings=None):
-        """Trace + AOT-compile with every layer in eval mode (serving
+        return self._lower_in_eval(fn, args, out_shardings).compile()
+
+    def _lower_in_eval(self, fn, args, out_shardings=None):
+        """Trace for AOT compilation with every layer in eval mode (serving
         semantics: dropout off), then restore each layer's OWN flag — an
         engine must not flip a training model's mode as a side effect.
         Under a mesh the paged-pool sharding context is installed for the
@@ -659,7 +760,7 @@ class DecodeEngine:
             kw = dict(donate_argnums=(1,))
             if out_shardings is not None:
                 kw["out_shardings"] = out_shardings
-            return jax.jit(fn, **kw).lower(*args).compile()
+            return jax.jit(fn, **kw).lower(*args)
         finally:
             if self._mesh is not None:
                 set_paged_kv_sharding(*prev_ctx)
@@ -767,12 +868,17 @@ class DecodeEngine:
         # = pos + 1 for them, pos for the rest, whose state (and K/V) the
         # step must leave alone
         if self.paged:
+            # ``tok`` is as long as the step's own picked tokens (with the
+            # routed layers' counts behind them, where there are any): the
+            # next step is handed this step's output as it lies on the
+            # device, and the host never has to read it first
             def fn(leaves, pools, table, tok, pos, cow_src, cow_dst, key,
                    *end):
                 def body():
                     pools2 = self._apply_cow(pools, cow_src, cow_dst)
                     hidden, new, moe = self._backbone(
-                        tok[:, None], self._layer_caches(pools2, table),
+                        tok[:self.max_slots, None],
+                        self._layer_caches(pools2, table),
                         start_pos=pos, **dict(zip(("write_end",), end)))
                     nxt, ok = self._sample(hidden.value()[:, -1], key, moe)
                     return self._layer_results(pools2, new), nxt, ok
@@ -780,8 +886,9 @@ class DecodeEngine:
 
             pad = self._dev(jnp.zeros(self.max_slots, jnp.int32))
             args = (self._leaf_values(), self._pools,
-                    self._dev(self._pager.tables), self._dev(self._tok),
-                    self._dev(self._pos), pad, pad, self._greedy_key)
+                    self._dev(self._pager.tables),
+                    self._dev(self._host_tok()), self._dev(self._pos), pad,
+                    pad, self._greedy_key)
         else:
             def fn(leaves, caches, tok, pos, key, *end):
                 def body():
@@ -801,9 +908,19 @@ class DecodeEngine:
         if self.paged:
             from ..kernels.pallas import paged_decode
             traced = paged_decode.kernel_traces()
-        exe = self._compile_in_eval(fn, args,
-                                    out_shardings=self._pool_out_shardings()
-                                    if self.paged else None)
+            low = self._lower_in_eval(fn, args, self._pool_out_shardings())
+            n_out = low.out_info[1].shape[0]
+            if n_out != self._tok_len:
+                # counts from a layer __init__ did not know of: the step's
+                # output is the next step's input, so trace at its length
+                self._tok_len = n_out
+                args = args[:3] + (self._dev(self._host_tok()),) + args[4:]
+                low = self._lower_in_eval(fn, args,
+                                          self._pool_out_shardings())
+            exe = low.compile()
+            self._build_note(args[3])
+        else:
+            exe = self._compile_in_eval(fn, args)
         self._decode_exe = exe
         # which attention the trace took (the model chose from its input:
         # models/gpt.py::_paged_decode_attend); a silent fallback on the
@@ -818,6 +935,19 @@ class DecodeEngine:
         self._minted("decode", None, time.time() - t0, exe=exe,
                      tokens=self.max_slots)
         return exe
+
+    def _build_note(self, tok):
+        """The one helper program of the prepared step: write a chunk's
+        first token, still on the device, into the decode step's token
+        vector at its slot. (Its name keeps it apart from the engine's two
+        executables, which a profile lists as ``jit_fn``.)"""
+        def note_first_token(tok, first, slot):
+            return tok.at[slot].set(first)
+
+        kw = {} if self._repl is None else {"out_shardings": self._repl}
+        zero = self._dev(np.int32(0))
+        self._note_exe = jax.jit(note_first_token, **kw).lower(
+            tok, zero, zero).compile()
 
     def _build_chunk(self, sc: int):
         """Paged prefill chunk: run ``sc`` prompt tokens of ONE slot through
@@ -1172,9 +1302,17 @@ class DecodeEngine:
         then decode every live slot one token. Returns every request that
         reached a TERMINAL status since the last step (done / failed /
         expired / cancelled / rejected_draining — one list, one contract).
+
+        The paged engine without a drafter does the host's share of a step
+        while the device runs the step before it: a step LAUNCHES the
+        chunk and decode calls prepared under the last one, PREPARES the
+        next step's while they run, then COLLECTS (one wait, one
+        read-back) and books the tokens. Every call a step launches has
+        ended when ``step()`` returns, and a token is handed over in the
+        step that made it (``_step_planned``).
         """
         with _trace.span("engine/step") as whole:
-            finished = self._step()
+            finished = self._step(whole)
         mon = _monitor._active
         if mon is not None:
             # goodput bracket: the whole scheduler iteration; the executable
@@ -1183,9 +1321,14 @@ class DecodeEngine:
             mon.serve_sched(whole.t0, whole.t1)
         return finished
 
-    def _step(self) -> List[Request]:
+    def _step(self, whole) -> List[Request]:
         """The phases of one iteration, each one span; together they tile
-        ``engine/step``."""
+        ``engine/step`` (``whole``). After the sweep, the paged engine
+        without a drafter launches, prepares and collects
+        (``_step_planned``); a drafter's verify width depends on the
+        tokens it drafts, and the row cache prefills whole prompts at
+        admission, so those two admit, prefill and decode in turn, each
+        call waited for before the next line of Python runs."""
         finished: List[Request] = []
         with _trace.span("engine/sweep"):
             if self._terminal_buf:
@@ -1202,17 +1345,19 @@ class DecodeEngine:
             self._expire_sweep(now, finished)
             if self._draining:
                 self._drain_step(now, finished)
-        if not self._draining:
-            with _trace.span("engine/admit") as adm:
-                admitted, refused = self._admit_queued(finished)
-                adm.set(admitted=admitted, refused=refused)
-        if self._prefilling:
+        if self.paged and self.drafter is None:
+            self._step_planned(finished, whole)
+        else:
+            self._admit_phase(finished)
             for slot in sorted(self._prefilling,
                                key=lambda s: self._slot_seq[s]):
                 if slot in self._prefilling:   # an earlier ensure may evict
                     self._advance_prefill(slot, finished)
-        if self._live.any():
-            self._decode(finished)
+            if self._live.any():
+                if self.drafter is not None:
+                    self._decode_spec(finished)
+                else:
+                    self._decode(finished)
         if self._kv_pool is not None:
             # serialize freshly parked registered blocks OUT to the pool at
             # the end of the iteration — never inside the admission/decode
@@ -1246,6 +1391,12 @@ class DecodeEngine:
             steps += 1
         return out
 
+    def _admit_phase(self, finished: List[Request]):
+        if not self._draining:
+            with _trace.span("engine/admit") as adm:
+                admitted, refused = self._admit_queued(finished)
+                adm.set(admitted=admitted, refused=refused)
+
     def _admit_queued(self, finished: List[Request]):
         """Fold queued prompts into free slots (the admission half of
         step()). The "admit" fault site counts ATTEMPTS — a blocked
@@ -1278,12 +1429,31 @@ class DecodeEngine:
 
     # ----------------------------------------------------------- guardrails
 
-    def _release_slot_state(self, slot: int):
+    def _unforeseen(self, why: str):
+        """Something happened that a plan made beforehand could not know
+        of (``why``: stop, nan, cancel, expire, drain, preempt, blocks,
+        fault): the plan, if there is one, is thrown away, and the next
+        step is built from the state as it then is, before its launch.
+        Whatever the plan's making changed is real state that stays true
+        (requests admitted, blocks made writable, with their COW copies
+        still pending): only the device arguments derived from it go, and
+        the sampling keys drawn for them, which the rebuilt step reuses."""
+        plan, self._plan = self._plan, None
+        if plan is not None:
+            self._spare_keys[:0] = plan.keys
+            self._discarded = self._discarded or why
+
+    def _release_slot_state(self, slot: int, why: Optional[str]):
         """Return ``slot`` to the allocator and zero its host row — the ONE
         release path shared by finish / preempt / expire / cancel / engine
         failure, so a request's blocks can never be released twice (the
         pager decrefs exactly once; registered blocks re-park in the
-        prefix LRU with refcounts intact)."""
+        prefix LRU with refcounts intact). ``why`` says what a prepared
+        step could not have known (``_unforeseen``); None for a request
+        that stopped at the length it asked for."""
+        if why is not None:
+            self._unforeseen(why)
+        self._decode_cow.pop(slot, None)
         self._prefilling.pop(slot, None)
         self._live[slot] = False
         self._pos[slot] = 0
@@ -1371,7 +1541,7 @@ class DecodeEngine:
                      if st.req.deadline_exceeded(now)]:
             st = self._prefilling[slot]
             which = st.req.deadline_exceeded(now)
-            self._release_slot_state(slot)
+            self._release_slot_state(slot, "expire")
             self._terminalize(st.req, "expired",
                               f"{which} deadline exceeded mid-prefill "
                               f"({st.done}/{st.n} tokens cached)",
@@ -1382,7 +1552,7 @@ class DecodeEngine:
                 continue
             which = req.deadline_exceeded(now)
             if which is not None:
-                self._release_slot_state(slot)
+                self._release_slot_state(slot, "expire")
                 self._terminalize(req, "expired",
                                   f"{which} deadline exceeded mid-decode "
                                   f"({len(req.tokens)} tokens out)",
@@ -1413,14 +1583,14 @@ class DecodeEngine:
             return True
         for slot, st in list(self._prefilling.items()):
             if st.req is req:
-                self._release_slot_state(slot)
+                self._release_slot_state(slot, "cancel")
                 self._terminalize(req, "cancelled",
                                   "cancelled mid-prefill", None,
                                   where="prefill")
                 return True
         for slot in range(self.max_slots):
             if self._slot_req[slot] is req:
-                self._release_slot_state(slot)
+                self._release_slot_state(slot, "cancel")
                 self._terminalize(req, "cancelled",
                                   "cancelled mid-decode", None,
                                   where="decode")
@@ -1446,6 +1616,7 @@ class DecodeEngine:
         boundaries. Use ``drain()`` to also run the steps."""
         if self._draining:
             return
+        self._unforeseen("drain")
         self._draining = True
         self._drain_reported = False
         self._drain_t0 = self._clock()
@@ -1468,14 +1639,14 @@ class DecodeEngine:
         if self._drain_deadline is not None and now > self._drain_deadline:
             for slot in list(self._prefilling):
                 st = self._prefilling[slot]
-                self._release_slot_state(slot)
+                self._release_slot_state(slot, "drain")
                 self._terminalize(st.req, "expired",
                                   "drain grace exceeded mid-prefill",
                                   finished, where="drain")
             for slot in range(self.max_slots):
                 req = self._slot_req[slot]
                 if req is not None:
-                    self._release_slot_state(slot)
+                    self._release_slot_state(slot, "drain")
                     self._terminalize(req, "expired",
                                       "drain grace exceeded mid-decode",
                                       finished, where="drain")
@@ -1526,16 +1697,20 @@ class DecodeEngine:
         and never decodes onward on a runtime it just caught misbehaving.
         """
         why = f"engine failed: {exc}"
+        self._unforeseen("fault")
+        # (the plan that was being launched is gone with the step)
+        self._discarded = "fault"
+        self._picked = None
         for req in self._queue.drain_all():
             self._terminalize(req, "failed", why, None)
         for slot in list(self._prefilling):
             st = self._prefilling[slot]
-            self._release_slot_state(slot)
+            self._release_slot_state(slot, "fault")
             self._terminalize(st.req, "failed", why, None)
         for slot in range(self.max_slots):
             req = self._slot_req[slot]
             if req is not None:
-                self._release_slot_state(slot)
+                self._release_slot_state(slot, "fault")
                 self._terminalize(req, "failed", why, None)
         raise exc
 
@@ -1767,6 +1942,7 @@ class DecodeEngine:
         neither the local LRU nor the cross-process tier can serve K/V
         computed under the old weights. Returns the number of local
         blocks released."""
+        self._unforeseen("blocks")
         n = self._pager.drop_prefix_cache() if self.paged else 0
         if self._kv_pool is not None:
             self._pool_gen = int(self._kv_pool.bump_generation())
@@ -1854,52 +2030,67 @@ class DecodeEngine:
         the final chunk, emit the first generated token and promote the
         slot to the decode batch."""
         st = self._prefilling[slot]
-        p0 = st.done
+        p0 = st.sent
         sc = self._chunk_len(st.n)
         end = min(p0 + sc, st.n)
         host = dict(slot=slot, tokens=end - p0)
         with _trace.span("engine/prefill_host", **host):
-            copies, st.pending_copies = st.pending_copies, []
             more = self._ensure_or_evict(slot, p0, end)
             if more is None or slot not in self._prefilling:
                 return                     # this very slot was preempted
-            copies += more
+            st.pending_copies += more
             exe = self._prefill_exes.get(sc)
             if exe is None:
                 exe = self._build_chunk(sc)
-            ids = np.zeros((1, sc), np.int32)
-            ids[0, :end - p0] = st.prompt[p0:end]
-            src, dst = self._cow_args(copies)
+            n_cow = len(st.pending_copies)
+            ids, src, dst = self._chunk_inputs(st, sc, p0, end)
 
         def upload():
             return (self._dev(self._pager.tables), self._dev(ids),
-                    self._dev(jnp.int32(slot)), self._dev(jnp.int32(p0)),
+                    self._slot_index(slot), self._dev(jnp.int32(p0)),
                     self._dev(jnp.int32(end)), src, dst, self._next_key())
 
         def run(*args):
             self._pools, picked, ok = exe(self._leaf_values(), self._pools,
                                           *args)
+            self._chunk_launched(st, slot, end)
             # host readback inside the armed window (see _decode)
             return picked, bool(np.asarray(ok))
 
         (tok0, l_ok), call = self._dispatch_guarded(
             "chunk", sc, _PREFILL_SPANS, upload, run, **self._state_attrs(1))
         with _trace.span("engine/prefill_host", **host):
-            self._chunk_done(st, slot, sc, end, len(copies), tok0, l_ok,
-                             call, finished)
+            self._chunk_done(st, slot, sc, end, n_cow, tok0, l_ok,
+                             (call.t0, call.t1), finished)
+
+    def _chunk_inputs(self, st: _PrefillState, sc: int, p0: int, end: int):
+        """The chunk executable's ids (padded to ``sc``) for prompt
+        positions [p0, end), and the slot's pending COW pairs."""
+        ids = np.zeros((1, sc), np.int32)
+        ids[0, :end - p0] = st.prompt[p0:end]
+        return (ids,) + self._cow_args(st.pending_copies)
+
+    def _chunk_launched(self, st: _PrefillState, slot: int, end: int):
+        """The chunk covering ``slot``'s prompt up to ``end`` is with the
+        device: its COW copies went with it, and after a final chunk the
+        slot's cursor stands at the prompt's end."""
+        st.sent = end
+        st.pending_copies = []
+        if end >= st.n:
+            self._pos[slot] = st.n
 
     def _chunk_done(self, st: _PrefillState, slot: int, sc: int, end: int,
-                    n_cow: int, tok0, l_ok: bool, call, finished):
-        """Host bookkeeping after one chunk's executable has returned; on
-        the final chunk, the first token and the promotion to decode."""
+                    n_cow: int, tok0, l_ok: bool, ran, finished):
+        """Host bookkeeping after one chunk's executable has returned (it
+        was on the device during ``ran``, two instants); on the final
+        chunk, the first token and the promotion to decode."""
         p0 = st.done
-        chunk_s = call.dur_s
+        chunk_s = ran[1] - ran[0]
         st.prefill_s += chunk_s
         mon = _monitor._active
         if mon is not None:
             mon.serve_prefill_step(chunk_s, sc, tokens=end - p0,
-                                   engine_id=self.engine_id,
-                                   span=(call.t0, call.t1))
+                                   engine_id=self.engine_id, span=ran)
         st.done = end
         st.chunks += 1
         st.req._phase.event("chunk", p0=int(p0), end=int(end),
@@ -1909,7 +2100,7 @@ class DecodeEngine:
             # terminalize now instead of prefilling further (or streaming)
             req = st.req
             self._nan_logits(req, "chunk")
-            self._release_slot_state(slot)
+            self._release_slot_state(slot, "nan")
             self._terminalize(req, "failed", "non-finite logits (nan)",
                               finished, where="chunk")
             return
@@ -1924,7 +2115,6 @@ class DecodeEngine:
         req.t_first_token = time.time()
         req.tokens.append(t)
         self.tokens_generated += 1
-        self._pos[slot] = st.n
         self._tok[slot] = t
         self._live[slot] = True
         self._slot_req[slot] = req
@@ -1958,7 +2148,7 @@ class DecodeEngine:
         re-admission — vLLM's recompute-style preemption)."""
         st = self._prefilling.get(slot)
         req = st.req if st is not None else self._slot_req[slot]
-        self._release_slot_state(slot)
+        self._release_slot_state(slot, "preempt")
         req.status, req.slot = "queued", None
         req.tokens = []
         req.t_first_token = None
@@ -2029,7 +2219,7 @@ class DecodeEngine:
             # the slot never joined the decode batch; release it and fail
             # the request instead of streaming from NaN logits
             self._nan_logits(req, "prefill")
-            self._release_slot_state(slot)
+            self._release_slot_state(slot, "nan")
             self._terminalize(req, "failed", "non-finite logits (nan)",
                               finished, where="prefill")
             return
@@ -2054,91 +2244,356 @@ class DecodeEngine:
         if req._stop_hit():
             self._finish(req, finished)
 
-    def _decode_tables(self):
+    def _decode_tables(self, rows):
         """The block tables the decode executable may write through: a slot
-        that is not live (mid-prefill: it sits at pos 0 with its real row)
-        gets the trash row, or every decode step run while its prompt is
-        still being chunked would write a stale token's K/V at position 0
-        of its first — possibly shared — block."""
-        if not self._prefilling:
-            return self._pager.tables      # dead rows are trash already
-        return np.where(self._live[:, None], self._pager.tables,
+        the step does not advance (``rows`` is the mask of those it does —
+        a slot mid-prefill sits at pos 0 with its real row) gets the trash
+        row, or every decode step run while its prompt is still being
+        chunked would write a stale token's K/V at position 0 of its first
+        — possibly shared — block."""
+        return np.where(rows[:, None], self._pager.tables,
                         np.int32(TRASH_BLOCK))
 
-    def _decode(self, finished: List[Request]):
-        if self.drafter is not None:
-            return self._decode_spec(finished)
+    # ------------------------------------------------- the prepared step
+
+    def _step_planned(self, finished: List[Request], whole):
+        """One step of the paged engine without a drafter.
+
+        1. LAUNCH the plan made under the last step: its chunk calls, then
+           its decode call, back to back, nothing read back between them
+           (``engine/prefill_call``, ``engine/decode_call``: launch only).
+        2. PREPARE the next step while the device runs (``_plan_step``):
+           admission into the slots free now, every prefill's next chunk,
+           the decode rows (``ensure_writable`` at the cursor each will
+           have), and all of it uploaded. Nothing in it waits for the
+           device.
+        3. COLLECT (``engine/collect``): the one wait and the one
+           read-back of the step; then the chunks' and the decode's
+           bookkeeping, as ever (``_chunk_done``, ``_decode_done``).
+
+        The decode's tokens never wait for the host: a step's picked
+        tokens are the next step's ``tok`` as they lie on the device, with
+        the first token of a slot a chunk has just promoted written in by
+        ``note_first_token``. The host's copies (``_tok``, ``req.tokens``)
+        are filled at collect.
+
+        A plan holds for the state it was made from. What it could not
+        foresee (``_unforeseen``: a stop before the length asked for, a NaN
+        row, cancel, expiry, drain, a fault, ``drop_prefix_cache``) throws
+        it away, and this step is then built here, before its launch, from
+        the state as it is: today's cost for that one step (``plan`` =
+        ``rebuilt`` on the ``engine/step`` span, with its ``cause``). Pool
+        pressure is never resolved while a step is on the device: the plan
+        is given up (cause ``blocks``) and the next step evicts before its
+        launch. With nothing prepared and nothing on the device the step
+        is built here too (``sync``): a request that finds the engine idle
+        is not kept waiting a step."""
+        plan, self._plan = self._plan, None
+        cause, self._discarded = self._discarded, None
+        evicted = self.preemptions
+        if plan is not None:
+            how = "prepared"
+        else:
+            how = "rebuilt" if cause else "sync"
+            plan = self._plan_step(finished, None)
+            if plan is None:
+                return                     # nothing to run
+        whole.set(plan=how, **({"cause": cause} if how == "rebuilt" else {}))
+        self.plan_counts[how] += 1
+        if how == "rebuilt":
+            self.plan_causes[cause] = self.plan_causes.get(cause, 0) + 1
+        wd = self._watchdog
+        launched: List[_ChunkCall] = []
+        try:
+            self._launch(plan, launched)
+            if self.preemptions == evicted:
+                self._plan = self._plan_step(finished, plan)
+            else:
+                # this step had to evict to be built: the pool is short,
+                # and a plan made now would take the victim straight back
+                # in. The next step is built when its turn comes, as this
+                # one was.
+                self._discarded = "preempt"
+            if wd is not None:
+                # the clock starts again for the wait: what the host took
+                # to prepare the next step is not the device hanging
+                wd.arm(keep=True, **self._armed)
+            with _trace.span("engine/collect") as wait:
+                # the wait and the step's one read-back; inside the armed
+                # window: a hang in the device sync is a hang in the call
+                d = plan.decode
+                got = jax.device_get(
+                    ([(c.tok0, c.ok) for c in launched],
+                     None if d is None else (d.picked, d.ok)))
+        except Exception as e:
+            if wd is not None:
+                # a hang that then RAISED: the raise is the failure that
+                # propagates (see _dispatch_guarded)
+                wd.fired = None
+            self._fail_engine(e)
+        finally:
+            if wd is not None:
+                wd.disarm()
+        if wd is not None and wd.fired is not None:
+            fired, wd.fired = wd.fired, None
+            self._fail_engine(EngineHangError(
+                f"{fired.get('kind', '?')} dispatch took "
+                f"{fired.get('elapsed_s', 0):.2f}s "
+                f"(> {HANG_ENV}={wd.hang_s}s); WARN + flight dump emitted "
+                f"while it hung"))
+        # when each call was on the device, as far as the host can say:
+        # from its launch to the next call's, the last to the end of the
+        # wait. Together they cover the step's device time once.
+        edges = [c.span.t0 for c in launched]
+        if plan.decode is not None:
+            edges.append(plan.decode.span.t0)
+        edges.append(wait.t1)
+        for i, (c, (tok0, ok)) in enumerate(zip(launched, got[0])):
+            with _trace.span("engine/prefill_host", slot=c.slot,
+                             tokens=c.end - c.p0):
+                self._chunk_done(c.st, c.slot, c.sc, c.end, c.n_cow, tok0,
+                                 bool(ok), (edges[i], edges[i + 1]),
+                                 finished)
+        if plan.decode is not None:
+            self._decode_done(plan.decode.rows, got[1],
+                              (edges[-2], edges[-1]), finished)
+
+    def _arm(self, kind: str, bucket, first: bool):
+        """Fire the chaos seam for one launch, inside the watchdog's
+        window: armed at the step's first launch, moved on to each later
+        one, open until the step has collected."""
+        wd = self._watchdog
+        if wd is not None:
+            traces = [r._trace for r in self._slot_req if r is not None]
+            traces += [st.req._trace for st in self._prefilling.values()]
+            self._armed = dict(kind=kind, bucket=bucket,
+                               engine=self.engine_id, traces=traces)
+            wd.arm(keep=not first, **self._armed)
+        if self._faults is not None:
+            self._faults.fire(kind)
+
+    def _launch(self, plan: _Plan, launched: List[_ChunkCall]):
+        """Hand the plan's calls to the device, chunks first, decode last,
+        and note on the host what is now under way: how far each prompt is
+        covered, every decoded row's cursor one further."""
+        first = True
+        for c in plan.chunks:
+            if self._prefilling.get(c.slot) is not c.st:
+                continue       # preempted by a later ensure of this plan
+            self._arm("chunk", c.sc, first)
+            first = False
+            with _trace.span("engine/prefill_call",
+                             **self._state_attrs(1)) as c.span:
+                self._pools, c.tok0, c.ok = self._prefill_exes[c.sc](
+                    self._leaf_values(), self._pools, *c.args)
+                c.args = None
+            self._chunk_launched(c.st, c.slot, c.end)
+            launched.append(c)
+        d = plan.decode
+        if d is None:
+            return
+        self._arm("decode", None, first)
+        with _trace.span("engine/decode_call", **d.attrs) as d.span:
+            tok = d.tok if d.tok is not None else self._picked
+            for slot_dev, c in d.firsts:
+                tok = self._note_exe(tok, c.tok0, slot_dev)
+            self._pools, d.picked, d.ok = self._decode_exe(
+                self._leaf_values(), self._pools, d.args[0], tok,
+                *d.args[1:])
+            d.args = d.tok = None
+        self._picked = d.picked
+        for slot in d.rows:
+            self._pos[slot] += 1
+            self._decode_cow.pop(slot, None)
+
+    def _plan_step(self, finished: List[Request],
+                   flight: Optional[_Plan]) -> Optional[_Plan]:
+        """Make the next step's plan: admit, set up every prefill's next
+        chunk and the decode rows, upload the arguments. ``flight`` is the
+        step on the device meanwhile (None: nothing is, and pool pressure
+        may evict). The host state read here is as of everything LAUNCHED
+        (``_PrefillState.sent``, ``_pos``); only token counts lag, by the
+        step in flight. Returns None where nothing will need to run, or
+        where the plan had to be given up (``_discarded`` then says why)."""
+        ahead = flight is not None
+        self._drawn = []
+        try:
+            self._admit_phase(finished)
+            chunks = []
+            for slot in sorted(self._prefilling,
+                               key=lambda s: self._slot_seq[s]):
+                st = self._prefilling.get(slot)   # an earlier ensure may evict
+                if st is None or st.sent >= st.n:
+                    continue     # (its final chunk is on the device)
+                c = self._plan_chunk(slot, st, ahead)
+                if c is False:
+                    return None
+                if c is not None:
+                    chunks.append(c)
+            decode = self._plan_decode(chunks, flight)
+            if decode is False:
+                return None
+            if not chunks and decode is None:
+                return None
+            plan = _Plan(chunks, decode, self._drawn)
+            self._drawn = None
+            return plan
+        finally:
+            if self._drawn is not None:
+                # given up half-way: the keys drawn so far are the next
+                # build's first
+                self._spare_keys[:0] = self._drawn
+                self._drawn = None
+
+    def _give_up(self, why: Optional[str]) -> bool:
+        """A plan cannot be made while a step is on the device: for want of
+        blocks (``why``; the next step evicts before its launch), or of an
+        executable that has yet to be compiled, which is not done inside
+        the watchdog's window (None: the one such step reads ``sync``)."""
+        if why is not None:
+            self._discarded = self._discarded or why
+        return False
+
+    def _plan_chunk(self, slot: int, st: _PrefillState, ahead: bool):
+        """``slot``'s next chunk as a call with its arguments uploaded.
+        None: the slot itself was preempted for its blocks. False: the
+        plan is given up."""
+        p0 = st.sent
+        sc = self._chunk_len(st.n)
+        end = min(p0 + sc, st.n)
+        with _trace.span("engine/prefill_host", slot=slot, tokens=end - p0):
+            if sc not in self._prefill_exes:
+                if ahead:
+                    return self._give_up(None)
+                self._build_chunk(sc)
+            if ahead:
+                more = self._pager.ensure_writable(slot, p0, end)
+                if more is None:
+                    return self._give_up("blocks")
+            else:
+                more = self._ensure_or_evict(slot, p0, end)
+                if more is None or slot not in self._prefilling:
+                    return None
+            # pending until the call is launched: a plan thrown away in
+            # between leaves them to the one built in its place
+            st.pending_copies += more
+            ids, src, dst = self._chunk_inputs(st, sc, p0, end)
+            args = (self._dev(self._pager.tables.copy()), self._dev(ids),
+                    self._slot_index(slot), self._dev(np.int32(p0)),
+                    self._dev(np.int32(end)), src, dst, self._next_key())
+            return _ChunkCall(slot, st, sc, p0, end, len(st.pending_copies),
+                              args)
+
+    def _decode_row(self, slot: int, chunks: dict, flight: Optional[_Plan]):
+        """Whether the decode being planned advances ``slot``: (request,
+        cursor, the chunk call whose first token is the row's token and is
+        not in the step in flight's picked tokens, or None), or None for a
+        slot it leaves alone. A request that has, with what is launched,
+        the tokens it asked for is left alone: it ends at the next collect
+        at the latest."""
+        flying = flight is not None and flight.decode is not None \
+            and slot in flight.decode.rows
+        first = None
+        if self._live[slot]:
+            req = self._slot_req[slot]
+            tokens, pos = len(req.tokens) + flying, int(self._pos[slot])
+        else:
+            st = self._prefilling.get(slot)
+            if st is None:
+                return None
+            req = st.req
+            if slot in chunks:
+                # its final chunk is in THIS plan: the decode right behind
+                # it takes the slot along, unless its first token can end
+                # the request (then it joins once that token is known to
+                # be none such: no step runs past a request's end)
+                if req.eos_token_id is not None:
+                    return None
+                first, tokens, pos = chunks[slot], 1, st.n
+            elif st.sent >= st.n:
+                # its final chunk is on the device, and so is the decode
+                # behind it if that took the slot along
+                tokens, pos = 1 + flying, int(self._pos[slot])
+                if not flying:
+                    first = next(c for c in flight.chunks if c.st is st)
+            else:
+                return None
+        if tokens >= req.max_new_tokens:
+            return None
+        return req, pos, first
+
+    def _plan_decode(self, chunks: List[_ChunkCall],
+                     flight: Optional[_Plan]):
+        """The decode call with its arguments uploaded, None where no slot
+        is to be advanced, False where the plan is given up."""
+        ahead = flight is not None
+        final = {c.slot: c for c in chunks if c.final}
         with _trace.span("engine/decode_prepare") as prep:
-            exe = self._decode_exe
-            if exe is None:
-                exe = self._build_decode()
-            n_cow = preempted = 0
-            if self.paged:
-                # make every live slot's write target private + present. A
-                # preempted victim's pending copies are DROPPED with it —
-                # its freed blocks may be re-handed to the very slot being
-                # ensured
-                copies_by_slot = {}
-                slot = 0
-                while slot < self.max_slots:
-                    if not self._live[slot]:
-                        slot += 1
-                        continue
-                    p = int(self._pos[slot])
-                    c = self._pager.ensure_writable(slot, p, p + 1)
-                    if c is None:
-                        victim = self._youngest_victim(slot)
-                        self._preempt(victim)
-                        preempted += 1
-                        copies_by_slot.pop(victim, None)
-                        if victim == slot:  # self-preempted: skip this row
-                            slot += 1
-                        continue            # else retry the same slot
-                    copies_by_slot[slot] = c
+            if self._decode_exe is None:
+                if ahead:
+                    return self._give_up(None)
+                self._build_decode()
+            # make every row's write target private + present. A preempted
+            # victim's pending copies are DROPPED with it (_release_slot_
+            # state) — its freed blocks may be re-handed to the very slot
+            # being ensured
+            rows = {}          # slot: (request, cursor, first token's chunk)
+            preempted = 0
+            slot = 0
+            while slot < self.max_slots:
+                row = self._decode_row(slot, final, flight)
+                if row is None:
                     slot += 1
-                if not self._live.any():    # everyone self-preempted
-                    prep.set(live=0, cow=0, preempted=preempted)
-                    return
-                for s, c in copies_by_slot.items():
-                    if c:
-                        n_cow += len(c)
-                        self._slot_req[s]._phase.event("cow", n=len(c))
-                src, dst = self._cow_args(
-                    [p for c in copies_by_slot.values() for p in c])
-            prep.set(live=self.live_count, cow=n_cow, preempted=preempted)
-        call_attrs = dict(path=self._decode_attention,
-                          **self._state_attrs(self.live_count))
-        # which slots the step may advance (see _build_decode)
-        end = (self._pos + self._live,) if self._has_state else ()
-        if self.paged:
+                    continue
+                req, pos, first = row
+                c = self._pager.ensure_writable(slot, pos, pos + 1)
+                if c is None:
+                    if ahead:
+                        return self._give_up("blocks")
+                    victim = self._youngest_victim(slot)
+                    self._preempt(victim)
+                    preempted += 1
+                    rows.pop(victim, None)
+                    if victim == slot:      # self-preempted: skip this row
+                        slot += 1
+                    continue                # else retry the same slot
+                if c:
+                    self._decode_cow.setdefault(slot, []).extend(c)
+                    req._phase.event("cow", n=len(c))
+                rows[slot] = row
+                slot += 1
+            copies = [p for s in rows for p in self._decode_cow.get(s, ())]
+            prep.set(live=len(rows), cow=len(copies), preempted=preempted)
+            if not rows:
+                return None
+            mask = np.zeros(self.max_slots, bool)
+            pos = np.zeros(self.max_slots, np.int32)
+            for s, (_, cursor, _) in rows.items():
+                mask[s], pos[s] = True, cursor
+            attrs = dict(path=self._decode_attention,
+                         **self._state_attrs(len(rows)))
             # the live KV blocks this step has to read; the gather path read
             # max_slots * max_blocks_per_slot whatever this says
-            call_attrs["kv_blocks"] = int(
-                (self._pos[self._live] // self.block_size + 1).sum())
+            attrs["kv_blocks"] = int((pos[mask] // self.block_size + 1).sum())
+            src, dst = self._cow_args(copies)
+            # which slots the step may advance (see _build_decode)
+            end = (self._dev(pos + mask),) if self._has_state else ()
+            args = (self._dev(self._decode_tables(mask)), self._dev(pos),
+                    src, dst, self._next_key()) + end
+            on_device = ahead and flight.decode is not None
+            return _DecodeCall(
+                {s: req for s, (req, _, _) in rows.items()},
+                None if on_device else self._dev(self._host_tok()),
+                [(self._slot_index(s), first)
+                 for s, (_, _, first) in rows.items() if first is not None],
+                args, attrs)
 
-            def upload():
-                return (self._dev(self._decode_tables()),
-                        self._dev(self._tok), self._dev(self._pos), src,
-                        dst, self._next_key(), *map(self._dev, end))
-
-            def run(*args):
-                self._pools, picked, ok = exe(self._leaf_values(),
-                                              self._pools, *args)
-                # host readback inside the armed window: a hang in the
-                # device sync is a hang in the dispatch
-                return np.asarray(picked), np.asarray(ok)
-        else:
-            def upload():
-                return (jnp.asarray(self._tok), jnp.asarray(self._pos),
-                        self._next_key(), *map(jnp.asarray, end))
-
-            def run(*args):
-                self._caches, picked, ok = exe(self._leaf_values(),
-                                               self._caches, *args)
-                return np.asarray(picked), np.asarray(ok)
-
-        (nxt, l_ok), call = self._dispatch_guarded(
-            "decode", None, _DECODE_SPANS, upload, run, **call_attrs)
+    def _decode_done(self, rows: dict, got, ran, finished):
+        """Book the tokens of one decode step over ``rows`` ({slot: its
+        request}; their cursors moved on when it was launched). ``got``:
+        its picked tokens and finite-logits flags, read back; it was on
+        the device during ``ran``."""
+        nxt, l_ok = got
         with _trace.span("engine/decode_finish") as fin:
             if len(nxt) > self.max_slots:
                 # the routed layers' counts ride behind the tokens
@@ -2148,9 +2603,12 @@ class DecodeEngine:
                 fin.set(moe_assignments=int(moe[0]), moe_local=int(moe[1]),
                         moe_touched=int(moe[2]))
             live = n_tok = n_done = 0
-            for slot in range(self.max_slots):
-                req = self._slot_req[slot]
-                if req is None:
+            for slot, req in rows.items():
+                if self._slot_req[slot] is not req:
+                    # its final chunk, launched right ahead of this step,
+                    # turned out non-finite: the request has failed, the
+                    # row is dropped, and what it wrote lies in blocks
+                    # released with the request
                     continue
                 live += 1
                 if not bool(l_ok[slot]):
@@ -2158,7 +2616,7 @@ class DecodeEngine:
                     # and free the slot; the rest of the batch streams on
                     # untouched
                     self._nan_logits(req, "decode")
-                    self._release_slot_state(slot)
+                    self._release_slot_state(slot, "nan")
                     self._terminalize(req, "failed",
                                       "non-finite logits (nan)", finished,
                                       where="decode")
@@ -2167,7 +2625,6 @@ class DecodeEngine:
                 req.tokens.append(t)
                 self.tokens_generated += 1
                 n_tok += 1
-                self._pos[slot] += 1
                 self._tok[slot] = t
                 if req._stop_hit():
                     self._finish(req, finished)
@@ -2176,12 +2633,44 @@ class DecodeEngine:
             fin.set(tokens=n_tok, finished=n_done)
             mon = _monitor._active
             if mon is not None:
-                mon.serve_step(call.dur_s, live, len(self._queue),
-                               engine_id=self.engine_id,
-                               span=(call.t0, call.t1))
+                mon.serve_step(ran[1] - ran[0], live, len(self._queue),
+                               engine_id=self.engine_id, span=ran)
                 if self.paged:
                     mon.serve_paged(self._pager.stats(), self.kv_util(),
                                     engine_id=self.engine_id)
+
+    # ------------------------------------------------ the row cache's step
+
+    def _decode(self, finished: List[Request]):
+        """One decode step of the contiguous row cache, waited for."""
+        with _trace.span("engine/decode_prepare") as prep:
+            exe = self._decode_exe
+            if exe is None:
+                exe = self._build_decode()
+            prep.set(live=self.live_count, cow=0, preempted=0)
+        call_attrs = dict(path=self._decode_attention,
+                          **self._state_attrs(self.live_count))
+        # which slots the step may advance (see _build_decode)
+        end = (self._pos + self._live,) if self._has_state else ()
+
+        def upload():
+            return (jnp.asarray(self._tok), jnp.asarray(self._pos),
+                    self._next_key(), *map(jnp.asarray, end))
+
+        def run(*args):
+            self._caches, picked, ok = exe(self._leaf_values(),
+                                           self._caches, *args)
+            # host readback inside the armed window: a hang in the
+            # device sync is a hang in the dispatch
+            return np.asarray(picked), np.asarray(ok)
+
+        got, call = self._dispatch_guarded(
+            "decode", None, _DECODE_SPANS, upload, run, **call_attrs)
+        rows = {slot: req for slot, req in enumerate(self._slot_req)
+                if req is not None}
+        for slot in rows:
+            self._pos[slot] += 1
+        self._decode_done(rows, got, (call.t0, call.t1), finished)
 
     def _decode_spec(self, finished: List[Request]):
         """Speculative decode step: per live slot, draft up to
@@ -2262,7 +2751,7 @@ class DecodeEngine:
                     # accept test: fail the request (release_slot frees the
                     # speculative reservation with the rest of its blocks)
                     self._nan_logits(req, "verify")
-                    self._release_slot_state(slot)
+                    self._release_slot_state(slot, "nan")
                     self._terminalize(req, "failed",
                                       "non-finite logits (nan)", finished,
                                       where="verify")
@@ -2311,7 +2800,10 @@ class DecodeEngine:
                                 engine_id=self.engine_id)
 
     def _finish(self, req: Request, finished: List[Request]):
-        self._release_slot_state(req.slot)
+        # a stop at the length asked for is the one a prepared step knows of
+        self._release_slot_state(
+            req.slot,
+            None if len(req.tokens) >= req.max_new_tokens else "stop")
         self._deadline_reqs.discard(req)
         req.status, req.t_done = "done", time.time()
         self._retire_id(req)
@@ -2449,6 +2941,11 @@ class DecodeEngine:
                                 block_size=self.block_size,
                                 preemptions=self.preemptions,
                                 prefilling=len(self._prefilling))
+        if self.paged and self.drafter is None:
+            # steps that ran an executable, by how they came by their plan
+            # (_step_planned), and what the rebuilt ones lost theirs to
+            out["plan"] = dict(self.plan_counts,
+                               causes=dict(self.plan_causes))
         if self._kv_pool is not None:
             out["pool"] = self.pool_stats()
         if self.drafter is not None:
@@ -2552,6 +3049,7 @@ def generate_via_engine(lm, input_ids, max_new_tokens: int = 32,
         # same request in the same slot call-over-call (the free list's
         # post-drain order is history-dependent; the engine is idle here)
         engine._key = jax.random.PRNGKey(int(seed))
+        engine._spare_keys.clear()
         if engine.live_count == 0 and not engine._queue:
             engine._slots = SlotAllocator(engine.max_slots)
     reqs = [engine.submit(row, max_new_tokens=max_new_tokens,

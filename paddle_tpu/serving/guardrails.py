@@ -275,13 +275,19 @@ class DispatchWatchdog:
                                         name="serve-watchdog")
         self._thread.start()
 
-    def arm(self, **info):
+    def arm(self, keep: bool = False, **info):
         """Enter an armed window; ``info`` names the dispatch (kind,
         bucket, engine, live trace ids) for the WARN. A latched ``fired``
         from a PREVIOUS window is dropped here — it belonged to a dispatch
         whose failure already propagated (e.g. a hang that then raised),
-        and a fresh healthy dispatch must not inherit it."""
+        and a fresh healthy dispatch must not inherit it. ``keep``: this
+        is a later dispatch of the SAME engine step (its calls are
+        launched back to back and waited for once), so the window moves on
+        to it, unless an earlier one of the step already hung: that
+        verdict stands, and names the call that earned it."""
         with self._cond:
+            if keep and self.fired is not None:
+                return
             self.fired = None
             self._armed = info
             self._armed_at = time.monotonic()

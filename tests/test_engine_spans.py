@@ -80,7 +80,8 @@ def test_children_of_engine_step_tile_it():
         loose.append(1.0 - covered / (st.t1 - st.t0))
     assert names == {"engine/sweep", "engine/admit", "engine/prefill_host",
                      "engine/prefill_call", "engine/decode_prepare",
-                     "engine/decode_call", "engine/decode_finish"}
+                     "engine/decode_call", "engine/collect",
+                     "engine/decode_finish"}
     # host time of a step that no phase accounts for: the median step, so
     # that one descheduled worker of a loaded test host decides nothing
     assert statistics.median(loose) < 0.05, sorted(loose)[-5:]

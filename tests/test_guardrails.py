@@ -464,6 +464,62 @@ def test_hang_then_raise_does_not_poison_next_dispatch(tiny):
         eng.close()
 
 
+def test_watchdog_names_the_call_of_the_step_that_hung(tiny):
+    """A step launches its chunk and its decode back to back and waits
+    once. A chunk launch that hangs keeps its verdict while the window
+    moves on to the decode behind it: the failure names the chunk."""
+    eng = DecodeEngine(
+        tiny, max_slots=2, max_len=32, block_size=8, prefill_chunk=8,
+        hang_s=0.05,
+        fault_schedule=FaultSchedule.parse("slow@chunk:1:0.4"))
+    try:
+        req = eng.submit([1, 2, 3], max_new_tokens=4)
+        with pytest.warns(RuntimeWarning, match="chunk executable"):
+            with pytest.raises(EngineHangError, match="chunk dispatch"):
+                eng.run()
+        eng._pager.check_invariants()
+        assert req.status == "failed" and eng.live_count == 0
+        assert eng._plan is None and not eng._prefilling
+        ok = eng.submit([7, 8, 9], max_new_tokens=3)
+        eng.run()
+        assert ok.status == "done"
+    finally:
+        eng.close()
+
+
+def test_watchdog_window_is_open_until_the_step_has_collected(tiny,
+                                                              monkeypatch):
+    """The launches return at once; a device that never answers shows in
+    the step's one wait. The window is still armed there, on the last
+    call launched."""
+    import jax
+    eng = DecodeEngine(tiny, max_slots=2, max_len=32, block_size=8,
+                       prefill_chunk=8, hang_s=0.05)
+    try:
+        warm = eng.submit([1, 2, 3], max_new_tokens=2)
+        eng.run()
+        assert warm.status == "done"
+        real = jax.device_get
+
+        def stuck(tree):
+            time.sleep(0.4)
+            return real(tree)
+
+        req = eng.submit([4, 5, 6], max_new_tokens=4)
+        monkeypatch.setattr(jax, "device_get", stuck)
+        with pytest.warns(RuntimeWarning, match="decode executable"):
+            with pytest.raises(EngineHangError, match="decode dispatch"):
+                eng.run()
+        monkeypatch.setattr(jax, "device_get", real)
+        eng._pager.check_invariants()
+        assert req.status == "failed" and eng.live_count == 0
+        ok = eng.submit([7, 8, 9], max_new_tokens=3)
+        eng.run()
+        assert ok.status == "done"
+    finally:
+        eng.close()
+
+
 def test_chaos_gate_mixed_schedule(tiny, monkeypatch):
     """THE tier-1 chaos gate: a scripted PADDLE_SERVE_FAULT schedule (env
     path) over a pressure-sized pool, mixing expiry + cancel + injected

@@ -102,7 +102,9 @@ def test_chunked_prefill_spreads_admission(engine, tiny):
     """Mechanism gate (timing-free): a 20-token prompt with chunk 8 admits
     over 3 iterations, and an already-live slot decodes one token in EACH
     of them — the monolithic freeze is gone. Greedy output still equals
-    the eager loop."""
+    the eager loop. The engine is busy when the prompt arrives, so the
+    step under way was prepared without it: it is admitted while that one
+    runs, and its chunks are the three steps after."""
     rng = np.random.RandomState(1)
     short = rng.randint(1, 64, 3).tolist()
     long_p = rng.randint(1, 64, 20).tolist()
@@ -115,10 +117,11 @@ def test_chunked_prefill_spreads_admission(engine, tiny):
     while b.status in ("queued", "prefilling"):
         engine.step()
         progressed.append(len(a.tokens))
-    # 3 chunk iterations ([0,8),[8,16),[16,20)) => first token on the 3rd
-    assert len(progressed) == 3
+    # the step it is admitted under, then 3 chunk iterations ([0,8),
+    # [8,16),[16,20)) => first token on the 3rd of those
+    assert len(progressed) == 4
     # the live slot advanced one token per iteration, never stalled out
-    assert progressed == [tok_before + 1 + i for i in range(3)]
+    assert progressed == [tok_before + 1 + i for i in range(4)]
     engine.run()
     np.testing.assert_array_equal(_eager(tiny, long_p, 4), b.output_tokens)
     np.testing.assert_array_equal(_eager(tiny, short, 12), a.output_tokens)

@@ -139,8 +139,8 @@ def test_a_decode_step_leaves_other_slots_state_alone(tiny):
     before_b, before_free = state_rows(eng, b.slot), state_rows(eng, free)
     exe = eng._decode_exe                        # one decode step alone
     eng._pools, _, _ = exe(
-        eng._leaf_values(), eng._pools, eng._dev(eng._decode_tables()),
-        eng._dev(eng._tok), eng._dev(eng._pos),
+        eng._leaf_values(), eng._pools, eng._dev(eng._decode_tables(eng._live)),
+        eng._dev(eng._host_tok()), eng._dev(eng._pos),
         *eng._cow_args([]), eng._next_key(),
         eng._dev(eng._pos + eng._live))
     for was, now in zip(before_b + before_free,
