@@ -2,7 +2,7 @@
 """chip_smoke.py — the quickest proof that paddle_tpu still starts on the chip.
 
 One process, holding the chip, drives the two main paths once through the
-entry points a user calls, at the FULL width of the bench's GPT-medium
+entry points a user calls, at the FULL width of a GPT-medium
 (vocab 50,304, hidden 1024, 16 layers, 8 heads of head_dim 128, 1024
 positions, bf16 params with fp32 masters), on random weights from a seed:
 
@@ -11,7 +11,7 @@ positions, bf16 params with fp32 masters), on random weights from a seed:
                    (AdamW, multi_precision) at B=16, S=1024
   3. kernel        flash_pair_packed and paged_decode_attention compiled by
                    Mosaic vs plain jax.numpy
-                   causal attention, forward and d(qkv), at the bench shape
+                   causal attention, forward and d(qkv), at that shape
   4. server        serving.DecodeEngine(paged, chunked prefill) answering 8
                    staggered requests that share a 64-token prefix
 
@@ -104,7 +104,7 @@ def build_model(paddle, cfg_kw):
     paddle.seed(SEED)
     model = GPTForCausalLM(GPTConfig(hidden_dropout_prob=0.0,
                                      attention_dropout_prob=0.0, **cfg_kw))
-    # AMP-O2 analog, as bench.py: bf16 working params, fp32 masters in AdamW
+    # AMP-O2 analog: bf16 working params, fp32 masters in AdamW
     for _, p in model.named_parameters():
         p._data = p.value().astype("bfloat16")
     return model
@@ -339,7 +339,7 @@ def phase_server(jax, paddle, model, size, n_dev, on_tpu):
         from paddle_tpu.models import shard_gpt_tp
         set_mesh(Mesh(np.asarray(jax.devices()[:n_dev]), ("model",)))
         shard_gpt_tp(model)
-    engine = DecodeEngine(model, paged=True, **size["engine"])
+    engine = DecodeEngine(model, **size["engine"])
     pager = engine._pager
 
     # warm-up request alone: mints the chunk + decode executables, and when

@@ -180,17 +180,21 @@ def init_rpc(name: str, rank: Optional[int] = None,
         else:
             bus.connect(w.rank, w.ip, w.port)
     agent = _Agent(name, rank, world_size, store, bus, workers)
-    # barrier: everyone connected before anyone issues calls. The global is
-    # only published on success — a timed-out init tears the agent down so a
-    # retry isn't blocked by a half-initialized world.
+    # barrier: everyone connected before anyone issues calls. The agent is
+    # published BEFORE it: a peer that leaves the barrier first may call in
+    # while this process still sleeps in it, and what it calls
+    # (get_worker_info, a nested rpc) needs the global. A timed-out init
+    # takes it back and tears the agent down, so a retry isn't blocked by a
+    # half-initialized world.
+    _AGENT = agent
     store.add("rpc/ready", 1)
     deadline = time.time() + 300
     while int(store.add("rpc/ready", 0)) < world_size:
         if time.time() > deadline:
+            _AGENT = None
             agent.shutdown()
             raise TimeoutError("rpc init barrier timed out")
         time.sleep(0.02)
-    _AGENT = agent
 
 
 def rpc_sync(to: str, fn, args=None, kwargs=None, timeout: float = -1):

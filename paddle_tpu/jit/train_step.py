@@ -910,11 +910,10 @@ class TrainStep:
         rematerializes (measured FLOPs then include recompute replays, so
         MFU must source from the analytic model while HFU stays measured).
         For a transformer whose config exposes num_layers/hidden_size, the
-        attention-dot term (12·L·d·S per token, fwd+bwd — the bench.py
-        constant) is added: without it the ledger's analytic would sit
-        ~10% under bench's on the GPT config, and under recompute — where
-        the analytic is the sole MFU source — the two figures would
-        disagree by pure constant skew.
+        attention-dot term (12·L·d·S per token, fwd+bwd) is added: without
+        it the ledger's analytic would sit ~10% under the measured count
+        on the GPT config, and under recompute — where the analytic is
+        the sole MFU source — MFU would read low by pure constant skew.
         """
         from ..monitor.goodput import analytic_train_flops_per_token
         tokens = 1

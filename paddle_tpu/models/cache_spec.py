@@ -2,9 +2,9 @@
 its head, and, PER LAYER, what that layer caches between calls.
 
   ``kv_layer(heads, width)``   keys and values of every past position: the
-                               engine backs it with paged block pools (or a
-                               row buffer) and hands the layer ``(pool_k,
-                               pool_v, block_table)`` / ``(k_buf, v_buf)``.
+                               engine backs it with paged block pools
+                               and hands the layer ``(pool_k, pool_v,
+                               block_table)``.
                                Pools are ``[blocks, block, heads, width]``;
                                with ``merged_rows`` ``[blocks, block *
                                heads, width]``, row ``t * heads + h``: the

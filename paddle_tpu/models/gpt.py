@@ -382,7 +382,8 @@ class GPTAttention(nn.Layer):
 
         Two cache layouts:
           * contiguous — ``(k_buf, v_buf, pos)`` with [B, M, nh, hd]
-            buffers, each batch row owning one row;
+            buffers, each batch row owning one row, and ONE scalar cursor
+            (generate()'s lockstep batch);
           * paged — ``(pool_k, pool_v, table, pos, write_end)`` with
             [NB, BS, nh, hd] pools shared by all slots and a [B, mbs] int32
             block table. K/V lands at physical ``(table[b, p//BS], p%BS)``;
@@ -392,9 +393,9 @@ class GPTAttention(nn.Layer):
             table redirect to trash block 0 so a shared or out-of-range
             block can never be corrupted by padding.
 
-        `pos` is a scalar (one shared cursor: generate()'s lockstep batch /
-        one slot's prefill chunk) or a [B] vector (per-row cursors: the
-        serving engine's slots, each batch row a request at its own depth).
+        The paged layout's `pos` is a scalar (one slot's prefill chunk) or
+        a [B] vector (per-row cursors: the serving engine's decode step,
+        each batch row a request at its own depth).
         """
         b, s, h = x.shape
         nh, hd = self.num_heads, self.head_dim
@@ -408,17 +409,11 @@ class GPTAttention(nn.Layer):
                 return self.out_proj(Tensor(ctx.reshape(b, s, h))), new_cache
             k_buf, v_buf = _paged_kv_gather(*new_cache, kv_cache[2])
         else:
-            k_buf, v_buf, pos = kv_cache   # jnp arrays + int32 scalar/[B]
-            if jnp.ndim(pos) == 1:
-                upd = lambda buf, kv, p: jax.lax.dynamic_update_slice(
-                    buf, kv, (p, 0, 0))
-                k_buf = jax.vmap(upd)(k_buf, k.astype(k_buf.dtype), pos)
-                v_buf = jax.vmap(upd)(v_buf, v.astype(v_buf.dtype), pos)
-            else:
-                k_buf = jax.lax.dynamic_update_slice(
-                    k_buf, k.astype(k_buf.dtype), (0, pos, 0, 0))
-                v_buf = jax.lax.dynamic_update_slice(
-                    v_buf, v.astype(v_buf.dtype), (0, pos, 0, 0))
+            k_buf, v_buf, pos = kv_cache   # jnp arrays + int32 scalar
+            k_buf = jax.lax.dynamic_update_slice(
+                k_buf, k.astype(k_buf.dtype), (0, pos, 0, 0))
+            v_buf = jax.lax.dynamic_update_slice(
+                v_buf, v.astype(v_buf.dtype), (0, pos, 0, 0))
             new_cache = (k_buf, v_buf)
         if jnp.ndim(pos) == 1:
             q_pos = (pos[:, None] + jnp.arange(s))[:, None, :, None]

@@ -175,18 +175,11 @@ class LlamaAttention(nn.Layer):
                 return self.o_proj(Tensor(ctx.reshape(b, s, h))), new_cache
             k_buf, v_buf = _paged_kv_gather(*new_cache, kv_cache[2])
         else:
-            k_buf, v_buf, _ = kv_cache      # [B, M, n_kv, hd] + cursor
-            if jnp.ndim(pos) == 1:
-                # per-slot cursors (serving engine): vmapped per-row writes
-                upd = lambda buf, kv, p: jax.lax.dynamic_update_slice(
-                    buf, kv, (p, 0, 0))
-                k_buf = jax.vmap(upd)(k_buf, kv_.astype(k_buf.dtype), pos)
-                v_buf = jax.vmap(upd)(v_buf, vv.astype(v_buf.dtype), pos)
-            else:
-                k_buf = jax.lax.dynamic_update_slice(
-                    k_buf, kv_.astype(k_buf.dtype), (0, pos, 0, 0))
-                v_buf = jax.lax.dynamic_update_slice(
-                    v_buf, vv.astype(v_buf.dtype), (0, pos, 0, 0))
+            k_buf, v_buf, _ = kv_cache  # [B, M, n_kv, hd] + scalar cursor
+            k_buf = jax.lax.dynamic_update_slice(
+                k_buf, kv_.astype(k_buf.dtype), (0, pos, 0, 0))
+            v_buf = jax.lax.dynamic_update_slice(
+                v_buf, vv.astype(v_buf.dtype), (0, pos, 0, 0))
             new_cache = (k_buf, v_buf)
         if jnp.ndim(pos) == 1:
             q_pos = (pos[:, None] + jnp.arange(s))[:, None, None, :, None]

@@ -31,8 +31,7 @@ serving through ``serving.DecodeEngine`` and a full forward. Training needs
 the chunked scan's backward and the router's auxiliary loss (ROADMAP).
 
 Caches, one per layer (``decode_spec()`` declares them): a full layer takes
-paged ``(pool_k, pool_v, table)`` with merged-row pools (``cache_spec``) or
-GPT's contiguous ``(k_buf, v_buf)``; a
+paged ``(pool_k, pool_v, table)`` with merged-row pools (``cache_spec``); a
 linear layer ``(state [B, nv, dk, dv] float32, conv_tail [B, width - 1,
 channels])``, the rows of the sequences in the call. A call whose
 ``start_pos`` is 0 starts from a zero state whatever the row held (data, not
@@ -326,7 +325,7 @@ class GatedAttention(_Weights):
         ctx = None
         if cache is None:
             k_buf, v_buf = k, v
-        elif len(cache) == 3:           # paged: [NB, BS * n_kv, hd] pools
+        else:                           # paged: [NB, BS * n_kv, hd] pools
             we = end if end is not None else jnp.asarray(pos, jnp.int32) + s
             new_cache = self._write_merged(cache, k, v, positions, we)
             ctx = self._attend_merged(cache[2], pos, q, new_cache)
@@ -334,19 +333,6 @@ class GatedAttention(_Weights):
                 with jax.named_scope("kv_gather"):
                     k_buf, v_buf = (jnp.take(p, cache[2], axis=0).reshape(
                         b, -1, nkv, hd) for p in new_cache)
-        else:                                                 # contiguous
-            k_buf, v_buf = cache
-            if jnp.ndim(pos) == 1:
-                upd = lambda buf, kv, p: jax.lax.dynamic_update_slice(
-                    buf, kv, (p, 0, 0))
-                k_buf = jax.vmap(upd)(k_buf, k.astype(k_buf.dtype), pos)
-                v_buf = jax.vmap(upd)(v_buf, v.astype(v_buf.dtype), pos)
-            else:
-                k_buf = jax.lax.dynamic_update_slice(
-                    k_buf, k.astype(k_buf.dtype), (0, pos, 0, 0))
-                v_buf = jax.lax.dynamic_update_slice(
-                    v_buf, v.astype(v_buf.dtype), (0, pos, 0, 0))
-            new_cache = (k_buf, v_buf)
         if ctx is None:
             m = k_buf.shape[1]
             qh = q.reshape(b, s, nkv, nh // nkv, hd).astype(jnp.float32)

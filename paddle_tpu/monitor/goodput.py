@@ -32,8 +32,7 @@ monitor already receives (no new hot-path instrumentation of its own):
   ``productive_s / sum(state_s)`` by construction, so the fraction always
   reconstructs from the exported per-state gauges.
 
-Peak FLOPs resolve from the device-kind table below (the ``bench.py``
-source of truth, now shared) with the ``PADDLE_PEAK_FLOPS`` env override
+Peak FLOPs resolve from the device-kind table below with the ``PADDLE_PEAK_FLOPS`` env override
 for device kinds the table does not know — an unknown chip degrades to
 flop *counts* without utilization ratios, never to a wrong ratio.
 
@@ -76,9 +75,8 @@ _PRIORITY = {"compile": 60, "reshard": 50, "ckpt": 40, "data_wait": 30,
 # accounting bucket)
 _STATE_OF = {"ckpt_bg": "ckpt"}
 
-# peak dense-matmul FLOP/s per chip by device kind (prefix match). The
-# bench.py table, promoted here as the single source of truth; extend via
-# env PADDLE_PEAK_FLOPS on kinds this table does not know.
+# peak dense-matmul FLOP/s per chip by device kind (prefix match); extend
+# via env PADDLE_PEAK_FLOPS on kinds this table does not know.
 PEAK_FLOPS = {"TPU v5 lite": 197e12, "TPU v4": 275e12,
               "TPU v5p": 459e12, "TPU v6 lite": 918e12}
 
@@ -109,14 +107,13 @@ def executable_cost_stats(compiled) -> Optional[dict]:
 
 def analytic_train_flops_per_token(n_params, num_layers=None,
                                    hidden_size=None, seq=None) -> float:
-    """The analytic training FLOP model, ONE copy for bench.py and the
-    ledger: 6 FLOPs per parameter per token (fwd 2 + bwd 4) plus the
-    attention-dot term 12·L·d·S per token (scores + context, fwd+bwd),
-    which parameter counting misses entirely. ``n_params`` is the caller's
-    choice of parameter population — bench passes matmul params only
-    (block weights + tied lm-head), the TrainStep ledger passes all
-    trainable params (it cannot classify them; embeddings/norms add ~0.5%
-    at GPT-medium scale)."""
+    """The analytic training FLOP model of the ledger: 6 FLOPs per
+    parameter per token (fwd 2 + bwd 4) plus the attention-dot term
+    12·L·d·S per token (scores + context, fwd+bwd), which parameter
+    counting misses entirely. ``n_params`` is the caller's choice of
+    parameter population — the TrainStep ledger passes all trainable
+    params (it cannot classify them; embeddings/norms add ~0.5% at
+    GPT-medium scale)."""
     f = 6.0 * float(n_params)
     if num_layers and hidden_size and seq:
         f += 12.0 * num_layers * hidden_size * seq

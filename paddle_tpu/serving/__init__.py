@@ -4,10 +4,10 @@ continuous batching.
 The "millions of users" half of the north star: where ``jit.TrainStep``
 compiles the whole training step into one executable per shape bucket,
 ``serving.DecodeEngine`` does the same for generation — a fixed-shape
-decode step over a preallocated slotted KV cache (zero recompiles under
-any admission/eviction pattern) plus bucketed prefill, scheduled at
-iteration granularity (Orca) so short and long requests share the batch
-without padding each other out (vLLM-style slot paging on the batch axis).
+decode step over a preallocated block-paged KV cache (zero recompiles
+under any admission/eviction pattern) plus chunked or bucketed prefill,
+scheduled at iteration granularity (Orca) so short and long requests share
+the batch without padding each other out (vLLM's block page table).
 Under a "model"-axis mesh with a sharded model the executables go SPMD
 (tensor-parallel decode: KV pools head-sharded, page table replicated),
 and a persistent LRU prefix cache parks refcount-0 prompt blocks so

@@ -5,7 +5,7 @@ host-side pieces so a router (serving/router.py) can place, health-check
 and drain replicas without ever importing engine internals:
 
 * **Directories** — the discovery plane. ``LocalDirectory`` is an
-  in-memory dict (in-process fleets: tests, ``bench.py decode --router``);
+  in-memory dict (in-process fleets: tests);
   ``KVDirectory`` rides the launch KV master (distributed/launch/
   master.py) under ``/{job}/serve/{engine}``, the same store + idiom the
   fleet-telemetry collector uses. The store has no server-side TTL, so
@@ -66,9 +66,8 @@ def resolve_serve_master() -> Optional[str]:
 class LocalDirectory:
     """In-process discovery: a dict with the KVDirectory contract. The
     same object is shared by endpoints (put) and the router (list), so
-    in-process fleets — tier-1 chaos tests, the router bench lane — run
-    the identical registration/staleness/incarnation logic with zero
-    sockets."""
+    in-process fleets (the tier-1 chaos tests) run the identical
+    registration/staleness/incarnation logic with zero sockets."""
 
     def __init__(self):
         self._store: Dict[str, dict] = {}

@@ -5,8 +5,8 @@ AOT executable (``jax.jit(...).lower().compile()``) minted ONCE per shape
 bucket, and the steady state runs zero recompiles no matter which requests
 come and go.
 
-Default (``paged=True``) memory model — **block page table** (vLLM, Kwon
-et al. 2023): the KV pool is per-layer ``[kv_blocks, block_size, n_kv,
+The memory model is a **block page table** (vLLM, Kwon et al. 2023): the
+KV pool is per-layer ``[kv_blocks, block_size, n_kv,
 hd]`` K/V pairs plus a fixed-shape ``[max_slots, max_blocks_per_slot]``
 int32 block-index table. Which physical block backs which logical position
 is table DATA, never executable shape — admissions, evictions, block
@@ -29,11 +29,6 @@ executable and no extra dispatch. Executable families:
   bucketed whole-prompt chunk per admission (monolithic; one executable
   per prompt-length bucket, the PR 6 scheduling behavior).
 
-``paged=False`` keeps the slot-owns-a-row layout (per-layer
-``[max_slots, max_len, n_kv, hd]`` buffers, bucketed monolithic prefill
-writing the K/V block at the slot row) — the control arm the paged
-microbenches gate against.
-
 **Tensor-parallel decode**: when ``distributed.env.get_mesh()`` has a
 "model" axis of degree > 1 AND the model rides it (shard_gpt_tp /
 shard_llama_tp / mp_layers), the same executables mint as SPMD programs —
@@ -42,8 +37,7 @@ each KV pool placed ``NamedSharding(mesh, P(None, None, "model", None))``
 on their Column/RowParallel placements, and the block table / cursors /
 token ids / COW pairs committed mesh-REPLICATED host data, so the
 ``BlockPager`` never learns about the mesh and the zero-recompile
-contract survives block churn on it. ``paged=False`` refuses a sharded
-model (the row cache is single-chip by design).
+contract survives block churn on it.
 
 The pager's **persistent prefix cache** outlives tenants: registered
 prompt blocks park in an LRU at refcount zero and later same-prefix
@@ -91,10 +85,6 @@ from .scheduler import (TERMINAL_STATUSES, AdmissionQueue, Request,
 __all__ = ["DecodeEngine", "Request", "generate_via_engine",
            "quantize_for_serving", "EngineHangError", "TERMINAL_STATUSES"]
 
-
-# the (host phase, executable call) spans of the two kinds of dispatch
-_PREFILL_SPANS = ("engine/prefill_host", "engine/prefill_call")
-_DECODE_SPANS = ("engine/decode_prepare", "engine/decode_call")
 
 # terminal caller-supplied request ids remembered per engine for dedup
 # (a requeue retry arriving AFTER completion still returns the original)
@@ -241,15 +231,14 @@ class DecodeEngine:
     Knobs:
       max_slots        batch rows of the decode step (concurrent requests)
       max_len          per-slot KV horizon; prompt + new tokens must fit
-      paged            block page table (default) vs slot-owns-a-row cache
-      block_size       tokens per KV block (paged)
+      block_size       tokens per KV block
       kv_blocks        physical pool size incl. the reserved trash block;
-                       default max_slots*ceil(max_len/block_size)+1 (full
-                       row-cache capacity) — set it SMALLER to oversubscribe
-                       (prefix sharing is what makes that safe)
-      prefill_chunk    paged only: at most this many prompt tokens run per
-                       scheduler iteration through ONE [1, chunk] executable
-                       (None: whole-prompt bucketed chunks, monolithic)
+                       default max_slots*ceil(max_len/block_size)+1 (every
+                       slot at its full horizon) — set it SMALLER to
+                       oversubscribe (prefix sharing is what makes that safe)
+      prefill_chunk    at most this many prompt tokens run per scheduler
+                       iteration through ONE [1, chunk] executable (None:
+                       whole-prompt bucketed chunks, monolithic)
       prefill_buckets  padded prompt lengths for monolithic prefill (one
                        executable each); default: powers of two up to
                        max_len; unused when prefill_chunk is set
@@ -270,15 +259,15 @@ class DecodeEngine:
                        cache tier: parked registered blocks export to it
                        and registry-miss admissions fetch + adopt from it
                        (``kvpool.resolve_kv_pool()`` picks by env). None
-                       (the default) disables the tier entirely; requires
-                       paged=True.
+                       (the default) disables the tier entirely.
 
     ``submit()`` validates and queues; ``step()`` runs ONE scheduler
     iteration (admit into free slots, advance pending prefill chunks, then
-    one decode step over all live slots); ``run()`` drains. The paged
-    engine without a drafter launches a step's calls back to back and
-    prepares the NEXT step's (admission, chunk and decode set-up, argument
-    uploads) while the device runs them (``_step_planned``). Telemetry lands
+    one decode step over all live slots); ``run()`` drains. A step's calls
+    are launched back to back and the NEXT step's (admission, chunk and
+    decode set-up, argument uploads) prepared while the device runs them
+    (``_step_planned``; a drafter's verify calls are made in turn, after
+    the step's chunks). Telemetry lands
     under ``serve/*`` when the monitor is enabled, and every minted
     executable bumps ``compile_count`` (the serving recompile sentinel —
     flat in steady state).
@@ -319,6 +308,13 @@ class DecodeEngine:
         if quantize not in (None, "int8"):
             raise ValueError(f"quantize must be None or 'int8', "
                              f"got {quantize!r}")
+        if not paged:
+            # the argument stays only until benchmark/system.py, which no
+            # PR but a ``benchmark`` one may edit, stops passing paged=True
+            raise ValueError(
+                "paged=False: the slot-owns-a-row cache was deleted in PR 31 "
+                "(the block page table with chunked prefill serves "
+                "everything it did); drop the argument")
         spec = _model_spec(model)
         if max_len > spec.max_pos:
             raise ValueError(
@@ -335,7 +331,6 @@ class DecodeEngine:
         self.quantize = quantize
         self.max_slots = int(max_slots)
         self.max_len = int(max_len)
-        self.paged = bool(paged)
         self._do_sample = bool(do_sample)
         self._temperature = float(temperature)
         self._top_k = int(top_k)
@@ -351,10 +346,6 @@ class DecodeEngine:
                     "speculative decoding with recurrent-state layers needs "
                     "a state snapshot to roll rejected drafts back to: the "
                     "verify executable is not built for such a model")
-            if not self.paged:
-                raise NotImplementedError(
-                    "speculative decoding requires paged=True (speculative "
-                    "K/V lands in trash-redirectable BlockPager positions)")
             if self._do_sample:
                 raise NotImplementedError(
                     "speculative decoding is greedy-only (acceptance is "
@@ -412,11 +403,6 @@ class DecodeEngine:
         self._pool_sh = None
         self._kv_pin = False
         if self._mesh is not None:
-            if not self.paged:
-                raise NotImplementedError(
-                    "tensor-parallel serving requires paged=True (the row "
-                    "cache is single-chip; shard the paged pool's head "
-                    "axis instead)")
             self._repl = NamedSharding(self._mesh, P())
             if spec.n_kv_heads % self._tp == 0:
                 pool_spec = P(None, None, "model", None)
@@ -449,64 +435,44 @@ class DecodeEngine:
                 if isinstance(sh, NamedSharding) and sh.mesh == self._mesh:
                     continue
                 t._data = jax.device_put(a, self._repl)
-        if self.paged:
-            if block_size < 1:
-                raise ValueError(f"block_size must be >= 1, got {block_size}")
-            self.block_size = int(min(block_size, self.max_len))
-            self._mbs = -(-self.max_len // self.block_size)
-            if kv_blocks is None:
-                kv_blocks = self.max_slots * self._mbs + 1
-            if kv_blocks < self._mbs + 2:
-                raise ValueError(
-                    f"kv_blocks {kv_blocks} cannot back even one full slot "
-                    f"({self._mbs} blocks + trash)")
-            self.kv_blocks = int(kv_blocks)
-            if prefill_chunk is not None and not (
-                    1 <= int(prefill_chunk) <= self.max_len):
-                raise ValueError(f"prefill_chunk must lie in [1, max_len="
-                                 f"{self.max_len}], got {prefill_chunk}")
-            self.prefill_chunk = None if prefill_chunk is None \
-                else int(prefill_chunk)
-            def _pool(c):
-                rows = (self.block_size * c.n_kv_heads,) if c.merged_rows \
-                    else (self.block_size, c.n_kv_heads)
-                z = jnp.zeros((self.kv_blocks,) + rows + (c.head_dim,),
-                              self._cache_dtype)
-                return z if self._pool_sh is None \
-                    else jax.device_put(z, self._pool_sh)
-            self._pools = [(_pool(c), _pool(c)) if c.kind == "kv"
-                           else self._state_rows(c) for c in spec.layers]
-            # a prefix hit would skip tokens a recurrent state has to see
-            self._pager = BlockPager(self.kv_blocks, self.block_size,
-                                     self.max_slots, self._mbs,
-                                     prefix_cache=not self._has_state)
-            self._caches = None
-            # in-flight chunked prefills: slot -> _PrefillState
-            self._prefilling: dict = {}
-            self._admit_seq = itertools.count()   # eviction picks youngest
-            self._slot_seq = [0] * self.max_slots
-            self.preemptions = 0
-        else:
-            if prefill_chunk is not None:
-                raise ValueError("prefill_chunk requires paged=True")
-            self.block_size = self.kv_blocks = None
-            self.prefill_chunk = None
-            self._pools = self._pager = None
-            self._prefilling = {}
-            self.preemptions = 0
-            self._caches = [
-                (jnp.zeros((self.max_slots, self.max_len, c.n_kv_heads,
-                            c.head_dim), self._cache_dtype),
-                 jnp.zeros((self.max_slots, self.max_len, c.n_kv_heads,
-                            c.head_dim), self._cache_dtype))
-                if c.kind == "kv" else self._state_rows(c)
-                for c in spec.layers]
+        if block_size < 1:
+            raise ValueError(f"block_size must be >= 1, got {block_size}")
+        self.block_size = int(min(block_size, self.max_len))
+        self._mbs = -(-self.max_len // self.block_size)
+        if kv_blocks is None:
+            kv_blocks = self.max_slots * self._mbs + 1
+        if kv_blocks < self._mbs + 2:
+            raise ValueError(
+                f"kv_blocks {kv_blocks} cannot back even one full slot "
+                f"({self._mbs} blocks + trash)")
+        self.kv_blocks = int(kv_blocks)
+        if prefill_chunk is not None and not (
+                1 <= int(prefill_chunk) <= self.max_len):
+            raise ValueError(f"prefill_chunk must lie in [1, max_len="
+                             f"{self.max_len}], got {prefill_chunk}")
+        self.prefill_chunk = None if prefill_chunk is None \
+            else int(prefill_chunk)
+        def _pool(c):
+            rows = (self.block_size * c.n_kv_heads,) if c.merged_rows \
+                else (self.block_size, c.n_kv_heads)
+            z = jnp.zeros((self.kv_blocks,) + rows + (c.head_dim,),
+                          self._cache_dtype)
+            return z if self._pool_sh is None \
+                else jax.device_put(z, self._pool_sh)
+        self._pools = [(_pool(c), _pool(c)) if c.kind == "kv"
+                       else self._state_rows(c) for c in spec.layers]
+        # a prefix hit would skip tokens a recurrent state has to see
+        self._pager = BlockPager(self.kv_blocks, self.block_size,
+                                 self.max_slots, self._mbs,
+                                 prefix_cache=not self._has_state)
+        # in-flight chunked prefills: slot -> _PrefillState
+        self._prefilling: dict = {}
+        self._admit_seq = itertools.count()   # eviction picks youngest
+        self._slot_seq = [0] * self.max_slots
+        self.preemptions = 0
         # ---- cross-process prefix-cache tier (serving/kvpool.py): parked
         # registered blocks export to the pool, registry-miss admissions
         # fetch + adopt. All host state; zero effect when kv_pool is None.
-        if kv_pool is not None and not self.paged:
-            raise ValueError("kv_pool requires paged=True (the pool moves "
-                             "page-table blocks)")
         if kv_pool is not None and self._has_state:
             raise NotImplementedError(
                 "pool export/adopt moves K/V blocks only: a model with "
@@ -540,8 +506,7 @@ class DecodeEngine:
                                  f"[1, max_len={self.max_len}]: {buckets}")
         self.prefill_buckets = sorted(set(buckets))
         # host-side slot state: cursors/last-token per row; dead rows sit at
-        # pos 0 (their decode writes land on a row — or, paged, the trash
-        # block — that the next admission rewrites)
+        # pos 0 (their decode writes land on the trash block)
         self._pos = np.zeros(self.max_slots, np.int32)
         self._tok = np.zeros(self.max_slots, np.int32)
         self._live = np.zeros(self.max_slots, bool)
@@ -552,7 +517,7 @@ class DecodeEngine:
         self._decode_attention = None
         self._verify_exe = None
         self._prefill_exes = {}
-        # ---- the prepared step (paged, no drafter; _step_planned). The
+        # ---- the prepared step (_step_planned). The
         # plan made for the next step while the last one ran, and why one
         # was thrown away since (or could not be made); the last decode's
         # picked tokens, still on the device, which the next decode takes
@@ -603,7 +568,7 @@ class DecodeEngine:
         self._clock = time.time
         self._faults = fault_schedule if fault_schedule is not None \
             else FaultSchedule.from_env()
-        if self.paged and self._faults is not None:
+        if self._faults is not None:
             self._pager.fault_schedule = self._faults
         if hang_s is None:
             try:
@@ -655,7 +620,7 @@ class DecodeEngine:
         if mon is not None:
             mon.serve_engine(self.max_slots, self.max_len,
                              self.prefill_buckets, quantize,
-                             engine_id=self.engine_id, paged=self.paged,
+                             engine_id=self.engine_id, paged=True,
                              block_size=self.block_size,
                              kv_blocks=self.kv_blocks,
                              prefill_chunk=self.prefill_chunk, tp=self._tp,
@@ -799,15 +764,14 @@ class DecodeEngine:
         return tuple(jnp.zeros((self.max_slots,) + shape, jnp.dtype(dtype))
                      for shape, dtype in layer.arrays)
 
-    def _layer_caches(self, pools, table=None, slot=None):
+    def _layer_caches(self, pools, table, slot=None):
         """What each layer's cached forward is handed: a kv layer its pools
         and ``table`` (the rows of the call's slots), a state layer its
         arrays' rows, ``slot``'s alone for a one-slot call."""
         out = []
         for layer, cache in zip(self.spec.layers, pools):
             if layer.kind == "kv":
-                out.append(tuple(cache) + ((table,) if table is not None
-                                           else ()))
+                out.append(tuple(cache) + (table,))
             elif slot is None:
                 out.append(tuple(cache))
             else:
@@ -866,71 +830,48 @@ class DecodeEngine:
     def _build_decode(self):
         # a model with state layers is told which slots are live: write_end
         # = pos + 1 for them, pos for the rest, whose state (and K/V) the
-        # step must leave alone
-        if self.paged:
-            # ``tok`` is as long as the step's own picked tokens (with the
-            # routed layers' counts behind them, where there are any): the
-            # next step is handed this step's output as it lies on the
-            # device, and the host never has to read it first
-            def fn(leaves, pools, table, tok, pos, cow_src, cow_dst, key,
-                   *end):
-                def body():
-                    pools2 = self._apply_cow(pools, cow_src, cow_dst)
-                    hidden, new, moe = self._backbone(
-                        tok[:self.max_slots, None],
-                        self._layer_caches(pools2, table),
-                        start_pos=pos, **dict(zip(("write_end",), end)))
-                    nxt, ok = self._sample(hidden.value()[:, -1], key, moe)
-                    return self._layer_results(pools2, new), nxt, ok
-                return self._traced(leaves, body)
+        # step must leave alone.
+        # ``tok`` is as long as the step's own picked tokens (with the
+        # routed layers' counts behind them, where there are any): the
+        # next step is handed this step's output as it lies on the
+        # device, and the host never has to read it first
+        def fn(leaves, pools, table, tok, pos, cow_src, cow_dst, key, *end):
+            def body():
+                pools2 = self._apply_cow(pools, cow_src, cow_dst)
+                hidden, new, moe = self._backbone(
+                    tok[:self.max_slots, None],
+                    self._layer_caches(pools2, table),
+                    start_pos=pos, **dict(zip(("write_end",), end)))
+                nxt, ok = self._sample(hidden.value()[:, -1], key, moe)
+                return self._layer_results(pools2, new), nxt, ok
+            return self._traced(leaves, body)
 
-            pad = self._dev(jnp.zeros(self.max_slots, jnp.int32))
-            args = (self._leaf_values(), self._pools,
-                    self._dev(self._pager.tables),
-                    self._dev(self._host_tok()), self._dev(self._pos), pad,
-                    pad, self._greedy_key)
-        else:
-            def fn(leaves, caches, tok, pos, key, *end):
-                def body():
-                    hidden, new, moe = self._backbone(
-                        tok[:, None], self._layer_caches(caches),
-                        start_pos=pos, **dict(zip(("write_end",), end)))
-                    nxt, ok = self._sample(hidden.value()[:, -1], key, moe)
-                    return self._layer_results(caches, new), nxt, ok
-                return self._traced(leaves, body)
-
-            args = (self._leaf_values(), self._caches,
-                    jnp.asarray(self._tok), jnp.asarray(self._pos),
-                    self._greedy_key)
+        pad = self._dev(jnp.zeros(self.max_slots, jnp.int32))
+        args = (self._leaf_values(), self._pools,
+                self._dev(self._pager.tables),
+                self._dev(self._host_tok()), self._dev(self._pos), pad,
+                pad, self._greedy_key)
         if self._has_state:
             args += (self._dev(self._pos),)
         t0 = time.time()
-        if self.paged:
-            from ..kernels.pallas import paged_decode
-            traced = paged_decode.kernel_traces()
+        from ..kernels.pallas import paged_decode
+        traced = paged_decode.kernel_traces()
+        low = self._lower_in_eval(fn, args, self._pool_out_shardings())
+        n_out = low.out_info[1].shape[0]
+        if n_out != self._tok_len:
+            # counts from a layer __init__ did not know of: the step's
+            # output is the next step's input, so trace at its length
+            self._tok_len = n_out
+            args = args[:3] + (self._dev(self._host_tok()),) + args[4:]
             low = self._lower_in_eval(fn, args, self._pool_out_shardings())
-            n_out = low.out_info[1].shape[0]
-            if n_out != self._tok_len:
-                # counts from a layer __init__ did not know of: the step's
-                # output is the next step's input, so trace at its length
-                self._tok_len = n_out
-                args = args[:3] + (self._dev(self._host_tok()),) + args[4:]
-                low = self._lower_in_eval(fn, args,
-                                          self._pool_out_shardings())
-            exe = low.compile()
-            self._build_note(args[3])
-        else:
-            exe = self._compile_in_eval(fn, args)
+        exe = low.compile()
+        self._build_note(args[3])
         self._decode_exe = exe
         # which attention the trace took (the model chose from its input:
         # models/gpt.py::_paged_decode_attend); a silent fallback on the
         # chip would otherwise look like "no gain"
-        if not self.paged:
-            self._decode_attention = "contiguous"
-        elif paged_decode.kernel_traces() > traced:
-            self._decode_attention = "paged_kernel"
-        else:
-            self._decode_attention = "gather"
+        self._decode_attention = "paged_kernel" \
+            if paged_decode.kernel_traces() > traced else "gather"
         # the decode step advances one token per SLOT per call
         self._minted("decode", None, time.time() - t0, exe=exe,
                      tokens=self.max_slots)
@@ -1068,49 +1009,6 @@ class DecodeEngine:
         self._minted("adopt", None, time.time() - t0, exe=exe)
         return exe
 
-    def _build_prefill(self, sb: int):
-        spec = self.spec
-
-        def fn(leaves, caches, ids, slot, true_len, key):
-            def body():
-                # a state layer starts from its own zero row and, unlike
-                # K/V, has to be told where the bucket's padding begins
-                small = [
-                    (jnp.zeros((1, sb, c.n_kv_heads, c.head_dim),
-                               self._cache_dtype),
-                     jnp.zeros((1, sb, c.n_kv_heads, c.head_dim),
-                               self._cache_dtype))
-                    if c.kind == "kv" else tuple(
-                        jnp.zeros((1,) + a.shape[1:], a.dtype) for a in big)
-                    for c, big in zip(spec.layers, caches)]
-                hidden, small_new, _ = self._backbone(
-                    ids, small, start_pos=jnp.int32(0),
-                    **({"write_end": true_len} if self._has_state else {}))
-                # logits from the TRUE last prompt token; the bucket's
-                # padding tail is causally invisible to it
-                h_last = jax.lax.dynamic_slice_in_dim(
-                    hidden.value(), true_len - 1, 1, axis=1)[:, 0]
-                logits = self._head(h_last)
-                tok0 = self._pick(logits, key).astype(jnp.int32)
-                ok = jnp.all(jnp.isfinite(logits))
-                new_caches = [
-                    tuple(jax.lax.dynamic_update_slice(
-                        big, sm.astype(big.dtype),
-                        (slot,) + (0,) * (big.ndim - 1))
-                        for big, sm in zip(bigs, smalls))
-                    for bigs, smalls in zip(caches, small_new)]
-                return new_caches, tok0[0], ok
-            return self._traced(leaves, body)
-
-        args = (self._leaf_values(), self._caches,
-                jnp.zeros((1, sb), jnp.int32), jnp.int32(0), jnp.int32(1),
-                self._greedy_key)
-        t0 = time.time()
-        exe = self._compile_in_eval(fn, args)
-        self._prefill_exes[sb] = exe
-        self._minted("prefill", sb, time.time() - t0, exe=exe, tokens=sb)
-        return exe
-
     # ----------------------------------------------------------- requests
 
     def _bucket_for(self, n: int) -> Optional[int]:
@@ -1182,7 +1080,7 @@ class DecodeEngine:
             self._reject(req, f"prompt {n} + max_new_tokens "
                               f"{req.max_new_tokens} exceeds engine "
                               f"max_len {self.max_len}")
-        elif self.paged and self._pager.blocks_for(
+        elif self._pager.blocks_for(
                 n + req.max_new_tokens) > self._pager.usable_blocks:
             self._reject(req, f"request needs "
                               f"{self._pager.blocks_for(n + req.max_new_tokens)} "
@@ -1287,8 +1185,7 @@ class DecodeEngine:
 
     @property
     def active_count(self) -> int:
-        """Admitted concurrent requests: decoding + mid-prefill. The figure
-        the paged-vs-row concurrency microbench gates on."""
+        """Admitted concurrent requests: decoding + mid-prefill."""
         return self.live_count + len(self._prefilling)
 
     @property
@@ -1303,13 +1200,14 @@ class DecodeEngine:
         reached a TERMINAL status since the last step (done / failed /
         expired / cancelled / rejected_draining — one list, one contract).
 
-        The paged engine without a drafter does the host's share of a step
-        while the device runs the step before it: a step LAUNCHES the
+        The engine does the host's share of a step while the device runs
+        the step before it: a step LAUNCHES the
         chunk and decode calls prepared under the last one, PREPARES the
         next step's while they run, then COLLECTS (one wait, one
         read-back) and books the tokens. Every call a step launches has
         ended when ``step()`` returns, and a token is handed over in the
-        step that made it (``_step_planned``).
+        step that made it (``_step_planned``, which also says what a
+        drafter changes).
         """
         with _trace.span("engine/step") as whole:
             finished = self._step(whole)
@@ -1323,12 +1221,8 @@ class DecodeEngine:
 
     def _step(self, whole) -> List[Request]:
         """The phases of one iteration, each one span; together they tile
-        ``engine/step`` (``whole``). After the sweep, the paged engine
-        without a drafter launches, prepares and collects
-        (``_step_planned``); a drafter's verify width depends on the
-        tokens it drafts, and the row cache prefills whole prompts at
-        admission, so those two admit, prefill and decode in turn, each
-        call waited for before the next line of Python runs."""
+        ``engine/step`` (``whole``). After the sweep the engine launches,
+        prepares and collects (``_step_planned``)."""
         finished: List[Request] = []
         with _trace.span("engine/sweep"):
             if self._terminal_buf:
@@ -1345,19 +1239,7 @@ class DecodeEngine:
             self._expire_sweep(now, finished)
             if self._draining:
                 self._drain_step(now, finished)
-        if self.paged and self.drafter is None:
-            self._step_planned(finished, whole)
-        else:
-            self._admit_phase(finished)
-            for slot in sorted(self._prefilling,
-                               key=lambda s: self._slot_seq[s]):
-                if slot in self._prefilling:   # an earlier ensure may evict
-                    self._advance_prefill(slot, finished)
-            if self._live.any():
-                if self.drafter is not None:
-                    self._decode_spec(finished)
-                else:
-                    self._decode(finished)
+        self._step_planned(finished, whole)
         if self._kv_pool is not None:
             # serialize freshly parked registered blocks OUT to the pool at
             # the end of the iteration — never inside the admission/decode
@@ -1413,13 +1295,10 @@ class DecodeEngine:
                     self._queue.pop()
                     self._terminalize(head, "failed", str(e), finished)
                     continue
-            if self.paged:
-                if not self._try_admit_paged(head):
-                    refused = 1
-                    break          # head-of-line waits for blocks, FIFO kept
-                self._queue.pop()
-            else:
-                self._admit(self._queue.pop(), self._slots.alloc(), finished)
+            if not self._try_admit_paged(head):
+                refused = 1
+                break          # head-of-line waits for blocks, FIFO kept
+            self._queue.pop()
             admitted += 1
         # whoever is still queued waits, from here to the next look, for
         # blocks (the head was refused) or for a slot (none was free)
@@ -1459,8 +1338,7 @@ class DecodeEngine:
         self._pos[slot] = 0
         self._tok[slot] = 0
         self._slot_req[slot] = None
-        if self.paged:
-            self._pager.release_slot(slot)
+        self._pager.release_slot(slot)
         self._slots.release(slot)
 
     def _nan_logits(self, req: Request, where: str):
@@ -1747,22 +1625,21 @@ class DecodeEngine:
             + (f"; flight dump {dump_path}" if dump_path else ""),
             RuntimeWarning)
 
-    def _dispatch_guarded(self, kind: str, bucket, spans, upload, call,
-                          **call_attrs):
-        """Run one decode/chunk dispatch under the guardrails: the chaos
-        seam fires first (a ``slow`` lands inside the armed window — that
-        is how the watchdog is tested), the watchdog brackets the uploads,
-        the call + host sync, and any exception or detected hang routes
-        through ``_fail_engine`` so the engine fails loudly with consistent
-        state. ``spans`` names two: ``upload()`` makes the executable's
-        device arguments under one more span of the host phase, and
-        ``call(*args)`` dispatches, waits and reads back under the call's
-        own, which is therefore dispatch, device run and read-back alone
-        and carries ``call_attrs``.
-        ``call`` must COMMIT the donated
-        pools/caches to the engine itself before returning — on the hang
-        path the dispatch completed (the old buffers are donated away), so
-        the commit must not depend on this function returning normally.
+    def _dispatch_guarded(self, kind: str, bucket, upload, call):
+        """Run one dispatch that is waited for (the drafter's verify call)
+        under the guardrails: the chaos seam fires first (a ``slow`` lands
+        inside the armed window — that is how the watchdog is tested), the
+        watchdog brackets the uploads, the call + host sync, and any
+        exception or detected hang routes through ``_fail_engine`` so the
+        engine fails loudly with consistent state. ``upload()`` makes the
+        executable's device arguments under one more
+        ``engine/decode_prepare`` span, and ``call(*args)`` dispatches,
+        waits and reads back under ``engine/decode_call``, which is
+        therefore dispatch, device run and read-back alone.
+        ``call`` must COMMIT the donated pools to the engine itself before
+        returning — on the hang path the dispatch completed (the old
+        buffers are donated away), so the commit must not depend on this
+        function returning normally.
         Returns (what ``call`` returned, the call's span)."""
         wd = self._watchdog
         if wd is not None:
@@ -1773,9 +1650,9 @@ class DecodeEngine:
         try:
             if self._faults is not None:
                 self._faults.fire(kind)
-            with _trace.span(spans[0]):
+            with _trace.span("engine/decode_prepare"):
                 args = upload()
-            with _trace.span(spans[1], **call_attrs) as call_span:
+            with _trace.span("engine/decode_call") as call_span:
                 out = call(*args)
                 del args           # released inside the span that used them
         except Exception as e:
@@ -1943,7 +1820,7 @@ class DecodeEngine:
         computed under the old weights. Returns the number of local
         blocks released."""
         self._unforeseen("blocks")
-        n = self._pager.drop_prefix_cache() if self.paged else 0
+        n = self._pager.drop_prefix_cache()
         if self._kv_pool is not None:
             self._pool_gen = int(self._kv_pool.bump_generation())
             self._exported.clear()
@@ -1961,8 +1838,9 @@ class DecodeEngine:
         # the head-of-line request retries this path EVERY step while it
         # waits for blocks: snapshot the pager's sharing counters so a
         # refused attempt leaves them untouched (a 100-step wait must not
-        # inflate prefix_hits by 100 — bench's hit rate and the summary's
-        # hits/admissions figure read these as per-ADMISSION counts)
+        # inflate prefix_hits by 100 — the benchmark's hit counters and the
+        # summary's hits/admissions figure read these as per-ADMISSION
+        # counts)
         ctrs = self._pager.sharing_counters()
         cov = self._pager.share_prefix(slot, req.prompt)
         pool_meta = None
@@ -2023,45 +1901,6 @@ class DecodeEngine:
         if copies:
             ph.event("cow", n=len(copies))
         return True
-
-    def _advance_prefill(self, slot: int, finished: List[Request]):
-        """Run ONE chunk of ``slot``'s pending prefill (at most
-        ``prefill_chunk`` prompt tokens) through the chunk executable; on
-        the final chunk, emit the first generated token and promote the
-        slot to the decode batch."""
-        st = self._prefilling[slot]
-        p0 = st.sent
-        sc = self._chunk_len(st.n)
-        end = min(p0 + sc, st.n)
-        host = dict(slot=slot, tokens=end - p0)
-        with _trace.span("engine/prefill_host", **host):
-            more = self._ensure_or_evict(slot, p0, end)
-            if more is None or slot not in self._prefilling:
-                return                     # this very slot was preempted
-            st.pending_copies += more
-            exe = self._prefill_exes.get(sc)
-            if exe is None:
-                exe = self._build_chunk(sc)
-            n_cow = len(st.pending_copies)
-            ids, src, dst = self._chunk_inputs(st, sc, p0, end)
-
-        def upload():
-            return (self._dev(self._pager.tables), self._dev(ids),
-                    self._slot_index(slot), self._dev(jnp.int32(p0)),
-                    self._dev(jnp.int32(end)), src, dst, self._next_key())
-
-        def run(*args):
-            self._pools, picked, ok = exe(self._leaf_values(), self._pools,
-                                          *args)
-            self._chunk_launched(st, slot, end)
-            # host readback inside the armed window (see _decode)
-            return picked, bool(np.asarray(ok))
-
-        (tok0, l_ok), call = self._dispatch_guarded(
-            "chunk", sc, _PREFILL_SPANS, upload, run, **self._state_attrs(1))
-        with _trace.span("engine/prefill_host", **host):
-            self._chunk_done(st, slot, sc, end, n_cow, tok0, l_ok,
-                             (call.t0, call.t1), finished)
 
     def _chunk_inputs(self, st: _PrefillState, sc: int, p0: int, end: int):
         """The chunk executable's ids (padded to ``sc``) for prompt
@@ -2179,71 +2018,6 @@ class DecodeEngine:
             if victim == slot:
                 return None
 
-    def _admit(self, req: Request, slot: int, finished: List[Request]):
-        n = len(req.prompt)
-        sb = self._bucket_for(n)           # validated at submit
-        ids = np.zeros((1, sb), np.int32)
-        ids[0, :n] = req.prompt
-        exe = self._prefill_exes.get(sb)
-        if exe is None:
-            exe = self._build_prefill(sb)
-        # queue wait measured DIRECTLY at slot assignment (was derived as
-        # t_first_token - t_submit - dt, which charges host bookkeeping to
-        # the queue and can go negative when the clocks disagree with the
-        # subtraction)
-        req._trace_phase("prefill", t0=self._close_queue_phase(req, slot),
-                         slot=slot, bucket=sb)
-
-        def upload():
-            return (jnp.asarray(ids), jnp.int32(slot), jnp.int32(n),
-                    self._next_key())
-
-        def run(*args):
-            self._caches, picked, ok = exe(self._leaf_values(),
-                                           self._caches, *args)
-            return int(picked), bool(np.asarray(ok))
-
-        try:
-            (t, l_ok), call = self._dispatch_guarded(
-                "chunk", sb, _PREFILL_SPANS, upload, run)
-        except BaseException as e:
-            # the half-admitted slot is in neither _prefilling nor
-            # _slot_req yet, so _fail_engine could not release it — and
-            # its tenant must terminalize like everyone else
-            self._slots.release(slot)
-            if not req.finished:
-                self._terminalize(req, "failed", f"engine failed: {e}",
-                                  None)
-            raise
-        if not l_ok:
-            # the slot never joined the decode batch; release it and fail
-            # the request instead of streaming from NaN logits
-            self._nan_logits(req, "prefill")
-            self._release_slot_state(slot, "nan")
-            self._terminalize(req, "failed", "non-finite logits (nan)",
-                              finished, where="prefill")
-            return
-        dt = call.dur_s
-        req.slot, req.status = slot, "running"
-        req.t_first_token = time.time()
-        req.tokens.append(t)
-        self.tokens_generated += 1
-        self._pos[slot] = n
-        self._tok[slot] = t
-        self._live[slot] = True
-        self._slot_req[slot] = req
-        mon = _monitor._active
-        if mon is not None:
-            mon.serve_prefill_step(dt, sb, tokens=n,
-                                   engine_id=self.engine_id,
-                                   span=(call.t0, call.t1))
-            mon.serve_admitted(req.t_first_token - req.t_submit, sb, dt)
-        req._phase.set(chunks=1, exe_s=round(dt, 6))
-        req._trace_phase("decode")
-        req._trace.set(ttft_s=round(req.t_first_token - req.t_submit, 6))
-        if req._stop_hit():
-            self._finish(req, finished)
-
     def _decode_tables(self, rows):
         """The block tables the decode executable may write through: a slot
         the step does not advance (``rows`` is the mask of those it does —
@@ -2257,7 +2031,7 @@ class DecodeEngine:
     # ------------------------------------------------- the prepared step
 
     def _step_planned(self, finished: List[Request], whole):
-        """One step of the paged engine without a drafter.
+        """One step of the engine.
 
         1. LAUNCH the plan made under the last step: its chunk calls, then
            its decode call, back to back, nothing read back between them
@@ -2287,7 +2061,13 @@ class DecodeEngine:
         is given up (cause ``blocks``) and the next step evicts before its
         launch. With nothing prepared and nothing on the device the step
         is built here too (``sync``): a request that finds the engine idle
-        is not kept waiting a step."""
+        is not kept waiting a step.
+
+        With a drafter the plan holds the chunks alone and none is made
+        ahead (every step reads ``sync``): how wide a verify call is
+        depends on the tokens drafted from the ones before, so after the
+        collect each live slot drafts, verifies and is waited for in turn
+        (``_decode_spec``)."""
         plan, self._plan = self._plan, None
         cause, self._discarded = self._discarded, None
         evicted = self.preemptions
@@ -2296,17 +2076,27 @@ class DecodeEngine:
         else:
             how = "rebuilt" if cause else "sync"
             plan = self._plan_step(finished, None)
-            if plan is None:
-                return                     # nothing to run
-        whole.set(plan=how, **({"cause": cause} if how == "rebuilt" else {}))
-        self.plan_counts[how] += 1
-        if how == "rebuilt":
-            self.plan_causes[cause] = self.plan_causes.get(cause, 0) + 1
+        if plan is not None:
+            whole.set(plan=how,
+                      **({"cause": cause} if how == "rebuilt" else {}))
+            self.plan_counts[how] += 1
+            if how == "rebuilt":
+                self.plan_causes[cause] = self.plan_causes.get(cause, 0) + 1
+            self._run_plan(plan, finished, evicted)
+        if self.drafter is not None and self._live.any():
+            self._decode_spec(finished)
+
+    def _run_plan(self, plan: _Plan, finished: List[Request], evicted: int):
+        """Launch ``plan``, prepare the next step under it, collect, and
+        book what the calls returned. ``evicted``: the engine's preemptions
+        before this step was built, if it was built just now."""
         wd = self._watchdog
         launched: List[_ChunkCall] = []
         try:
             self._launch(plan, launched)
-            if self.preemptions == evicted:
+            if self.drafter is not None:
+                pass          # never ahead: built in turn (_step_planned)
+            elif self.preemptions == evicted:
                 self._plan = self._plan_step(finished, plan)
             else:
                 # this step had to evict to be built: the pool is short,
@@ -2389,10 +2179,12 @@ class DecodeEngine:
                 c.args = None
             self._chunk_launched(c.st, c.slot, c.end)
             launched.append(c)
-        d = plan.decode
-        if d is None:
-            return
-        self._arm("decode", None, first)
+        if plan.decode is not None:
+            self._arm("decode", None, first)
+            self._decode(plan.decode)
+
+    def _decode(self, d: _DecodeCall):
+        """Hand a plan's decode call to the device."""
         with _trace.span("engine/decode_call", **d.attrs) as d.span:
             tok = d.tok if d.tok is not None else self._picked
             for slot_dev, c in d.firsts:
@@ -2430,7 +2222,9 @@ class DecodeEngine:
                     return None
                 if c is not None:
                     chunks.append(c)
-            decode = self._plan_decode(chunks, flight)
+            # (a drafter's verify calls are no part of a plan: _step_planned)
+            decode = None if self.drafter is not None \
+                else self._plan_decode(chunks, flight)
             if decode is False:
                 return None
             if not chunks and decode is None:
@@ -2635,42 +2429,8 @@ class DecodeEngine:
             if mon is not None:
                 mon.serve_step(ran[1] - ran[0], live, len(self._queue),
                                engine_id=self.engine_id, span=ran)
-                if self.paged:
-                    mon.serve_paged(self._pager.stats(), self.kv_util(),
-                                    engine_id=self.engine_id)
-
-    # ------------------------------------------------ the row cache's step
-
-    def _decode(self, finished: List[Request]):
-        """One decode step of the contiguous row cache, waited for."""
-        with _trace.span("engine/decode_prepare") as prep:
-            exe = self._decode_exe
-            if exe is None:
-                exe = self._build_decode()
-            prep.set(live=self.live_count, cow=0, preempted=0)
-        call_attrs = dict(path=self._decode_attention,
-                          **self._state_attrs(self.live_count))
-        # which slots the step may advance (see _build_decode)
-        end = (self._pos + self._live,) if self._has_state else ()
-
-        def upload():
-            return (jnp.asarray(self._tok), jnp.asarray(self._pos),
-                    self._next_key(), *map(jnp.asarray, end))
-
-        def run(*args):
-            self._caches, picked, ok = exe(self._leaf_values(),
-                                           self._caches, *args)
-            # host readback inside the armed window: a hang in the
-            # device sync is a hang in the dispatch
-            return np.asarray(picked), np.asarray(ok)
-
-        got, call = self._dispatch_guarded(
-            "decode", None, _DECODE_SPANS, upload, run, **call_attrs)
-        rows = {slot: req for slot, req in enumerate(self._slot_req)
-                if req is not None}
-        for slot in rows:
-            self._pos[slot] += 1
-        self._decode_done(rows, got, (call.t0, call.t1), finished)
+                mon.serve_paged(self._pager.stats(), self.kv_util(),
+                                engine_id=self.engine_id)
 
     def _decode_spec(self, finished: List[Request]):
         """Speculative decode step: per live slot, draft up to
@@ -2744,7 +2504,7 @@ class DecodeEngine:
             # on dispatch failure _fail_engine terminalizes every tenant
             # and releases the pager state — the reservation dies with it
             (out, l_ok), call = self._dispatch_guarded(
-                "verify", vw, _DECODE_SPANS, upload, run)
+                "verify", vw, upload, run)
             with _trace.span("engine/decode_finish", slot=slot) as fin:
                 if not bool(l_ok):
                     # a NaN anywhere in the verify window poisons the
@@ -2830,15 +2590,11 @@ class DecodeEngine:
     # ------------------------------------------------------------- insight
 
     def kv_util(self) -> float:
-        """Live cached tokens / pooled token capacity — the paged memory
-        headroom figure bench.py reports. (Row cache: capacity is the full
-        slot grid, which is exactly what paging exists to beat.)"""
+        """Live cached tokens / pooled token capacity: the pool's memory
+        headroom."""
         cached = int(self._pos[self._live].sum()) \
             + sum(st.done for st in self._prefilling.values())
-        if self.paged:
-            cap = self._pager.usable_blocks * self.block_size
-        else:
-            cap = self.max_slots * self.max_len
+        cap = self._pager.usable_blocks * self.block_size
         return cached / cap if cap else 0.0
 
     def door_state(self, top_prefixes: int = 8) -> dict:
@@ -2858,28 +2614,24 @@ class DecodeEngine:
             "free_slots": int(self._slots.n_free),
             "queue_depth": int(self.queue_depth),
             "active": int(self.active_count),
-            "free_blocks": 0,
-            "block_size": int(self.block_size) if self.paged else 0,
-            "prefix_keys": [],
-            "prefix_hits": 0,
+            "free_blocks": int(self._pager.free_blocks
+                               + self._pager.lru_blocks),
+            "block_size": int(self.block_size),
+            "prefix_keys": self._pager.prefix_digests(top_prefixes),
+            "prefix_hits": int(self._pager.prefix_hits),
         }
-        if self.paged:
-            out["free_blocks"] = int(self._pager.free_blocks
-                                     + self._pager.lru_blocks)
-            out["prefix_hits"] = int(self._pager.prefix_hits)
-            out["prefix_keys"] = self._pager.prefix_digests(top_prefixes)
         # pool tier: generation + hit count travel in the door blob, so
         # the router can prefer warm-pool hosts and spot a generation skew
         out["pool_gen"] = int(self._pool_gen) \
             if self._kv_pool is not None else None
         out["pool_hits"] = int(self._pager.pool_hits) \
-            if self.paged and self._kv_pool is not None else 0
+            if self._kv_pool is not None else 0
         return out
 
     def pool_stats(self) -> dict:
         """Cumulative cross-process pool figures (engine side): transfer
         counters plus the pager's splice counters — the ``pool/*`` gauges
-        and the bench ``--pool`` lane read this."""
+        read this."""
         return {
             "gen": int(self._pool_gen),
             "exports": self.pool_exports,
@@ -2890,11 +2642,9 @@ class DecodeEngine:
             "fetch_s": round(self.pool_fetch_s, 6),
             "adopted_blocks": self.pool_adopted_blocks,
             "adopted_tokens": self.pool_adopted_tokens,
-            "pool_hits": int(self._pager.pool_hits) if self.paged else 0,
-            "pool_hit_tokens": int(self._pager.pool_hit_tokens)
-            if self.paged else 0,
-            "pending_exports": len(self._pager.pending_exports)
-            if self.paged else 0,
+            "pool_hits": int(self._pager.pool_hits),
+            "pool_hit_tokens": int(self._pager.pool_hit_tokens),
+            "pending_exports": len(self._pager.pending_exports),
         }
 
     def stats(self) -> dict:
@@ -2904,8 +2654,8 @@ class DecodeEngine:
             if self._decode_exe is not None else len(self._prefill_exes),
             "decode_steps": self.decode_steps,
             # what the decode executable was traced with: "paged_kernel"
-            # (kernels/pallas/paged_decode.py), "gather" (the dense view),
-            # "contiguous" (row cache); None before its first trace
+            # (kernels/pallas/paged_decode.py) or "gather" (the dense view);
+            # None before its first trace
             "decode_attention": self._decode_attention,
             "tokens_generated": self.tokens_generated,
             "live_slots": self.live_count,
@@ -2936,16 +2686,13 @@ class DecodeEngine:
         if self.moe_counts is not None:
             out["moe"] = dict(zip(("assignments", "local", "touched"),
                                   map(int, self.moe_counts)))
-        if self.paged:
-            out["paged"] = dict(self._pager.stats().as_dict(),
-                                block_size=self.block_size,
-                                preemptions=self.preemptions,
-                                prefilling=len(self._prefilling))
-        if self.paged and self.drafter is None:
-            # steps that ran an executable, by how they came by their plan
-            # (_step_planned), and what the rebuilt ones lost theirs to
-            out["plan"] = dict(self.plan_counts,
-                               causes=dict(self.plan_causes))
+        out["paged"] = dict(self._pager.stats().as_dict(),
+                            block_size=self.block_size,
+                            preemptions=self.preemptions,
+                            prefilling=len(self._prefilling))
+        # steps that ran a plan, by how they came by it (_step_planned),
+        # and what the rebuilt ones lost theirs to
+        out["plan"] = dict(self.plan_counts, causes=dict(self.plan_causes))
         if self._kv_pool is not None:
             out["pool"] = self.pool_stats()
         if self.drafter is not None:
@@ -3038,7 +2785,7 @@ def generate_via_engine(lm, input_ids, max_new_tokens: int = 32,
     if engine is None:
         if len(engines) >= 4:
             engines.pop(next(iter(engines)))
-        engine = DecodeEngine(lm, max_slots=slots, max_len=ml, paged=True,
+        engine = DecodeEngine(lm, max_slots=slots, max_len=ml,
                               prefill_chunk=chunk,
                               do_sample=do_sample, temperature=temperature,
                               top_k=top_k, seed=seed)

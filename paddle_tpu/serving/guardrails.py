@@ -8,7 +8,7 @@ that make that possible:
 * **`FaultSchedule`** — the ``PADDLE_SERVE_FAULT`` chaos seam, the serving
   mirror of ``PADDLE_CKPT_FAULT`` (distributed/checkpoint.py): a scripted
   schedule of faults fired at exact call counts of the engine's
-  interesting sites, so a test (or ``bench.py decode --chaos``) can drive
+  interesting sites, so a test can drive
   expiry, cancellation, preemption, hang detection and drain through the
   very same code paths production traffic would, with zero randomness.
 

@@ -79,7 +79,7 @@ class NoEngineAvailable(RuntimeError):
 
 
 class LocalEngineClient:
-    """In-process engine handle (tests, ``bench.py decode --router``).
+    """In-process engine handle (tests).
     ``kill()`` is the chaos stand-in for SIGKILL: every later call raises
     EngineDown, and the harness stops stepping the engine — the router
     must then prove ejection + requeue-elsewhere, exactly as it would

@@ -58,7 +58,7 @@ class Drafter:
     """Interface the engine drives. ``propose`` may return FEWER than k
     tokens (or none — the engine degrades to a plain one-token verify);
     it must never raise on a well-formed request. ``name`` keys the
-    per-drafter monitor counters and the bench/summary breakdowns."""
+    per-drafter monitor counters and the summary's breakdown."""
 
     name = "drafter"
     max_k = 4          # proposal ceiling; the engine sizes its verify width

@@ -1,7 +1,7 @@
 """Where the persistent XLA compilation cache lives — the ONE place that says.
 
-``bench.py`` and ``chip_smoke.py`` call :func:`enable_compile_cache`; nothing
-else in the tree names a cache directory. The directory is part of the cache
+``chip_smoke.py`` and the benchmark (``benchmark/system.py``) call
+:func:`enable_compile_cache`; nothing else in the tree names a cache directory. The directory is part of the cache
 key, so it must not move between runs: it is either wherever the operator
 put it (``JAX_COMPILATION_CACHE_DIR``, which jax reads by itself) or one
 fixed, git-ignored directory inside the checkout — never a temp dir, a pid

@@ -27,8 +27,6 @@ CPU XLA, module-scoped fixtures sharing compiled executables.
 import io
 import json
 import os
-import subprocess
-import sys
 import time
 
 import numpy as np
@@ -496,9 +494,13 @@ def test_watchdog_window_is_open_until_the_step_has_collected(tiny,
     eng = DecodeEngine(tiny, max_slots=2, max_len=32, block_size=8,
                        prefill_chunk=8, hang_s=0.05)
     try:
+        # each executable's first run takes 30 ms alone and more beside
+        # five other workers: that is no hang
+        eng._watchdog.hang_s = 30.0
         warm = eng.submit([1, 2, 3], max_new_tokens=2)
         eng.run()
         assert warm.status == "done"
+        eng._watchdog.hang_s = 0.05
         real = jax.device_get
 
         def stuck(tree):
@@ -698,32 +700,3 @@ def test_summary_guardrails_block_and_pool_thrash_warn(tmp_path):
     text = out.getvalue()
     assert "WARNING" in text and "pool-thrash" in text
     assert "raise kv_blocks or lower deadlines" in text
-
-
-# ----------------------------------------------------- satellite: bench smoke
-
-
-def test_bench_tiny_chaos_smoke():
-    """bench.py decode --paged --chaos (BENCH_TINY): rc=124-safe
-    best-so-far lines carry chaos/expired/cancelled, the engine survives
-    the fixed fault schedule, drains, and its invariants hold."""
-    env = dict(os.environ, BENCH_TINY="1", JAX_PLATFORMS="cpu")
-    env.pop("PADDLE_MONITOR", None)
-    env.pop("PADDLE_SERVE_FAULT", None)
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "decode",
-         "--paged", "--chaos"],
-        capture_output=True, text=True, timeout=420, env=env, cwd=REPO)
-    assert out.returncode == 0, out.stderr[-2000:]
-    lines = [l for l in out.stdout.splitlines() if l.startswith("{")]
-    assert len(lines) >= 2, out.stdout
-    best = json.loads(lines[-2])
-    assert best["metric"] == "gpt_medium_decode_tokens_per_sec_per_chip"
-    assert best["chaos"] is True and best["value"] > 0
-    assert best["expired"] >= 1 and best["cancelled"] >= 1
-    assert best["steady_state_recompiles"] == 0
-    assert best["ttft_p95_ms"] >= best["ttft_p50_ms"]
-    tail = json.loads(lines[-1])
-    assert tail["metric"] == "decode_chaos_drain"
-    assert tail["drained"] is True and tail["invariants"] == "ok"
-    assert tail["expired"] >= 1 and tail["cancelled"] >= 1

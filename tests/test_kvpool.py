@@ -23,8 +23,6 @@ The contract under test:
     new tokens; the router's poll reconstructs streams across resets.
   * metrics_summary: pool section renders, the allocator-bug WARN skips
     pool-tagged rejects, and the cold-start-never-adopts WARN fires.
-  * bench.py ``decode --pool`` emits the rc=124-safe line with
-    pool_hit_rate / adopted_tokens and zero steady-state recompiles.
 """
 import io
 import json
@@ -614,33 +612,6 @@ def test_summary_kv_pool_section_and_cold_start_warn(tmp_path):
     text = out.getvalue()
     assert "adopted 2 blocks / 16 tokens" in text
     assert "WARNING" not in text
-
-
-# ----------------------------------------------------- satellite: bench lane
-
-
-def test_bench_tiny_pool_decode_smoke():
-    """CI satellite: bench.py decode --paged --pool under BENCH_TINY
-    emits the rc=124-safe best-so-far line with pool_hit_rate /
-    adopted_tokens / TTFT percentiles and zero steady-state recompiles
-    with adoption on the measured path."""
-    env = dict(os.environ, BENCH_TINY="1", JAX_PLATFORMS="cpu")
-    for k in ("PADDLE_MONITOR", "PADDLE_SERVE_FAULT", "XLA_FLAGS"):
-        env.pop(k, None)
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "decode",
-         "--paged", "--pool"],
-        capture_output=True, text=True, timeout=420, env=env, cwd=REPO)
-    assert out.returncode == 0, out.stderr[-2000:]
-    lines = [l for l in out.stdout.splitlines() if l.startswith("{")]
-    assert lines, out.stdout
-    rec = json.loads([l for l in lines if '"pool"' in l][-1])
-    assert rec["metric"] == "gpt_medium_decode_tokens_per_sec_per_chip"
-    assert rec["pool"] is True and rec["paged"] is True
-    assert rec["pool_hit_rate"] > 0
-    assert rec["adopted_tokens"] >= 16 and rec["pool_fetch_hits"] >= 1
-    assert rec["ttft_p50_ms"] is not None and rec["ttft_p95_ms"] is not None
-    assert rec["steady_state_recompiles"] == 0
 
 
 # ------------------------------------------- acceptance: two-process gate

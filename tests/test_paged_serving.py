@@ -8,9 +8,9 @@ The contract under test:
   * Engine greedy decoding with paging + prefix sharing + chunked prefill
     enabled equals the eager compiled `generate()` loop token-for-token
     (GPT and LLaMA), even across pool-pressure preemptions.
-  * A shared-prefix workload admits >= 2x the concurrent requests of the
-    row cache at fixed KV pool bytes (the PagedAttention claim, counted
-    deterministically).
+  * A shared-prefix workload admits >= 2x the concurrent requests that a
+    row of ``max_len`` positions each would allow at the same KV pool
+    bytes (the PagedAttention claim, counted deterministically).
   * Chunked prefill bounds the per-iteration stall: a long prompt admits
     over ceil(n/chunk) iterations while live slots keep decoding; the
     timing gate (max stall <= 0.25x monolithic at >= 0.9x throughput) is
@@ -25,8 +25,6 @@ tests/test_serving.py.
 import io
 import json
 import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -215,24 +213,17 @@ def test_refcounts_survive_finish_evict_churn(tiny):
 
 def test_concurrency_2x_at_fixed_kv_bytes(tiny):
     """The PagedAttention microbench gate: at FIXED KV pool bytes, a
-    shared-prefix workload admits >= 2x the concurrent requests of the row
-    cache. Row arm: 4 slots x 64 positions = 256 pooled tokens, so
-    concurrency is structurally 4. Paged arm: 31 usable blocks x 8 = 248
-    pooled tokens (strictly fewer bytes), prefix sharing stores the common
-    32 tokens once — 12+ tenants fit simultaneously."""
+    shared-prefix workload admits >= 2x the concurrent requests a cache
+    that gives every request a whole ``max_len`` row could hold. Such a
+    row cache (the engine had one until PR 31) holds 256 pooled tokens as
+    4 rows of 64 positions, so its concurrency is structurally 4. Paged:
+    31 usable blocks x 8 = 248 pooled tokens (strictly fewer bytes),
+    prefix sharing stores the common 32 tokens once — 12+ tenants fit
+    simultaneously."""
     rng = np.random.RandomState(5)
     prefix = rng.randint(1, 64, 32).tolist()
     prompts = [prefix + [40 + i, 41 + i, 42 + i, 43 + i] for i in range(16)]
-
-    row = DecodeEngine(tiny, max_slots=4, max_len=64, paged=False,
-                       prefill_buckets=[48])
-    for p in prompts:
-        row.submit(p, max_new_tokens=4)
-    row_peak = 0
-    while row.queue_depth or row.live_count:
-        row.step()
-        row_peak = max(row_peak, row.active_count)
-    assert row_peak == 4                      # slots == bytes/max_len
+    row_peak = 256 // 64                      # slots == bytes/max_len
 
     paged = DecodeEngine(tiny, max_slots=16, max_len=64, block_size=8,
                          kv_blocks=32, prefill_chunk=16)
@@ -248,6 +239,14 @@ def test_concurrency_2x_at_fixed_kv_bytes(tiny):
     assert paged_peak >= 2 * row_peak, \
         f"paged admitted {paged_peak} concurrent vs row {row_peak}"
     assert paged.preemptions == 0             # sharing fit them for real
+
+
+def test_the_row_cache_is_refused_by_name(tiny):
+    """``paged`` is kept for its one caller and accepts only True."""
+    with pytest.raises(ValueError, match="page table.*chunked prefill"):
+        DecodeEngine(tiny, max_slots=4, max_len=64, paged=False)
+    assert DecodeEngine(tiny, max_slots=4, max_len=64, paged=True) \
+        .stats()["paged"]["block_size"] == 16
 
 
 def test_eviction_preemption_parity(tiny):
@@ -607,31 +606,6 @@ def test_summary_fragmentation_warn(tmp_path):
     assert "free blocks >= the slot's need" in out.getvalue()
 
 
-# ----------------------------------------------------- satellite: bench smoke
-
-
-def test_bench_tiny_paged_decode_smoke():
-    """bench.py decode --paged (BENCH_TINY config) emits best-so-far JSON
-    lines carrying kv_util + TTFT percentiles with zero steady-state
-    recompiles — the rc=124-safe contract for the driver's decode round."""
-    env = dict(os.environ, BENCH_TINY="1", JAX_PLATFORMS="cpu")
-    env.pop("PADDLE_MONITOR", None)
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "decode",
-         "--paged"],
-        capture_output=True, text=True, timeout=420, env=env, cwd=REPO)
-    assert out.returncode == 0, out.stderr[-2000:]
-    lines = [l for l in out.stdout.splitlines() if l.startswith("{")]
-    assert lines, out.stdout
-    rec = json.loads(lines[-1])
-    assert rec["metric"] == "gpt_medium_decode_tokens_per_sec_per_chip"
-    assert rec["paged"] is True
-    assert rec["value"] > 0
-    assert 0 < rec["kv_util"] <= 1
-    assert rec["ttft_p50_ms"] > 0 and rec["ttft_p95_ms"] >= rec["ttft_p50_ms"]
-    assert rec["steady_state_recompiles"] == 0
-
-
 # --------------------------------------------------- slow: the timing gates
 
 
@@ -670,7 +644,7 @@ def test_chunked_prefill_stall_gate():
             eng.step()
         # best-of-2 admission windows: the 2-core host throws occasional
         # 2x scheduler outliers into single steps; the achieved (minimum)
-        # max-stall is the honest figure, bench best-so-far style
+        # max-stall is the honest figure
         best_stall = float("inf")
         for _ in range(2):
             r = eng.submit(long_prompt, max_new_tokens=4)

@@ -28,20 +28,34 @@ def engine(prog, **kw):
 _REF = {}
 
 
-def reference_gaps(arrays, model, prompt, tokens):
-    """How far each served token's reference logit lies under the
-    reference's best at its position (0: the reference's own choice). One
-    forward pass over prompt + served tokens, padded to one length so the
-    reference compiles once (padding is causally invisible)."""
+def reference_logits(arrays, model, seq):
+    """The reference's logits over ``seq``: one forward pass, padded to one
+    length so the reference compiles once (padding is causally
+    invisible)."""
     _, ref = family()
     if "fn" not in _REF:
         _REF["fn"] = jax.jit(lambda w, ids: ref.logits(w, ids, model))
-    seq = list(prompt) + list(tokens)[:-1]
     ids = np.zeros((1, 96), np.int32)
     ids[0, :len(seq)] = seq
-    lg = np.asarray(_REF["fn"](arrays, jnp.asarray(ids)))[0]
-    at = lg[len(prompt) - 1:len(seq)]
+    return np.asarray(_REF["fn"](arrays, jnp.asarray(ids)))[0, :len(seq)]
+
+
+def reference_gaps(arrays, model, prompt, tokens):
+    """How far each served token's reference logit lies under the
+    reference's best at its position (0: the reference's own choice),
+    teacher-forced over prompt + served tokens."""
+    seq = list(prompt) + list(tokens)[:-1]
+    at = reference_logits(arrays, model, seq)[len(prompt) - 1:]
     return at.max(-1) - at[np.arange(len(tokens)), np.asarray(tokens)]
+
+
+def reference_greedy(arrays, model, prompt, n):
+    """The ``n`` tokens the reference picks after ``prompt``, one forward
+    pass a token and no cache at all."""
+    seq = list(prompt)
+    for _ in range(n):
+        seq.append(int(reference_logits(arrays, model, seq)[-1].argmax()))
+    return seq[len(prompt):]
 
 
 @pytest.fixture(scope="module")
@@ -94,21 +108,6 @@ def test_a_mixed_batch_with_kernels_interpreted_follows_the_reference(tiny):
     moe = st["moe"]
     assert moe["assignments"] == 4 * 4 * sum(len(r.tokens) - 1 for r in reqs)
     assert 0 < moe["local"] < moe["assignments"] and moe["touched"] > 0
-
-
-def test_the_row_cache_serves_what_the_paged_cache_serves(tiny):
-    prog, _, _ = tiny
-    rng = np.random.default_rng(8)
-    prompts = [rng.integers(0, 512, n).tolist() for n in (5, 19, 2)]
-    served = []
-    for paged in (True, False):
-        eng = DecodeEngine(prog, max_slots=2, max_len=64, paged=paged,
-                           **(dict(block_size=8, prefill_chunk=CHUNK)
-                              if paged else {}))
-        reqs = [eng.submit(p, max_new_tokens=7) for p in prompts]
-        eng.run()
-        served.append([r.tokens for r in reqs])
-    assert served[0] == served[1]
 
 
 def state_rows(eng, slot):

@@ -8,8 +8,8 @@ import-time failure:
 
 * every module under ``paddle_tpu`` must import cleanly (the walk is also
   the package-wide smoke test the health plane's lazy imports rely on);
-* a source scan over the whole package (plus ``tools/`` and ``bench.py``,
-  which register against the same live registries) collects every literal
+* a source scan over the whole package (plus ``tools/``, which registers
+  against the same live registries) collects every literal
   ``counter("...")`` / ``gauge("...")`` / ``histogram("...")`` name —
   including the static prefix of f-string names — and asserts no name is
   claimed by two instrument types, nor any dynamic-prefix family by a
@@ -62,14 +62,12 @@ _CALL = re.compile(r'\.(counter|gauge|histogram)\(\s*(f?)"([^"\n]+)"')
 
 def _scan_sources():
     """{metric name or f-string prefix: {instrument types}} over the whole
-    registering surface (package + tools + bench)."""
-    roots = [os.path.join(REPO, "paddle_tpu"), os.path.join(REPO, "tools"),
-             os.path.join(REPO, "bench.py")]
+    registering surface (package + tools)."""
+    roots = [os.path.join(REPO, "paddle_tpu"), os.path.join(REPO, "tools")]
     claims = {}
     for root in roots:
-        paths = [root] if root.endswith(".py") else [
-            os.path.join(dp, f) for dp, _, fs in os.walk(root)
-            for f in fs if f.endswith(".py")]
+        paths = [os.path.join(dp, f) for dp, _, fs in os.walk(root)
+                 for f in fs if f.endswith(".py")]
         for path in paths:
             with open(path, encoding="utf-8") as fh:
                 src = fh.read()

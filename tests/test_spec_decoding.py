@@ -22,14 +22,11 @@ The contract under test:
     raise@spec_reserve degrades to a one-token verify with parity intact.
   * Telemetry: serve/spec_* counters + the accepted-per-step gauge are
     live, metrics_summary renders the speculation sub-block with the
-    per-drafter breakdown and WARNs on the wasted-work signature, and
-    bench.py decode --spec emits accepted_per_step > 1.0 under BENCH_TINY.
+    per-drafter breakdown and WARNs on the wasted-work signature.
 """
 import io
 import json
 import os
-import subprocess
-import sys
 
 import numpy as np
 import pytest
@@ -141,10 +138,7 @@ def test_prompt_lookup_proposes_continuations():
         PromptLookupDrafter(max_n=1, min_n=2)
 
 
-def test_spec_requires_paged_and_greedy(tiny):
-    with pytest.raises(NotImplementedError, match="paged=True"):
-        DecodeEngine(tiny, max_slots=2, max_len=32, paged=False,
-                     prefill_buckets=[8], drafter=PromptLookupDrafter())
+def test_spec_requires_greedy(tiny):
     with pytest.raises(NotImplementedError, match="greedy"):
         DecodeEngine(tiny, max_slots=2, max_len=32, block_size=8,
                      prefill_chunk=8, do_sample=True,
@@ -195,6 +189,44 @@ def test_spec_parity_gpt_full_machinery(tiny, which):
         # half the layers of a 2-layer model still predict the next token
         # often enough to beat one-token-per-dispatch
         assert spec["accepted_per_step"] > 1.0, spec
+
+
+@pytest.mark.parametrize("which", ["prompt_lookup", "draft_model",
+                                   "early_exit"])
+def test_a_drafters_chunks_go_through_the_plan(tiny, which):
+    """A drafter's engine admits, plans, launches and collects its prefill
+    chunks as every engine does: two slots mid-prefill in one step have
+    their chunks launched back to back and read back once, under one
+    ``engine/collect``; no step is prepared ahead (``plan`` reads
+    ``sync``); and the streams are the no-drafter engine's."""
+    import time
+    from paddle_tpu.monitor import trace
+    rng = np.random.RandomState(21)
+    prompts = [rng.randint(1, 64, n).tolist() for n in (20, 19)]
+    geo = dict(max_slots=4, max_len=48, block_size=8, prefill_chunk=8)
+    plain = DecodeEngine(tiny, **geo)
+    want = [plain.submit(p, max_new_tokens=9) for p in prompts]
+    plain.run()
+    eng = DecodeEngine(tiny, drafter=_make_drafter(which, tiny), **geo)
+    reqs = [eng.submit(p, max_new_tokens=9) for p in prompts]
+    t0 = time.perf_counter()
+    eng.step()
+    t1 = time.perf_counter()
+    assert [r.status for r in reqs] == ["prefilling"] * 2
+    (step,) = trace.spans(t0, t1, "engine/step")
+    assert step.attrs["plan"] == "sync"
+    (collect,) = trace.spans(t0, t1, "engine/collect")
+    calls = trace.spans(t0, t1, "engine/prefill_call")
+    assert len(calls) == 2
+    assert calls[0].t1 <= calls[1].t0 and calls[1].t1 <= collect.t0
+    assert eng._plan is None                 # nothing is prepared ahead
+    eng.run()
+    assert [r.tokens for r in reqs] == [r.tokens for r in want]
+    st = eng.stats()
+    assert st["plan"]["prepared"] == st["plan"]["rebuilt"] == 0
+    assert st["plan"]["sync"] == 3           # the steps that ran chunks
+    assert st["spec"]["steps"] > 0
+    eng._pager.check_invariants()
 
 
 @pytest.mark.parametrize("which", ["prompt_lookup", "draft_model",
@@ -550,30 +582,3 @@ def test_summary_spec_warn_on_zero_acceptance(tmp_path):
     assert ms.summarize([healthy], out=out) == 0
     assert "WARNING" not in out.getvalue()
     assert "drafter draft_model: drafted 40  accepted 30" in out.getvalue()
-
-
-# ----------------------------------------------------- satellite: bench smoke
-
-
-def test_bench_tiny_spec_decode_smoke():
-    """bench.py decode --spec (BENCH_TINY config) emits the rc=124-safe
-    best-so-far line with accepted_per_step > 1.0 (the per-chip decode
-    speedup criterion), the draft hit rate, and zero steady-state
-    recompiles with the drafter on."""
-    env = dict(os.environ, BENCH_TINY="1", JAX_PLATFORMS="cpu")
-    env.pop("PADDLE_MONITOR", None)
-    out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py"), "decode",
-         "--spec"],
-        capture_output=True, text=True, timeout=420, env=env, cwd=REPO)
-    assert out.returncode == 0, out.stderr[-2000:]
-    lines = [l for l in out.stdout.splitlines() if l.startswith("{")]
-    assert lines, out.stdout
-    rec = json.loads(lines[-1])
-    assert rec["metric"] == "gpt_medium_decode_tokens_per_sec_per_chip"
-    assert rec["paged"] is True                  # --spec forces paged
-    assert rec["spec"] == "prompt_lookup"
-    assert rec["value"] > 0
-    assert rec["accepted_per_step"] > 1.0, rec
-    assert 0 < rec["draft_hit_rate"] <= 1.0
-    assert rec["steady_state_recompiles"] == 0
