@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import pytest
 
 from paddle_tpu.kernels.pallas.flash_pair import flash_pair, \
-    pair_layout_supported
+    pair_layout_supported, pair_schedule
 
 
 def _oracle(qkv, heads, d, causal):
@@ -113,6 +113,105 @@ def test_pair_backward_long_fused(causal, L):
     g_ref = jax.grad(f_ref)(qkv)
     np.testing.assert_allclose(np.asarray(g_pair), np.asarray(g_ref),
                                rtol=1e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("L", [1900, 1920])
+def test_pair_diagonal_meets_the_tail(L):
+    """A length that is no multiple of the 256-row sub-tile: 1,920 walks 15
+    sub-tiles of 128, and 1,900 pads 20 columns into the last of them, where
+    the last q tile's diagonal crosses too: the masked body carries both."""
+    b, heads, d = 1, 2, 64
+    assert pair_schedule(L, True, 2, d)["sub_k"] == 128
+    qkv = _rand_qkv(b, L, heads, d, seed=9)
+    seed = jnp.asarray([0], jnp.int32)
+
+    def f_pair(x):
+        return flash_pair(x, seed, heads, d, True, 1.0 / math.sqrt(d),
+                          512, 0.0, True)
+
+    out, vjp = jax.vjp(f_pair, qkv)
+    ref, vjp_ref = jax.vjp(lambda x: _oracle(x, heads, d, True), qkv)
+    # exact float32 on the CPU; under tools/run_tpu_tests.sh the interpreted
+    # kernel's and the oracle's float32 products ride bf16 MXU passes, and
+    # fifteen rescaled sub-tiles of them miss 2e-3 (PR 32's first chip run)
+    loose = 1 if jax.default_backend() == "cpu" else 5
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-3 * loose, atol=2e-3 * loose)
+    w = _rand_qkv(b, L, heads, d, seed=10)[..., :heads * d]
+    np.testing.assert_allclose(np.asarray(vjp(w)[0]),
+                               np.asarray(vjp_ref(w)[0]),
+                               rtol=1e-2, atol=2e-2 * loose)
+
+
+def _pieces(L, causal, sched):
+    """(with any visible entry, of those not wholly visible) among the
+    schedule's tiles of the padded square, entry by entry."""
+    bq, sk = (sched[k] // sched["split"] for k in ("block_q", "sub_k"))
+    pad = -(-L // 128) * 128
+    rows, cols = np.arange(pad)[:, None], np.arange(pad)[None, :]
+    valid = np.broadcast_to(cols < L, (pad, pad))
+    if causal:
+        valid = valid & (rows >= cols)
+    live = partial = 0
+    for r in range(0, pad, bq):
+        for c in range(0, pad, sk):
+            piece = valid[r:r + bq, c:c + sk]
+            live += bool(piece.any())
+            partial += bool(piece.any() and not piece.all())
+    return live, partial
+
+
+@pytest.mark.parametrize("d", [64, 128])            # hpb 2 and 1
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("L", [384, 1900, 2048, 4096])
+def test_pair_schedule_walks_only_what_is_visible(L, causal, d):
+    """The loop bounds the kernels run by, evaluated on ints: every piece
+    with a visible entry is walked and no other, and the masked body runs
+    on exactly the pieces the diagonal or the padded tail touches."""
+    sched = pair_schedule(L, causal, max(1, 128 // d), d)
+    live, partial = _pieces(L, causal, sched)
+    assert sched["tiles_run"] == sched["tiles_min"] == live
+    # a slab is masked whole: a split diagonal piece masks 3 tiles for the
+    # 2 the diagonal crosses
+    assert partial <= sched["tiles_masked"] <= partial * (
+        sched["split"] + 1) // 2
+    assert sched["block_k"] % sched["sub_k"] == 0
+    if not causal:
+        assert sched["tiles_run"] == sched["tiles_square"]
+        assert (sched["tiles_masked"] == 0) == (L % 128 == 0)
+    elif L >= 2048:
+        # the triangle, not three quarters of the square
+        assert sched["tiles_run"] / sched["tiles_square"] <= 0.57
+
+
+def test_pair_schedule_names_the_backward_form():
+    assert pair_schedule(2048, True, 2, 64, "bwd")["form"] == "fused"
+    assert pair_schedule(4096, True, 2, 64, "bwd")["form"] == "fused"
+    assert pair_schedule(8192, True, 2, 64, "bwd")["form"] == "split"
+    assert pair_schedule(2048, True, 2, 64, "bwd",
+                         max_fused_bwd=1024)["form"] == "split"
+    assert pair_schedule(8192, True, 2, 64, "fwd")["form"] == "fused"
+
+
+def test_a_trace_leaves_its_schedule_on_the_span_layer():
+    """The schedule is static per traced signature, so one
+    ``flash_pair/schedule`` span a trace of ``_pair_fwd`` / ``_pair_bwd``
+    says how often it engages: same numbers as ``pair_schedule``."""
+    import time
+    import paddle_tpu.kernels.pallas.flash_pair as fp
+    from paddle_tpu.monitor import trace
+    b, L, heads, d = 1, 640, 2, 64
+    qkv = _rand_qkv(b, L, heads, d, seed=12)
+    fp._pair_fwd.clear_cache()          # a cached trace records nothing
+    fp._pair_bwd.clear_cache()
+    t0 = time.perf_counter()
+    jax.grad(lambda x: fp.flash_pair_packed(
+        x, heads, True, interpret=True).sum())(qkv)
+    got = trace.spans(t0, time.perf_counter(), "flash_pair/schedule")
+    assert [s.attrs["kernel"] for s in got] == ["fwd", "bwd"]
+    for s in got:
+        assert s.attrs == pair_schedule(L, True, 2, d, s.attrs["kernel"])
+    assert got[0].attrs["tiles_masked"] > 0
 
 
 def test_fused_bwd_cutoff_scales_with_lane_width():

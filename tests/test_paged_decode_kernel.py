@@ -5,9 +5,10 @@ lengths round a block edge, mixed batches, tables whose rows share physical
 blocks, grouped queries, head widths 64 and 128, float32 and bfloat16
 pools; stale garbage past a slot's length and in unreferenced blocks must
 not reach the output. The Mosaic compiles at the real decode shapes are at
-the end (a described v5e; no chip needed): this kernel's, and those of the
-other serving kernels (`gdn.py`, `moe_grouped.py`), kept in this ONE file
-because only one test process may load the TPU's compiler."""
+the end (a described v5e; no chip needed): this kernel's, those of the
+other serving kernels (`gdn.py`, `moe_grouped.py`) and the training
+attention's (`flash_pair.py`), kept in this ONE file because only one test
+process may load the TPU's compiler."""
 import math
 
 import numpy as np
@@ -264,3 +265,39 @@ def test_mosaic_compiles_the_grouped_expert_matmul(one_chip, tokens):
     assert exe.as_text().count('custom_call_target="tpu_custom_call"') == 1
     assert "moe_grouped" in exe.as_text()
     assert exe.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+@pytest.mark.parametrize("b,L,heads,d,causal,max_fused_bwd", [
+    (8, 2048, 16, 64, True, None),     # gpt3-350m.pretrain_2k's 48 calls
+    (4, 2048, 16, 128, True, None),    # GPT-3 XL's width, one head a block
+    (16, 1024, 16, 64, True, None),    # one major tile, four sub-tiles
+    (32, 512, 12, 64, False, None),    # BERT: no mask is ever built
+    (2, 1900, 16, 64, True, None),     # padded tail under the diagonal
+    (2, 4096, 16, 64, True, None),     # the longest the fused backward holds
+    (2, 4096, 16, 64, True, 0),        # the split backward's two kernels
+    (2, 2048, 2, 256, True, None),     # a 256-lane head block
+])
+def test_mosaic_compiles_the_packed_flash_attention(one_chip, b, L, heads, d,
+                                                    causal, max_fused_bwd):
+    """`flash_pair`'s forward and backward at the geometries its tests and
+    callers name: the dynamic trip counts, the transposed score pieces and
+    the full-length dk/dv scratch all inside Mosaic's VMEM."""
+    from paddle_tpu.kernels.pallas import flash_pair as fp
+    hpb = fp._heads_per_block(d)
+    scale, pad = 1.0 / math.sqrt(d), -(-L // 128) * 128
+    qkv = ((b, L, 3 * heads * d), jnp.bfloat16)
+    ctx = ((b, L, heads * d), jnp.bfloat16)
+    seed = ((1,), jnp.int32)
+    fwd = compile_for(
+        one_chip, lambda x, s: fp._pair_fwd(x, s, heads, d, causal, scale,
+                                            512), qkv, seed)
+    assert fwd.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    bwd = compile_for(
+        one_chip, lambda x, o, lse, g, s: fp._pair_bwd(
+            x, o, lse, g, s, heads, d, causal, scale, 512,
+            max_fused_bwd=max_fused_bwd),
+        qkv, ctx, ((b, heads // hpb, hpb, pad), jnp.float32), ctx, seed)
+    form = fp.pair_schedule(L, causal, hpb, d, "bwd",
+                            max_fused_bwd=max_fused_bwd)["form"]
+    assert (bwd.as_text().count('custom_call_target="tpu_custom_call"')
+            == {"fused": 1, "split": 2}[form])
