@@ -35,7 +35,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .util import tpu_placement
+from .util import note_state_kernel, tpu_placement
 
 CHUNK = 64                    # positions per chunk of the chunked form
 HEADS_PER_STEP = 8            # value heads one grid step of the kernel takes
@@ -207,5 +207,6 @@ def gdn_decode_step(q, k, v, g, beta, state, live):
                                g[:, None], beta[:, None], state)
         keep = live[:, None, None, None]
         return o[:, 0], jnp.where(keep, new, state)
+    note_state_kernel("gdn_decode")
     return _decode_call(q, k, v, jnp.exp(g), beta, live, state,
                         interpret=mode == "interpret")

@@ -3,6 +3,20 @@ from __future__ import annotations
 
 import jax
 
+# The Pallas kernels of a recurrent state's decode step (``gdn_decode``,
+# ``ssd_decode``) traced into programs, by name and in order: the engine reads
+# it round its decode trace to say which step that executable took, as it
+# reads ``paged_decode.kernel_traces()`` for the attention.
+_STATE_KERNELS: list = []
+
+
+def note_state_kernel(name: str) -> None:
+    _STATE_KERNELS.append(name)
+
+
+def state_kernels_traced(since: int = 0) -> list:
+    return _STATE_KERNELS[since:]
+
 
 def tpu_placement(x) -> bool:
     """True when `x` will execute on a real TPU. Must NOT observe the value:

@@ -17,3 +17,5 @@ from .llama import (LlamaConfig, LlamaModel, LlamaForCausalLM, llama_tiny,  # no
                     llama_7b, shard_llama_tp)
 from .qwen3_next import (Qwen3NextConfig, Qwen3NextModel,  # noqa: F401
                          Qwen3NextForCausalLM, qwen3_next_tiny)
+from .falcon_h1 import (FalconH1Config, FalconH1Model,  # noqa: F401
+                        FalconH1ForCausalLM, falcon_h1_tiny)

@@ -1,5 +1,7 @@
 """What a causal LM tells the serving engine: its cached-forward backbone,
-its head, and, PER LAYER, what that layer caches between calls.
+its head, and, PER LAYER, what that layer caches between calls: one entry,
+or a tuple of entries for a block that keeps several caches (a state-space
+mixer beside an attention mixer: ``(kv_layer(..), state_layer(..))``).
 
   ``kv_layer(heads, width)``   keys and values of every past position: the
                                engine backs it with paged block pools
@@ -25,7 +27,9 @@ pager allocate and thread caches from ``spec.layers`` and ask nothing else
 about the architecture. The backbone is called as ``backbone(ids,
 kv_caches=[per-layer cache], start_pos=, write_end=)`` and returns (hidden,
 [per-layer new cache]); positions at or past ``write_end`` are padding (or a
-dead decode slot) and must change no cache.
+dead decode slot) and must change no cache. A layer of several entries is
+handed, and returns, a tuple of caches in its entries' order
+(``spec.map_entries`` keeps that structure for whoever walks the caches).
 """
 from __future__ import annotations
 
@@ -58,12 +62,28 @@ class ModelSpec(namedtuple("ModelSpec", [
         return len(self.layers)
 
     @property
+    def entries(self) -> list:
+        """Every cache entry, layer by layer."""
+        return [c for layer in self.layers
+                for c in ((layer,) if isinstance(layer, CacheLayer)
+                          else layer)]
+
+    def map_entries(self, fn, *per_layer) -> list:
+        """``fn(entry, *that entry's items)`` over every entry, the results
+        in the layers' own structure: ``per_layer`` are lists shaped like
+        ``layers`` (one item a single-entry layer, a tuple of items a layer
+        of several)."""
+        return [fn(layer, *items) if isinstance(layer, CacheLayer)
+                else tuple(fn(c, *its) for c, *its in zip(layer, *items))
+                for layer, *items in zip(self.layers, *per_layer)]
+
+    @property
     def kv_layers(self) -> list:
-        return [c for c in self.layers if c.kind == "kv"]
+        return [c for c in self.entries if c.kind == "kv"]
 
     @property
     def state_layers(self) -> list:
-        return [c for c in self.layers if c.kind == "state"]
+        return [c for c in self.entries if c.kind == "state"]
 
     def _kv_geometry(self):
         geo = {(c.n_kv_heads, c.head_dim) for c in self.kv_layers}

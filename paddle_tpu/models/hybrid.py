@@ -1,0 +1,183 @@
+"""What the hybrid decoders (``qwen3_next.py``, ``falcon_h1.py``) share: a
+bag of raw parameters, the float32 RMS norm, positions and validity of a
+cached call, rotate-half rotary, the depthwise convolution that carries its
+last inputs between calls, and grouped-query attention over merged-row
+paged pools (``cache_spec.kv_layer(merged_rows=True)``) with the paged
+decode kernel where the call is a decode step. Raw-array math, no
+``Tensor`` inside.
+"""
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+
+from .. import nn
+
+
+def _dot(x, w):
+    return jnp.dot(x, w, precision="highest" if x.dtype == jnp.float32
+                   else None)
+
+
+def rms_norm(x, w, eps, centred=True):
+    """``x / rms(x) * (1 + w)`` (``centred``) or ``* w``, in float32, back
+    in ``x``'s dtype."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True) + eps)
+    wf = w.astype(jnp.float32)
+    return (y * (1.0 + wf if centred else wf)).astype(x.dtype)
+
+
+def _positions(pos, s):
+    """[B or 1, S] absolute positions from a scalar or per-row start."""
+    pos = jnp.asarray(pos, jnp.int32)
+    return (pos[:, None] if pos.ndim else pos[None, None]) \
+        + jnp.arange(s, dtype=jnp.int32)[None, :]
+
+
+def _valid(pos, end, b, s):
+    """[B, S] bool: positions before ``end`` (None: all)."""
+    if end is None:
+        return jnp.ones((b, s), bool)
+    end = jnp.asarray(end, jnp.int32)
+    return jnp.broadcast_to(
+        _positions(pos, s) < (end[:, None] if end.ndim else end), (b, s))
+
+
+def _fresh(pos, valid):
+    """[B, 1] bool: the call starts its sequence (``pos == 0``) and has
+    something valid to write. A dead decode slot sits at position 0 too:
+    only a call that writes starts afresh."""
+    fresh = (jnp.asarray(pos, jnp.int32) == 0)
+    fresh = fresh[:, None] if fresh.ndim else fresh[None, None]
+    return jnp.broadcast_to(fresh, (valid.shape[0], 1)) \
+        & jnp.any(valid, axis=1, keepdims=True)
+
+
+class _Weights(nn.Layer):
+    """A bag of raw parameters made with one initializer; ``cfg`` gives
+    ``dtype`` and ``initializer_range``."""
+
+    def __init__(self, cfg):
+        super().__init__()
+        self._cfg = cfg
+        self._normal = nn.initializer.Normal(0.0, cfg.initializer_range)
+
+    def mat(self, *shape):
+        return self.create_parameter(shape, dtype=self._cfg.dtype,
+                                     default_initializer=self._normal)
+
+    def const(self, value, *shape):
+        return self.create_parameter(
+            shape, dtype=self._cfg.dtype,
+            default_initializer=nn.initializer.Constant(value))
+
+
+def conv_with_tail(mixed, tail, weight, valid, bias=None):
+    """Causal depthwise convolution over [tail | this call's inputs], then
+    SiLU, in float32: ``mixed [B, S, C]``, ``tail [B, width - 1, C]`` the
+    inputs before the call, ``weight [C, width]`` (``bias [C]``). Returns
+    (activations [B, S, C] float32, the next call's tail: the last
+    ``width - 1`` inputs before the first position that is not ``valid``)."""
+    s, width = mixed.shape[1], weight.shape[1]
+    f32 = jnp.float32
+    full = jnp.concatenate([tail.astype(mixed.dtype), mixed], axis=1)
+    w = weight.astype(f32)
+    conv = sum(full[:, j:j + s].astype(f32) * w[:, j] for j in range(width))
+    if bias is not None:
+        conv = conv + bias.astype(f32)
+    n_valid = jnp.sum(valid.astype(jnp.int32), axis=1)    # [B]
+    new_tail = jax.vmap(lambda f, n: jax.lax.dynamic_slice_in_dim(
+        f, n, width - 1, axis=0))(full, n_valid).astype(tail.dtype)
+    return jax.nn.silu(conv), new_tail
+
+
+def rope(t, positions, rot, theta):
+    """Rotate-half rotary on the first ``rot`` dims; t [B, S, n, hd]."""
+    half = rot // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rot)
+    ang = positions.astype(jnp.float32)[..., None] * inv  # [B|1, S, half]
+    cos, sin = jnp.cos(ang)[:, :, None], jnp.sin(ang)[:, :, None]
+    tf = t.astype(jnp.float32)
+    x1, x2, rest = tf[..., :half], tf[..., half:rot], tf[..., rot:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
+                            rest], axis=-1).astype(t.dtype)
+
+
+def _write_merged(cache, k, v, positions, end):
+    """``gpt._paged_kv_write`` for pools whose block is one matrix of
+    (position, KV head) rows: position ``p`` of head ``h`` lands at
+    ``(table[b, p // BS], (p % BS) * n_kv + h)``; positions at or past
+    ``end`` or beyond the table go to trash block 0."""
+    pool_k, pool_v, table = cache
+    b, s, nkv = k.shape[:3]
+    bs_blk, mbs = pool_k.shape[1] // nkv, table.shape[1]
+    wpos = jnp.broadcast_to(positions, (b, s))
+    end = jnp.asarray(end, jnp.int32)
+    end = end[:, None] if end.ndim else end
+    with jax.named_scope("kv_write"):
+        lidx = wpos // bs_blk
+        phys = jnp.take_along_axis(
+            jnp.broadcast_to(table, (b, mbs)),
+            jnp.minimum(lidx, mbs - 1), axis=1)
+        phys = jnp.where((wpos < end) & (lidx < mbs), phys, 0)
+        row = (wpos % bs_blk)[..., None] * nkv \
+            + jnp.arange(nkv, dtype=jnp.int32)
+        at = (phys[..., None], row)
+        return (pool_k.at[at].set(k.astype(pool_k.dtype)),
+                pool_v.at[at].set(v.astype(pool_v.dtype)))
+
+
+def _attend_merged(table, pos, q, pools, nkv):
+    """The decode step's attention through the paged kernel (one query
+    position a slot, per-slot cursors, a TPU or its test seam), or None
+    for the gathered view."""
+    from ..kernels.pallas import paged_decode
+    if q.shape[1] != 1 or jnp.ndim(pos) != 1:
+        return None
+    mode = paged_decode.kernel_mode(q, pools[0], n_kv=nkv)
+    if mode is None:
+        return None
+    with jax.named_scope("paged_decode"):
+        return paged_decode.paged_decode_attention(
+            q, pools[0], pools[1], table, pos + 1, n_kv=nkv,
+            interpret=mode == "interpret")
+
+
+def grouped_attention(q, k, v, cache, pos, positions, end):
+    """Causal softmax attention of ``q [B, S, nh, hd]`` (rotated) over
+    ``k v [B, S, nkv, hd]``, ``nh / nkv`` query heads a KV head, scale
+    ``hd ** -0.5``. With ``cache`` = merged-row ``(pool_k, pool_v, table)``
+    the call's K/V are written at their positions first (before
+    ``end``) and the queries see everything the table holds up to their
+    own position. Returns (context [B, S, nh * hd] in ``q``'s dtype, the
+    pools after the write or None)."""
+    b, s, nh, hd = q.shape
+    nkv = k.shape[2]
+    new_cache = ctx = None
+    if cache is None:
+        k_buf, v_buf = k, v
+    else:                           # paged: [NB, BS * n_kv, hd] pools
+        we = end if end is not None else jnp.asarray(pos, jnp.int32) + s
+        new_cache = _write_merged(cache, k, v, positions, we)
+        ctx = _attend_merged(cache[2], pos, q, new_cache, nkv)
+        if ctx is None:
+            with jax.named_scope("kv_gather"):
+                k_buf, v_buf = (jnp.take(p, cache[2], axis=0).reshape(
+                    b, -1, nkv, hd) for p in new_cache)
+    if ctx is None:
+        m = k_buf.shape[1]
+        qh = q.reshape(b, s, nkv, nh // nkv, hd).astype(jnp.float32)
+        scores = jnp.einsum("bqkgd,bmkd->bkgqm", qh,
+                            k_buf.astype(jnp.float32),
+                            precision="highest") / math.sqrt(hd)
+        key_pos = jnp.arange(m)[None, None, None, None, :]
+        q_pos = positions[:, None, None, :, None]
+        scores = jnp.where(key_pos <= q_pos, scores, -1e30)
+        probs = jax.nn.softmax(scores, axis=-1)
+        ctx = jnp.einsum("bkgqm,bmkd->bqkgd", probs,
+                         v_buf.astype(jnp.float32),
+                         precision="highest").astype(q.dtype)
+    return ctx.reshape(b, s, nh * hd), new_cache

@@ -39,7 +39,6 @@ shape); positions at or past ``write_end`` change nothing.
 """
 from __future__ import annotations
 
-import math
 from dataclasses import dataclass
 
 import jax
@@ -50,6 +49,8 @@ from ..core.tensor import Tensor
 from ..incubate.distributed.models.moe.held import HeldExpertsMoE
 from ..kernels.pallas import gdn
 from .cache_spec import ModelSpec, kv_layer, state_layer
+from .hybrid import (_dot, _fresh, _positions, _valid, _Weights,
+                     conv_with_tail, grouped_attention, rms_norm, rope)
 
 __all__ = ["Qwen3NextConfig", "Qwen3NextModel", "Qwen3NextForCausalLM",
            "qwen3_next_tiny"]
@@ -108,54 +109,6 @@ def qwen3_next_tiny(**overrides) -> Qwen3NextConfig:
     return Qwen3NextConfig(**cfg)
 
 
-def _dot(x, w):
-    return jnp.dot(x, w, precision="highest" if x.dtype == jnp.float32
-                   else None)
-
-
-def rms_norm(x, w, eps, centred=True):
-    """``x / rms(x) * (1 + w)`` (``centred``) or ``* w``, in float32, back
-    in ``x``'s dtype."""
-    xf = x.astype(jnp.float32)
-    y = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), -1, keepdims=True) + eps)
-    wf = w.astype(jnp.float32)
-    return (y * (1.0 + wf if centred else wf)).astype(x.dtype)
-
-
-def _positions(pos, s):
-    """[B or 1, S] absolute positions from a scalar or per-row start."""
-    pos = jnp.asarray(pos, jnp.int32)
-    return (pos[:, None] if pos.ndim else pos[None, None]) \
-        + jnp.arange(s, dtype=jnp.int32)[None, :]
-
-
-def _valid(pos, end, b, s):
-    """[B, S] bool: positions before ``end`` (None: all)."""
-    if end is None:
-        return jnp.ones((b, s), bool)
-    end = jnp.asarray(end, jnp.int32)
-    return jnp.broadcast_to(
-        _positions(pos, s) < (end[:, None] if end.ndim else end), (b, s))
-
-
-class _Weights(nn.Layer):
-    """A bag of raw parameters made with one initializer."""
-
-    def __init__(self, cfg: Qwen3NextConfig):
-        super().__init__()
-        self._cfg = cfg
-        self._normal = nn.initializer.Normal(0.0, cfg.initializer_range)
-
-    def mat(self, *shape):
-        return self.create_parameter(shape, dtype=self._cfg.dtype,
-                                     default_initializer=self._normal)
-
-    def const(self, value, *shape):
-        return self.create_parameter(
-            shape, dtype=self._cfg.dtype,
-            default_initializer=nn.initializer.Constant(value))
-
-
 class GatedDeltaNet(_Weights):
     def __init__(self, cfg: Qwen3NextConfig):
         super().__init__(cfg)
@@ -188,26 +141,13 @@ class GatedDeltaNet(_Weights):
             tail = jnp.zeros((b, self.width - 1, self.channels), x.dtype)
         else:
             state, tail = cache
-            fresh = (jnp.asarray(pos, jnp.int32) == 0)
-            fresh = fresh[:, None] if fresh.ndim else fresh[None, None]
-            # a dead decode slot sits at position 0 too: only a call with
-            # something valid to write starts afresh
-            fresh = jnp.broadcast_to(fresh, (b, 1)) \
-                & jnp.any(valid, axis=1, keepdims=True)
+            fresh = _fresh(pos, valid)
             tail = jnp.where(fresh[..., None], jnp.zeros_like(tail), tail)
         qkvz = _dot(x, self.in_proj_qkvz.value())
         mixed, z = qkvz[..., :self.channels], qkvz[..., self.channels:]
         ba = _dot(x, self.in_proj_ba.value()).astype(f32)
-        # causal depthwise convolution over [tail | this call's inputs]
-        full = jnp.concatenate([tail.astype(mixed.dtype), mixed], axis=1)
-        w = self.conv1d.value().astype(f32)
-        conv = sum(full[:, j:j + s].astype(f32) * w[:, j]
-                   for j in range(self.width))
-        conv = jax.nn.silu(conv)
-        # the last width-1 inputs before `end` are the next call's tail
-        n_valid = jnp.sum(valid.astype(jnp.int32), axis=1)    # [B]
-        new_tail = jax.vmap(lambda f, n: jax.lax.dynamic_slice_in_dim(
-            f, n, self.width - 1, axis=0))(full, n_valid).astype(tail.dtype)
+        conv, new_tail = conv_with_tail(mixed, tail, self.conv1d.value(),
+                                        valid)
         q = conv[..., :self.key_dim].reshape(b, s, self.nk, self.dk)
         k = conv[..., self.key_dim:2 * self.key_dim].reshape(
             b, s, self.nk, self.dk)
@@ -259,57 +199,6 @@ class GatedAttention(_Weights):
         self.q_norm = self.const(0.0, self.hd)
         self.k_norm = self.const(0.0, self.hd)
 
-    def _rope(self, t, positions):
-        """Rotate-half rotary on the first ``rot`` dims; t [B, S, n, hd]."""
-        half = self.rot // 2
-        inv = self.theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0
-                             / self.rot)
-        ang = positions.astype(jnp.float32)[..., None] * inv  # [B|1, S, half]
-        cos, sin = jnp.cos(ang)[:, :, None], jnp.sin(ang)[:, :, None]
-        tf = t.astype(jnp.float32)
-        x1, x2, rest = tf[..., :half], tf[..., half:self.rot], \
-            tf[..., self.rot:]
-        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
-                                rest], axis=-1).astype(t.dtype)
-
-    def _write_merged(self, cache, k, v, positions, end):
-        """``gpt._paged_kv_write`` for pools whose block is one matrix of
-        (position, KV head) rows: position ``p`` of head ``h`` lands at
-        ``(table[b, p // BS], (p % BS) * n_kv + h)``; positions at or past
-        ``end`` or beyond the table go to trash block 0."""
-        pool_k, pool_v, table = cache
-        b, s = k.shape[:2]
-        bs_blk, mbs = pool_k.shape[1] // self.nkv, table.shape[1]
-        wpos = jnp.broadcast_to(positions, (b, s))
-        end = jnp.asarray(end, jnp.int32)
-        end = end[:, None] if end.ndim else end
-        with jax.named_scope("kv_write"):
-            lidx = wpos // bs_blk
-            phys = jnp.take_along_axis(
-                jnp.broadcast_to(table, (b, mbs)),
-                jnp.minimum(lidx, mbs - 1), axis=1)
-            phys = jnp.where((wpos < end) & (lidx < mbs), phys, 0)
-            row = (wpos % bs_blk)[..., None] * self.nkv \
-                + jnp.arange(self.nkv, dtype=jnp.int32)
-            at = (phys[..., None], row)
-            return (pool_k.at[at].set(k.astype(pool_k.dtype)),
-                    pool_v.at[at].set(v.astype(pool_v.dtype)))
-
-    def _attend_merged(self, table, pos, q, pools):
-        """The decode step's attention through the paged kernel (one query
-        position a slot, per-slot cursors, a TPU or its test seam), or None
-        for the gathered view."""
-        from ..kernels.pallas import paged_decode
-        if q.shape[1] != 1 or jnp.ndim(pos) != 1:
-            return None
-        mode = paged_decode.kernel_mode(q, pools[0], n_kv=self.nkv)
-        if mode is None:
-            return None
-        with jax.named_scope("paged_decode"):
-            return paged_decode.paged_decode_attention(
-                q, pools[0], pools[1], table, pos + 1, n_kv=self.nkv,
-                interpret=mode == "interpret")
-
     def apply(self, x, cache, pos, end):
         b, s, _ = x.shape
         nh, nkv, hd = self.nh, self.nkv, self.hd
@@ -320,33 +209,10 @@ class GatedAttention(_Weights):
         q = rms_norm(q, self.q_norm.value(), self.eps)
         k = rms_norm(k, self.k_norm.value(), self.eps)
         positions = _positions(pos, s)
-        q, k = self._rope(q, positions), self._rope(k, positions)
-        new_cache = None
-        ctx = None
-        if cache is None:
-            k_buf, v_buf = k, v
-        else:                           # paged: [NB, BS * n_kv, hd] pools
-            we = end if end is not None else jnp.asarray(pos, jnp.int32) + s
-            new_cache = self._write_merged(cache, k, v, positions, we)
-            ctx = self._attend_merged(cache[2], pos, q, new_cache)
-            if ctx is None:
-                with jax.named_scope("kv_gather"):
-                    k_buf, v_buf = (jnp.take(p, cache[2], axis=0).reshape(
-                        b, -1, nkv, hd) for p in new_cache)
-        if ctx is None:
-            m = k_buf.shape[1]
-            qh = q.reshape(b, s, nkv, nh // nkv, hd).astype(jnp.float32)
-            scores = jnp.einsum("bqkgd,bmkd->bkgqm", qh,
-                                k_buf.astype(jnp.float32),
-                                precision="highest") / math.sqrt(hd)
-            key_pos = jnp.arange(m)[None, None, None, None, :]
-            q_pos = positions[:, None, None, :, None]
-            scores = jnp.where(key_pos <= q_pos, scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1)
-            ctx = jnp.einsum("bkgqm,bmkd->bqkgd", probs,
-                             v_buf.astype(jnp.float32),
-                             precision="highest").astype(q.dtype)
-        ctx = ctx.reshape(b, s, nh * hd)
+        q = rope(q, positions, self.rot, self.theta)
+        k = rope(k, positions, self.rot, self.theta)
+        ctx, new_cache = grouped_attention(q, k, v, cache, pos, positions,
+                                           end)
         ctx = (ctx.astype(jnp.float32)
                * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(x.dtype)
         return _dot(ctx, self.o_proj.value()), new_cache

@@ -123,8 +123,8 @@ def serving_mesh(leaves):
 def _model_spec(model) -> ModelSpec:
     """What the model says of itself (``models/cache_spec.py``): the
     cached-forward backbone, the LM head, and per layer what it caches
-    (``kv`` with heads and width, or ``state`` with its arrays). The engine
-    asks nothing else about the architecture."""
+    (``kv`` with heads and width, ``state`` with its arrays, or several
+    such entries). The engine asks nothing else about the architecture."""
     describe = getattr(model, "decode_spec", None)
     if describe is None:
         raise TypeError(
@@ -368,6 +368,10 @@ class DecodeEngine:
             int(np.prod(p.shape)) if p.ndim else 1
             for _, p in model.named_parameters())
         self._cache_dtype = spec.head_weight.value().dtype
+        # K and V of one position, every kv entry: what a call span's
+        # ``kv_bytes`` counts per live context token
+        self._kv_bytes = jnp.dtype(self._cache_dtype).itemsize * sum(
+            2 * c.n_kv_heads * c.head_dim for c in spec.kv_layers)
         # ---- tensor-parallel decode over the device mesh: with a "model"
         # axis of degree > 1 and a model riding it, the executables become
         # SPMD programs — KV pools shard on the head axis (hd fallback for
@@ -376,7 +380,7 @@ class DecodeEngine:
         # arguments stay replicated host data (the BlockPager is untouched)
         self._mesh, self._tp = serving_mesh(self._leaves)
         if self._mesh is not None and (self._has_state or any(
-                c.merged_rows for c in spec.layers)):
+                c.merged_rows for c in spec.entries)):
             raise NotImplementedError(
                 "tensor-parallel serving of recurrent-state layers needs a "
                 "sharding rule for the state arrays and for merged-row K/V "
@@ -459,8 +463,9 @@ class DecodeEngine:
                           self._cache_dtype)
             return z if self._pool_sh is None \
                 else jax.device_put(z, self._pool_sh)
-        self._pools = [(_pool(c), _pool(c)) if c.kind == "kv"
-                       else self._state_rows(c) for c in spec.layers]
+        self._pools = spec.map_entries(
+            lambda c: (_pool(c), _pool(c)) if c.kind == "kv"
+            else self._state_rows(c))
         # a prefix hit would skip tokens a recurrent state has to see
         self._pager = BlockPager(self.kv_blocks, self.block_size,
                                  self.max_slots, self._mbs,
@@ -515,6 +520,7 @@ class DecodeEngine:
         self._queue = AdmissionQueue(max_queue)
         self._decode_exe = None
         self._decode_attention = None
+        self._decode_state = None
         self._verify_exe = None
         self._prefill_exes = {}
         # ---- the prepared step (_step_planned). The
@@ -765,30 +771,28 @@ class DecodeEngine:
                      for shape, dtype in layer.arrays)
 
     def _layer_caches(self, pools, table, slot=None):
-        """What each layer's cached forward is handed: a kv layer its pools
-        and ``table`` (the rows of the call's slots), a state layer its
-        arrays' rows, ``slot``'s alone for a one-slot call."""
-        out = []
-        for layer, cache in zip(self.spec.layers, pools):
-            if layer.kind == "kv":
-                out.append(tuple(cache) + (table,))
-            elif slot is None:
-                out.append(tuple(cache))
-            else:
-                out.append(tuple(jax.lax.dynamic_slice_in_dim(a, slot, 1, 0)
-                                 for a in cache))
-        return out
+        """What each layer's cached forward is handed, entry by entry: a
+        kv entry its pools and ``table`` (the rows of the call's slots), a
+        state entry its arrays' rows, ``slot``'s alone for a one-slot
+        call."""
+        def hand(entry, cache):
+            if entry.kind == "kv":
+                return tuple(cache) + (table,)
+            if slot is None:
+                return tuple(cache)
+            return tuple(jax.lax.dynamic_slice_in_dim(a, slot, 1, 0)
+                         for a in cache)
+        return self.spec.map_entries(hand, pools)
 
     def _layer_results(self, pools, new, slot=None):
         """The caches to keep after a call: what the backbone returned, a
         one-slot call's state rows written back at ``slot``."""
-        if slot is None or not self._has_state:
-            return [tuple(n) for n in new]
-        return [tuple(n) if layer.kind == "kv" else tuple(
-                    jax.lax.dynamic_update_slice_in_dim(
-                        a, r.astype(a.dtype), slot, 0)
-                    for a, r in zip(cache, n))
-                for layer, cache, n in zip(self.spec.layers, pools, new)]
+        def keep(entry, cache, n):
+            if entry.kind == "kv" or slot is None:
+                return tuple(n)
+            return tuple(jax.lax.dynamic_update_slice_in_dim(
+                a, r.astype(a.dtype), slot, 0) for a, r in zip(cache, n))
+        return self.spec.map_entries(keep, pools, new)
 
     def _backbone(self, ids, caches, **kw):
         """The cached forward, with whatever its routed layers count of
@@ -807,10 +811,10 @@ class DecodeEngine:
         reads or writes. Padded entries are (0, 0) trash-to-trash no-ops,
         so the shape is always [max_slots] and COW never retraces. State
         layers have no blocks to copy."""
-        return [(c[0].at[dst].set(jnp.take(c[0], src, axis=0)),
-                 c[1].at[dst].set(jnp.take(c[1], src, axis=0)))
-                if layer.kind == "kv" else c
-                for layer, c in zip(self.spec.layers, pools)]
+        return self.spec.map_entries(
+            lambda entry, c: (c[0].at[dst].set(jnp.take(c[0], src, axis=0)),
+                              c[1].at[dst].set(jnp.take(c[1], src, axis=0)))
+            if entry.kind == "kv" else c, pools)
 
     def _sample(self, hidden_last, key, moe=None):
         """LM head + pick over ``hidden_last [B, H]``: (token ids int32 [B],
@@ -855,7 +859,9 @@ class DecodeEngine:
             args += (self._dev(self._pos),)
         t0 = time.time()
         from ..kernels.pallas import paged_decode
+        from ..kernels.pallas.util import state_kernels_traced
         traced = paged_decode.kernel_traces()
+        state_traced = len(state_kernels_traced())
         low = self._lower_in_eval(fn, args, self._pool_out_shardings())
         n_out = low.out_info[1].shape[0]
         if n_out != self._tok_len:
@@ -872,6 +878,11 @@ class DecodeEngine:
         # chip would otherwise look like "no gain"
         self._decode_attention = "paged_kernel" \
             if paged_decode.kernel_traces() > traced else "gather"
+        # and which step a model with state entries took for them: the
+        # kernel's name, or "scan" for the ``jax.numpy`` recurrence
+        if self._has_state:
+            self._decode_state = "+".join(sorted(set(
+                state_kernels_traced(state_traced)))) or "scan"
         # the decode step advances one token per SLOT per call
         self._minted("decode", None, time.time() - t0, exe=exe,
                      tokens=self.max_slots)
@@ -1676,13 +1687,16 @@ class DecodeEngine:
 
     # ------------------------------------------------- paged scheduling
 
-    def _state_attrs(self, slots: int) -> dict:
-        """What a call span of a model with state layers carries: the slots
-        whose recurrent state the call reads and writes, and its bytes."""
-        if not self._has_state:
-            return {}
-        return dict(state_slots=int(slots),
-                    state_bytes=int(slots) * self._state_bytes)
+    def _cache_attrs(self, slots: int, tokens: int) -> dict:
+        """What a call span says of the caches the call goes through: the
+        K/V bytes of the live context ``tokens`` it reads and, for a model
+        with state entries, the slots whose recurrent state it reads and
+        writes, and its bytes."""
+        attrs = dict(kv_bytes=int(tokens) * self._kv_bytes)
+        if self._has_state:
+            attrs.update(state_slots=int(slots),
+                         state_bytes=int(slots) * self._state_bytes)
+        return attrs
 
     def _chunk_len(self, n: int) -> int:
         """Shape of the chunk executable serving a length-n prompt: the
@@ -2173,7 +2187,7 @@ class DecodeEngine:
             self._arm("chunk", c.sc, first)
             first = False
             with _trace.span("engine/prefill_call",
-                             **self._state_attrs(1)) as c.span:
+                             **self._cache_attrs(1, c.end)) as c.span:
                 self._pools, c.tok0, c.ok = self._prefill_exes[c.sc](
                     self._leaf_values(), self._pools, *c.args)
                 c.args = None
@@ -2364,8 +2378,10 @@ class DecodeEngine:
             pos = np.zeros(self.max_slots, np.int32)
             for s, (_, cursor, _) in rows.items():
                 mask[s], pos[s] = True, cursor
-            attrs = dict(path=self._decode_attention,
-                         **self._state_attrs(len(rows)))
+            attrs = dict(path=self._decode_attention, **self._cache_attrs(
+                len(rows), int((pos[mask] + 1).sum())))
+            if self._has_state:
+                attrs["state_path"] = self._decode_state
             # the live KV blocks this step has to read; the gather path read
             # max_slots * max_blocks_per_slot whatever this says
             attrs["kv_blocks"] = int((pos[mask] // self.block_size + 1).sum())
@@ -2657,6 +2673,9 @@ class DecodeEngine:
             # (kernels/pallas/paged_decode.py) or "gather" (the dense view);
             # None before its first trace
             "decode_attention": self._decode_attention,
+            # and with which recurrent-state step: the Pallas kernel's name
+            # ("ssd_decode", "gdn_decode") or "scan"; None without state
+            "decode_state": self._decode_state,
             "tokens_generated": self.tokens_generated,
             "live_slots": self.live_count,
             "queue_depth": self.queue_depth,

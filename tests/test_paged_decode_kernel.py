@@ -249,6 +249,43 @@ def test_mosaic_compiles_the_delta_rule_decode_step(one_chip):
     assert exe.memory_analysis().temp_size_in_bytes < (1 << 20)
 
 
+def test_mosaic_compiles_five_query_heads_a_kv_head(one_chip):
+    """Falcon-H1's attention mixer: 20 query heads on 4 KV heads of 128 (a
+    group of 5: not a power of two, under a sublane tile), 128 slots,
+    merged-row pools of 64 rows a block. One kernel, no pool-sized copy."""
+    b, nh, n_kv, hd, mbs, nb = 128, 20, 4, 128, 256, 16384
+    exe = compile_for(
+        one_chip, lambda q, k, v, t, n: paged_decode_attention(
+            q, k, v, t, n, n_kv=n_kv),
+        ((b, 1, nh, hd), jnp.bfloat16),
+        ((nb, BS * n_kv, hd), jnp.bfloat16),
+        ((nb, BS * n_kv, hd), jnp.bfloat16), ((b, mbs), jnp.int32),
+        ((b,), jnp.int32))
+    assert exe.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    assert exe.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+def test_mosaic_compiles_the_state_space_decode_step(one_chip):
+    """128 slots x 32 heads of [256, 128] float32 state in 2 groups: one
+    kernel, a group's 16 heads a grid step, the state updated in place
+    (aliased, no second copy)."""
+    from paddle_tpu.kernels.pallas import ssd
+    b, h, n, p, g = 128, 32, 256, 128, 2
+    exe = compile_for(
+        one_chip, lambda x, dec, dt, bm, cm, live, forget, s:
+        ssd._decode_call(x, dec, dt, bm, cm, live, forget, s,
+                         interpret=False),
+        ((b, h, p), jnp.float32), ((b, h), jnp.float32),
+        ((b, h), jnp.float32), ((b, g, n), jnp.float32),
+        ((b, g, n), jnp.float32), ((b,), jnp.bool_), ((b,), jnp.bool_),
+        ((b, h, n, p), jnp.float32))
+    assert exe.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    assert "ssd_decode" in exe.as_text()
+    # not donated here, so one copy of the state is the most there may be
+    assert exe.memory_analysis().temp_size_in_bytes <= b * h * n * p * 4 \
+        + (1 << 20)
+
+
 @pytest.mark.parametrize("tokens", [128, 512])
 def test_mosaic_compiles_the_grouped_expert_matmul(one_chip, tokens):
     """128 held experts of 2048 x 512, top-10: a decode step's 128 tokens
