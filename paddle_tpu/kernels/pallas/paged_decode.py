@@ -31,10 +31,21 @@ MXU's float32 accumulator; float32 pools take a float32 matmul at the
 highest precision. Scores, the online softmax (running max / sum) and the
 context accumulator are float32.
 
-Grid: one step per slot. Inside, a slot's live blocks arrive in chunks of
-``pages_per_chunk`` table entries, each entry one async copy per pool, into
-a double buffer; the next chunk (the next slot's first chunk at a slot's
-end) is in flight while the current one is computed on.
+Grid: one step per slot. Inside, a slot's live blocks arrive in chunks,
+each table entry one async copy per pool. A chunk is sized in BYTES
+(``chunk_pages``: 512 KB of one pool, 8 pages of 16 KV heads x 128 in
+bfloat16, 32 of 4 KV heads), because what a page costs to start and to
+await is scalar work that does not shrink with the page. The chunks of
+all slots are one sequence through a ring of ``RING`` buffers: while one
+is computed on, the next two are in flight (a cursor, carried across grid
+steps, names the chunk whose copies start next), so the copy engine always
+has a chunk queued behind the one it is moving. A chunk's trip is one basic
+block: one wait a pool for a whole chunk (the semaphore counts bytes; a
+part of a chunk is awaited a power of two of pages at a time), the copies of
+the chunk two ahead started in straight-line code, each under its own
+predicate (no loop, no branch, none past a slot's length), then the two
+products. What a short chunk leaves stale in V's buffer is zeroed page by
+page, and only the pages the buffer's last chunk had fetched.
 """
 from __future__ import annotations
 
@@ -50,7 +61,9 @@ from jax.experimental.pallas import tpu as pltpu
 from .util import tpu_placement
 
 _NEG = -1e30                  # the gather path's mask value
-PAGES_PER_CHUNK = 8           # table entries fetched per compute chunk
+CHUNK_BYTES = 512 << 10       # one pool's share of a chunk
+MAX_PAGES = 64                # each copy of a chunk is straight-line code
+RING = 3                      # chunk buffers: one computed on, two in flight
 
 # Test seam, shaped like models/gpt.py::set_paged_kv_sharding: entered round
 # an engine's trace, it makes the model take this kernel off the TPU too,
@@ -59,6 +72,8 @@ _FORCE = {"interpret": False}
 # How often the kernel was traced into a program: the engine reads it round
 # its decode trace to say which path that executable took.
 _TRACES = {"n": 0}
+# ... and with which walk: table entries a chunk, bytes a page of one pool
+_GEOMETRY = {"kv_chunk_pages": None, "kv_page_bytes": None}
 
 
 @contextlib.contextmanager
@@ -72,6 +87,17 @@ def force_interpret(on: bool = True):
 
 def kernel_traces() -> int:
     return _TRACES["n"]
+
+
+def kernel_geometry() -> dict:
+    """The geometry of the last trace: `kv_chunk_pages`, `kv_page_bytes`."""
+    return dict(_GEOMETRY)
+
+
+def chunk_pages(page_bytes: int, table_width: int) -> int:
+    """Table entries a chunk holds: `CHUNK_BYTES` of one pool, whatever a
+    page weighs (8 pages of 64 KB, 32 of 16 KB), within the table."""
+    return max(1, min(CHUNK_BYTES // page_bytes, MAX_PAGES, table_width))
 
 
 def kernel_mode(q, pool_k, n_kv=None):
@@ -122,82 +148,121 @@ def _dot_exact(a, b, dims):
 
 
 def _kernel(len_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref,
-            kbuf, vbuf, sem, first_buf, *,
+            kbuf, vbuf, sem, ring, *,
             pages, block, n_kv, group, mbs, n_slots, scale):
-    # grid (slot,), sequential: the double buffer and `first_buf` (which
-    # half holds this slot's first chunk) carry across steps
+    # grid (slot,), sequential. `ring` carries across steps: [0] the ring
+    # slot that holds the chunk computed on next, [1] and [2] the slot and
+    # the chunk whose copies are started next (the cursor), [3 + r] the
+    # pages of ring slot r that the chunk computed on there last had fetched
     b = pl.program_id(0)
     nh, hd = q_ref.shape
-    rows = pages * block * n_kv          # rows of one chunk: (page, t, head)
+    depth, nb = kbuf.shape[0], k_hbm.shape[0]
+    page_rows = block * n_kv             # rows of one page: (t, head)
+    rows = pages * page_rows             # rows of one chunk
     span = pages * block                 # positions of one chunk
 
     def n_pages(slot):
-        return jnp.minimum((len_ref[slot] + block - 1) // block, mbs)
+        return jnp.clip((len_ref[slot] + block - 1) // block, 1, mbs)
 
-    def copies(slot, c, buf, fn):
-        """Apply `fn` (start or wait) to the async copies of chunk `c` of
-        `slot`: one per pool per LIVE table entry, none past the length."""
-        first = c * pages
+    def issue(to, unrolled=True):
+        """Start the copies of the chunk at the cursor into ring slot `to`,
+        one per pool per LIVE table entry, and move the cursor on. Inside a
+        trip: straight line, each copy under its own predicate, so no loop
+        and no branch parts it from the chunk's compute. (The call's first
+        chunks, once a call, take a loop: less to trace and to lower.)"""
+        slot, c = ring[1], ring[2]
+        s = jnp.minimum(slot, n_slots - 1)
+        live, first = n_pages(s), c * pages
+        cnt = jnp.where(slot < n_slots, live - first, 0)
 
-        def one(j, _):
-            page = tab_ref[slot * mbs + first + j]
-            fn(pltpu.make_async_copy(k_hbm.at[page], kbuf.at[buf, j],
-                                     sem.at[buf, 0]))
-            fn(pltpu.make_async_copy(v_hbm.at[page], vbuf.at[buf, j],
-                                     sem.at[buf, 1]))
+        def start(j):
+            entry = first + j if mbs % pages == 0 \
+                else jnp.minimum(first + j, mbs - 1)
+            # (the copies are not bounds-checked: the check was two thirds
+            # of a start's scalar work)
+            page = jnp.clip(tab_ref[s * mbs + entry], 0, nb - 1)
 
-        jax.lax.fori_loop(0, jnp.minimum(n_pages(slot) - first, pages), one,
-                          None)
+            @pl.when(j < cnt)
+            def _():
+                pltpu.make_async_copy(k_hbm.at[page], kbuf.at[to, j],
+                                      sem.at[to, 0]).start()
+                pltpu.make_async_copy(v_hbm.at[page], vbuf.at[to, j],
+                                      sem.at[to, 1]).start()
 
-    start = functools.partial(copies, fn=lambda dma: dma.start())
-    wait = functools.partial(copies, fn=lambda dma: dma.wait())
+        if unrolled:
+            for j in range(pages):
+                start(j)
+        else:
+            jax.lax.fori_loop(0, pages, lambda j, _: start(j), None)
+        more = first + pages < live
+        ring[1] = jnp.where(more, slot, slot + 1)
+        ring[2] = jnp.where(more, c + 1, 0)
+
+    def await_(cnt, at):
+        """Wait for `cnt` pages of both pools in ring slot `at`. A copy's
+        semaphore counts bytes, so the pages are awaited a power of two at
+        a time: one wait a pool for a whole chunk."""
+        bit = 1 << (pages.bit_length() - 1)
+        while bit:
+            @pl.when((cnt & bit) != 0)
+            def _(bit=bit):
+                for pool, buf, i in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                    pltpu.make_async_copy(
+                        pool.at[pl.ds(0, bit)], buf.at[at, pl.ds(0, bit)],
+                        sem.at[at, i]).wait()
+            bit >>= 1
 
     @pl.when(b == 0)
     def _():
-        first_buf[0] = 0
-        start(0, 0, 0)
+        ring[0] = ring[1] = ring[2] = 0
+        for to in range(depth):
+            ring[3 + to] = pages         # the buffers may hold anything
+        for to in range(depth - 1):
+            issue(to, unrolled=False)
 
-    base = first_buf[0]
     length = len_ref[b]
-    n_chunks = (n_pages(b) + pages - 1) // pages
-
-    # column r of a chunk is position r // n_kv of KV head r % n_kv; row i
-    # of the scores is query head i, which reads KV head i // group
-    col = jax.lax.broadcasted_iota(jnp.int32, (nh, rows), 1)
-    row = jax.lax.broadcasted_iota(jnp.int32, (nh, rows), 0)
-    col_pos = col // n_kv
-    own_head = (col - col_pos * n_kv) == row // group
-    row_pos = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) // n_kv
+    live = n_pages(b)
+    n_chunks = (live + pages - 1) // pages
     q = q_ref[...]
 
     def chunk(c, carry):
         m, l, acc = carry
-        buf = (base + c) % 2
+        at = ring[0]
+        ring[0] = jnp.where(at + 1 == depth, 0, at + 1)
+        left = (length - c * span) * n_kv        # live rows from here on
+        cnt = jnp.minimum(live - c * pages, pages)
 
-        @pl.when(c + 1 < n_chunks)
-        def _():
-            start(b, c + 1, 1 - buf)
+        # the pages of V this chunk did not fetch may hold anything, inf
+        # and nan too, and 0 * that is nan in the context product: those
+        # the last chunk in this ring slot fetched are zeroed, the ones
+        # past them were zeroed then
+        def zero(j, _):
+            vbuf[at, j] = jnp.zeros(vbuf.shape[2:], vbuf.dtype)
 
-        @pl.when((c + 1 == n_chunks) & (b + 1 < n_slots))
-        def _():
-            start(b + 1, 0, 1 - buf)
+        jax.lax.fori_loop(cnt, ring[3 + at], zero, None)
+        ring[3 + at] = cnt
+        await_(cnt, at)
+        # the page the length ends in: its stale tail is zeroed like the
+        # pages past it (a chunk that is live throughout rewrites a page)
+        edge = jnp.clip((left - 1) // page_rows, 0, pages - 1)
+        tail = edge * page_rows + jax.lax.broadcasted_iota(
+            jnp.int32, (page_rows, 1), 0)
+        v_edge = vbuf[at, edge]
+        vbuf[at, edge] = jnp.where(tail < left, v_edge,
+                                   jnp.zeros_like(v_edge))
+        issue(jnp.where(at == 0, depth - 1, at - 1))
 
-        wait(b, c, buf)
-
-        @pl.when(c + 1 == n_chunks)
-        def _():
-            # rows past the length (the block's stale tail, entries never
-            # fetched) may hold anything, inf and nan too: 0 * that is nan
-            # in the context product, so they are zeroed where they lie
-            v_all = vbuf[buf].reshape(rows, -1)
-            vbuf[buf] = jnp.where(c * span + row_pos < length, v_all,
-                                  jnp.zeros_like(v_all)).reshape(
-                                      vbuf.shape[1:])
-
-        k2 = kbuf[buf].reshape(rows, -1)
-        v2 = vbuf[buf].reshape(rows, -1)
+        k2 = kbuf[at].reshape(rows, -1)
+        v2 = vbuf[at].reshape(rows, -1)
         s = _dot_exact(q, k2, ((1,), (1,))) * scale          # [nh, rows]
-        s = jnp.where(own_head & (c * span + col_pos < length), s, _NEG)
+        # column r of a chunk is position r // n_kv of KV head r % n_kv;
+        # row i of the scores is query head i, which reads KV head
+        # i // group
+        col = jax.lax.broadcasted_iota(jnp.int32, (nh, rows), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (nh, rows), 0)
+        col_head = col & (n_kv - 1) if n_kv & (n_kv - 1) == 0 \
+            else jax.lax.rem(col, n_kv)
+        s = jnp.where((col_head == row // group) & (col < left), s, _NEG)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)           # masked columns: exactly 0
         alpha = jnp.exp(m - m_new)
@@ -210,7 +275,6 @@ def _kernel(len_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref,
         (jnp.full((nh, 1), _NEG, jnp.float32),
          jnp.zeros((nh, 1), jnp.float32),
          jnp.zeros((nh, hd), jnp.float32)))
-    first_buf[0] = (base + n_chunks) % 2
     o_ref[...] = (acc / l).astype(o_ref.dtype)
 
 
@@ -226,8 +290,10 @@ def paged_decode_attention(q, pool_k, pool_v, table, lengths, *,
     matrix a block the kernel works on; for few KV heads the 4-D form is
     tiled so that viewing it this way copies the pool); ``table`` [B, mbs]
     int32 block ids; ``lengths`` [B] int32, the live positions of each slot
-    (cursor + 1, at least 1). Returns the context [B, 1, nh, hd] in ``q``'s
-    dtype.
+    (cursor + 1, at least 1: a slot always walks one page). A chunk of the
+    walk is ``chunk_pages`` table entries, from the page's bytes;
+    ``pages_per_chunk`` overrides that for the tests. Returns the context
+    [B, 1, nh, hd] in ``q``'s dtype.
     """
     if n_kv is None:
         nb, block, n_kv, hd = pool_k.shape
@@ -235,10 +301,13 @@ def paged_decode_attention(q, pool_k, pool_v, table, lengths, *,
         pool_v = pool_v.reshape(nb, block * n_kv, hd)
     assert q.shape[1] == 1 and q.shape[2] % n_kv == 0 \
         and pool_v.shape == pool_k.shape and pool_k.shape[1] % n_kv == 0
+    page_bytes = pool_k.shape[1] * pool_k.shape[2] * pool_k.dtype.itemsize
+    pages = min(pages_per_chunk, table.shape[1]) if pages_per_chunk \
+        else chunk_pages(page_bytes, table.shape[1])
     _TRACES["n"] += 1
+    _GEOMETRY.update(kv_chunk_pages=pages, kv_page_bytes=page_bytes)
     return _attend(q, pool_k, pool_v, table, lengths, interpret=interpret,
-                   pages=min(pages_per_chunk or PAGES_PER_CHUNK,
-                             table.shape[1]), n_kv=n_kv)
+                   pages=pages, n_kv=n_kv)
 
 
 # jitted so that a model's layers share ONE trace and ONE Mosaic lowering of
@@ -253,7 +322,7 @@ def _attend(q, pool_k, pool_v, table, lengths, *, interpret, pages, n_kv):
         _kernel, pages=pages, block=block, n_kv=n_kv, group=nh // n_kv,
         mbs=mbs, n_slots=b, scale=1.0 / math.sqrt(hd))
     head_block = pl.BlockSpec((None, nh, hd), lambda i, *_: (i, 0, 0))
-    buf = pltpu.VMEM((2, pages, block * n_kv, hd), pool_k.dtype)
+    buf = pltpu.VMEM((RING, pages, block * n_kv, hd), pool_k.dtype)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -263,11 +332,12 @@ def _attend(q, pool_k, pool_v, table, lengths, *, interpret, pages, n_kv):
                       pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=head_block,
-            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2)),
-                            pltpu.SMEM((1,), jnp.int32)]),
+            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((RING, 2)),
+                            pltpu.SMEM((3 + RING,), jnp.int32)]),
         out_shape=jax.ShapeDtypeStruct((b, nh, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",),
+            disable_bounds_checks=True),
         interpret=interpret,
         name="paged_decode",
     )(lengths.astype(jnp.int32), table.reshape(-1).astype(jnp.int32),

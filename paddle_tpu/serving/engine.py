@@ -520,6 +520,7 @@ class DecodeEngine:
         self._queue = AdmissionQueue(max_queue)
         self._decode_exe = None
         self._decode_attention = None
+        self._decode_geometry = {}
         self._decode_state = None
         self._verify_exe = None
         self._prefill_exes = {}
@@ -878,6 +879,10 @@ class DecodeEngine:
         # chip would otherwise look like "no gain"
         self._decode_attention = "paged_kernel" \
             if paged_decode.kernel_traces() > traced else "gather"
+        # and with which walk of the table the kernel was traced
+        # (`kv_chunk_pages`, `kv_page_bytes`: it sizes a chunk from its input)
+        self._decode_geometry = paged_decode.kernel_geometry() \
+            if self._decode_attention == "paged_kernel" else {}
         # and which step a model with state entries took for them: the
         # kernel's name, or "scan" for the ``jax.numpy`` recurrence
         if self._has_state:
@@ -2378,8 +2383,9 @@ class DecodeEngine:
             pos = np.zeros(self.max_slots, np.int32)
             for s, (_, cursor, _) in rows.items():
                 mask[s], pos[s] = True, cursor
-            attrs = dict(path=self._decode_attention, **self._cache_attrs(
-                len(rows), int((pos[mask] + 1).sum())))
+            attrs = dict(path=self._decode_attention, **self._decode_geometry,
+                         **self._cache_attrs(len(rows),
+                                             int((pos[mask] + 1).sum())))
             if self._has_state:
                 attrs["state_path"] = self._decode_state
             # the live KV blocks this step has to read; the gather path read

@@ -105,6 +105,27 @@ def test_a_mixed_batch_with_kernels_interpreted_follows_the_reference(tiny):
         fam.state_bytes_per_slot(model, elem=4)
 
 
+def test_decode_call_spans_carry_the_kernels_geometry(tiny):
+    """The hybrid's attention mixers reach the paged kernel over merged-row
+    pools; every ``engine/decode_call`` span says with which walk of the
+    table the kernel was traced (1 KV head of 16, float32, blocks of 8:
+    512 B a page, a chunk bounded by the table's 12 entries)."""
+    import time
+    from paddle_tpu.monitor import trace
+    prog, _, _ = tiny
+    eng = engine(prog)
+    t0 = time.perf_counter()
+    with paged_decode.force_interpret(), ssd.force_interpret():
+        eng.submit(list(range(1, 10)), max_new_tokens=4)
+        eng.run()
+        traced = paged_decode.kernel_geometry()
+    calls = trace.spans(t0, time.perf_counter(), "engine/decode_call")
+    assert traced == {"kv_chunk_pages": 12, "kv_page_bytes": 8 * 1 * 16 * 4}
+    assert calls and all(c.attrs["path"] == "paged_kernel" for c in calls)
+    for c in calls:
+        assert {k: c.attrs[k] for k in traced} == traced
+
+
 def test_every_block_is_handed_both_of_its_caches(tiny):
     prog, _, model = tiny
     spec = prog.decode_spec()

@@ -16,12 +16,20 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from paddle_tpu.kernels.pallas.paged_decode import (kernel_mode,
+from paddle_tpu.kernels.pallas.paged_decode import (chunk_pages,
+                                                    kernel_geometry,
+                                                    kernel_mode,
                                                     paged_decode_attention)
 from paddle_tpu.models.gpt import _paged_kv_gather
 
 BS, MBS = 16, 8            # block size; table width: max_len 128
 MAX_LEN = BS * MBS
+# a grouped-query pool as the hybrids keep them: 4 KV heads of 128 in
+# bfloat16 is 16 KB a page, for which the kernel derives chunks of 32 pages
+# (512 positions); a table of 40 entries holds one whole chunk and a part
+NARROW = dict(nh=8, n_kv=4, hd=128, dtype=jnp.bfloat16, mbs=40, nb=200)
+EDGE = 32 * BS             # positions of one derived chunk of NARROW
+NARROW_MAX = BS * NARROW["mbs"]
 
 
 def gather_path(q, pool_k, pool_v, table, lengths):
@@ -42,7 +50,7 @@ def gather_path(q, pool_k, pool_v, table, lengths):
 
 
 def case(lengths, *, nh=4, n_kv=4, hd=64, dtype=jnp.float32, seed=0,
-         table=None, nb=40):
+         table=None, nb=40, mbs=MBS):
     rng = np.random.RandomState(seed)
     b = len(lengths)
     q = jnp.asarray(rng.randn(b, 1, nh, hd), jnp.float32).astype(dtype)
@@ -50,7 +58,7 @@ def case(lengths, *, nh=4, n_kv=4, hd=64, dtype=jnp.float32, seed=0,
                                   jnp.float32).astype(dtype)
                       for _ in range(2))
     if table is None:       # block 0 is the engine's trash block
-        table = rng.randint(1, nb, (b, MBS))
+        table = rng.randint(1, nb, (b, mbs))
     return (q, pool_k, pool_v, jnp.asarray(table, jnp.int32),
             jnp.asarray(lengths, jnp.int32))
 
@@ -69,20 +77,45 @@ def check(args, **kw):
     return got
 
 
+def derived_pages(args):
+    """What the kernel was last traced with, checked against the rule."""
+    geo = kernel_geometry()
+    page_bytes = BS * args[1].shape[2] * args[1].shape[3] \
+        * args[1].dtype.itemsize
+    assert geo == {"kv_page_bytes": page_bytes, "kv_chunk_pages":
+                   chunk_pages(page_bytes, args[3].shape[1])}
+    return geo["kv_chunk_pages"]
+
+
 @pytest.mark.parametrize("length", [1, BS - 1, BS, BS + 1])
 def test_lengths_round_a_block_edge(length):
     check(case([length, length]))
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_mixed_batch_dead_slot_and_full_slot(dtype):
+@pytest.mark.parametrize("length", [1, EDGE - 1, EDGE, EDGE + 1, NARROW_MAX])
+def test_lengths_round_an_edge_of_the_derived_chunk(length):
+    """16 KB pages: the kernel walks them 32 at a time, and a length one
+    under, at and one over that edge, and the table's end, read the same as
+    the gathered view."""
+    args = case([length, length, 3], **NARROW)
+    check(args)
+    assert derived_pages(args) == 32
+
+
+@pytest.mark.parametrize("dtype,geometry", [
+    (jnp.float32, None), (jnp.bfloat16, None), (jnp.bfloat16, NARROW)])
+def test_mixed_batch_dead_slot_and_full_slot(dtype, geometry):
     """One slot at pos 0 on the trash row (a dead or mid-prefill slot), one
-    at max_len - 1, the rest between; several chunks a slot."""
-    args = case([1, MAX_LEN, 37, 64, 100], dtype=dtype, seed=1)
+    at max_len - 1, the rest between; several chunks a slot (chunks of 2
+    pages, or the 32 the kernel derives for 16 KB pages)."""
+    if geometry is None:
+        args = case([1, MAX_LEN, 37, 64, 100], dtype=dtype, seed=1)
+    else:
+        args = case([1, NARROW_MAX, 37, EDGE + 40, 1], seed=1, **geometry)
     table = np.array(args[3])
     table[0] = 0
     args = args[:3] + (jnp.asarray(table),) + args[4:]
-    check(args, pages_per_chunk=2)
+    check(args, **({"pages_per_chunk": 2} if geometry is None else {}))
 
 
 @pytest.mark.parametrize("pages", [1, 3, 8])
@@ -90,10 +123,13 @@ def test_chunk_width_does_not_matter(pages):
     check(case([5, 77, MAX_LEN, 16], seed=2), pages_per_chunk=pages)
 
 
-def test_rows_that_share_physical_blocks():
+@pytest.mark.parametrize("lengths,geometry", [
+    ([40, 33, 47], {}), ([EDGE + 9, 33, EDGE - 1], NARROW)],
+    ids=["wide_pages", "derived_chunk_of_32"])
+def test_rows_that_share_physical_blocks(lengths, geometry):
     """Prefix sharing: three slots read the same first two blocks, then
     their own."""
-    args = case([40, 33, 47], seed=3)
+    args = case(lengths, seed=3, **geometry)
     table = np.array(args[3])
     table[:, :2] = table[0, :2]
     check(args[:3] + (jnp.asarray(table),) + args[4:])
@@ -110,10 +146,14 @@ def test_head_widths_and_pool_dtypes(hd, dtype):
     check(case([17, 90, 1], nh=4, n_kv=2, hd=hd, dtype=dtype, seed=5))
 
 
-def test_float32_query_over_bfloat16_pools():
+@pytest.mark.parametrize("lengths,geometry", [
+    ([30, 128], {}), ([30, EDGE + 1, NARROW_MAX],
+                      dict(NARROW, dtype=jnp.float32))],
+    ids=["wide_pages", "derived_chunk_of_32"])
+def test_float32_query_over_bfloat16_pools(lengths, geometry):
     """A query that is not bfloat16-exact meets the stored bfloat16 blocks
     with its float32 value (split in exact bfloat16 terms, not rounded)."""
-    q, pk, pv, table, lengths = case([30, 128], seed=6)
+    q, pk, pv, table, lengths = case(lengths, seed=6, **geometry)
     args = (q, pk.astype(jnp.bfloat16), pv.astype(jnp.bfloat16), table,
             lengths)
     got = paged_decode_attention(*args, interpret=True)
@@ -122,18 +162,25 @@ def test_float32_query_over_bfloat16_pools():
                                atol=5e-6)
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_stale_garbage_never_reaches_the_output(dtype):
+@pytest.mark.parametrize("dtype,geometry", [
+    (jnp.float32, None), (jnp.bfloat16, None), (jnp.bfloat16, NARROW)])
+def test_stale_garbage_never_reaches_the_output(dtype, geometry):
     """inf, nan and huge values past each slot's length (the live block's
-    tail, the table's dead entries) and in blocks no row references change
-    nothing: the output is bit-equal to the clean pools'."""
-    lengths = [1, BS - 1, BS + 1, 70]
-    q, pk, pv, table, L = case(lengths, dtype=dtype, seed=7)
+    tail, the table's dead entries, whose pages the kernel neither fetches
+    nor leaves as they lie in its buffers) and in blocks no row references
+    change nothing: the output is bit-equal to the clean pools'."""
+    if geometry is None:
+        lengths, kw = [1, BS - 1, BS + 1, 70], {"pages_per_chunk": 2}
+        q, pk, pv, table, L = case(lengths, dtype=dtype, seed=7)
+    else:       # a whole chunk and a part; a part alone; the edge itself
+        lengths, kw = [1, EDGE + BS + 1, BS - 1, EDGE, NARROW_MAX - 3], {}
+        q, pk, pv, table, L = case(lengths, seed=7, **geometry)
     table = np.array(table)
-    table[:] = np.arange(1, 1 + table.size).reshape(table.shape) % 39 + 1
+    table[:] = np.arange(1, 1 + table.size).reshape(table.shape) \
+        % (pk.shape[0] - 1) + 1
     table[0] = 0
     clean = paged_decode_attention(q, pk, pv, jnp.asarray(table), L,
-                                   interpret=True, pages_per_chunk=2)
+                                   interpret=True, **kw)
     junk = np.array([np.inf, -np.inf, np.nan, 3e38], np.float32)
     live = np.zeros((pk.shape[0], BS), bool)       # (block, offset) read
     for row, n in zip(table, lengths):
@@ -146,10 +193,32 @@ def test_stale_garbage_never_reaches_the_output(dtype):
     dirty = paged_decode_attention(
         q, jnp.asarray(dirty_k).astype(dtype),
         jnp.asarray(dirty_v).astype(dtype), jnp.asarray(table), L,
-        interpret=True, pages_per_chunk=2)
+        interpret=True, **kw)
     assert np.isfinite(np.asarray(dirty, np.float32)).all()
     np.testing.assert_array_equal(np.asarray(clean, np.float32),
                                   np.asarray(dirty, np.float32))
+
+
+@pytest.mark.parametrize("n_kv,hd,mbs,pages", [
+    (16, 128, 128, 8),      # GPT-3 XL: 64 KB a page, as before
+    (4, 128, 256, 32),      # Falcon-H1: 16 KB
+    (2, 256, 256, 32),      # Qwen3-Next: 16 KB
+    (8, 128, 128, 16),      # 8 KV heads of 128: 32 KB
+    (8, 128, 4, 4),         # a table narrower than a chunk bounds it
+])
+def test_a_chunk_is_sized_in_bytes(n_kv, hd, mbs, pages):
+    """512 KB of one pool a chunk, whatever a page weighs, within the
+    table's width: decided from the shapes the kernel is handed."""
+    assert chunk_pages(BS * n_kv * hd * 2, mbs) == pages
+    shapes = (jax.ShapeDtypeStruct((2, 1, n_kv, hd), jnp.bfloat16),
+              jax.ShapeDtypeStruct((64, BS * n_kv, hd), jnp.bfloat16),
+              jax.ShapeDtypeStruct((64, BS * n_kv, hd), jnp.bfloat16),
+              jax.ShapeDtypeStruct((2, mbs), jnp.int32),
+              jax.ShapeDtypeStruct((2,), jnp.int32))
+    jax.eval_shape(lambda *a: paged_decode_attention(
+        *a, interpret=True, n_kv=n_kv), *shapes)
+    assert kernel_geometry() == {"kv_chunk_pages": pages,
+                                 "kv_page_bytes": BS * n_kv * hd * 2}
 
 
 def test_off_the_tpu_the_model_keeps_the_gather_path():
@@ -261,6 +330,30 @@ def test_mosaic_compiles_five_query_heads_a_kv_head(one_chip):
         ((nb, BS * n_kv, hd), jnp.bfloat16),
         ((nb, BS * n_kv, hd), jnp.bfloat16), ((b, mbs), jnp.int32),
         ((b,), jnp.int32))
+    assert exe.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    assert exe.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+@pytest.mark.parametrize("q_dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16_query", "f32_query"])
+@pytest.mark.parametrize("nh,n_kv,hd,nb", [
+    (20, 4, 128, 16384), (16, 2, 256, 20480)],
+    ids=["falcon_h1", "qwen3_next"])
+def test_mosaic_compiles_the_derived_chunk_of_16_kb_pages(one_chip, nh, n_kv,
+                                                          hd, nb, q_dtype):
+    """The two hybrids' decode shapes, 128 slots over merged-row pools of
+    16 KB a page: the kernel derives chunks of 32 pages (three buffers of
+    512 KB a pool, 64 page copies a chunk in straight-line code), also with
+    a float32 query, whose three bfloat16 terms triple the score rows."""
+    b, mbs = 128, 256
+    exe = compile_for(
+        one_chip, lambda q, k, v, t, n: paged_decode_attention(
+            q, k, v, t, n, n_kv=n_kv),
+        ((b, 1, nh, hd), q_dtype), ((nb, BS * n_kv, hd), jnp.bfloat16),
+        ((nb, BS * n_kv, hd), jnp.bfloat16), ((b, mbs), jnp.int32),
+        ((b,), jnp.int32))
+    assert kernel_geometry() == {"kv_chunk_pages": 32,
+                                 "kv_page_bytes": 16384}
     assert exe.as_text().count('custom_call_target="tpu_custom_call"') == 1
     assert exe.memory_analysis().temp_size_in_bytes < (1 << 20)
 

@@ -416,6 +416,38 @@ def test_decode_call_span_counts_the_live_blocks(tiny):
     assert [c.attrs["kv_blocks"] for c in calls] == [1, 1, 4, 5, 5, 3, 4]
 
 
+def test_decode_call_spans_say_which_walk_the_kernel_took(tiny):
+    """The kernel sizes a chunk from its input (kernels/pallas/
+    paged_decode.py::chunk_pages) and notes what it was traced with; every
+    ``engine/decode_call`` span of an engine on the kernel carries it, a
+    gather engine's spans carry neither attribute."""
+    import time
+    from paddle_tpu.kernels.pallas import paged_decode
+    from paddle_tpu.monitor import trace
+    eng = DecodeEngine(tiny, max_slots=4, max_len=48, block_size=8,
+                       prefill_chunk=8)
+    t0 = time.perf_counter()
+    with paged_decode.force_interpret():
+        eng.submit(list(range(1, 12)), max_new_tokens=4)
+        eng.run()
+        traced = paged_decode.kernel_geometry()
+    calls = trace.spans(t0, time.perf_counter(), "engine/decode_call")
+    assert eng.stats()["decode_attention"] == "paged_kernel" and calls
+    # float32 pools, 2 heads of 16, blocks of 8: 1 KB a page; a chunk of
+    # 512 KB would be 512 pages: the table's 6 entries bound it
+    assert traced == {"kv_chunk_pages": 6, "kv_page_bytes": 8 * 2 * 16 * 4}
+    for c in calls:
+        assert {k: c.attrs[k] for k in traced} == traced
+    t0 = time.perf_counter()
+    gather = DecodeEngine(tiny, max_slots=4, max_len=48, block_size=8,
+                          prefill_chunk=8)
+    gather.submit(list(range(1, 12)), max_new_tokens=4)
+    gather.run()
+    calls = trace.spans(t0, time.perf_counter(), "engine/decode_call")
+    assert calls and all("kv_chunk_pages" not in c.attrs
+                         and "kv_page_bytes" not in c.attrs for c in calls)
+
+
 # ----------------------------------------------------- satellite: pager unit
 
 
