@@ -7,6 +7,18 @@ an expert-parallel deployment, without its exchange.
 experts in float32, keeps the published top-k and their weights, and returns
 
     the shared expert's output  +  sum over assignments to HELD experts
+                                +  the zero experts' term
+
+A router may be WIDER than the real experts: ``n_zero`` zero-compute
+(identity) experts follow them, ids ``n_routed .. n_routed + n_zero - 1``
+(LongCat-Flash). An assignment to one adds ``weight * x`` and multiplies
+nothing, so the compute a token costs varies with its choice. They hold no
+weights: every chip computes their term alike from the replicated router,
+and it is added WHOLE here (counted once when shares are summed, like the
+shared expert). ``choice_bias`` gives the router a per-output bias that
+only steers WHICH experts are kept (top-k of ``softmax + bias``), never
+their weights; ``scaling`` multiplies the kept weights (the model's
+``routed_scaling_factor``).
 
 so an assignment to an absent expert is left out: its owner adds it, on
 another chip, and the partial sum is what goes on to the next layer. Summed
@@ -22,7 +34,8 @@ auxiliary loss and a backward through the grouped matmul are not here yet.
 
 ``collect_counters()`` is how a serving executable reads the step's routing:
 inside it every call adds three traced integers (assignments of valid
-tokens, those that fell on held experts, held experts with at least one).
+tokens, those that fell on held experts, held experts with at least one),
+and a layer with zero experts a fourth (assignments to zero experts).
 """
 from __future__ import annotations
 
@@ -45,7 +58,8 @@ class _Counters:
         self.items = []
 
     def total(self):
-        """int32 [3] summed over the calls recorded, or None for none."""
+        """int32 [3] (or [4]: ``HeldExpertsMoE.counter_names``) summed
+        over the calls recorded, or None for none."""
         if not self.items:
             return None
         return jnp.sum(jnp.stack(self.items), axis=0).astype(jnp.int32)
@@ -61,17 +75,28 @@ def collect_counters():
         _COUNTERS.pop()
 
 
-def route_topk(x, router_w, top_k: int, norm_topk_prob: bool):
-    """Float32 router: softmax over ALL routed experts, top-k, weights
+def route_topk(x, router_w, top_k: int, norm_topk_prob: bool,
+               choice_bias=None, scaling: float = 1.0):
+    """Float32 router: softmax over ALL the router's outputs, top-k, weights
     renormalised to sum 1 when the model says so. ``x [T, H]``, ``router_w
-    [H, n_routed]``. Returns (ids [T, k] int32, weights [T, k] float32)."""
+    [H, outputs]``. With ``choice_bias [outputs]`` the k are the top of
+    ``softmax + bias`` and their weights still the softmax's own; the
+    weights are multiplied by ``scaling``. Returns (ids [T, k] int32,
+    weights [T, k] float32)."""
     logits = jnp.einsum("th,he->te", x.astype(jnp.float32),
                         router_w.astype(jnp.float32), precision="highest",
                         preferred_element_type=jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
-    weights, ids = jax.lax.top_k(probs, top_k)
+    if choice_bias is None:
+        weights, ids = jax.lax.top_k(probs, top_k)
+    else:
+        _, ids = jax.lax.top_k(probs + choice_bias.astype(jnp.float32),
+                               top_k)
+        weights = jnp.take_along_axis(probs, ids, axis=-1)
     if norm_topk_prob:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    if scaling != 1.0:
+        weights = weights * scaling
     return ids.astype(jnp.int32), weights
 
 
@@ -83,14 +108,16 @@ def _swiglu(x, gate_w, up_w, down_w):
 
 
 class HeldExpertsMoE(Layer):
-    """Router over ``n_routed`` experts + the ``count`` of them held here
-    (global ids ``offset .. offset + count - 1``) + an optional shared
-    expert gated by ``sigmoid(x w_s)``."""
+    """Router over ``n_routed`` experts (and ``n_zero`` zero-compute experts
+    after them) + the ``count`` real ones held here (global ids ``offset ..
+    offset + count - 1``) + an optional shared expert gated by ``sigmoid(x
+    w_s)``."""
 
     def __init__(self, hidden_size: int, expert_width: int, n_routed: int,
                  top_k: int, *, offset: int = 0, count: int = None,
                  norm_topk_prob: bool = True, shared_width: int = 0,
-                 std: float = 0.02, dtype=None):
+                 n_zero: int = 0, choice_bias: bool = False,
+                 scaling: float = 1.0, std: float = 0.02, dtype=None):
         super().__init__()
         count = n_routed if count is None else count
         if not (0 <= offset and offset + count <= n_routed and count >= 1):
@@ -99,6 +126,7 @@ class HeldExpertsMoE(Layer):
         self.n_routed, self.top_k = int(n_routed), int(top_k)
         self.offset, self.count = int(offset), int(count)
         self.norm_topk_prob = bool(norm_topk_prob)
+        self.n_zero, self.scaling = int(n_zero), float(scaling)
         normal = initializer.Normal(0.0, std)
 
         def mat(*shape):
@@ -106,7 +134,11 @@ class HeldExpertsMoE(Layer):
                                          default_initializer=normal)
 
         h, i = hidden_size, expert_width
-        self.gate = mat(h, n_routed)                    # the router
+        self.gate = mat(h, n_routed + self.n_zero)      # the router
+        self.gate_bias = self.create_parameter(
+            (n_routed + self.n_zero,), dtype=dtype,
+            default_initializer=initializer.Constant(0.0)) \
+            if choice_bias else None
         self.experts_gate_proj = mat(count, h, i)
         self.experts_up_proj = mat(count, h, i)
         self.experts_down_proj = mat(count, i, h)
@@ -117,23 +149,40 @@ class HeldExpertsMoE(Layer):
             self.shared_down_proj = mat(shared_width, h)
             self.shared_expert_gate = mat(h, 1)
 
+    @property
+    def counter_names(self) -> tuple:
+        """What each entry of a call's counter vector counts."""
+        return ("assignments", "local", "touched") \
+            + (("zero",) if self.n_zero else ())
+
     def apply(self, x, valid=None):
         """``x [T, H]`` raw array in the model's dtype, ``valid [T]`` bool
         (default: all). Returns [T, H] in ``x``'s dtype."""
         t = x.shape[0]
         valid = jnp.ones((t,), bool) if valid is None else valid
         with jax.named_scope("moe_route"):
-            ids, weights = route_topk(x, self.gate.value(), self.top_k,
-                                      self.norm_topk_prob)
+            ids, weights = route_topk(
+                x, self.gate.value(), self.top_k, self.norm_topk_prob,
+                None if self.gate_bias is None else self.gate_bias.value(),
+                self.scaling)
         with jax.named_scope("moe_experts"):
             out, counts = moe_grouped.moe_grouped(
                 x, ids, weights, valid, self.experts_gate_proj.value(),
                 self.experts_up_proj.value(), self.experts_down_proj.value(),
                 self.offset)
+        if self.n_zero:
+            with jax.named_scope("zero_experts"):
+                to_zero = ids >= self.n_routed
+                w_zero = jnp.sum(jnp.where(to_zero, weights, 0.0), axis=-1)
+                out = out + x.astype(jnp.float32) * w_zero[:, None]
         if _COUNTERS:
-            _COUNTERS[-1].items.append(jnp.stack([
-                jnp.sum(valid.astype(jnp.int32)) * self.top_k,
-                jnp.sum(counts), jnp.sum((counts > 0).astype(jnp.int32))]))
+            counted = [jnp.sum(valid.astype(jnp.int32)) * self.top_k,
+                       jnp.sum(counts),
+                       jnp.sum((counts > 0).astype(jnp.int32))]
+            if self.n_zero:
+                counted.append(jnp.sum(
+                    (to_zero & valid[:, None]).astype(jnp.int32)))
+            _COUNTERS[-1].items.append(jnp.stack(counted))
         if self.shared_width:
             with jax.named_scope("shared_expert"):
                 prec = "highest" if x.dtype == jnp.float32 else None

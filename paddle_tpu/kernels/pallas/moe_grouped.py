@@ -20,7 +20,13 @@ an expert no token chose is never touched.
                  weights of experts WITH tokens only, and once each (tiles
                  of one expert are adjacent; an unchanged block index is not
                  fetched again). Tiles past the used count keep the last
-                 expert's index (no fetch) and write zeros.
+                 expert's index (no fetch) and write zeros. An expert
+                 whose three matrices do not fit the kernel's fast memory
+                 twice (6144 x 2048: 75 MB) is walked in blocks of its
+                 intermediate width on a second grid axis, the down
+                 projection's partial products summed in float32
+                 (``inter_block``); a tile past the used count keeps the
+                 last block too.
   dense_masked   the plain form: every held expert over every token, times
                  the assignment's weight or 0. The CPU path, and the oracle.
 
@@ -112,6 +118,47 @@ def plan(ids, valid, offset: int, count: int, tile: int = TILE):
             "used": used.astype(jnp.int32), "counts": counts}
 
 
+def inter_block(h: int, inter: int, elem: int) -> int:
+    """Columns of the intermediate width a grid step multiplies by: all of
+    them where two experts' matrices fit ``VMEM_LIMIT`` (one computed on,
+    one in flight), else the widest 128-multiple divisor of ``inter`` that
+    does."""
+    def fits(cols):
+        return 2 * 3 * h * cols * elem <= VMEM_LIMIT - (4 << 20)
+    if fits(inter):
+        return inter
+    for cols in range(inter - 128, 0, -128):
+        if inter % cols == 0 and fits(cols):
+            return cols
+    return 128
+
+
+def _kernel_blocked(expert_ref, used_ref, x_ref, wg_ref, wu_ref, wd_ref,
+                    o_ref, acc_ref):
+    """`_kernel` over one block of the intermediate width a step: grid
+    (tile, block), the down projection's partial products summed in
+    `acc_ref`."""
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    @pl.when(i < used_ref[0])
+    def _():
+        x = x_ref[...]
+        prec = jax.lax.Precision.HIGHEST if x.dtype == jnp.float32 else None
+        dot = functools.partial(jnp.dot, precision=prec,
+                                preferred_element_type=jnp.float32)
+        gate, up = dot(x, wg_ref[...]), dot(x, wu_ref[...])
+        hid = (jax.nn.silu(gate) * up).astype(x.dtype)
+        acc_ref[...] += dot(hid, wd_ref[...])
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
 def _kernel(expert_ref, used_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref):
     i = pl.program_id(0)
 
@@ -134,6 +181,34 @@ def _kernel(expert_ref, used_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref):
 def _grouped_ffn(xs, expert, used, w_gate, w_up, w_down, *, tile, interpret):
     rows, h = xs.shape
     _, _, inter = w_gate.shape
+    cols = inter_block(h, inter, w_gate.dtype.itemsize)
+    if cols < inter:
+        n_j = inter // cols
+
+        def at(i, j, u):        # a tile past the used ones: no new block
+            return jnp.where(i < u[0], j, n_j - 1)
+
+        x_block = pl.BlockSpec((tile, h), lambda i, j, *_: (i, 0))
+        return pl.pallas_call(
+            _kernel_blocked,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(rows // tile, n_j),
+                in_specs=[x_block,
+                          pl.BlockSpec((None, h, cols), lambda i, j, e, u:
+                                       (e[i], 0, at(i, j, u))),
+                          pl.BlockSpec((None, h, cols), lambda i, j, e, u:
+                                       (e[i], 0, at(i, j, u))),
+                          pl.BlockSpec((None, cols, h), lambda i, j, e, u:
+                                       (e[i], at(i, j, u), 0))],
+                out_specs=x_block,
+                scratch_shapes=[pltpu.VMEM((tile, h), jnp.float32)]),
+            out_shape=jax.ShapeDtypeStruct((rows, h), xs.dtype),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=VMEM_LIMIT),
+            interpret=interpret,
+            name="moe_grouped",
+        )(expert, used.reshape(1), xs, w_gate, w_up, w_down)
     x_block = pl.BlockSpec((tile, h), lambda i, *_: (i, 0))
     return pl.pallas_call(
         _kernel,

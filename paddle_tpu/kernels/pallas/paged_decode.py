@@ -58,21 +58,27 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .util import tpu_placement
+from .util import note_attention_kernel, tpu_placement
 
 _NEG = -1e30                  # the gather path's mask value
 CHUNK_BYTES = 512 << 10       # one pool's share of a chunk
 MAX_PAGES = 64                # each copy of a chunk is straight-line code
 RING = 3                      # chunk buffers: one computed on, two in flight
+# bfloat16 terms the latent kernel's float32 probabilities meet the values
+# in (1: rounded once, as the context itself is when it leaves in bfloat16;
+# 3: exact, as the K/V kernel). 64 heads a row put the latent kernel at the
+# ridge (121 FLOP/B in one pass), so three terms would make it compute-bound:
+# 0.415 / 0.473 / 0.534 ms with one / two / three at the served shape
+LATENT_P_TERMS = 1
 
 # Test seam, shaped like models/gpt.py::set_paged_kv_sharding: entered round
 # an engine's trace, it makes the model take this kernel off the TPU too,
 # through the Pallas interpreter.
 _FORCE = {"interpret": False}
-# How often the kernel was traced into a program: the engine reads it round
-# its decode trace to say which path that executable took.
-_TRACES = {"n": 0}
-# ... and with which walk: table entries a chunk, bytes a page of one pool
+# With which walk the kernel was last traced (that it WAS traced, it notes
+# in ``util.note_attention_kernel``: the engine reads that round its traces
+# to say which path an executable took): table entries a chunk, bytes a page
+# of one pool
 _GEOMETRY = {"kv_chunk_pages": None, "kv_page_bytes": None}
 
 
@@ -83,10 +89,6 @@ def force_interpret(on: bool = True):
         yield
     finally:
         _FORCE["interpret"] = prev
-
-
-def kernel_traces() -> int:
-    return _TRACES["n"]
 
 
 def kernel_geometry() -> dict:
@@ -100,15 +102,22 @@ def chunk_pages(page_bytes: int, table_width: int) -> int:
     return max(1, min(CHUNK_BYTES // page_bytes, MAX_PAGES, table_width))
 
 
+def _seam(q):
+    """``"interpret"`` inside ``force_interpret``, ``"mosaic"`` where ``q``
+    will run on a TPU (if the shapes fit: the caller's check), else None."""
+    if _FORCE["interpret"]:
+        return "interpret"
+    return "mosaic" if tpu_placement(q) else None
+
+
 def kernel_mode(q, pool_k, n_kv=None):
     """How the paged decode step should attend: ``"mosaic"`` on a TPU whose
     tiling the shapes fit, ``"interpret"`` inside ``force_interpret``, None
     for the gather path. Decided from the input's shapes and placement.
     ``n_kv`` says a 3-D pool's rows are (position, KV head) pairs."""
-    if _FORCE["interpret"]:
-        return "interpret"
-    if not tpu_placement(q):
-        return None
+    mode = _seam(q)
+    if mode != "mosaic":
+        return mode
     if n_kv is None:
         bs, n_kv, hd = pool_k.shape[1:]
     else:
@@ -128,10 +137,11 @@ def _split3(x):
     return hi, mid, lo
 
 
-def _dot_exact(a, b, dims):
+def _dot_exact(a, b, dims, terms=3):
     """``a`` (small, [M, *]) against a pool chunk ``b`` as stored, float32
     out, with ``a``'s float32 value: bfloat16 blocks meet ``a`` split in
-    exact bfloat16 terms stacked on M; anything else a float32 matmul."""
+    exact bfloat16 terms stacked on M (the first ``terms`` of the three);
+    anything else a float32 matmul."""
     dn = (dims, ((), ()))
     if b.dtype != jnp.bfloat16:
         return jax.lax.dot_general(
@@ -141,21 +151,36 @@ def _dot_exact(a, b, dims):
     if a.dtype == jnp.bfloat16:
         return jax.lax.dot_general(a, b, dn,
                                    preferred_element_type=jnp.float32)
+    if terms == 1:
+        return jax.lax.dot_general(a.astype(jnp.bfloat16), b, dn,
+                                   preferred_element_type=jnp.float32)
     m = a.shape[0]
-    out = jax.lax.dot_general(jnp.concatenate(_split3(a), axis=0), b, dn,
-                              preferred_element_type=jnp.float32)
-    return out[:m] + out[m:2 * m] + out[2 * m:]
+    out = jax.lax.dot_general(
+        jnp.concatenate(_split3(a)[:terms], axis=0), b, dn,
+        preferred_element_type=jnp.float32)
+    acc = out[:m]
+    for i in range(1, terms):
+        acc = acc + out[i * m:(i + 1) * m]
+    return acc
 
 
-def _kernel(len_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref,
-            kbuf, vbuf, sem, ring, *,
-            pages, block, n_kv, group, mbs, n_slots, scale):
+def _kernel(len_ref, tab_ref, q_ref, *refs,
+            pages, block, n_kv, group, mbs, n_slots, scale, v_lanes=None,
+            p_terms=3):
+    # refs: the pools in HBM (K and V; with `v_lanes` ONE, whose first
+    # `v_lanes` lanes are the values), the output, a ring buffer a pool,
+    # the semaphores, the ring's state
+    n_pools = 1 if v_lanes else 2
+    hbm, o_ref = refs[:n_pools], refs[n_pools]
+    bufs, (sem, ring) = refs[n_pools + 1:2 * n_pools + 1], \
+        refs[2 * n_pools + 1:]
+    k_hbm, kbuf, vbuf = hbm[0], bufs[0], bufs[-1]
     # grid (slot,), sequential. `ring` carries across steps: [0] the ring
     # slot that holds the chunk computed on next, [1] and [2] the slot and
     # the chunk whose copies are started next (the cursor), [3 + r] the
     # pages of ring slot r that the chunk computed on there last had fetched
     b = pl.program_id(0)
-    nh, hd = q_ref.shape
+    nh, hd = o_ref.shape
     depth, nb = kbuf.shape[0], k_hbm.shape[0]
     page_rows = block * n_kv             # rows of one page: (t, head)
     rows = pages * page_rows             # rows of one chunk
@@ -184,10 +209,9 @@ def _kernel(len_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref,
 
             @pl.when(j < cnt)
             def _():
-                pltpu.make_async_copy(k_hbm.at[page], kbuf.at[to, j],
-                                      sem.at[to, 0]).start()
-                pltpu.make_async_copy(v_hbm.at[page], vbuf.at[to, j],
-                                      sem.at[to, 1]).start()
+                for i, (pool, buf) in enumerate(zip(hbm, bufs)):
+                    pltpu.make_async_copy(pool.at[page], buf.at[to, j],
+                                          sem.at[to, i]).start()
 
         if unrolled:
             for j in range(pages):
@@ -206,7 +230,7 @@ def _kernel(len_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref,
         while bit:
             @pl.when((cnt & bit) != 0)
             def _(bit=bit):
-                for pool, buf, i in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                for i, (pool, buf) in enumerate(zip(hbm, bufs)):
                     pltpu.make_async_copy(
                         pool.at[pl.ds(0, bit)], buf.at[at, pl.ds(0, bit)],
                         sem.at[at, i]).wait()
@@ -254,20 +278,26 @@ def _kernel(len_ref, tab_ref, q_ref, k_hbm, v_hbm, o_ref,
 
         k2 = kbuf[at].reshape(rows, -1)
         v2 = vbuf[at].reshape(rows, -1)
+        if v_lanes:
+            v2 = v2[:, :v_lanes]
         s = _dot_exact(q, k2, ((1,), (1,))) * scale          # [nh, rows]
         # column r of a chunk is position r // n_kv of KV head r % n_kv;
         # row i of the scores is query head i, which reads KV head
         # i // group
         col = jax.lax.broadcasted_iota(jnp.int32, (nh, rows), 1)
-        row = jax.lax.broadcasted_iota(jnp.int32, (nh, rows), 0)
-        col_head = col & (n_kv - 1) if n_kv & (n_kv - 1) == 0 \
-            else jax.lax.rem(col, n_kv)
-        s = jnp.where((col_head == row // group) & (col < left), s, _NEG)
+        if n_kv == 1:
+            own = col < left
+        else:
+            row = jax.lax.broadcasted_iota(jnp.int32, (nh, rows), 0)
+            col_head = col & (n_kv - 1) if n_kv & (n_kv - 1) == 0 \
+                else jax.lax.rem(col, n_kv)
+            own = (col_head == row // group) & (col < left)
+        s = jnp.where(own, s, _NEG)
         m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)           # masked columns: exactly 0
         alpha = jnp.exp(m - m_new)
         l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
-        acc = alpha * acc + _dot_exact(p, v2, ((1,), (0,)))
+        acc = alpha * acc + _dot_exact(p, v2, ((1,), (0,)), p_terms)
         return m_new, l, acc
 
     m, l, acc = jax.lax.fori_loop(
@@ -304,42 +334,84 @@ def paged_decode_attention(q, pool_k, pool_v, table, lengths, *,
     page_bytes = pool_k.shape[1] * pool_k.shape[2] * pool_k.dtype.itemsize
     pages = min(pages_per_chunk, table.shape[1]) if pages_per_chunk \
         else chunk_pages(page_bytes, table.shape[1])
-    _TRACES["n"] += 1
+    note_attention_kernel("paged_kernel")
     _GEOMETRY.update(kv_chunk_pages=pages, kv_page_bytes=page_bytes)
     return _attend(q, pool_k, pool_v, table, lengths, interpret=interpret,
                    pages=pages, n_kv=n_kv)
 
 
+def latent_mode(q, pool, rank: int):
+    """``kernel_mode`` for the latent geometry: ``q [B, 1, nh, lanes]``,
+    ``pool [NB, BS, lanes]``, the values the first ``rank`` lanes."""
+    mode = _seam(q)
+    sublanes = 32 // jnp.dtype(pool.dtype).itemsize
+    if mode == "mosaic" and (pool.shape[2] % 128 or rank % 128
+                             or pool.shape[1] % sublanes):
+        return None
+    return mode
+
+
+def latent_decode_attention(q, pool, table, lengths, *, rank, scale,
+                            interpret=False, pages_per_chunk=None):
+    """Latent attention's decode step, absorbed form: one query position a
+    slot, ``q [B, 1, nh, lanes]`` = ``[q_nope W_k^T (rank) | q_rope | 0]``
+    against the rows ``[latent | rotated key | 0]`` of ``pool [NB, BS,
+    lanes]`` that ``table`` / ``lengths`` say are live (as
+    ``paged_decode_attention``), scores times ``scale``, the context over
+    the rows' first ``rank`` lanes. Returns [B, 1, nh, rank] in ``q``'s
+    dtype. The walk is ``paged_decode``'s with one pool; a chunk's pages
+    are rounded up to a multiple of 8, so that the scores' columns fill
+    whole lane tiles (25 pages of 20 KB become 32: 0.415 ms against 24's
+    0.440 at the served shape)."""
+    assert q.shape[1] == 1 and q.shape[3] == pool.shape[2] >= rank
+    page_bytes = pool.shape[1] * pool.shape[2] * pool.dtype.itemsize
+    pages = pages_per_chunk or min(
+        -(-chunk_pages(page_bytes, table.shape[1]) // 8) * 8, MAX_PAGES)
+    pages = min(pages, table.shape[1])
+    note_attention_kernel("mla_decode")
+    _GEOMETRY.update(kv_chunk_pages=pages, kv_page_bytes=page_bytes)
+    return _attend(q, pool, None, table, lengths, interpret=interpret,
+                   pages=pages, n_kv=1, v_lanes=int(rank),
+                   scale=float(scale), p_terms=LATENT_P_TERMS)
+
+
 # jitted so that a model's layers share ONE trace and ONE Mosaic lowering of
 # the kernel (it is the same program in each; lowered once a layer, it was
 # most of a minute of every start on the chip's host, cache hit or not)
-@functools.partial(jax.jit, static_argnames=("interpret", "pages", "n_kv"))
-def _attend(q, pool_k, pool_v, table, lengths, *, interpret, pages, n_kv):
+@functools.partial(jax.jit, static_argnames=(
+    "interpret", "pages", "n_kv", "v_lanes", "scale", "p_terms"))
+def _attend(q, pool_k, pool_v, table, lengths, *, interpret, pages, n_kv,
+            v_lanes=None, scale=None, p_terms=3):
+    """``pool_v`` None: the latent geometry, the values ``pool_k``'s first
+    ``v_lanes`` lanes (the kernel ``mla_decode``)."""
     b, _, nh, hd = q.shape
     nb, block = pool_k.shape[0], pool_k.shape[1] // n_kv
     mbs = table.shape[1]
+    pools = (pool_k,) if pool_v is None else (pool_k, pool_v)
+    out_hd = v_lanes or hd
     kernel = functools.partial(
         _kernel, pages=pages, block=block, n_kv=n_kv, group=nh // n_kv,
-        mbs=mbs, n_slots=b, scale=1.0 / math.sqrt(hd))
-    head_block = pl.BlockSpec((None, nh, hd), lambda i, *_: (i, 0, 0))
+        mbs=mbs, n_slots=b, scale=scale or 1.0 / math.sqrt(hd),
+        v_lanes=v_lanes, p_terms=p_terms)
     buf = pltpu.VMEM((RING, pages, block * n_kv, hd), pool_k.dtype)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(b,),
-            in_specs=[head_block,
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=head_block,
-            scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((RING, 2)),
-                            pltpu.SMEM((3 + RING,), jnp.int32)]),
-        out_shape=jax.ShapeDtypeStruct((b, nh, hd), q.dtype),
+            in_specs=[pl.BlockSpec((None, nh, hd), lambda i, *_: (i, 0, 0))]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
+            out_specs=pl.BlockSpec((None, nh, out_hd),
+                                   lambda i, *_: (i, 0, 0)),
+            scratch_shapes=[buf] * len(pools)
+            + [pltpu.SemaphoreType.DMA((RING, len(pools))),
+               pltpu.SMEM((3 + RING,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((b, nh, out_hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             disable_bounds_checks=True),
         interpret=interpret,
-        name="paged_decode",
+        name="mla_decode" if v_lanes else "paged_decode",
     )(lengths.astype(jnp.int32), table.reshape(-1).astype(jnp.int32),
-      q.reshape(b, nh, hd), pool_k, pool_v)
-    return out.reshape(b, 1, nh, hd)
+      q.reshape(b, nh, hd), *pools)
+    return out.reshape(b, 1, nh, out_hd)

@@ -5,8 +5,7 @@ import jax
 
 # The Pallas kernels of a recurrent state's decode step (``gdn_decode``,
 # ``ssd_decode``) traced into programs, by name and in order: the engine reads
-# it round its decode trace to say which step that executable took, as it
-# reads ``paged_decode.kernel_traces()`` for the attention.
+# it round its decode trace to say which step that executable took.
 _STATE_KERNELS: list = []
 
 
@@ -16,6 +15,20 @@ def note_state_kernel(name: str) -> None:
 
 def state_kernels_traced(since: int = 0) -> list:
     return _STATE_KERNELS[since:]
+
+
+# ... and the kernels of a step's attention, noted the same way
+# (``paged_kernel``: ``paged_decode``'s K/V walk; ``mla_decode``: its latent
+# geometry), so that a silent fallback to the gathered view shows.
+_ATTENTION_KERNELS: list = []
+
+
+def note_attention_kernel(name: str) -> None:
+    _ATTENTION_KERNELS.append(name)
+
+
+def attention_kernels_traced(since: int = 0) -> list:
+    return _ATTENTION_KERNELS[since:]
 
 
 def tpu_placement(x) -> bool:

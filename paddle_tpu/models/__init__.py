@@ -19,3 +19,5 @@ from .qwen3_next import (Qwen3NextConfig, Qwen3NextModel,  # noqa: F401
                          Qwen3NextForCausalLM, qwen3_next_tiny)
 from .falcon_h1 import (FalconH1Config, FalconH1Model,  # noqa: F401
                         FalconH1ForCausalLM, falcon_h1_tiny)
+from .longcat_flash import (LongCatFlashConfig, LongCatFlashModel,  # noqa: F401
+                            LongCatFlashForCausalLM, longcat_flash_tiny)

@@ -1,7 +1,8 @@
 """What a causal LM tells the serving engine: its cached-forward backbone,
 its head, and, PER LAYER, what that layer caches between calls: one entry,
 or a tuple of entries for a block that keeps several caches (a state-space
-mixer beside an attention mixer: ``(kv_layer(..), state_layer(..))``).
+mixer beside an attention mixer: ``(kv_layer(..), state_layer(..))``; two
+latent-attention sublayers: ``(latent_layer(..), latent_layer(..))``).
 
   ``kv_layer(heads, width)``   keys and values of every past position: the
                                engine backs it with paged block pools
@@ -16,6 +17,20 @@ mixer beside an attention mixer: ``(kv_layer(..), state_layer(..))``).
                                a [.., 2, 256] pool gets small tiles that
                                every scatter and every kernel call has to
                                re-lay, a whole-pool copy each
+  ``latent_layer(rank, rope)`` ONE row of every past position, ``[normed
+                               latent (rank) | rotated shared key (rope)]``,
+                               which every query head reads (latent
+                               attention, absorbed form: the first ``rank``
+                               lanes are also the values). Blocks behind the
+                               block table like K/V, so the prefix cache,
+                               copy-on-write and preemption work on it by
+                               mechanism; ONE pool an entry, ``[blocks,
+                               block, lanes]``, the layer handed ``(pool,
+                               block_table)``. ``lanes`` is ``rank + rope``
+                               rounded up to whole 128-lane tiles (the chip
+                               lays a row out in whole tiles whatever is
+                               declared: 576 takes 640, the pad zero); what
+                               is COUNTED a token stays ``rank + rope``
   ``state_layer(arrays)``      a fixed-size recurrent state per sequence,
                                ``arrays`` = ((shape, dtype), ...) for ONE
                                sequence: the engine keeps ``[max_slots, *shape]``
@@ -45,6 +60,16 @@ def kv_layer(n_kv_heads: int, head_dim: int,
              merged_rows: bool = False) -> CacheLayer:
     return CacheLayer("kv", int(n_kv_heads), int(head_dim), (),
                       bool(merged_rows))
+
+
+def latent_layer(rank: int, rope: int) -> CacheLayer:
+    """``head_dim`` is the row's counted width, ``rank + rope``."""
+    return CacheLayer("latent", 1, int(rank) + int(rope), (), True)
+
+
+def pool_lanes(width: int) -> int:
+    """Lanes of a latent pool's row: ``width`` in whole 128-lane tiles."""
+    return -(-int(width) // 128) * 128
 
 
 def state_layer(arrays) -> CacheLayer:
@@ -82,15 +107,21 @@ class ModelSpec(namedtuple("ModelSpec", [
         return [c for c in self.entries if c.kind == "kv"]
 
     @property
+    def latent_layers(self) -> list:
+        return [c for c in self.entries if c.kind == "latent"]
+
+    @property
     def state_layers(self) -> list:
         return [c for c in self.entries if c.kind == "state"]
 
     def _kv_geometry(self):
         geo = {(c.n_kv_heads, c.head_dim) for c in self.kv_layers}
         if len(geo) != 1:
+            kinds = sorted({c.kind for c in self.entries})
             raise NotImplementedError(
                 f"the engine's KV pools share one geometry; this model's "
-                f"kv layers give {sorted(geo)}")
+                f"\"kv\" entries give {sorted(geo)} (its entries are of "
+                f"kind {kinds})")
         return geo.pop()
 
     @property

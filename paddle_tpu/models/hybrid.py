@@ -1,7 +1,8 @@
-"""What the hybrid decoders (``qwen3_next.py``, ``falcon_h1.py``) share: a
-bag of raw parameters, the float32 RMS norm, positions and validity of a
-cached call, rotate-half rotary, the depthwise convolution that carries its
-last inputs between calls, and grouped-query attention over merged-row
+"""What the raw-array decoders (``qwen3_next.py``, ``falcon_h1.py``,
+``longcat_flash.py``) share: a bag of raw parameters, the float32 RMS norm,
+positions and validity of a cached call, rotate-half rotary, the depthwise
+convolution that carries its last inputs between calls, where a call's
+positions land in a paged pool, and grouped-query attention over merged-row
 paged pools (``cache_spec.kv_layer(merged_rows=True)``) with the paged
 decode kernel where the call is a decode step. Raw-array math, no
 ``Tensor`` inside.
@@ -106,6 +107,33 @@ def rope(t, positions, rot, theta):
                             rest], axis=-1).astype(t.dtype)
 
 
+def _block_of(table, wpos, end, bs_blk):
+    """[B, S] physical block of each position ``wpos`` through ``table``;
+    positions at or past ``end`` or beyond the table go to trash block
+    0."""
+    b, mbs = wpos.shape[0], table.shape[1]
+    lidx = wpos // bs_blk
+    phys = jnp.take_along_axis(
+        jnp.broadcast_to(table, (b, mbs)),
+        jnp.minimum(lidx, mbs - 1), axis=1)
+    return jnp.where((wpos < end) & (lidx < mbs), phys, 0)
+
+
+def _write_end(end):
+    end = jnp.asarray(end, jnp.int32)
+    return end[:, None] if end.ndim else end
+
+
+def write_rows(pool, table, rows, positions, end):
+    """One row a position into ``pool [NB, BS, lanes]``: ``rows [B, S,
+    lanes]`` land at ``(table[b, p // BS], p % BS)``, the trash block where
+    ``_block_of`` says so. Returns the pool after the write."""
+    b, s = rows.shape[:2]
+    wpos = jnp.broadcast_to(positions, (b, s))
+    phys = _block_of(table, wpos, _write_end(end), pool.shape[1])
+    return pool.at[phys, wpos % pool.shape[1]].set(rows.astype(pool.dtype))
+
+
 def _write_merged(cache, k, v, positions, end):
     """``gpt._paged_kv_write`` for pools whose block is one matrix of
     (position, KV head) rows: position ``p`` of head ``h`` lands at
@@ -113,16 +141,10 @@ def _write_merged(cache, k, v, positions, end):
     ``end`` or beyond the table go to trash block 0."""
     pool_k, pool_v, table = cache
     b, s, nkv = k.shape[:3]
-    bs_blk, mbs = pool_k.shape[1] // nkv, table.shape[1]
+    bs_blk = pool_k.shape[1] // nkv
     wpos = jnp.broadcast_to(positions, (b, s))
-    end = jnp.asarray(end, jnp.int32)
-    end = end[:, None] if end.ndim else end
     with jax.named_scope("kv_write"):
-        lidx = wpos // bs_blk
-        phys = jnp.take_along_axis(
-            jnp.broadcast_to(table, (b, mbs)),
-            jnp.minimum(lidx, mbs - 1), axis=1)
-        phys = jnp.where((wpos < end) & (lidx < mbs), phys, 0)
+        phys = _block_of(table, wpos, _write_end(end), bs_blk)
         row = (wpos % bs_blk)[..., None] * nkv \
             + jnp.arange(nkv, dtype=jnp.int32)
         at = (phys[..., None], row)

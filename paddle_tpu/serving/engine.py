@@ -72,10 +72,11 @@ from .. import monitor as _monitor
 from ..monitor import trace as _trace
 from ..core.tensor import Tensor
 from ..distributed.env import get_mesh
-from ..models.cache_spec import ModelSpec
+from ..models.cache_spec import ModelSpec, pool_lanes
 from ..models.gpt import (_lm_head_logits, _pick_token,
                           _resolve_decode_horizon, set_paged_kv_sharding)
 from ..distributed.reshard import snapshot as _snapshot
+from ..kernels.pallas.util import attention_kernels_traced
 from .guardrails import (HANG_ENV, DispatchWatchdog, EngineHangError,
                          FaultSchedule, InjectedFault)
 from .pager import TRASH_BLOCK, BlockPager, prefix_digest
@@ -85,6 +86,10 @@ from .scheduler import (TERMINAL_STATUSES, AdmissionQueue, Request,
 __all__ = ["DecodeEngine", "Request", "generate_via_engine",
            "quantize_for_serving", "EngineHangError", "TERMINAL_STATUSES"]
 
+
+# cache entries whose content is blocks behind the block table (the pager's
+# blocks, copy-on-write and the prefix cache apply); a "state" entry is rows
+_PAGED = ("kv", "latent")
 
 # terminal caller-supplied request ids remembered per engine for dedup
 # (a requeue retry arriving AFTER completion still returns the original)
@@ -328,6 +333,9 @@ class DecodeEngine:
         # instead of K/V): what they cannot do yet is refused by name
         self._has_state = bool(spec.state_layers)
         self._state_bytes = spec.state_bytes_per_slot
+        # ... and layers that cache one latent row a position (blocks
+        # behind the table like K/V: the pager serves them by mechanism)
+        self._has_latent = bool(spec.latent_layers)
         self.quantize = quantize
         self.max_slots = int(max_slots)
         self.max_len = int(max_len)
@@ -346,6 +354,12 @@ class DecodeEngine:
                     "speculative decoding with recurrent-state layers needs "
                     "a state snapshot to roll rejected drafts back to: the "
                     "verify executable is not built for such a model")
+            if self._has_latent:
+                raise NotImplementedError(
+                    "speculative decoding over latent-attention entries "
+                    "needs a verify executable that walks cache entries "
+                    "(it hands every layer a K pool and a V pool): not "
+                    "built for a model whose layers cache latent rows")
             if self._do_sample:
                 raise NotImplementedError(
                     "speculative decoding is greedy-only (acceptance is "
@@ -370,8 +384,9 @@ class DecodeEngine:
         self._cache_dtype = spec.head_weight.value().dtype
         # K and V of one position, every kv entry: what a call span's
         # ``kv_bytes`` counts per live context token
-        self._kv_bytes = jnp.dtype(self._cache_dtype).itemsize * sum(
+        self._kv_bytes = jnp.dtype(self._cache_dtype).itemsize * (sum(
             2 * c.n_kv_heads * c.head_dim for c in spec.kv_layers)
+            + sum(c.head_dim for c in spec.latent_layers))
         # ---- tensor-parallel decode over the device mesh: with a "model"
         # axis of degree > 1 and a model riding it, the executables become
         # SPMD programs — KV pools shard on the head axis (hd fallback for
@@ -379,6 +394,13 @@ class DecodeEngine:
         # RowParallel placements, and the block table / cursors / COW index
         # arguments stay replicated host data (the BlockPager is untouched)
         self._mesh, self._tp = serving_mesh(self._leaves)
+        if self._mesh is not None and self._has_latent:
+            raise NotImplementedError(
+                "tensor-parallel serving of latent-attention entries needs "
+                "a head-sharded placement of the absorbed queries over a "
+                "REPLICATED latent pool (one row serves every head, so "
+                "there is no head axis to shard the pool on) and an "
+                "\"expert\" axis for held experts: not implemented")
         if self._mesh is not None and (self._has_state or any(
                 c.merged_rows for c in spec.entries)):
             raise NotImplementedError(
@@ -459,12 +481,17 @@ class DecodeEngine:
         def _pool(c):
             rows = (self.block_size * c.n_kv_heads,) if c.merged_rows \
                 else (self.block_size, c.n_kv_heads)
-            z = jnp.zeros((self.kv_blocks,) + rows + (c.head_dim,),
+            lanes = pool_lanes(c.head_dim) if c.kind == "latent" \
+                else c.head_dim
+            z = jnp.zeros((self.kv_blocks,) + rows + (lanes,),
                           self._cache_dtype)
             return z if self._pool_sh is None \
                 else jax.device_put(z, self._pool_sh)
+        # a kv entry: a K pool and a V pool; a latent entry: ONE pool of
+        # rows; a state entry: its arrays, a row a slot
         self._pools = spec.map_entries(
             lambda c: (_pool(c), _pool(c)) if c.kind == "kv"
+            else (_pool(c),) if c.kind == "latent"
             else self._state_rows(c))
         # a prefix hit would skip tokens a recurrent state has to see
         self._pager = BlockPager(self.kv_blocks, self.block_size,
@@ -478,6 +505,11 @@ class DecodeEngine:
         # ---- cross-process prefix-cache tier (serving/kvpool.py): parked
         # registered blocks export to the pool, registry-miss admissions
         # fetch + adopt. All host state; zero effect when kv_pool is None.
+        if kv_pool is not None and self._has_latent:
+            raise NotImplementedError(
+                "pool export/adopt speaks [layers, block, kv heads, width] "
+                "K and V blocks: a model whose layers cache latent rows "
+                "needs a wire codec for its [block, lanes] entries")
         if kv_pool is not None and self._has_state:
             raise NotImplementedError(
                 "pool export/adopt moves K/V blocks only: a model with "
@@ -520,6 +552,7 @@ class DecodeEngine:
         self._queue = AdmissionQueue(max_queue)
         self._decode_exe = None
         self._decode_attention = None
+        self._prefill_attention: dict = {}   # chunk length -> its path
         self._decode_geometry = {}
         self._decode_state = None
         self._verify_exe = None
@@ -539,12 +572,16 @@ class DecodeEngine:
         self._note_exe = None
         self._armed: dict = {}             # the watchdog's current window
         # length of the decode executable's token vector: max_slots, plus
-        # the routed layers' three counts where the model has such layers
+        # the routed layers' counts where the model has such layers
         # (what the trace really returned decides: _build_decode)
         from ..incubate.distributed.models.moe.held import HeldExpertsMoE
-        self._tok_len = self.max_slots + 3 * any(
-            isinstance(l, HeldExpertsMoE)
-            for l in model.sublayers(include_self=True))
+        self._moe_names = max(
+            (l.counter_names for l in model.sublayers(include_self=True)
+             if isinstance(l, HeldExpertsMoE)), key=len, default=())
+        self._tok_len = self.max_slots + len(self._moe_names)
+        # the decode step is told which slots are live (write_end) where a
+        # layer keeps per-slot state, or counts the tokens it routes
+        self._tells_live = self._has_state or bool(self._moe_names)
         self._slot_dev: dict = {}
         # how each step that ran an executable came by its plan, and why
         # the rebuilt ones lost theirs (stats()["plan"])
@@ -777,7 +814,7 @@ class DecodeEngine:
         state entry its arrays' rows, ``slot``'s alone for a one-slot
         call."""
         def hand(entry, cache):
-            if entry.kind == "kv":
+            if entry.kind in _PAGED:
                 return tuple(cache) + (table,)
             if slot is None:
                 return tuple(cache)
@@ -789,7 +826,7 @@ class DecodeEngine:
         """The caches to keep after a call: what the backbone returned, a
         one-slot call's state rows written back at ``slot``."""
         def keep(entry, cache, n):
-            if entry.kind == "kv" or slot is None:
+            if entry.kind in _PAGED or slot is None:
                 return tuple(n)
             return tuple(jax.lax.dynamic_update_slice_in_dim(
                 a, r.astype(a.dtype), slot, 0) for a, r in zip(cache, n))
@@ -813,13 +850,13 @@ class DecodeEngine:
         so the shape is always [max_slots] and COW never retraces. State
         layers have no blocks to copy."""
         return self.spec.map_entries(
-            lambda entry, c: (c[0].at[dst].set(jnp.take(c[0], src, axis=0)),
-                              c[1].at[dst].set(jnp.take(c[1], src, axis=0)))
-            if entry.kind == "kv" else c, pools)
+            lambda entry, c: tuple(p.at[dst].set(jnp.take(p, src, axis=0))
+                                   for p in c)
+            if entry.kind in _PAGED else c, pools)
 
     def _sample(self, hidden_last, key, moe=None):
         """LM head + pick over ``hidden_last [B, H]``: (token ids int32 [B],
-        per-row finite-logits flag). With ``moe`` (the routed layers' three
+        per-row finite-logits flag). With ``moe`` (the routed layers'
         counts) the ids carry them as a tail, so one fetch brings both."""
         with jax.named_scope("lm_head_sample"):
             logits = self._head(hidden_last)
@@ -833,9 +870,10 @@ class DecodeEngine:
         return nxt, ok
 
     def _build_decode(self):
-        # a model with state layers is told which slots are live: write_end
-        # = pos + 1 for them, pos for the rest, whose state (and K/V) the
-        # step must leave alone.
+        # a model with state layers (or routed layers, which count their
+        # tokens) is told which slots are live: write_end = pos + 1 for
+        # them, pos for the rest, whose state (and K/V) the step must leave
+        # alone.
         # ``tok`` is as long as the step's own picked tokens (with the
         # routed layers' counts behind them, where there are any): the
         # next step is handed this step's output as it lies on the
@@ -856,12 +894,12 @@ class DecodeEngine:
                 self._dev(self._pager.tables),
                 self._dev(self._host_tok()), self._dev(self._pos), pad,
                 pad, self._greedy_key)
-        if self._has_state:
+        if self._tells_live:
             args += (self._dev(self._pos),)
         t0 = time.time()
         from ..kernels.pallas import paged_decode
         from ..kernels.pallas.util import state_kernels_traced
-        traced = paged_decode.kernel_traces()
+        traced = len(attention_kernels_traced())
         state_traced = len(state_kernels_traced())
         low = self._lower_in_eval(fn, args, self._pool_out_shardings())
         n_out = low.out_info[1].shape[0]
@@ -877,12 +915,11 @@ class DecodeEngine:
         # which attention the trace took (the model chose from its input:
         # models/gpt.py::_paged_decode_attend); a silent fallback on the
         # chip would otherwise look like "no gain"
-        self._decode_attention = "paged_kernel" \
-            if paged_decode.kernel_traces() > traced else "gather"
+        self._decode_attention = self._attention_path(traced)
         # and with which walk of the table the kernel was traced
         # (`kv_chunk_pages`, `kv_page_bytes`: it sizes a chunk from its input)
         self._decode_geometry = paged_decode.kernel_geometry() \
-            if self._decode_attention == "paged_kernel" else {}
+            if self._decode_attention != "gather" else {}
         # and which step a model with state entries took for them: the
         # kernel's name, or "scan" for the ``jax.numpy`` recurrence
         if self._has_state:
@@ -892,6 +929,15 @@ class DecodeEngine:
         self._minted("decode", None, time.time() - t0, exe=exe,
                      tokens=self.max_slots)
         return exe
+
+    @staticmethod
+    def _attention_path(mark: int) -> str:
+        """Which attention kernels were traced since ``mark`` (a length of
+        ``kernels/pallas/util.py::attention_kernels_traced()``):
+        ``"paged_kernel"`` (the K/V walk), ``"mla_decode"`` (its latent
+        geometry), or ``"gather"`` for the view."""
+        return "+".join(sorted(set(attention_kernels_traced(mark)))) \
+            or "gather"
 
     def _build_note(self, tok):
         """The one helper program of the prepared step: write a chunk's
@@ -941,9 +987,11 @@ class DecodeEngine:
                 self._dev(jnp.int32(0)), self._dev(jnp.int32(0)),
                 self._dev(jnp.int32(1)), pad, pad, self._greedy_key)
         t0 = time.time()
+        traced = len(attention_kernels_traced())
         exe = self._compile_in_eval(fn, args,
                                     out_shardings=self._pool_out_shardings())
         self._prefill_exes[sc] = exe
+        self._prefill_attention[sc] = self._attention_path(traced)
         self._minted("prefill", sc, time.time() - t0, exe=exe, tokens=sc)
         return exe
 
@@ -2192,6 +2240,7 @@ class DecodeEngine:
             self._arm("chunk", c.sc, first)
             first = False
             with _trace.span("engine/prefill_call",
+                             path=self._prefill_attention[c.sc],
                              **self._cache_attrs(1, c.end)) as c.span:
                 self._pools, c.tok0, c.ok = self._prefill_exes[c.sc](
                     self._leaf_values(), self._pools, *c.args)
@@ -2393,7 +2442,7 @@ class DecodeEngine:
             attrs["kv_blocks"] = int((pos[mask] // self.block_size + 1).sum())
             src, dst = self._cow_args(copies)
             # which slots the step may advance (see _build_decode)
-            end = (self._dev(pos + mask),) if self._has_state else ()
+            end = (self._dev(pos + mask),) if self._tells_live else ()
             args = (self._dev(self._decode_tables(mask)), self._dev(pos),
                     src, dst, self._next_key()) + end
             on_device = ahead and flight.decode is not None
@@ -2416,8 +2465,8 @@ class DecodeEngine:
                 moe = nxt[self.max_slots:].astype(np.int64)
                 self.moe_counts = moe if self.moe_counts is None \
                     else self.moe_counts + moe
-                fin.set(moe_assignments=int(moe[0]), moe_local=int(moe[1]),
-                        moe_touched=int(moe[2]))
+                fin.set(**{f"moe_{n}": int(v)
+                           for n, v in zip(self._moe_names, moe)})
             live = n_tok = n_done = 0
             for slot, req in rows.items():
                 if self._slot_req[slot] is not req:
@@ -2709,7 +2758,7 @@ class DecodeEngine:
                             "bytes_per_slot": self._state_bytes,
                             "slots": self.max_slots}
         if self.moe_counts is not None:
-            out["moe"] = dict(zip(("assignments", "local", "touched"),
+            out["moe"] = dict(zip(self._moe_names,
                                   map(int, self.moe_counts)))
         out["paged"] = dict(self._pager.stats().as_dict(),
                             block_size=self.block_size,

@@ -431,3 +431,41 @@ def test_mosaic_compiles_the_packed_flash_attention(one_chip, b, L, heads, d,
                             max_fused_bwd=max_fused_bwd)["form"]
     assert (bwd.as_text().count('custom_call_target="tpu_custom_call"')
             == {"fused": 1, "split": 2}[form])
+
+
+def test_mosaic_compiles_the_latent_geometry(one_chip):
+    """LongCat-Flash's decode step: 64 query heads on ONE cached row of 512
+    + 64 lanes in 640, 128 slots, 20 KB pages in chunks of 32: one kernel
+    named `mla_decode`, one pool, the context 512 wide, no pool-sized
+    copy."""
+    from paddle_tpu.kernels.pallas.paged_decode import (
+        kernel_geometry, latent_decode_attention)
+    b, nh, lanes, rank, mbs, nb = 128, 64, 640, 512, 256, 16384
+    exe = compile_for(
+        one_chip, lambda q, pool, t, n: latent_decode_attention(
+            q, pool, t, n, rank=rank, scale=192 ** -0.5),
+        ((b, 1, nh, lanes), jnp.bfloat16), ((nb, BS, lanes), jnp.bfloat16),
+        ((b, mbs), jnp.int32), ((b,), jnp.int32))
+    text = exe.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "mla_decode" in text
+    assert exe.memory_analysis().temp_size_in_bytes < (1 << 20)
+    assert kernel_geometry() == {"kv_chunk_pages": 32,
+                                 "kv_page_bytes": BS * lanes * 2}
+
+
+def test_mosaic_compiles_an_expert_walked_in_blocks(one_chip):
+    """LongCat-Flash's experts (6144 x 2048: 75 MB, more than the kernel's
+    fast memory holds twice) in blocks of 512 columns on a second grid
+    axis, at the decode step's 128 tokens x top-12 over 16 held."""
+    from paddle_tpu.kernels.pallas import moe_grouped as mg
+    tiles = -(-128 * 12 // mg.TILE) + 16
+    bf = jnp.bfloat16
+    exe = compile_for(
+        one_chip, lambda xs, e, u, g, up, d: mg._grouped_ffn(
+            xs, e, u, g, up, d, tile=mg.TILE, interpret=False),
+        ((tiles * mg.TILE, 6144), bf), ((tiles,), jnp.int32),
+        ((), jnp.int32), ((16, 6144, 2048), bf), ((16, 6144, 2048), bf),
+        ((16, 2048, 6144), bf))
+    assert exe.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    assert exe.memory_analysis().temp_size_in_bytes < (1 << 20)
