@@ -8,7 +8,11 @@ length. At decode (``S == 1``, per-slot cursors) that view was nine tenths
 of the step's device time. This kernel walks each slot's table instead and
 streams only the ``ceil(length / BS)`` blocks that hold live positions from
 HBM through VMEM, in the pools' own dtype; nothing past a slot's length is
-fetched or computed on, and no view ever exists in HBM.
+fetched or computed on, and no view ever exists in HBM. (Who still attends
+over a dense view: tensor-parallel serving, the decode step off the chip,
+and ``generate()``'s contiguous cache, which has no table. A prefill chunk
+or a speculative verify walks its slot's key blocks in ``jax.numpy``:
+``models/hybrid.py::walk_keys``.)
 
 Layout contract: the pools stay ``[NB, BS, n_kv, hd]`` (the pager, the kv
 pool's wire codec and the reshard snapshot all speak it). A block is viewed

@@ -17,9 +17,11 @@ def state_kernels_traced(since: int = 0) -> list:
     return _STATE_KERNELS[since:]
 
 
-# ... and the kernels of a step's attention, noted the same way
+# ... and what a call's attention went through, noted the same way
 # (``paged_kernel``: ``paged_decode``'s K/V walk; ``mla_decode``: its latent
-# geometry), so that a silent fallback to the gathered view shows.
+# geometry; ``key_walk``: a chunk's ``jax.numpy`` walk of its slot's key
+# blocks, ``models/hybrid.py::walk_keys``), so that a silent fallback to the
+# gathered view shows.
 _ATTENTION_KERNELS: list = []
 
 

@@ -289,8 +289,12 @@ def _paged_kv_gather(pool_k, pool_v, table):
     into contiguous [B, mbs*BS, n_kv, hd] buffers with ``jnp.take`` on the
     block axis — the caller's causal mask (key position <= query position)
     hides the stale tail exactly as it does for the contiguous layout.
-    Under a tensor-parallel mesh the views are constrained like the pools,
-    so the gather stays shard-local on the head axis."""
+    Who still attends over it: tensor-parallel serving (chunks, verify and
+    the decode step; the views are constrained like the pools there, so
+    the gather stays shard-local on the head axis) and the decode step
+    where the paged kernel does not run (the CPU). One chip's chunks and
+    speculative verify walk the slot's key blocks instead
+    (``_paged_chunk_attend``), GPT's and LLaMA's alike."""
     shard = _paged_kv_pin()
     with jax.named_scope("kv_gather"):
         b, mbs = table.shape
@@ -312,8 +316,9 @@ def _paged_decode_attend(kv_cache, q, pools):
     (``kernels/pallas/paged_decode.py``: a TPU, or its test seam). The
     kernel reads each slot's live blocks straight from the just-written
     pools; returns the context [B, 1, nh, hd], or None for every other
-    caller (prefill chunks, speculative verify, TP serving, the CPU), which
-    attend over ``_paged_kv_gather``'s dense view."""
+    caller: prefill chunks and speculative verify (``_paged_chunk_attend``),
+    TP serving and the CPU's decode step (``_paged_kv_gather``'s dense
+    view)."""
     table, pos = kv_cache[2], kv_cache[3]
     if (q.shape[1] != 1 or jnp.ndim(pos) != 1
             or _PAGED_KV_SHARD["sharding"] is not None):
@@ -326,6 +331,22 @@ def _paged_decode_attend(kv_cache, q, pools):
         return paged_decode.paged_decode_attention(
             q, pools[0], pools[1], table, pos + 1,
             interpret=mode == "interpret")
+
+
+def _paged_chunk_attend(kv_cache, q, pools):
+    """One slot's chunk over the paged cache (a scalar cursor: prefill
+    chunks, speculative verify), no pool sharding installed: the queries
+    walk the key blocks the slot holds before ``write_end``
+    (``models/hybrid.py::walk_keys``), float32 at the default precision as
+    over the view. Returns the context [B, S, nh, hd], or None for the
+    callers that keep ``_paged_kv_gather``'s dense view: TP serving and the
+    decode step off the chip (per-slot cursors)."""
+    table, pos, write_end = kv_cache[2:]
+    if jnp.ndim(pos) != 0 or _PAGED_KV_SHARD["sharding"] is not None:
+        return None
+    from .hybrid import _positions, walk_grouped
+    return walk_grouped(q, pools, table, _positions(pos, q.shape[1]),
+                        write_end, pools[0].shape[2])
 
 
 class GPTAttention(nn.Layer):
@@ -387,8 +408,10 @@ class GPTAttention(nn.Layer):
           * paged — ``(pool_k, pool_v, table, pos, write_end)`` with
             [NB, BS, nh, hd] pools shared by all slots and a [B, mbs] int32
             block table. K/V lands at physical ``(table[b, p//BS], p%BS)``;
-            the read side gathers each row's blocks back into a contiguous
-            [B, mbs*BS, nh, hd] view via ``jnp.take`` on the block axis.
+            the read side is the decode kernel, a chunk's walk of the
+            slot's key blocks, or each row's blocks gathered back into a
+            contiguous [B, mbs*BS, nh, hd] view via ``jnp.take`` on the
+            block axis (``_paged_kv_gather`` says who takes which).
             Writes past ``write_end`` (padded chunk tails) or past the
             table redirect to trash block 0 so a shared or out-of-range
             block can never be corrupted by padding.
@@ -405,6 +428,8 @@ class GPTAttention(nn.Layer):
             pos = kv_cache[3]
             new_cache = _paged_kv_write(kv_cache, k, v)
             ctx = _paged_decode_attend(kv_cache, q, new_cache)
+            if ctx is None:
+                ctx = _paged_chunk_attend(kv_cache, q, new_cache)
             if ctx is not None:
                 return self.out_proj(Tensor(ctx.reshape(b, s, h))), new_cache
             k_buf, v_buf = _paged_kv_gather(*new_cache, kv_cache[2])

@@ -2,10 +2,11 @@
 ``longcat_flash.py``) share: a bag of raw parameters, the float32 RMS norm,
 positions and validity of a cached call, rotate-half rotary, the depthwise
 convolution that carries its last inputs between calls, where a call's
-positions land in a paged pool, and grouped-query attention over merged-row
-paged pools (``cache_spec.kv_layer(merged_rows=True)``) with the paged
-decode kernel where the call is a decode step. Raw-array math, no
-``Tensor`` inside.
+positions land in a paged pool, the walk of a prefill chunk's queries over
+the key blocks its slot holds (``walk_keys``; GPT and LLaMA take it too),
+and grouped-query attention over merged-row paged pools
+(``cache_spec.kv_layer(merged_rows=True)``) with the paged decode kernel
+where the call is a decode step. Raw-array math, no ``Tensor`` inside.
 """
 from __future__ import annotations
 
@@ -134,6 +135,130 @@ def write_rows(pool, table, rows, positions, end):
     return pool.at[phys, wpos % pool.shape[1]].set(rows.astype(pool.dtype))
 
 
+# float32 score elements of one trip of ``walk_keys``: its key block is sized
+# from them (as ``longcat_flash.SCORE_BLOCK`` sizes a block of heads). A loop
+# costs its layer some ten small launches a trip and the scheduler's overlap
+# with the next layer's weights, so few score rows want a long trip and many
+# a short one: 256 keys for 64 heads x 512 queries, 512 for 20 x 512, and
+# for 16 x 256 the whole 2,048-wide row in one trip, which is no loop at
+# all (the sweep and the cells on the chip: ``PERF.md`` section 6, PR 36)
+WALK_SCORES = 1 << 23
+_WALK_GEOMETRY: dict = {}
+
+
+def walk_geometry() -> dict:
+    """The geometry of the last traced walk: ``key_block``."""
+    return dict(_WALK_GEOMETRY)
+
+
+def key_block_for(score_rows, block_size, width):
+    """Key positions a trip of ``walk_keys`` takes for ``score_rows`` (heads
+    x queries) rows of scores: the power-of-two number of blocks that makes
+    ``WALK_SCORES`` score elements, within the table's ``width``
+    positions."""
+    blocks = max(1, WALK_SCORES // (score_rows * block_size))
+    blocks = 1 << (blocks.bit_length() - 1)
+    return block_size * min(blocks, -(-width // block_size))
+
+
+def walk_keys(table, positions, end, block_size, score_rows, fetch, score,
+              value):
+    """Causal softmax attention of ONE slot's chunk over the keys its
+    table row holds before ``end``: the row is walked in trips of
+    ``key_block`` positions (``key_block_for`` the call's ``score_rows``,
+    heads x queries), ``ceil(end / key_block)`` of them and no more (a
+    traced count: one program whatever ``end``), with the running maximum,
+    sum and unnormalised context of an online softmax in float32. Nothing
+    past the last trip is fetched, scored or normalised. Where one trip
+    holds the row there is no loop.
+
+    ``table [B, mbs]`` the block table's rows, ``positions [1, S]`` the
+    queries' (one scalar cursor), ``end`` the scalar end of what the slot
+    holds. What a geometry fetches and how it scores is the caller's:
+
+    * ``fetch(entries [B, key_block // block_size])`` -> what a trip holds
+      of the pools (position ``i`` of the trip at index ``i``);
+    * ``score(held)`` -> float32 scores ``[..., S, key_block]``, scaled;
+    * ``value(probs, held)`` -> float32 ``[..., S, D]``: ``probs [..., S,
+      key_block]`` (float32, not yet normalised) times the trip's values.
+
+    A key is seen by the queries at or after it (``-1e30`` elsewhere, so a
+    trip wholly in a query's future changes nothing for it). Returns the
+    context, float32 ``[..., S, D]``."""
+    from ..kernels.pallas.util import note_attention_kernel
+    if positions.shape[0] != 1:
+        raise ValueError("walk_keys serves one cursor a call; per-row "
+                         f"cursors came with positions {positions.shape}")
+    note_attention_kernel("key_walk")
+    key_block = key_block_for(score_rows, block_size,
+                              table.shape[1] * block_size)
+    _WALK_GEOMETRY.update(key_block=key_block)
+    per = key_block // block_size
+    # a width that is no multiple of a trip: the last trip's tail reads
+    # trash block 0, past every position a live query can see
+    table = jnp.pad(table, ((0, 0), (0, -table.shape[1] % per)))
+    trips = jnp.clip(-(-jnp.asarray(end, jnp.int32) // key_block), 1,
+                     table.shape[1] // per)
+    q_pos = positions[0][:, None]
+
+    def trip(t):
+        held = fetch(jax.lax.dynamic_slice_in_dim(table, t * per, per, 1))
+        key_pos = t * key_block + jnp.arange(key_block, dtype=jnp.int32)
+        return held, jnp.where(key_pos[None, :] <= q_pos, score(held), -1e30)
+
+    def step(t, carry):
+        top, total, ctx = carry
+        held, sc = trip(t)
+        new_top = jnp.maximum(top, jnp.max(sc, axis=-1))
+        probs = jnp.exp(sc - new_top[..., None])
+        keep = jnp.exp(top - new_top)
+        return (new_top, total * keep + jnp.sum(probs, axis=-1),
+                ctx * keep[..., None] + value(probs, held))
+
+    def shapes():
+        held, sc = trip(jnp.int32(0))
+        return sc[..., 0], value(sc, held)
+
+    rows, ctx = jax.eval_shape(shapes)
+    f32 = jnp.float32
+    start = (jnp.full(rows.shape, -1e30, f32), jnp.zeros(rows.shape, f32),
+             jnp.zeros(ctx.shape, f32))
+    if table.shape[1] == per:       # a trip holds the row: straight-line code
+        _, total, ctx = step(jnp.int32(0), start)
+    else:
+        _, total, ctx = jax.lax.fori_loop(0, trips, step, start)
+    return ctx / total[..., None]
+
+
+def walk_grouped(q, pools, table, positions, end, nkv, precision=None):
+    """``walk_keys`` for grouped-query K/V pools: ``q [B, S, nh, hd]`` over
+    ``pools`` = (K, V) whose block holds ``BS`` positions of ``nkv`` heads,
+    as ``[NB, BS, nkv, hd]`` or as merged rows ``[NB, BS * nkv, hd]``.
+    Float32 operands at ``precision``, scale ``hd ** -0.5``. Returns the
+    context [B, S, nh, hd] in ``q``'s dtype."""
+    b, s, nh, hd = q.shape
+    f32 = jnp.float32
+    bs_blk = math.prod(pools[0].shape[1:]) // (nkv * hd)
+    qh = q.reshape(b, s, nkv, nh // nkv, hd).astype(f32)
+
+    def fetch(entries):
+        with jax.named_scope("kv_gather"):
+            return tuple(jnp.take(p, entries, axis=0, mode="clip").reshape(
+                b, -1, nkv, hd).astype(f32) for p in pools)
+
+    def score(held):
+        return jnp.einsum("bqkgd,bmkd->bkgqm", qh, held[0],
+                          precision=precision) / math.sqrt(hd)
+
+    def value(probs, held):
+        return jnp.einsum("bkgqm,bmkd->bkgqd", probs, held[1],
+                          precision=precision)
+
+    ctx = walk_keys(table, positions, end, bs_blk, nh * s, fetch, score,
+                    value)
+    return jnp.moveaxis(ctx, 3, 1).reshape(b, s, nh, hd).astype(q.dtype)
+
+
 def _write_merged(cache, k, v, positions, end):
     """``gpt._paged_kv_write`` for pools whose block is one matrix of
     (position, KV head) rows: position ``p`` of head ``h`` lands at
@@ -174,8 +299,11 @@ def grouped_attention(q, k, v, cache, pos, positions, end):
     ``hd ** -0.5``. With ``cache`` = merged-row ``(pool_k, pool_v, table)``
     the call's K/V are written at their positions first (before
     ``end``) and the queries see everything the table holds up to their
-    own position. Returns (context [B, S, nh * hd] in ``q``'s dtype, the
-    pools after the write or None)."""
+    own position: a decode step through the paged kernel where it runs, one
+    slot's chunk (a scalar cursor) by ``walk_keys`` over the blocks before
+    ``end``, the rest (the decode step off the chip) over the gathered
+    view. Returns (context [B, S, nh * hd] in ``q``'s dtype, the pools
+    after the write or None)."""
     b, s, nh, hd = q.shape
     nkv = k.shape[2]
     new_cache = ctx = None
@@ -185,6 +313,9 @@ def grouped_attention(q, k, v, cache, pos, positions, end):
         we = end if end is not None else jnp.asarray(pos, jnp.int32) + s
         new_cache = _write_merged(cache, k, v, positions, we)
         ctx = _attend_merged(cache[2], pos, q, new_cache, nkv)
+        if ctx is None and jnp.ndim(pos) == 0:      # one slot's chunk
+            ctx = walk_grouped(q, new_cache, cache[2], positions, we, nkv,
+                               precision="highest")
         if ctx is None:
             with jax.named_scope("kv_gather"):
                 k_buf, v_buf = (jnp.take(p, cache[2], axis=0).reshape(

@@ -153,11 +153,12 @@ class LlamaAttention(nn.Layer):
         (queries fold onto their KV head). Inference-only raw-array math —
         mirrors GPTAttention._forward_cached, including the paged layout
         (``(pool_k, pool_v, table, pos, write_end)``: block-pooled K/V read
-        through the table: gpt._paged_kv_write, then the decode kernel or
-        gpt._paged_kv_gather's dense view)."""
+        through the table: gpt._paged_kv_write, then the decode kernel, a
+        chunk's walk of its key blocks or gpt._paged_kv_gather's dense
+        view)."""
         from ..core.tensor import Tensor
-        from .gpt import (_paged_decode_attend, _paged_kv_gather,
-                          _paged_kv_write)
+        from .gpt import (_paged_chunk_attend, _paged_decode_attend,
+                          _paged_kv_gather, _paged_kv_write)
 
         b, s, h = x.shape
         nh, nkv, hd = self.num_heads, self.num_kv, self.head_dim
@@ -171,6 +172,8 @@ class LlamaAttention(nn.Layer):
         if len(kv_cache) == 5:
             new_cache = _paged_kv_write(kv_cache, kv_, vv)
             ctx = _paged_decode_attend(kv_cache, qv, new_cache)
+            if ctx is None:
+                ctx = _paged_chunk_attend(kv_cache, qv, new_cache)
             if ctx is not None:
                 return self.o_proj(Tensor(ctx.reshape(b, s, h))), new_cache
             k_buf, v_buf = _paged_kv_gather(*new_cache, kv_cache[2])

@@ -43,8 +43,11 @@ writes its rows at their positions first (before ``write_end``); then
   Pallas kernel ``mla_decode`` (``kernels/pallas/paged_decode.py``), else
   over the gathered view;
 * a prefill chunk (and the full forward) runs the EXPANDED form: per-head
-  ``k_nope`` and ``v`` from the rows the table holds, heads in blocks
-  (cheaper than the absorbed form once many queries share the expansion).
+  ``k_nope`` and ``v`` from the cached rows (cheaper than the absorbed form
+  once many queries share the expansion). A chunk walks the rows its slot
+  holds before ``write_end`` in key blocks, all heads a trip
+  (``hybrid.walk_keys``), and never the rest of the table row; the full
+  forward, which has no table, takes its own rows whole, heads in blocks.
 
 Inference-only raw-array math (as ``qwen3_next.py``): serving through
 ``serving.DecodeEngine`` and a full forward. The Omni model's audio and
@@ -63,7 +66,7 @@ from ..core.tensor import Tensor
 from ..incubate.distributed.models.moe.held import HeldExpertsMoE
 from .cache_spec import ModelSpec, latent_layer
 from .hybrid import (_dot, _positions, _valid, _Weights, rms_norm, rope,
-                     write_rows)
+                     walk_keys, write_rows)
 
 __all__ = ["LongCatFlashConfig", "LongCatFlashModel",
            "LongCatFlashForCausalLM", "longcat_flash_tiny"]
@@ -214,6 +217,47 @@ class LatentAttention(_Weights):
             ctx = jnp.moveaxis(ctx, 0, 2)
         return ctx.reshape(b, s, nh * self.vd)
 
+    def _walked(self, q_nope, q_rope, pool, table, positions, end):
+        """One slot's chunk: the EXPANDED form block by block
+        (``hybrid.walk_keys``): a trip takes its rows of ``pool`` through
+        ``table``, expands ``k_nope | v`` for them from their latent, all
+        heads at once, and scores as ``_expanded`` does (operands in the
+        rows' dtype, float32 accumulation and softmax). Returns the
+        context [B, S, nh * vd]."""
+        b, s, nh = q_nope.shape[:3]
+        dt = q_nope.dtype
+        prec = "highest" if dt == jnp.float32 else None
+        f32 = jnp.float32
+        w = self._kv_b()
+
+        def fetch(entries):
+            with jax.named_scope("kv_gather"):
+                rows = jnp.take(pool, entries, axis=0, mode="clip").reshape(
+                    b, -1, pool.shape[2])
+            kv = jnp.einsum("bmr,rhd->bmhd", rows[..., :self.rank], w,
+                            precision=prec,
+                            preferred_element_type=f32).astype(dt)
+            return kv, rows[..., self.rank:self.rank + self.rot]
+
+        def score(held):
+            kv, k_rope = held
+            return (jnp.einsum("bqhd,bmhd->bhqm", q_nope,
+                               kv[..., :self.nope], precision=prec,
+                               preferred_element_type=f32)
+                    + jnp.einsum("bqhd,bmd->bhqm", q_rope, k_rope,
+                                 precision=prec, preferred_element_type=f32)
+                    ) * self.scale
+
+        def value(probs, held):
+            return jnp.einsum("bhqm,bmhd->bhqd", probs.astype(dt),
+                              held[0][..., self.nope:], precision=prec,
+                              preferred_element_type=f32)
+
+        ctx = walk_keys(table, positions, end, pool.shape[1], nh * s, fetch,
+                        score, value)                       # [B,nh,S,vd]
+        return jnp.moveaxis(ctx, 1, 2).astype(dt).reshape(b, s,
+                                                          nh * self.vd)
+
     def _absorbed(self, q_nope, q_rope, pool, table, pos):
         """The decode step: ``[q_nope Wkvb_k^T | q_rope]`` against the
         cached rows, context over their latent lanes, then ``Wkvb_v``.
@@ -277,10 +321,8 @@ class LatentAttention(_Weights):
                                          pos)
             else:
                 with jax.named_scope("mla_prefill"):
-                    with jax.named_scope("kv_gather"):
-                        held = jnp.take(new_pool, table, axis=0).reshape(
-                            b, -1, lanes)
-                    ctx = self._expanded(q_nope, q_rope, held, positions)
+                    ctx = self._walked(q_nope, q_rope, new_pool, table,
+                                       positions, we)
         return _dot(ctx, self.o_proj.value()), \
             (None if new_pool is None else (new_pool,))
 

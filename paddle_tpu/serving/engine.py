@@ -553,6 +553,10 @@ class DecodeEngine:
         self._decode_exe = None
         self._decode_attention = None
         self._prefill_attention: dict = {}   # chunk length -> its path
+        # ... and, where that path walks the slot's key blocks, the key
+        # positions of a trip; what the chunks read of their table rows
+        self._prefill_key_block: dict = {}
+        self.kv_walked = self.kv_table = 0
         self._decode_geometry = {}
         self._decode_state = None
         self._verify_exe = None
@@ -992,6 +996,9 @@ class DecodeEngine:
                                     out_shardings=self._pool_out_shardings())
         self._prefill_exes[sc] = exe
         self._prefill_attention[sc] = self._attention_path(traced)
+        if self._prefill_attention[sc] == "key_walk":
+            from ..models.hybrid import walk_geometry
+            self._prefill_key_block[sc] = walk_geometry()["key_block"]
         self._minted("prefill", sc, time.time() - t0, exe=exe, tokens=sc)
         return exe
 
@@ -1751,6 +1758,19 @@ class DecodeEngine:
                          state_bytes=int(slots) * self._state_bytes)
         return attrs
 
+    def _kv_walked(self, sc: int, end: int) -> int:
+        """Key positions the chunk executable ``sc`` attends over for a
+        slot that holds ``end``: whole trips of the walk
+        (``models/hybrid.py::walk_keys``), or the table row's width where
+        the chunk attends over the gathered view. Counted for
+        ``stats()["prefill_attention"]``."""
+        width = self._mbs * self.block_size
+        kb = self._prefill_key_block.get(sc)
+        walked = -(-end // kb) * kb if kb else width
+        self.kv_walked += walked
+        self.kv_table += width
+        return walked
+
     def _chunk_len(self, n: int) -> int:
         """Shape of the chunk executable serving a length-n prompt: the
         fixed ``prefill_chunk``, else the monolithic bucket for n (sized as
@@ -2241,6 +2261,7 @@ class DecodeEngine:
             first = False
             with _trace.span("engine/prefill_call",
                              path=self._prefill_attention[c.sc],
+                             kv_walked=self._kv_walked(c.sc, c.end),
                              **self._cache_attrs(1, c.end)) as c.span:
                 self._pools, c.tok0, c.ok = self._prefill_exes[c.sc](
                     self._leaf_values(), self._pools, *c.args)
@@ -2728,6 +2749,15 @@ class DecodeEngine:
             # (kernels/pallas/paged_decode.py) or "gather" (the dense view);
             # None before its first trace
             "decode_attention": self._decode_attention,
+            # the chunk executables' attention ("key_walk": the slot's key
+            # blocks before the call's end; "gather": the whole table row
+            # as a view) and the share of their table rows the chunks read
+            "prefill_attention": {
+                "path": "+".join(sorted(set(
+                    self._prefill_attention.values()))) or None,
+                "kv_walked": self.kv_walked, "kv_table": self.kv_table,
+                "share": round(self.kv_walked / self.kv_table, 4)
+                if self.kv_table else None},
             # and with which recurrent-state step: the Pallas kernel's name
             # ("ssd_decode", "gdn_decode") or "scan"; None without state
             "decode_state": self._decode_state,

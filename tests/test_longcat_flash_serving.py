@@ -317,11 +317,13 @@ def test_the_latent_walk_rounds_its_chunk_up_to_eight_pages():
 
 # --------------------------------------------------- spans and refusals
 
-def test_call_spans_say_which_attention_and_count_latent_rows(tiny):
+def test_call_spans_say_which_attention_and_count_latent_rows(tiny,
+                                                              monkeypatch):
     """`path` on every decode and chunk call (the latent kernel names
-    itself; the view is `gather`), `kv_bytes` = live context x 4 rows of
-    24 numbers, `moe_zero` beside the other three on the finish span, and
-    the executables hold the new named scopes."""
+    itself; the view is `gather`; a chunk walks its slot's key blocks:
+    `key_walk`, with the keys it walked beside it), `kv_bytes` = live
+    context x 4 rows of 24 numbers, `moe_zero` beside the other three on
+    the finish span, and the executables hold the new named scopes."""
     from paddle_tpu.monitor import trace
     prog, _, model = tiny
     fam, _ = family()
@@ -342,7 +344,12 @@ def test_call_spans_say_which_attention_and_count_latent_rows(tiny):
         chunks = trace.spans(t0, t1, "engine/prefill_call")
         assert [s.attrs["kv_bytes"] for s in chunks] == \
             [per_token * n for n in (CHUNK, 19)]
-        assert {s.attrs["path"] for s in chunks} == {"gather"}
+        assert {s.attrs["path"] for s in chunks} == {"key_walk"}
+        # a trip of the walk holds this engine's whole table row
+        assert [s.attrs["kv_walked"] for s in chunks] == [96, 96]
+        assert eng.stats()["prefill_attention"] == {
+            "path": "key_walk", "kv_walked": 192, "kv_table": 192,
+            "share": 1.0}
         fins = trace.spans(t0, t1, "engine/decode_finish")
         for s in fins:
             assert s.attrs["moe_assignments"] == 2 * 4      # layers x top-k
@@ -355,6 +362,24 @@ def test_call_spans_say_which_attention_and_count_latent_rows(tiny):
         "mla_project", "latent_write", "mla_decode", "dense_ffn",
         "zero_experts", "moe_route", "moe_experts"))
     assert "mla_prefill" in eng._prefill_exes[CHUNK].as_text()
+    # trips of one chunk's length (4 heads x 16 queries x 16 keys): a
+    # chunk walks whole trips up to its end, not the 96 of the row
+    from paddle_tpu.models import hybrid
+    monkeypatch.setattr(hybrid, "WALK_SCORES", 4 * CHUNK * CHUNK)
+    eng = engine(prog)
+    assert eng.stats()["prefill_attention"]["share"] is None
+    t0 = time.perf_counter()
+    eng.submit(list(range(1, 22)), max_new_tokens=2)
+    eng.submit(list(range(30, 30 + 2 * CHUNK)), max_new_tokens=2)
+    eng.run()
+    chunks = trace.spans(t0, time.perf_counter(), "engine/prefill_call")
+    assert sorted((s.attrs["kv_bytes"] // per_token, s.attrs["kv_walked"])
+                  for s in chunks) == [(CHUNK, CHUNK), (CHUNK, CHUNK),
+                                       (21, 2 * CHUNK),
+                                       (2 * CHUNK, 2 * CHUNK)]
+    assert eng.stats()["prefill_attention"] == {
+        "path": "key_walk", "kv_walked": 6 * CHUNK, "kv_table": 4 * 96,
+        "share": 0.25}
 
 
 def test_what_a_latent_entry_cannot_do_yet_is_refused_by_name(tiny):
@@ -405,7 +430,7 @@ def test_the_other_models_are_handed_what_they_were():
     assert {s.attrs["path"] for s in trace.spans(
         t0, t1, "engine/decode_call")} == {"gather"}
     assert {s.attrs["path"] for s in trace.spans(
-        t0, t1, "engine/prefill_call")} == {"gather"}
+        t0, t1, "engine/prefill_call")} == {"key_walk"}
     assert eng._tok_len == 2 and "moe" not in eng.stats()
     qwen = Qwen3NextForCausalLM(qwen3_next_tiny())
     qwen.eval()
