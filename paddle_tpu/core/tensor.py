@@ -14,15 +14,24 @@ monkey_patch_math_varbase pattern) to keep this module cycle-free.
 """
 from __future__ import annotations
 
+import sys
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..monitor import trace as _trace
 from . import dtype as dtypes
 from .device import Place, get_default_place
 from .lazy import LazyArray
+
+# what the not-ready waits of ``Tensor.numpy()`` have been taking
+# (``trace.book``), a mean for each line of the caller's that waits (a
+# loop's ``float(loss)`` and the fetch of an evaluation that takes a
+# second are two lines, and neither is judged by the other): a wait far
+# over its own line's mean is sealed as a host/stall record
+_SYNC_MEANS: dict = {}
 
 
 class Tensor:
@@ -77,8 +86,20 @@ class Tensor:
         return d
 
     def numpy(self) -> np.ndarray:
-        self.value()  # force + cache any pending lazy computation
-        return np.asarray(self._data)
+        d = self.value()  # force + cache any pending lazy computation
+        ready = getattr(d, "is_ready", None)
+        if ready is None or ready():
+            return np.asarray(d)
+        # the host blocks here until the device has made the value: the
+        # one place a training loop waits (``float(loss)``)
+        with _trace.span("tensor/sync", bytes=d.nbytes) as sync:
+            out = np.asarray(d)
+        f = sys._getframe(1)
+        while f.f_back is not None and f.f_code.co_filename == __file__:
+            f = f.f_back          # out of item() / __float__ / tolist()
+        at = f"{f.f_code.co_filename}:{f.f_lineno}"
+        _trace.book(sync, [(at, sync.t1 - sync.t0)], _SYNC_MEANS, at=at)
+        return out
 
     def item(self, *args):
         if args:

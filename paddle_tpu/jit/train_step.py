@@ -774,6 +774,9 @@ class TrainStep:
         self._trace_n += 1
         with _trace.start_trace("train_step", self._trace_n, "step",
                                 step=self._trace_n) as t:
+            # what a stall record of this step's value fetch
+            # (``tensor/sync``) takes its deltas from
+            _trace.host_clocks()
             self._cur_trace = t
             try:
                 return self._call_impl(inputs)

@@ -30,7 +30,24 @@ recorder: ``RING_CAPACITY`` spans, on by default, no knob) that
 ``enable()`` turned one on. ``ring(False)`` exists so the ring's cost can
 be measured, not as a mode. In the ring and as an annotation a span of a
 trace is ``<trace>/<span>`` (``request/queue``, ``train_step/dispatch``);
-inside its trace, and so in the sink, it keeps the short name.
+inside its trace, and so in the sink, it keeps the short name. The ring
+says what it has lost: ``evicted()`` counts the spans pushed out since the
+process began, ``oldest()`` is where what it still holds begins.
+
+The same ring holds what the HOST did to a step, so that a step that took
+seconds with the device idle can say why. ``host_clocks()`` reads, once a
+step, what the operating system says of the stepping thread (CPU
+time of the process and of the thread, run-queue wait, involuntary
+switches, major faults; the pressure totals at most once a second). Two
+listeners, installed with this module and silent until they fire, record
+``gc/collect`` (a collection of ``GC_PAUSE_S`` or more) and
+``jax/compile`` (a backend compile or a compilation-cache read). A site
+that knows what its step launched (the serving engine its calls,
+``Tensor.numpy()`` its own wait) hands the finished step to ``book()``,
+which keeps the running mean of each kind of call and, where the step took
+far longer than their sum (``stalled``), seals ONE ``host/stall`` record
+over it (``stall``): where the time went (``site``), what the clocks moved
+by, and what of it was a collection or a compile.
 
 The sink (opt-in: ``enable()`` / ``PADDLE_TRACE``) is the Dapper-style
 part, scaled down to one process. Sampling is head-based and governs the
@@ -49,24 +66,41 @@ tracer start, so they line up with the monitor's ``ts`` fields.
 from __future__ import annotations
 
 import atexit
+import gc
 import itertools
 import os
+import resource
 import threading
 import time
+import warnings
 from collections import deque, namedtuple
 from typing import Dict, Optional
 
+from jax import monitoring as _jax_monitoring
 from jax.profiler import TraceAnnotation
 
 from .sink import JsonlSink
 
-__all__ = ["TRACE_SCHEMA_VERSION", "RING_CAPACITY", "Span", "SpanRecord",
-           "Tracer", "span", "record", "spans", "ring", "start_trace",
-           "enable", "disable", "enabled", "get", "current_trace_id",
-           "escalate"]
+__all__ = ["TRACE_SCHEMA_VERSION", "RING_CAPACITY", "STALL_FACTOR",
+           "STALL_EXCESS_S", "STALL_MIN_SAMPLES", "Span", "SpanRecord",
+           "Tracer", "span", "record", "spans", "ring", "evicted", "oldest",
+           "host_clocks", "stalled", "stall", "book", "start_trace", "enable",
+           "disable", "enabled", "get", "current_trace_id", "escalate"]
 
 TRACE_SCHEMA_VERSION = 1
-RING_CAPACITY = 32768
+# the fullest cell of the benchmark (chat: a step every 7 ms, nine spans a
+# step and the requests' own: up to 45 k spans in a 51 s window) fills a
+# third of it; PERF.md section 7 item 5 has the counts and the bytes
+RING_CAPACITY = 131072
+# a step has stalled where its wall time is over what its calls have been
+# taking by BOTH this factor and this many seconds, and every kind of call
+# involved has been seen this often (PERF.md section 6, PR 38, says what
+# the chip's clean runs read against them)
+STALL_FACTOR = 2.0
+STALL_EXCESS_S = 0.25
+STALL_MIN_SAMPLES = 8
+GC_PAUSE_S = 1e-3             # a collection shorter than this is not recorded
+PRESSURE_EVERY_S = 1.0        # the pressure files are read at most this often
 ANNOTATION_PREFIX = "paddle/"
 
 # the sink session, when one is enabled (sampling, JSONL, escalation)
@@ -77,6 +111,7 @@ _lock = threading.Lock()
 # ---- the flight recorder: every finished span of the process
 _ring: deque = deque(maxlen=RING_CAPACITY)
 _ring_on = True
+_evicted = [0]                    # spans the full ring has pushed out
 _span_ids = itertools.count(1)
 _tls = threading.local()          # .stack: this thread's open scoped spans
 # set by paddle_tpu.profiler while a Profiler records: (name, t0, t1) -> None
@@ -175,6 +210,8 @@ class Span:
         if _ring_on:
             # plain tuples of plain values: the collector stops tracking
             # them, so a full ring costs later collections nothing
+            if len(_ring) == _ring.maxlen:
+                _evicted[0] += 1
             _ring.append((self.name, self.t0, self.t1, self.span_id,
                           self.parent_id, self.key, self.attrs))
         emit = _profiler_emit
@@ -332,7 +369,6 @@ class Tracer:
         self._last_trace_id: Optional[str] = None
         self.traces_started = 0
         self.traces_sampled = 0
-        self.spans_written = 0
         self._escalated = 0
         self._escalate_reasons: Dict[str, int] = {}
         self._via_monitor = False
@@ -429,7 +465,6 @@ class Tracer:
                 rec["events_dropped"] = sp.events_dropped
             if self.sink is not None:
                 self.sink.write(rec)
-                self.spans_written += 1
         summary = {"v": TRACE_SCHEMA_VERSION, "kind": "trace",
                    "trace": tr.trace_id, "name": tr.trace_name,
                    "trace_kind": tr.kind, "ts": self.wall(tr.t0),
@@ -443,14 +478,6 @@ class Tracer:
             summary["attrs"] = tr.attrs
         if self.sink is not None:
             self.sink.write(summary)
-
-    # ------------------------------------------------------------- floating
-
-    def floating(self, name: str, t0: float, t1: float,
-                 adopt_kind: str = "step", **attrs):
-        """A completed span observed OUTSIDE any trace, for the next trace
-        of ``adopt_kind`` to adopt: ``record()`` under its older name."""
-        record(name, t0, t1, adopt_kind=adopt_kind, **attrs)
 
     # ------------------------------------------------------------ WARN hooks
 
@@ -573,6 +600,245 @@ def ring(on: bool):
     this exists so that its cost can be measured, not as a mode."""
     global _ring_on
     _ring_on = bool(on)
+
+
+def evicted() -> int:
+    """Spans the full ring has pushed out since the process began (counted
+    without a lock: exact on one thread). 0: it holds every span."""
+    return _evicted[0]
+
+
+def oldest() -> Optional[float]:
+    """``t0`` of the oldest span the ring holds; None where it holds
+    none. Spans enter as they END: a window that starts after it is held
+    whole but for what ended inside that one span."""
+    try:
+        return _ring[0][1]
+    except IndexError:
+        return None
+
+
+# ------------------------------------------------- what the host did to a step
+
+_RUSAGE_THREAD = getattr(resource, "RUSAGE_THREAD", resource.RUSAGE_SELF)
+# (stamp, "some" totals in us of cpu, io, memory): the last reading
+_pressure = None
+
+
+class _Fd:
+    """A file descriptor that closes with the thread that opened it."""
+
+    __slots__ = ("fd",)
+
+    def __init__(self, fd: int):
+        self.fd = fd
+
+    def __del__(self):
+        os.close(self.fd)
+
+
+def _read_clocks() -> tuple:
+    sched = getattr(_tls, "sched", None)
+    if sched is None:
+        try:
+            sched = _Fd(os.open("/proc/thread-self/schedstat", os.O_RDONLY))
+        except OSError:
+            sched = False
+        _tls.sched = sched
+    wait_ns = None
+    if sched:
+        try:    # "<on-cpu ns> <run-queue wait ns> <timeslices>"
+            wait_ns = int(os.pread(sched.fd, 64, 0).split()[1])
+        except (OSError, IndexError, ValueError):
+            pass
+    ru = resource.getrusage(_RUSAGE_THREAD)
+    return (time.process_time(), time.thread_time(), wait_ns,
+            ru.ru_nivcsw, ru.ru_majflt)
+
+
+def _read_pressure() -> tuple:
+    out = []
+    for what in ("cpu", "io", "memory"):
+        try:    # "some avg10=0.00 avg60=0.00 avg300=0.00 total=<us>"
+            with open(f"/proc/pressure/{what}", "rb") as f:
+                out.append(int(f.readline().rsplit(b"total=", 1)[1]))
+        except (OSError, IndexError, ValueError):
+            out.append(None)
+    return tuple(out)
+
+
+def host_clocks() -> Optional[tuple]:
+    """What the operating system says of the calling thread, read once a
+    step where the host has time for four system calls (the engine inside
+    ``engine/collect`` before it waits, the device busy with what was just
+    launched; ``train_step/call`` on entry): (CPU seconds of the process,
+    CPU seconds of the thread, nanoseconds the thread has waited on a run
+    queue or None where ``/proc/thread-self/schedstat`` is not there, its
+    involuntary context switches, its major page faults). The thread's
+    last TWO readings are kept, each with its instant: a ``host/stall``
+    record takes its deltas from the newest that was made before the
+    stalled step began, so that they cover the whole of it wherever in the
+    step this was called (and say over how long, ``clocks_s``). The
+    pressure totals (``/proc/pressure/{cpu,io,memory}``) are read beside
+    it at most once in ``PRESSURE_EVERY_S``. Nothing is read, and None
+    returned, while the ring is off."""
+    global _pressure
+    if not _ring_on:
+        return None
+    now = time.perf_counter()
+    clocks = _read_clocks()
+    last = getattr(_tls, "clocks", None)
+    _tls.clocks = (last and last[-1], (now, clocks))
+    if _pressure is None or now - _pressure[0] >= PRESSURE_EVERY_S:
+        _pressure = (now, _read_pressure())
+    return clocks
+
+
+def _on_gc(phase, info, _t0=[0.0]):
+    if phase == "start":
+        _t0[0] = time.perf_counter()
+        return
+    t1 = time.perf_counter()
+    if t1 - _t0[0] >= GC_PAUSE_S:
+        record("gc/collect", _t0[0], t1, generation=info["generation"],
+               collected=info["collected"])
+
+
+def _on_jax_duration(event: str, duration_secs: float, **_):
+    if event.endswith("backend_compile_duration") \
+            or event.endswith("cache_retrieval_time_sec"):
+        t1 = time.perf_counter()
+        record("jax/compile", t1 - duration_secs, t1,
+               event=event.rsplit("/", 1)[-1])
+
+
+# what the two listeners record: children of whatever span was open
+_LISTENED = ("gc/collect", "jax/compile")
+gc.callbacks.append(_on_gc)
+_jax_monitoring.register_event_duration_secs_listener(_on_jax_duration)
+
+
+def stalled(wall_s: float, expected_s: float) -> bool:
+    """Whether a step of ``wall_s`` seconds, whose calls have been taking
+    ``expected_s``, has stalled: over by both the factor and the excess."""
+    return wall_s > STALL_FACTOR * expected_s \
+        and wall_s - expected_s > STALL_EXCESS_S
+
+
+def book(step: Span, calls: list, means: dict, **attrs) -> Optional[Span]:
+    """Hold a finished ``step`` against what its ``calls`` ([(kind,
+    seconds)]: what each took this time) have been taking: ``means`` is the
+    site's own ``{kind: [samples, running mean]}``. Far over their sum
+    (``stalled``), with every kind seen ``STALL_MIN_SAMPLES`` times (a
+    compile, an opening step of first chunks are no stall), the step is
+    sealed as ONE ``host/stall`` record, which is returned, and its
+    intervals do not move the means; else they do. Nothing is held or
+    kept while the ring is off."""
+    if not _ring_on:
+        return None
+    expected = 0.0
+    for kind, _ in calls:
+        m = means.get(kind)
+        if m is None or m[0] < STALL_MIN_SAMPLES:
+            break
+        expected += m[1]
+    else:
+        if calls and stalled(step.t1 - step.t0, expected):
+            counts: dict = {}
+            for kind, _ in calls:
+                counts[kind] = counts.get(kind, 0) + 1
+            return stall(step, expected, counts, **attrs)
+    for kind, took in calls:
+        m = means.get(kind)
+        if m is None:
+            means[kind] = [1, took]
+        else:
+            m[0] += 1
+            m[1] += (took - m[1]) / min(m[0], 16)
+    return None
+
+
+def _covered(found: list, name: str) -> float:
+    """Seconds that the spans called ``name`` cover together (a cache read
+    lies inside its backend compile: counted once)."""
+    total, edge = 0.0, float("-inf")
+    for s in sorted((s for s in found if s.name == name),
+                    key=lambda s: s.t0):
+        total += max(0.0, s.t1 - max(s.t0, edge))
+        edge = max(edge, s.t1)
+    return round(total, 6)
+
+
+def _moved(keys, then, now, scales) -> dict:
+    """{key: how far a counter moved, scaled}; None where either reading
+    is missing."""
+    return {k: None if a is None or b is None else round((b - a) * sc, 6)
+            for k, a, b, sc in zip(keys, then, now, scales)}
+
+
+def stall(step: Span, expected_s: float, calls: dict, **attrs) -> Span:
+    """Seal the ONE ``host/stall`` record of ``step`` (a finished span of
+    the calling thread for which ``stalled`` held): ``site`` (the longest
+    span under it that has none under itself), ``wall_s``, ``expected_s``,
+    ``excess_s``, ``calls`` ({kind: count} of what it launched), how far
+    the thread's clocks moved since ``host_clocks()`` last read them
+    BEFORE the step began (``clocks_s`` ago, so over all of the step and
+    what lay between: ``cpu_process_s``, ``cpu_thread_s``,
+    ``runq_wait_s``, ``nivcsw``, ``majflt``), how far the pressure totals
+    moved since their last reading (``pressure_s`` ago:
+    ``pressure_cpu_s``, ``pressure_io_s``, ``pressure_memory_s``), the
+    seconds of ``gc/collect`` and ``jax/compile`` records inside the step
+    (any thread's: a collection holds every thread), and its three
+    ``longest`` such spans (a ``gc/collect`` or ``jax/compile`` record is
+    a child of whatever span was open and makes no parent of it: the span
+    a compile ran in is still the site). None stands where the system
+    does not say.
+    Open traces are escalated (``stall``), and the record is warned of and
+    handed to the monitor where one is on, as a dispatch hang is."""
+    global _pressure
+    now = time.perf_counter()
+    found = spans(step.t0, step.t1)
+    under, parents = {step.span_id}, set()
+    for s in reversed(found):           # a parent ends after its children
+        if s.parent_id in under and s.name not in _LISTENED:
+            under.add(s.span_id)
+            parents.add(s.parent_id)
+    longest = sorted(((s.t1 - s.t0, s.name) for s in found
+                      if s.span_id in under and s.span_id not in parents),
+                     reverse=True)[:3]
+    wall = step.t1 - step.t0
+    rec = dict(site=longest[0][1] if longest else step.name,
+               wall_s=round(wall, 6), expected_s=round(expected_s, 6),
+               excess_s=round(wall - expected_s, 6), calls=calls,
+               step=step.span_id)
+    # the newest reading made before the step began
+    then = next((r for r in reversed(getattr(_tls, "clocks", None) or ())
+                 if r and r[0] <= step.t0), (None, (None,) * 5))
+    rec["clocks_s"] = then[0] and round(now - then[0], 6)
+    rec.update(_moved(("cpu_process_s", "cpu_thread_s", "runq_wait_s",
+                       "nivcsw", "majflt"), then[1], _read_clocks(),
+                      (1, 1, 1e-9, 1, 1)))
+    p0, _pressure = _pressure or (None, (None,) * 3), \
+        (now, _read_pressure())
+    rec["pressure_s"] = p0[0] and round(now - p0[0], 6)
+    rec.update(_moved(("pressure_cpu_s", "pressure_io_s",
+                       "pressure_memory_s"), p0[1], _pressure[1],
+                      (1e-6,) * 3))
+    rec["gc_s"] = _covered(found, "gc/collect")
+    rec["compile_s"] = _covered(found, "jax/compile")
+    rec["longest"] = [[name, round(dur, 6)] for dur, name in longest]
+    rec.update(attrs)
+    sp = record("host/stall", step.t0, step.t1, **rec)
+    escalate("stall")
+    from . import emit        # the package imports this module first
+    emit("host_stall", **rec)
+    warnings.warn(
+        f"host stall: {step.name} took {wall:.3f}s where its calls have "
+        f"been taking {expected_s:.3f}s; most of it in {rec['site']} "
+        f"(run-queue wait {rec['runq_wait_s']}s, process CPU "
+        f"{rec['cpu_process_s']}s, gc {rec['gc_s']}s, compile "
+        f"{rec['compile_s']}s)", RuntimeWarning, stacklevel=4)
+    return sp
 
 
 # ------------------------------------------------------------- the sink session

@@ -591,6 +591,14 @@ class DecodeEngine:
         # the rebuilt ones lost theirs (stats()["plan"])
         self.plan_counts = {"prepared": 0, "rebuilt": 0, "sync": 0}
         self.plan_causes: dict = {}
+        # what each kind of call ("decode", "chunk<sc>", "verify") has been
+        # taking ({kind: [samples, running mean]}: of a step's time from
+        # its first launch to the end of its wait, an equal share a call),
+        # and this step's (kind, seconds); a step far over their sum is
+        # sealed as a host/stall record (trace.book; stats()["stalls"])
+        self._call_means: dict = {}
+        self._step_calls: list = []
+        self.stalls = 0
         # cumulative speculation counters (stats() + monitor mirrors)
         self.spec_steps = 0        # verify dispatches
         self.spec_drafted = 0      # tokens proposed by the drafter
@@ -1280,8 +1288,12 @@ class DecodeEngine:
         step that made it (``_step_planned``, which also says what a
         drafter changes).
         """
+        calls = self._step_calls = []     # (a step that raised left its own)
         with _trace.span("engine/step") as whole:
             finished = self._step(whole)
+        if calls and _trace.book(whole, calls, self._call_means,
+                                 engine=self.engine_id) is not None:
+            self.stalls += 1
         mon = _monitor._active
         if mon is not None:
             # goodput bracket: the whole scheduler iteration; the executable
@@ -1704,9 +1716,10 @@ class DecodeEngine:
         exception or detected hang routes through ``_fail_engine`` so the
         engine fails loudly with consistent state. ``upload()`` makes the
         executable's device arguments under one more
-        ``engine/decode_prepare`` span, and ``call(*args)`` dispatches,
-        waits and reads back under ``engine/decode_call``, which is
-        therefore dispatch, device run and read-back alone.
+        ``engine/decode_prepare`` span, and ``call(span, *args)``
+        dispatches, waits and reads back under ``engine/decode_call``
+        (``span``), which is therefore dispatch, device run and read-back
+        alone.
         ``call`` must COMMIT the donated pools to the engine itself before
         returning — on the hang path the dispatch completed (the old
         buffers are donated away), so the commit must not depend on this
@@ -1724,7 +1737,7 @@ class DecodeEngine:
             with _trace.span("engine/decode_prepare"):
                 args = upload()
             with _trace.span("engine/decode_call") as call_span:
-                out = call(*args)
+                out = call(call_span, *args)
                 del args           # released inside the span that used them
         except Exception as e:
             if wd is not None:
@@ -2051,7 +2064,7 @@ class DecodeEngine:
         if mon is not None:
             mon.serve_admitted(req.t_first_token - req.t_submit, sc,
                                st.prefill_s)
-        req._phase.set(chunks=st.chunks, exe_s=round(st.prefill_s, 6))
+        req._phase.set(chunks=st.chunks)
         req._trace_phase("decode")
         req._trace.set(ttft_s=round(req.t_first_token - req.t_submit, 6))
         if req._stop_hit():
@@ -2199,9 +2212,17 @@ class DecodeEngine:
                 # the wait and the step's one read-back; inside the armed
                 # window: a hang in the device sync is a hang in the call
                 d = plan.decode
-                got = jax.device_get(
+                # what the NEXT step's stall record takes its deltas from:
+                # read here, where the host is about to wait and the
+                # device is busy, not at the step's entry, where the
+                # device waits for the launch (the four system calls take
+                # 60 us on the chip's host)
+                _trace.host_clocks()
+                got = self._wait_fetch(
                     ([(c.tok0, c.ok) for c in launched],
-                     None if d is None else (d.picked, d.ok)))
+                     None if d is None else (d.picked, d.ok)),
+                    tuple(c.span.span_id for c in launched)
+                    + (() if d is None else (d.span.span_id,)))
         except Exception as e:
             if wd is not None:
                 # a hang that then RAISED: the raise is the failure that
@@ -2225,6 +2246,16 @@ class DecodeEngine:
         if plan.decode is not None:
             edges.append(plan.decode.span.t0)
         edges.append(wait.t1)
+        # for the stall record, each call an equal share of the step's
+        # device time: which interval a millisecond lands in moves with
+        # where the host happened to block (a chunk's time falls to the
+        # decode launched behind it), their sum does not
+        kinds = [f"chunk{c.sc}" for c in launched]
+        if plan.decode is not None:
+            kinds.append("decode")
+        if kinds:
+            share = (edges[-1] - edges[0]) / len(kinds)
+            self._step_calls.extend((kind, share) for kind in kinds)
         for i, (c, (tok0, ok)) in enumerate(zip(launched, got[0])):
             with _trace.span("engine/prefill_host", slot=c.slot,
                              tokens=c.end - c.p0):
@@ -2234,6 +2265,33 @@ class DecodeEngine:
         if plan.decode is not None:
             self._decode_done(plan.decode.rows, got[1],
                               (edges[-2], edges[-1]), finished)
+
+    def _wait_fetch(self, outs, calls: tuple):
+        """The two halves of a read-back, a span each. ``engine/wait``
+        [``calls``: the ids of the call spans whose results these are]:
+        the host copies of every array of ``outs`` are started (they queue
+        behind the compute, so the device never waits to be asked), then
+        the host blocks on the LAST array, an output of the last call
+        launched; it ends when the program knows the device has finished.
+        ``engine/fetch`` [``arrays``, ``bytes``]: the read-back itself,
+        as the host sees it (``np.asarray`` of each: what
+        ``jax.device_get`` does after starting the copies, at a third of
+        its host time). Returns ``outs`` as numpy arrays. Between the two
+        lies what the span layer takes to seal one span and open the next
+        (the fetch span is made before the wait, so nothing else does):
+        microseconds, tens of them while a profile is taken, with the
+        device idle; the step's ``engine/collect`` holds it, neither
+        child does."""
+        leaves = jax.tree_util.tree_leaves(outs)
+        fetch = _trace.span("engine/fetch", arrays=len(leaves),
+                            bytes=sum(a.nbytes for a in leaves))
+        with _trace.span("engine/wait", calls=calls):
+            for a in leaves:
+                a.copy_to_host_async()
+            if leaves:
+                jax.block_until_ready(leaves[-1])
+        with fetch:
+            return jax.tree_util.tree_map(np.asarray, outs)
 
     def _arm(self, kind: str, bucket, first: bool):
         """Fire the chaos seam for one launch, inside the watchdog's
@@ -2579,7 +2637,7 @@ class DecodeEngine:
                     ids[0, 1:1 + k] = drafts
                 end = p + 1 + k
                 src, dst = self._cow_args(copies)
-                prep.set(drafted=k, cow=len(copies))
+                prep.set(cow=len(copies))
 
             def upload():
                 return (self._dev(self._pager.tables), self._dev(ids),
@@ -2587,16 +2645,17 @@ class DecodeEngine:
                         self._dev(jnp.int32(end)), src, dst,
                         self._next_key())
 
-            def run(*args):
+            def run(call, *args):
                 self._pools, picked, ok = exe(self._leaf_values(),
                                               self._pools, *args)
                 # host readback inside the armed window (see _decode)
-                return np.asarray(picked), np.asarray(ok)
+                return self._wait_fetch((picked, ok), (call.span_id,))
 
             # on dispatch failure _fail_engine terminalizes every tenant
             # and releases the pager state — the reservation dies with it
             (out, l_ok), call = self._dispatch_guarded(
                 "verify", vw, upload, run)
+            self._step_calls.append(("verify", call.dur_s))
             with _trace.span("engine/decode_finish", slot=slot) as fin:
                 if not bool(l_ok):
                     # a NaN anywhere in the verify window poisons the
@@ -2630,7 +2689,7 @@ class DecodeEngine:
                 self.spec_accepted += a
                 self.spec_emitted += n_emit
                 drafter.observe(req, a, k)
-                fin.set(tokens=n_emit, accepted=a)
+                fin.set(tokens=n_emit)
                 mon = _monitor._active
                 if mon is not None:
                     mon.serve_spec_step(
@@ -2797,6 +2856,7 @@ class DecodeEngine:
         # steps that ran a plan, by how they came by it (_step_planned),
         # and what the rebuilt ones lost theirs to
         out["plan"] = dict(self.plan_counts, causes=dict(self.plan_causes))
+        out["stalls"] = self.stalls       # host/stall records sealed
         if self._kv_pool is not None:
             out["pool"] = self.pool_stats()
         if self.drafter is not None:

@@ -85,6 +85,33 @@ def test_children_of_engine_step_tile_it():
     # host time of a step that no phase accounts for: the median step, so
     # that one descheduled worker of a loaded test host decides nothing
     assert statistics.median(loose) < 0.05, sorted(loose)[-5:]
+    # the two halves of the read-back are children of engine/collect, not
+    # phases of the step: one wait and one fetch each, which tile it but
+    # for the reading of the host's clocks before the wait and the span
+    # layer's own time between and after them, and the wait names the
+    # calls whose results it waited for
+    between = []
+    collects = [s for s in spans if s.name == "engine/collect"]
+    assert len(collects) == len([s for s in steps if s.span_id in kids
+                                 and any(k.name == "engine/collect"
+                                         for k in kids[s.span_id])])
+    for c in collects:
+        wait, fetch = sorted(kids[c.span_id], key=lambda s: s.t0)
+        assert (wait.name, fetch.name) == ("engine/wait", "engine/fetch")
+        assert c.t0 <= wait.t0 <= wait.t1 <= fetch.t0 <= fetch.t1 <= c.t1
+        between.append(((c.t1 - c.t0) - (wait.t1 - wait.t0)
+                        - (fetch.t1 - fetch.t0)) / (c.t1 - c.t0))
+        launched = [k for k in kids[c.parent_id]
+                    if k.name in ("engine/prefill_call",
+                                  "engine/decode_call")]
+        assert list(wait.attrs["calls"]) == [k.span_id for k in sorted(
+            launched, key=lambda s: s.t0)]
+        assert fetch.attrs["arrays"] == 2 * len(launched)
+        assert fetch.attrs["bytes"] > 0
+    # (measured, like the step's own loose time: the median collect)
+    assert statistics.median(between) < 0.25, sorted(between)[-5:]
+    assert {s.name for s in spans} - names \
+        == {"engine/step", "engine/wait", "engine/fetch"}
     admits = [s for s in spans if s.name == "engine/admit"]
     assert sum(s.attrs["admitted"] for s in admits) == len(reqs)
     fin = [s for s in spans if s.name == "engine/decode_finish"]

@@ -453,7 +453,11 @@ def test_hang_then_raise_does_not_poison_next_dispatch(tiny):
             with pytest.raises(InjectedFault):
                 eng.run()
         assert doomed.status == "failed"
-        # next dispatch is healthy: no stale EngineHangError
+        # next dispatch is healthy: no stale EngineHangError (a latched
+        # verdict raises whatever the limit; the decode executable's FIRST
+        # run is this one, 12 ms alone and more beside five other workers,
+        # which is no hang)
+        eng._watchdog.hang_s = 30.0
         ok = eng.submit([4, 5, 6], max_new_tokens=3)
         eng.run()
         assert ok.status == "done"
@@ -501,18 +505,18 @@ def test_watchdog_window_is_open_until_the_step_has_collected(tiny,
         eng.run()
         assert warm.status == "done"
         eng._watchdog.hang_s = 0.05
-        real = jax.device_get
+        real = jax.block_until_ready     # the wait of engine/wait
 
         def stuck(tree):
             time.sleep(0.4)
             return real(tree)
 
         req = eng.submit([4, 5, 6], max_new_tokens=4)
-        monkeypatch.setattr(jax, "device_get", stuck)
+        monkeypatch.setattr(jax, "block_until_ready", stuck)
         with pytest.warns(RuntimeWarning, match="decode executable"):
             with pytest.raises(EngineHangError, match="decode dispatch"):
                 eng.run()
-        monkeypatch.setattr(jax, "device_get", real)
+        monkeypatch.setattr(jax, "block_until_ready", real)
         eng._pager.check_invariants()
         assert req.status == "failed" and eng.live_count == 0
         ok = eng.submit([7, 8, 9], max_new_tokens=3)

@@ -38,6 +38,8 @@ from paddle_tpu.models import GPTConfig, GPTForCausalLM
 from paddle_tpu.serving import DecodeEngine
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+# what the span layer's two listeners record, whenever they fire
+LISTENED = {"gc/collect", "jax/compile"}
 
 
 @pytest.fixture(autouse=True)
@@ -152,7 +154,7 @@ def test_ring_is_bounded_and_spans_selects_the_window():
         trace.record("unit/flood", 0.0, 0.0)
     everything = trace.spans(float("-inf"), float("inf"))
     assert len(everything) == trace.RING_CAPACITY
-    assert {g.name for g in everything} == {"unit/flood"}
+    assert {g.name for g in everything} - LISTENED == {"unit/flood"}
 
 
 def test_ring_and_parent_links_hold_under_threads():
@@ -446,8 +448,10 @@ def test_train_step_trace_spans_and_zero_recompile(tmp_path):
     assert "compile" in names_first and "dispatch" in names_first
     for tid, s in steps.items():
         if tid != first:
-            assert [p["name"] for p in s if p["parent"] is not None] \
-                == ["prepare", "dispatch"]
+            # (the listeners' records are children of whatever was open:
+            # the second step compiles the optimizer's `dev + 1.0`)
+            assert [p["name"] for p in s if p["parent"] is not None
+                    and p["name"] not in LISTENED] == ["prepare", "dispatch"]
             d = [p for p in s if p["name"] == "dispatch"][0]
             assert d["attrs"]["path"] == "aot" and d["attrs"]["bucket"] == 1
     # the recompile sentinel event carries the step's trace id
@@ -490,7 +494,7 @@ def test_request_trace_cannot_steal_step_floats(tmp_path):
     (mixed train+serve process)."""
     t = trace.enable(str(tmp_path / "mx.jsonl"))
     now = time.perf_counter()
-    t.floating("loader/wait", now - 0.002, now)        # step-addressed
+    trace.record("loader/wait", now - 0.002, now, adopt_kind="step")
     req_tr = t.start_trace("request", kind="request", current=False)
     req_tr.end(status="done")
     step_tr = t.start_trace("train_step", kind="step")

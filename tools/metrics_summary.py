@@ -237,6 +237,18 @@ def summarize(paths, show_events=False, out=sys.stdout):
 
     gauges_m = (metrics or {}).get("gauges", {})
 
+    # steps the host held up (monitor/trace.py::stall): one line a record,
+    # with what the README's reading table needs to tell the causes apart
+    for r in by_kind.get("host_stall", []):
+        print(f"\nWARNING: host stall of {r.get('excess_s', 0):.3f}s in "
+              f"{r.get('site', '?')} ({r.get('wall_s', 0):.3f}s where "
+              f"{r.get('expected_s', 0):.3f}s was expected): run-queue "
+              f"wait {r.get('runq_wait_s')}s, process CPU "
+              f"{r.get('cpu_process_s')}s, CPU pressure "
+              f"{r.get('pressure_cpu_s')}s, major faults {r.get('majflt')}, "
+              f"gc {r.get('gc_s')}s, compile {r.get('compile_s')}s",
+              file=out)
+
     # goodput accounting plane (monitor/goodput.py): the gap-free state
     # timeline + MFU/HFU. tools/goodput_report.py is the full per-rank
     # view; this section is the one-look health check + the two WARNs.
