@@ -386,6 +386,11 @@ def phase_server(jax, paddle, model, size, n_dev, on_tpu):
     say(f"server: decode_attention {attention}")
     assert attention == ("paged_kernel" if n_dev == 1 else "gather"), \
         attention
+    # and how its executables wrote the pools: block copies on one chip,
+    # XLA's scatter under tensor parallelism
+    kv_write = set(engine.stats()["kv_write"].values())
+    say(f"server: kv_write {sorted(kv_write)}")
+    assert kv_write == {"kernel" if n_dev == 1 else "scatter"}, kv_write
     assert pager.prefix_hits >= 1, pager.prefix_hits
     assert engine.compile_count == warm_mints, \
         f"steady-state recompiles: {engine.compile_count - warm_mints}"
@@ -412,7 +417,7 @@ def phase_server(jax, paddle, model, size, n_dev, on_tpu):
            "warm_mints": warm_mints, "warmup_s": warm_s, "serve_s": serve_s,
            "prefix_hits": int(pager.prefix_hits),
            "shared_hits": int(pager.shared_hits),
-           "decode_attention": attention,
+           "decode_attention": attention, "kv_write": sorted(kv_write),
            "steady_state_recompiles": 0, "first_token_worst_margin": worst,
            "hbm_in_use_peak": mem, "shards": evidence}
     engine.close()
@@ -486,9 +491,11 @@ def main(argv=None):
         flash_pair.flash_pair_packed = functools.partial(
             flash_pair.flash_pair_packed, interpret=True)
         # and the decode step takes the paged kernel as it does on one chip
-        from paddle_tpu.kernels.pallas import paged_decode
+        from paddle_tpu.kernels.pallas import paged_decode, pool_write
         seam = paged_decode.force_interpret()    # held to the end of main
         seam.__enter__()
+        writes = pool_write.force_interpret()    # and the pools' writes
+        writes.__enter__()
 
     phases = {}
     say(f"--- trainer ({n_dev} chip(s))")

@@ -33,6 +33,20 @@ def attention_kernels_traced(since: int = 0) -> list:
     return _ATTENTION_KERNELS[since:]
 
 
+# ... and how a call wrote its new rows into the paged pools (``kernel``:
+# ``pool_write``'s block copies; ``scatter``: XLA's), so that the spans of
+# the calls say which write an executable was traced with.
+_POOL_WRITES: list = []
+
+
+def note_pool_write(name: str) -> None:
+    _POOL_WRITES.append(name)
+
+
+def pool_writes_traced(since: int = 0) -> list:
+    return _POOL_WRITES[since:]
+
+
 def tpu_placement(x) -> bool:
     """True when `x` will execute on a real TPU. Must NOT observe the value:
     under deferred eager a .value() here would flush the pending graph at

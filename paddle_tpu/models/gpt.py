@@ -252,14 +252,23 @@ def _paged_kv_write(kv_cache, k, v):
     cursor(s) and the exclusive end of VALID new positions. ``k``/``v`` are
     this call's fresh projections, [B, S, n_kv, hd].
 
-    Scatters each position to ``(table[b, p // BS], p % BS)``; positions >=
-    write_end (padded chunk tails) or beyond the table width redirect to
-    trash block 0, so padding can never corrupt a live or shared block.
-    Under a tensor-parallel mesh (``set_paged_kv_sharding``) the updated
-    pools are constrained to the head-sharded placement, so the scatter
-    stays shard-local on the head axis. Returns the updated pools.
+    Each position lands at ``(table[b, p // BS], p % BS)``; positions >=
+    write_end (padded chunk tails) or beyond the table width are not
+    written, so padding can never corrupt a live or shared block. On one
+    chip as block copies (``kernels/pallas/pool_write.py``: nothing is
+    written into trash block 0 either); elsewhere an XLA scatter that
+    redirects them to the trash block. Under a tensor-parallel mesh
+    (``set_paged_kv_sharding``) the updated pools are constrained to the
+    head-sharded placement, so the scatter stays shard-local on the head
+    axis. Returns the updated pools.
     """
+    from .hybrid import kernel_write
     pool_k, pool_v, table, pos, write_end = kv_cache
+    with jax.named_scope("kv_write"):
+        done = kernel_write((pool_k, pool_v), (k, v), table, pos, write_end,
+                            sharded=_PAGED_KV_SHARD["sharding"] is not None)
+    if done is not None:
+        return tuple(done)
     b, s = k.shape[:2]
     bs_blk = pool_k.shape[1]
     mbs = table.shape[1]

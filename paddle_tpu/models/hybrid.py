@@ -125,10 +125,30 @@ def _write_end(end):
     return end[:, None] if end.ndim else end
 
 
+def kernel_write(pools, rows, table, start, end, sharded=False):
+    """The pools after ``kernels/pallas/pool_write.py`` wrote ``rows`` (one
+    array a pool) at positions ``start + i`` before ``end``, or None where
+    the kernel does not run (off a TPU, ``sharded`` pools, a layout it does
+    not fit): the caller scatters. Notes which it was
+    (``util.note_pool_write``)."""
+    from ..kernels.pallas import pool_write
+    from ..kernels.pallas.util import note_pool_write
+    mode = None if sharded else pool_write.kernel_mode(pools[0], rows[0])
+    if mode is None:
+        note_pool_write("scatter")
+        return None
+    return pool_write.write_rows(pools, rows, table, start, end,
+                                 interpret=mode == "interpret")
+
+
 def write_rows(pool, table, rows, positions, end):
     """One row a position into ``pool [NB, BS, lanes]``: ``rows [B, S,
-    lanes]`` land at ``(table[b, p // BS], p % BS)``, the trash block where
-    ``_block_of`` says so. Returns the pool after the write."""
+    lanes]`` land at ``(table[b, p // BS], p % BS)``; none at or past
+    ``end`` or beyond the table (the scatter sends those to the trash
+    block). Returns the pool after the write."""
+    done = kernel_write((pool,), (rows,), table, positions[:, 0], end)
+    if done is not None:
+        return done[0]
     b, s = rows.shape[:2]
     wpos = jnp.broadcast_to(positions, (b, s))
     phys = _block_of(table, wpos, _write_end(end), pool.shape[1])
@@ -263,12 +283,17 @@ def _write_merged(cache, k, v, positions, end):
     """``gpt._paged_kv_write`` for pools whose block is one matrix of
     (position, KV head) rows: position ``p`` of head ``h`` lands at
     ``(table[b, p // BS], (p % BS) * n_kv + h)``; positions at or past
-    ``end`` or beyond the table go to trash block 0."""
+    ``end`` or beyond the table are not written (the scatter sends them to
+    trash block 0)."""
     pool_k, pool_v, table = cache
     b, s, nkv = k.shape[:3]
     bs_blk = pool_k.shape[1] // nkv
     wpos = jnp.broadcast_to(positions, (b, s))
     with jax.named_scope("kv_write"):
+        done = kernel_write((pool_k, pool_v), (k, v), table, positions[:, 0],
+                            end)
+        if done is not None:
+            return tuple(done)
         phys = _block_of(table, wpos, _write_end(end), bs_blk)
         row = (wpos % bs_blk)[..., None] * nkv \
             + jnp.arange(nkv, dtype=jnp.int32)

@@ -76,7 +76,8 @@ from ..models.cache_spec import ModelSpec, pool_lanes
 from ..models.gpt import (_lm_head_logits, _pick_token,
                           _resolve_decode_horizon, set_paged_kv_sharding)
 from ..distributed.reshard import snapshot as _snapshot
-from ..kernels.pallas.util import attention_kernels_traced
+from ..kernels.pallas import pool_write
+from ..kernels.pallas.util import attention_kernels_traced, pool_writes_traced
 from .guardrails import (HANG_ENV, DispatchWatchdog, EngineHangError,
                          FaultSchedule, InjectedFault)
 from .pager import TRASH_BLOCK, BlockPager, prefix_digest
@@ -559,6 +560,9 @@ class DecodeEngine:
         self.kv_walked = self.kv_table = 0
         self._decode_geometry = {}
         self._decode_state = None
+        # how each executable wrote its new rows into the pools: "kernel"
+        # (kernels/pallas/pool_write.py) or "scatter" (XLA's), as traced
+        self._kv_write: dict = {}
         self._verify_exe = None
         self._prefill_exes = {}
         # ---- the prepared step (_step_planned). The
@@ -859,11 +863,26 @@ class DecodeEngine:
         """Fold the pager's pending copy-on-write block copies into the
         executable: ``pools[l][dst[i]] = pools[l][src[i]]`` before anything
         reads or writes. Padded entries are (0, 0) trash-to-trash no-ops,
-        so the shape is always [max_slots] and COW never retraces. State
-        layers have no blocks to copy."""
+        so the shape is always [max_slots] and COW never retraces. On one
+        chip one kernel copies the real pairs' blocks of every pool
+        (``pool_write.copy_blocks``: a padded pair costs a predicate);
+        under a mesh and off the chip a gather and a scatter of every pair.
+        State layers have no blocks to copy."""
+        flat = []
+        self.spec.map_entries(
+            lambda entry, c: flat.extend(c) if entry.kind in _PAGED else None,
+            pools)
+        mode = pool_write.copy_mode(flat[0]) \
+            if flat and self._mesh is None else None
+        if mode is None:
+            return self.spec.map_entries(
+                lambda entry, c: tuple(
+                    p.at[dst].set(jnp.take(p, src, axis=0)) for p in c)
+                if entry.kind in _PAGED else c, pools)
+        done = iter(pool_write.copy_blocks(flat, src, dst,
+                                           interpret=mode == "interpret"))
         return self.spec.map_entries(
-            lambda entry, c: tuple(p.at[dst].set(jnp.take(p, src, axis=0))
-                                   for p in c)
+            lambda entry, c: tuple(next(done) for _ in c)
             if entry.kind in _PAGED else c, pools)
 
     def _sample(self, hidden_last, key, moe=None):
@@ -913,6 +932,7 @@ class DecodeEngine:
         from ..kernels.pallas.util import state_kernels_traced
         traced = len(attention_kernels_traced())
         state_traced = len(state_kernels_traced())
+        writes = len(pool_writes_traced())
         low = self._lower_in_eval(fn, args, self._pool_out_shardings())
         n_out = low.out_info[1].shape[0]
         if n_out != self._tok_len:
@@ -928,6 +948,7 @@ class DecodeEngine:
         # models/gpt.py::_paged_decode_attend); a silent fallback on the
         # chip would otherwise look like "no gain"
         self._decode_attention = self._attention_path(traced)
+        self._kv_write["decode"] = self._write_path(writes)
         # and with which walk of the table the kernel was traced
         # (`kv_chunk_pages`, `kv_page_bytes`: it sizes a chunk from its input)
         self._decode_geometry = paged_decode.kernel_geometry() \
@@ -950,6 +971,13 @@ class DecodeEngine:
         geometry), or ``"gather"`` for the view."""
         return "+".join(sorted(set(attention_kernels_traced(mark)))) \
             or "gather"
+
+    @staticmethod
+    def _write_path(mark: int):
+        """How the rows were written since ``mark`` (a length of
+        ``pool_writes_traced()``): ``"kernel"``, ``"scatter"``, both
+        joined by ``+``, or None for no write."""
+        return "+".join(sorted(set(pool_writes_traced(mark)))) or None
 
     def _build_note(self, tok):
         """The one helper program of the prepared step: write a chunk's
@@ -1000,9 +1028,11 @@ class DecodeEngine:
                 self._dev(jnp.int32(1)), pad, pad, self._greedy_key)
         t0 = time.time()
         traced = len(attention_kernels_traced())
+        writes = len(pool_writes_traced())
         exe = self._compile_in_eval(fn, args,
                                     out_shardings=self._pool_out_shardings())
         self._prefill_exes[sc] = exe
+        self._kv_write[sc] = self._write_path(writes)
         self._prefill_attention[sc] = self._attention_path(traced)
         if self._prefill_attention[sc] == "key_walk":
             from ..models.hybrid import walk_geometry
@@ -1049,9 +1079,11 @@ class DecodeEngine:
                 self._dev(jnp.int32(0)), self._dev(jnp.int32(0)),
                 self._dev(jnp.int32(1)), pad, pad, self._greedy_key)
         t0 = time.time()
+        writes = len(pool_writes_traced())
         exe = self._compile_in_eval(fn, args,
                                     out_shardings=self._pool_out_shardings())
         self._verify_exe = exe
+        self._kv_write["verify"] = self._write_path(writes)
         self._minted("verify", vw, time.time() - t0, exe=exe, tokens=vw)
         return exe
 
@@ -2319,6 +2351,7 @@ class DecodeEngine:
             first = False
             with _trace.span("engine/prefill_call",
                              path=self._prefill_attention[c.sc],
+                             kv_write=self._kv_write[c.sc],
                              kv_walked=self._kv_walked(c.sc, c.end),
                              **self._cache_attrs(1, c.end)) as c.span:
                 self._pools, c.tok0, c.ok = self._prefill_exes[c.sc](
@@ -2511,7 +2544,9 @@ class DecodeEngine:
             pos = np.zeros(self.max_slots, np.int32)
             for s, (_, cursor, _) in rows.items():
                 mask[s], pos[s] = True, cursor
-            attrs = dict(path=self._decode_attention, **self._decode_geometry,
+            attrs = dict(path=self._decode_attention,
+                         kv_write=self._kv_write["decode"],
+                         **self._decode_geometry,
                          **self._cache_attrs(len(rows),
                                              int((pos[mask] + 1).sum())))
             if self._has_state:
@@ -2646,6 +2681,7 @@ class DecodeEngine:
                         self._next_key())
 
             def run(call, *args):
+                call.set(kv_write=self._kv_write["verify"])
                 self._pools, picked, ok = exe(self._leaf_values(),
                                               self._pools, *args)
                 # host readback inside the armed window (see _decode)
@@ -2820,6 +2856,10 @@ class DecodeEngine:
             # and with which recurrent-state step: the Pallas kernel's name
             # ("ssd_decode", "gdn_decode") or "scan"; None without state
             "decode_state": self._decode_state,
+            # how each executable wrote its rows into the pools ("kernel":
+            # kernels/pallas/pool_write.py; "scatter": XLA's), by executable
+            # ("decode", a chunk length, "verify")
+            "kv_write": {str(k): v for k, v in self._kv_write.items()},
             "tokens_generated": self.tokens_generated,
             "live_slots": self.live_count,
             "queue_depth": self.queue_depth,

@@ -233,3 +233,23 @@ def test_served_tokens_match_the_plain_forward_under_chunked_load(tiny):
                 compared += 1
                 assert t == int(row.argmax()), (r.id, j, best - second)
     assert compared > 400
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["scatter", "kernel"])
+def test_call_spans_say_how_the_pools_were_written(tiny, kernel):
+    """Every ``engine/prefill_call`` and ``engine/decode_call`` span carries
+    ``kv_write``: what its executable was traced with, ``kernel``
+    (``kernels/pallas/pool_write.py``, interpreted here) or ``scatter``
+    (XLA's, the CPU's), as ``stats()["kv_write"]`` says by executable."""
+    from paddle_tpu.kernels.pallas import pool_write
+    eng = DecodeEngine(tiny, max_slots=4, max_len=64, block_size=8,
+                       prefill_chunk=16)
+    way = "kernel" if kernel else "scatter"
+    with pool_write.force_interpret(kernel):
+        reqs, t0, t1 = _run(eng, _prompts(3, 20, seed=7), 5)
+    calls = [s for name in ("engine/prefill_call", "engine/decode_call")
+             for s in trace.spans(t0, t1, name)]
+    assert {s.name for s in calls} == {"engine/prefill_call",
+                                       "engine/decode_call"}
+    assert {s.attrs["kv_write"] for s in calls} == {way}
+    assert eng.stats()["kv_write"] == {"16": way, "decode": way}

@@ -469,3 +469,59 @@ def test_mosaic_compiles_an_expert_walked_in_blocks(one_chip):
         ((16, 2048, 6144), bf))
     assert exe.as_text().count('custom_call_target="tpu_custom_call"') == 1
     assert exe.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+# the pools' block-copy writes (kernels/pallas/pool_write.py) at the served
+# shapes: GPT-3 XL's 4-D pools, Falcon-H1's merged rows (4 KV heads) and
+# LongCat's latent rows, a decode step's rows and a chunk's
+@pytest.mark.parametrize("pool,rows,b,mbs", [
+    ((3000, 16, 16, 128), (32, 1, 16, 128), 32, 128),
+    ((3000, 16, 16, 128), (1, 256, 16, 128), 1, 128),
+    ((16384, 64, 128), (128, 1, 4, 128), 128, 256),
+    ((16384, 64, 128), (1, 512, 4, 128), 1, 256),
+    ((16384, 16, 640), (128, 1, 640), 128, 256),
+    ((16384, 16, 640), (1, 512, 640), 1, 256),
+], ids=["gpt_decode", "gpt_chunk", "merged_decode", "merged_chunk",
+        "latent_decode", "latent_chunk"])
+def test_mosaic_compiles_the_pool_writes_in_place(one_chip, pool, rows, b,
+                                                  mbs):
+    """One kernel, no scatter, and the donated pools written where they
+    lie: no copy of a pool (a lost alias copies it whole every call)."""
+    from paddle_tpu.kernels.pallas import pool_write
+    one = (b,) if rows[1] == 1 else ()
+    shapes = [(pool, jnp.bfloat16)] * 2 + [(rows, jnp.bfloat16)] * 2 + [
+        ((b, mbs), jnp.int32), (one, jnp.int32), (one, jnp.int32)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        exe = jax.jit(lambda pk, pv, k, v, t, p, e: pool_write.write_rows(
+            (pk, pv), (k, v), t, p, e), donate_argnums=(0, 1)).lower(
+                *args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    text = exe.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert " scatter(" not in text
+    dims = ",".join(map(str, pool))
+    assert not [l for l in text.splitlines()
+                if f"[{dims}]" in l and (" copy(" in l or "copy-start" in l)]
+    assert exe.memory_analysis().temp_size_in_bytes < (4 << 20)
+
+
+def test_mosaic_compiles_the_copy_on_write_of_48_pools(one_chip):
+    from paddle_tpu.kernels.pallas import pool_write
+    pool = jax.ShapeDtypeStruct((3000, 16, 16, 128), jnp.bfloat16,
+                                sharding=one_chip)
+    pair = jax.ShapeDtypeStruct((32,), jnp.int32, sharding=one_chip)
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        exe = jax.jit(pool_write.copy_blocks, donate_argnums=(0,)).lower(
+            [pool] * 48, pair, pair).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    text = exe.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert " copy(" not in text and " gather(" not in text
+    assert exe.memory_analysis().temp_size_in_bytes < (1 << 20)
