@@ -32,10 +32,20 @@ the next step (ROADMAP).
 Inference-only raw-array math, like the cached attention paths: the router's
 auxiliary loss and a backward through the grouped matmul are not here yet.
 
+``scoring`` may be ``"sigmoid"`` (an independent score an output), and
+``n_group`` / ``topk_group`` limit a token's choice to its ``topk_group``
+best of ``n_group`` equal groups of experts (DeepSeek-V3: sigmoid, 8 groups
+of 32, 4 kept, top-8 renormalised, x 2.5; a deployment maps a group onto a
+node, so a chip that holds part of one group is reached only by the tokens
+that kept that group). A shared expert is gated by ``sigmoid(x w_s)``
+(Qwen3-Next) or, without ``shared_gated``, added whole (DeepSeek-V3).
+
 ``collect_counters()`` is how a serving executable reads the step's routing:
 inside it every call adds three traced integers (assignments of valid
 tokens, those that fell on held experts, held experts with at least one),
-and a layer with zero experts a fourth (assignments to zero experts).
+a layer with zero experts a fourth (assignments to zero experts), and a
+layer with groups a fifth (over the valid tokens, the distinct groups each
+token's top-k spans, summed): ``counter_names`` says which.
 """
 from __future__ import annotations
 
@@ -58,7 +68,7 @@ class _Counters:
         self.items = []
 
     def total(self):
-        """int32 [3] (or [4]: ``HeldExpertsMoE.counter_names``) summed
+        """int32 [3] (to [5]: ``HeldExpertsMoE.counter_names``) summed
         over the calls recorded, or None for none."""
         if not self.items:
             return None
@@ -75,23 +85,48 @@ def collect_counters():
         _COUNTERS.pop()
 
 
+def _limit_groups(choice, n_group: int, topk_group: int):
+    """``choice [T, E]`` with every output outside each token's
+    ``topk_group`` best of ``n_group`` equal groups set to -inf; a group's
+    score is the sum of its two largest ``choice``."""
+    t, e = choice.shape
+    grouped = choice.reshape(t, n_group, e // n_group)
+    score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)      # [T, G]
+    _, keep = jax.lax.top_k(score, topk_group)                   # [T, kg]
+    kept = jnp.any(keep[..., None] == jnp.arange(n_group), axis=1)
+    return jnp.where(kept[..., None], grouped, -jnp.inf).reshape(t, e)
+
+
 def route_topk(x, router_w, top_k: int, norm_topk_prob: bool,
-               choice_bias=None, scaling: float = 1.0):
-    """Float32 router: softmax over ALL the router's outputs, top-k, weights
-    renormalised to sum 1 when the model says so. ``x [T, H]``, ``router_w
-    [H, outputs]``. With ``choice_bias [outputs]`` the k are the top of
-    ``softmax + bias`` and their weights still the softmax's own; the
-    weights are multiplied by ``scaling``. Returns (ids [T, k] int32,
-    weights [T, k] float32)."""
+               choice_bias=None, scaling: float = 1.0,
+               scoring: str = "softmax", n_group: int = 1,
+               topk_group: int = 1):
+    """Float32 router: ``scoring`` (softmax or sigmoid) over ALL the
+    router's outputs, top-k, weights renormalised to sum 1 when the model
+    says so. ``x [T, H]``, ``router_w [H, outputs]``. With ``choice_bias
+    [outputs]`` the k are the top of ``score + bias`` and their weights
+    still the scores' own; with ``n_group > 1`` the k are taken from the
+    ``topk_group`` best of ``n_group`` equal groups of outputs (a group's
+    score the sum of its two largest ``score + bias``; DeepSeek-V3's
+    group-limited choice). The weights are multiplied by ``scaling``.
+    Returns (ids [T, k] int32, weights [T, k] float32)."""
     logits = jnp.einsum("th,he->te", x.astype(jnp.float32),
                         router_w.astype(jnp.float32), precision="highest",
                         preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    if choice_bias is None:
+    if scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+    elif scoring == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"router scoring {scoring!r}: softmax or sigmoid")
+    if choice_bias is None and n_group == 1:
         weights, ids = jax.lax.top_k(probs, top_k)
     else:
-        _, ids = jax.lax.top_k(probs + choice_bias.astype(jnp.float32),
-                               top_k)
+        choice = probs if choice_bias is None \
+            else probs + choice_bias.astype(jnp.float32)
+        if n_group > 1:
+            choice = _limit_groups(choice, n_group, topk_group)
+        _, ids = jax.lax.top_k(choice, top_k)
         weights = jnp.take_along_axis(probs, ids, axis=-1)
     if norm_topk_prob:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
@@ -109,24 +144,36 @@ def _swiglu(x, gate_w, up_w, down_w):
 
 class HeldExpertsMoE(Layer):
     """Router over ``n_routed`` experts (and ``n_zero`` zero-compute experts
-    after them) + the ``count`` real ones held here (global ids ``offset ..
-    offset + count - 1``) + an optional shared expert gated by ``sigmoid(x
-    w_s)``."""
+    after them; ``scoring`` softmax or sigmoid; ``n_group`` groups of which
+    a token's choice keeps ``topk_group``) + the ``count`` real ones held
+    here (global ids ``offset .. offset + count - 1``) + an optional shared
+    expert, gated by ``sigmoid(x w_s)`` or, without ``shared_gated``,
+    added whole."""
 
     def __init__(self, hidden_size: int, expert_width: int, n_routed: int,
                  top_k: int, *, offset: int = 0, count: int = None,
                  norm_topk_prob: bool = True, shared_width: int = 0,
                  n_zero: int = 0, choice_bias: bool = False,
-                 scaling: float = 1.0, std: float = 0.02, dtype=None):
+                 scaling: float = 1.0, scoring: str = "softmax",
+                 n_group: int = 1, topk_group: int = 1,
+                 shared_gated: bool = True, std: float = 0.02, dtype=None):
         super().__init__()
         count = n_routed if count is None else count
         if not (0 <= offset and offset + count <= n_routed and count >= 1):
             raise ValueError(f"held experts [{offset}, {offset + count}) "
                              f"do not lie inside the router's {n_routed}")
+        if n_group > 1 and (n_zero or n_routed % n_group
+                            or not 1 <= topk_group <= n_group
+                            or top_k > topk_group * (n_routed // n_group)):
+            raise ValueError(
+                f"{n_routed} experts (+ {n_zero} zero) in {n_group} groups, "
+                f"top-{top_k} within {topk_group} of them")
         self.n_routed, self.top_k = int(n_routed), int(top_k)
         self.offset, self.count = int(offset), int(count)
         self.norm_topk_prob = bool(norm_topk_prob)
         self.n_zero, self.scaling = int(n_zero), float(scaling)
+        self.scoring = str(scoring)
+        self.n_group, self.topk_group = int(n_group), int(topk_group)
         normal = initializer.Normal(0.0, std)
 
         def mat(*shape):
@@ -147,13 +194,16 @@ class HeldExpertsMoE(Layer):
             self.shared_gate_proj = mat(h, shared_width)
             self.shared_up_proj = mat(h, shared_width)
             self.shared_down_proj = mat(shared_width, h)
-            self.shared_expert_gate = mat(h, 1)
+            if shared_gated:
+                self.shared_expert_gate = mat(h, 1)
+        self.shared_gated = bool(shared_width and shared_gated)
 
     @property
     def counter_names(self) -> tuple:
         """What each entry of a call's counter vector counts."""
         return ("assignments", "local", "touched") \
-            + (("zero",) if self.n_zero else ())
+            + (("zero",) if self.n_zero else ()) \
+            + (("groups",) if self.n_group > 1 else ())
 
     def apply(self, x, valid=None):
         """``x [T, H]`` raw array in the model's dtype, ``valid [T]`` bool
@@ -164,7 +214,7 @@ class HeldExpertsMoE(Layer):
             ids, weights = route_topk(
                 x, self.gate.value(), self.top_k, self.norm_topk_prob,
                 None if self.gate_bias is None else self.gate_bias.value(),
-                self.scaling)
+                self.scaling, self.scoring, self.n_group, self.topk_group)
         with jax.named_scope("moe_experts"):
             out, counts = moe_grouped.moe_grouped(
                 x, ids, weights, valid, self.experts_gate_proj.value(),
@@ -182,6 +232,12 @@ class HeldExpertsMoE(Layer):
             if self.n_zero:
                 counted.append(jnp.sum(
                     (to_zero & valid[:, None]).astype(jnp.int32)))
+            if self.n_group > 1:
+                group = ids // (self.n_routed // self.n_group)      # [T, k]
+                spans = jnp.any(group[..., None] == jnp.arange(
+                    self.n_group), axis=1)                          # [T, G]
+                counted.append(jnp.sum(
+                    (spans & valid[:, None]).astype(jnp.int32)))
             _COUNTERS[-1].items.append(jnp.stack(counted))
         if self.shared_width:
             with jax.named_scope("shared_expert"):
@@ -189,10 +245,13 @@ class HeldExpertsMoE(Layer):
                 sh = _swiglu(x, self.shared_gate_proj.value(),
                              self.shared_up_proj.value(),
                              self.shared_down_proj.value())
-                g = jax.nn.sigmoid(jnp.dot(
-                    x, self.shared_expert_gate.value(),
-                    precision=prec).astype(jnp.float32))
-                out = out + sh.astype(jnp.float32) * g
+                if self.shared_gated:
+                    g = jax.nn.sigmoid(jnp.dot(
+                        x, self.shared_expert_gate.value(),
+                        precision=prec).astype(jnp.float32))
+                    out = out + sh.astype(jnp.float32) * g
+                else:
+                    out = out + sh.astype(jnp.float32)
         return out.astype(x.dtype)
 
     def forward(self, x):

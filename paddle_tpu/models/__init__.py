@@ -21,3 +21,5 @@ from .falcon_h1 import (FalconH1Config, FalconH1Model,  # noqa: F401
                         FalconH1ForCausalLM, falcon_h1_tiny)
 from .longcat_flash import (LongCatFlashConfig, LongCatFlashModel,  # noqa: F401
                             LongCatFlashForCausalLM, longcat_flash_tiny)
+from .deepseek_v3 import (DeepseekV3Config, DeepseekV3Model,  # noqa: F401
+                          DeepseekV3ForCausalLM, deepseek_v3_tiny)

@@ -1,12 +1,14 @@
 """What the raw-array decoders (``qwen3_next.py``, ``falcon_h1.py``,
-``longcat_flash.py``) share: a bag of raw parameters, the float32 RMS norm,
-positions and validity of a cached call, rotate-half rotary, the depthwise
-convolution that carries its last inputs between calls, where a call's
-positions land in a paged pool, the walk of a prefill chunk's queries over
-the key blocks its slot holds (``walk_keys``; GPT and LLaMA take it too),
-and grouped-query attention over merged-row paged pools
-(``cache_spec.kv_layer(merged_rows=True)``) with the paged decode kernel
-where the call is a decode step. Raw-array math, no ``Tensor`` inside.
+``longcat_flash.py``, ``deepseek_v3.py``) share: a bag of raw parameters,
+the float32 RMS norm, the dense SwiGLU, positions and validity of a cached
+call, rotary (rotate-half or interleaved pairs, YaRN's frequencies), the
+depthwise convolution that carries its last inputs between calls, where a
+call's positions land in a paged pool, the walk of a prefill chunk's
+queries over the key blocks its slot holds (``walk_keys``; GPT and LLaMA
+take it too, and ``latent_attention.py``), and grouped-query attention over
+merged-row paged pools (``cache_spec.kv_layer(merged_rows=True)``) with the
+paged decode kernel where the call is a decode step. Raw-array math, no
+``Tensor`` inside.
 """
 from __future__ import annotations
 
@@ -14,6 +16,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .. import nn
 
@@ -77,6 +80,25 @@ class _Weights(nn.Layer):
             default_initializer=nn.initializer.Constant(value))
 
 
+class DenseFFN(_Weights):
+    """SwiGLU ``W_d(silu(W_g v) * W_u v)`` of ``width``, the product of the
+    two halves in float32."""
+
+    def __init__(self, cfg, width: int):
+        super().__init__(cfg)
+        h = cfg.hidden_size
+        self.gate_proj = self.mat(h, width)
+        self.up_proj = self.mat(h, width)
+        self.down_proj = self.mat(width, h)
+
+    def apply(self, v):
+        with jax.named_scope("dense_ffn"):
+            hid = jax.nn.silu(_dot(v, self.gate_proj.value())
+                              .astype(jnp.float32)) \
+                * _dot(v, self.up_proj.value()).astype(jnp.float32)
+            return _dot(hid.astype(v.dtype), self.down_proj.value())
+
+
 def conv_with_tail(mixed, tail, weight, valid, bias=None):
     """Causal depthwise convolution over [tail | this call's inputs], then
     SiLU, in float32: ``mixed [B, S, C]``, ``tail [B, width - 1, C]`` the
@@ -96,16 +118,71 @@ def conv_with_tail(mixed, tail, weight, valid, bias=None):
     return jax.nn.silu(conv), new_tail
 
 
-def rope(t, positions, rot, theta):
-    """Rotate-half rotary on the first ``rot`` dims; t [B, S, n, hd]."""
+def rope(t, positions, rot, theta, inv=None, interleave=False):
+    """Rotary on the first ``rot`` dims; t [B, S, n, hd]. Pair ``i`` is
+    dims ``(i, i + rot / 2)`` (rotate-half) or, with ``interleave``, dims
+    ``(2i, 2i + 1)`` (DeepSeek's layout); its frequency ``theta ** (-2i /
+    rot)``, or ``inv[i]`` where given (``rope_frequencies``)."""
     half = rot // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rot)
+    if inv is None:
+        inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / rot)
     ang = positions.astype(jnp.float32)[..., None] * inv  # [B|1, S, half]
     cos, sin = jnp.cos(ang)[:, :, None], jnp.sin(ang)[:, :, None]
     tf = t.astype(jnp.float32)
-    x1, x2, rest = tf[..., :half], tf[..., half:rot], tf[..., rot:]
+    rest = tf[..., rot:]
+    if interleave:
+        pairs = tf[..., :rot].reshape(tf.shape[:-1] + (half, 2))
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+        turned = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           axis=-1).reshape(tf.shape[:-1] + (rot,))
+        return jnp.concatenate([turned, rest], axis=-1).astype(t.dtype)
+    x1, x2 = tf[..., :half], tf[..., half:rot]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
                             rest], axis=-1).astype(t.dtype)
+
+
+def _yarn_mscale(factor, mscale):
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def rope_frequencies(rot, theta, scaling=None):
+    """(inverse frequencies [rot / 2] float32, or None for ``rope``'s own;
+    the factor on the softmax scale) of a configuration's
+    ``rope_scaling``. YaRN (``type`` "yarn"): frequencies between the
+    correction dims of ``beta_fast`` and ``beta_slow`` rotations over
+    ``original_max_position_embeddings`` are ramped from ``theta``'s own
+    (below) to ``1 / factor`` of them (above); the softmax scale takes
+    ``mscale(mscale_all_dim) ** 2``, ``mscale(m) = 0.1 m ln(factor) + 1``.
+    YaRN's factor ``mscale(mscale) / mscale(mscale_all_dim)`` on cos and
+    sin is 1 in every configuration served (DeepSeek-V3: both 1), and
+    another is refused."""
+    if not scaling:
+        return None, 1.0
+    kind = scaling.get("type", scaling.get("rope_type"))
+    if kind != "yarn":
+        raise NotImplementedError(f"rope_scaling of type {kind!r}")
+    factor = float(scaling["factor"])
+    orig = float(scaling["original_max_position_embeddings"])
+    theta = float(theta)
+
+    def correction_dim(rotations):
+        return rot * math.log(orig / (rotations * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(scaling.get("beta_fast", 32))), 0)
+    high = min(math.ceil(correction_dim(scaling.get("beta_slow", 1))),
+               rot - 1)
+    ramp = np.clip((np.arange(rot // 2) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    plain = theta ** (-np.arange(0, rot, 2) / rot)
+    inv = plain / factor * ramp + plain * (1.0 - ramp)
+    all_dims = scaling.get("mscale_all_dim", 0)
+    if _yarn_mscale(factor, scaling.get("mscale", 1)) \
+            != _yarn_mscale(factor, all_dims):
+        raise NotImplementedError("YaRN with mscale apart from "
+                                  "mscale_all_dim (cos and sin scaled)")
+    scores = _yarn_mscale(factor, all_dims) ** 2 if all_dims else 1.0
+    return inv.astype(np.float32), scores
 
 
 def _block_of(table, wpos, end, bs_blk):
@@ -156,7 +233,7 @@ def write_rows(pool, table, rows, positions, end):
 
 
 # float32 score elements of one trip of ``walk_keys``: its key block is sized
-# from them (as ``longcat_flash.SCORE_BLOCK`` sizes a block of heads). A loop
+# from them (as ``latent_attention.SCORE_BLOCK`` sizes a block of heads). A loop
 # costs its layer some ten small launches a trip and the scheduler's overlap
 # with the next layer's weights, so few score rows want a long trip and many
 # a short one: 256 keys for 64 heads x 512 queries, 512 for 20 x 512, and

@@ -177,11 +177,11 @@ def test_absorbed_decode_is_the_expanded_attention(tiny):
 
 
 def test_heads_in_blocks_are_the_heads_at_once(tiny, monkeypatch):
-    from paddle_tpu.models import longcat_flash as lf
+    from paddle_tpu.models import latent_attention as la
     prog, _, _ = tiny
     ids = ids_of(33, seed=2)
     whole = np.asarray(prog(paddle.to_tensor(ids)).value())
-    monkeypatch.setattr(lf, "SCORE_BLOCK", 2 * 33 * 33)   # 2 heads a block
+    monkeypatch.setattr(la, "SCORE_BLOCK", 2 * 33 * 33)   # 2 heads a block
     blocks = np.asarray(prog(paddle.to_tensor(ids)).value())
     assert float(np.abs(whole - blocks).max()) < 1e-5
 
