@@ -50,6 +50,24 @@ the chunk two ahead started in straight-line code, each under its own
 predicate (no loop, no branch, none past a slot's length), then the two
 products. What a short chunk leaves stale in V's buffer is zeroed page by
 page, and only the pages the buffer's last chunk had fetched.
+
+The latent geometry (``latent_decode_attention``: ONE pool whose rows every
+head reads, the kernel ``mla_decode``) walks the same chunks with the same
+copies, chunk rule and waits, in a body of its own, ``_latent_kernel``. At
+128 heads its products bound it, not its copies (a chunk's products pop
+576 results from the MXU whichever operand streams), so its trip does what
+the K/V walk's need not: (1) each ring slot is an allocation of its own
+and the trip is compiled once a ring slot, so that the starts of the chunk
+two ahead, which write another allocation than the products read, are
+scheduled under the products (with one allocation for the ring, their
+scalar work ran before the first product); (2) the context accumulates in
+a VMEM scratch, not in the loop's carry (a copy of 64 vregs a trip); (3) a
+slot's last chunk is computed only as far as its live rows, in pieces of
+``PIECE_PAGES``: the trip for the chunk's first k pieces is chosen at run
+time among static shapes. The queries stream past the chunk's rows as in
+the K/V walk: the other way round (the rows streaming past the queries
+held still) read the same at 128 heads and a third slower at 64 heads,
+which fill half the MXU's columns (PERF.md §3).
 """
 from __future__ import annotations
 
@@ -74,6 +92,10 @@ RING = 3                      # chunk buffers: one computed on, two in flight
 # ridge (121 FLOP/B in one pass), so three terms would make it compute-bound:
 # 0.415 / 0.473 / 0.534 ms with one / two / three at the served shape
 LATENT_P_TERMS = 1
+# pages of the latent walk's unit of stopping at a slot's live rows: 8 (128
+# rows) against 16 and a whole chunk, 0.406 / 0.410 / 0.429 ms a call at 128
+# heads on one TPU v5e
+PIECE_PAGES = 8
 
 # Test seam, shaped like models/gpt.py::set_paged_kv_sharding: entered round
 # an engine's trace, it makes the model take this kernel off the TPU too,
@@ -82,8 +104,11 @@ _FORCE = {"interpret": False}
 # With which walk the kernel was last traced (that it WAS traced, it notes
 # in ``util.note_attention_kernel``: the engine reads that round its traces
 # to say which path an executable took): table entries a chunk, bytes a page
-# of one pool
-_GEOMETRY = {"kv_chunk_pages": None, "kv_page_bytes": None}
+# of one pool, and the table entries of the walk's unit of computing (a whole
+# chunk in the K/V walk; a piece of the chunk in the latent walk, which
+# stops at a slot's live rows)
+_GEOMETRY = {"kv_chunk_pages": None, "kv_page_bytes": None,
+             "kv_piece_pages": None}
 
 
 @contextlib.contextmanager
@@ -96,7 +121,8 @@ def force_interpret(on: bool = True):
 
 
 def kernel_geometry() -> dict:
-    """The geometry of the last trace: `kv_chunk_pages`, `kv_page_bytes`."""
+    """The geometry of the last trace: `kv_chunk_pages`, `kv_page_bytes`,
+    `kv_piece_pages`."""
     return dict(_GEOMETRY)
 
 
@@ -168,27 +194,17 @@ def _dot_exact(a, b, dims, terms=3):
     return acc
 
 
-def _kernel(len_ref, tab_ref, q_ref, *refs,
-            pages, block, n_kv, group, mbs, n_slots, scale, v_lanes=None,
-            p_terms=3):
-    # refs: the pools in HBM (K and V; with `v_lanes` ONE, whose first
-    # `v_lanes` lanes are the values), the output, a ring buffer a pool,
-    # the semaphores, the ring's state
-    n_pools = 1 if v_lanes else 2
-    hbm, o_ref = refs[:n_pools], refs[n_pools]
-    bufs, (sem, ring) = refs[n_pools + 1:2 * n_pools + 1], \
-        refs[2 * n_pools + 1:]
-    k_hbm, kbuf, vbuf = hbm[0], bufs[0], bufs[-1]
-    # grid (slot,), sequential. `ring` carries across steps: [0] the ring
-    # slot that holds the chunk computed on next, [1] and [2] the slot and
-    # the chunk whose copies are started next (the cursor), [3 + r] the
-    # pages of ring slot r that the chunk computed on there last had fetched
-    b = pl.program_id(0)
-    nh, hd = o_ref.shape
-    depth, nb = kbuf.shape[0], k_hbm.shape[0]
-    page_rows = block * n_kv             # rows of one page: (t, head)
-    rows = pages * page_rows             # rows of one chunk
-    span = pages * block                 # positions of one chunk
+def _walk(len_ref, tab_ref, hbm, sem, ring, *, pages, block, mbs, n_slots,
+          dst):
+    """The copies of the walk that both kernels make: ``n_pages(slot)``,
+    ``issue(to)``, ``await_(cnt, at)`` and ``begin(b)``, the call's first
+    chunks (at grid step ``b`` 0). ``dst(i, r, idx)`` is pool ``i``'s
+    buffer of ring slot ``r`` at ``idx``. `ring` carries across grid steps:
+    [0] the ring slot that holds the chunk computed on next, [1] and [2]
+    the slot and the chunk whose copies are started next (the cursor),
+    [3 + r] the pages of ring slot r that the chunk computed on there last
+    had fetched."""
+    nb = hbm[0].shape[0]
 
     def n_pages(slot):
         return jnp.clip((len_ref[slot] + block - 1) // block, 1, mbs)
@@ -213,8 +229,8 @@ def _kernel(len_ref, tab_ref, q_ref, *refs,
 
             @pl.when(j < cnt)
             def _():
-                for i, (pool, buf) in enumerate(zip(hbm, bufs)):
-                    pltpu.make_async_copy(pool.at[page], buf.at[to, j],
+                for i, pool in enumerate(hbm):
+                    pltpu.make_async_copy(pool.at[page], dst(i, to, j),
                                           sem.at[to, i]).start()
 
         if unrolled:
@@ -227,26 +243,49 @@ def _kernel(len_ref, tab_ref, q_ref, *refs,
         ring[2] = jnp.where(more, c + 1, 0)
 
     def await_(cnt, at):
-        """Wait for `cnt` pages of both pools in ring slot `at`. A copy's
+        """Wait for `cnt` pages of every pool in ring slot `at`. A copy's
         semaphore counts bytes, so the pages are awaited a power of two at
         a time: one wait a pool for a whole chunk."""
         bit = 1 << (pages.bit_length() - 1)
         while bit:
             @pl.when((cnt & bit) != 0)
             def _(bit=bit):
-                for i, (pool, buf) in enumerate(zip(hbm, bufs)):
+                for i, pool in enumerate(hbm):
                     pltpu.make_async_copy(
-                        pool.at[pl.ds(0, bit)], buf.at[at, pl.ds(0, bit)],
+                        pool.at[pl.ds(0, bit)], dst(i, at, pl.ds(0, bit)),
                         sem.at[at, i]).wait()
             bit >>= 1
 
-    @pl.when(b == 0)
-    def _():
-        ring[0] = ring[1] = ring[2] = 0
-        for to in range(depth):
-            ring[3 + to] = pages         # the buffers may hold anything
-        for to in range(depth - 1):
-            issue(to, unrolled=False)
+    def begin(b):
+        @pl.when(b == 0)
+        def _():
+            ring[0] = ring[1] = ring[2] = 0
+            for to in range(RING):
+                ring[3 + to] = pages     # the buffers may hold anything
+            for to in range(RING - 1):
+                issue(to, unrolled=False)
+
+    return n_pages, issue, await_, begin
+
+
+def _kernel(len_ref, tab_ref, q_ref, *refs,
+            pages, block, n_kv, group, mbs, n_slots, scale):
+    # refs: the pools in HBM (K and V), the output, a ring buffer a pool,
+    # the semaphores, the ring's state (`_walk`)
+    hbm, o_ref = refs[:2], refs[2]
+    bufs, (sem, ring) = refs[3:5], refs[5:]
+    kbuf, vbuf = bufs
+    # grid (slot,), sequential
+    b = pl.program_id(0)
+    nh, hd = o_ref.shape
+    depth = kbuf.shape[0]
+    page_rows = block * n_kv             # rows of one page: (t, head)
+    rows = pages * page_rows             # rows of one chunk
+    span = pages * block                 # positions of one chunk
+    n_pages, issue, await_, begin = _walk(
+        len_ref, tab_ref, hbm, sem, ring, pages=pages, block=block, mbs=mbs,
+        n_slots=n_slots, dst=lambda i, r, idx: bufs[i].at[r, idx])
+    begin(b)
 
     length = len_ref[b]
     live = n_pages(b)
@@ -282,8 +321,6 @@ def _kernel(len_ref, tab_ref, q_ref, *refs,
 
         k2 = kbuf[at].reshape(rows, -1)
         v2 = vbuf[at].reshape(rows, -1)
-        if v_lanes:
-            v2 = v2[:, :v_lanes]
         s = _dot_exact(q, k2, ((1,), (1,))) * scale          # [nh, rows]
         # column r of a chunk is position r // n_kv of KV head r % n_kv;
         # row i of the scores is query head i, which reads KV head
@@ -301,7 +338,7 @@ def _kernel(len_ref, tab_ref, q_ref, *refs,
         p = jnp.exp(s - m_new)           # masked columns: exactly 0
         alpha = jnp.exp(m - m_new)
         l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
-        acc = alpha * acc + _dot_exact(p, v2, ((1,), (0,)), p_terms)
+        acc = alpha * acc + _dot_exact(p, v2, ((1,), (0,)))
         return m_new, l, acc
 
     m, l, acc = jax.lax.fori_loop(
@@ -310,6 +347,93 @@ def _kernel(len_ref, tab_ref, q_ref, *refs,
          jnp.zeros((nh, 1), jnp.float32),
          jnp.zeros((nh, hd), jnp.float32)))
     o_ref[...] = (acc / l).astype(o_ref.dtype)
+
+
+def _switch(index, branches, *args):
+    """``lax.switch`` as a balanced tree of two-way conds: Mosaic lowers a
+    switch to a chain of conds as deep as it has branches, and a chain of
+    12 overflows its layout pass's stack."""
+    if len(branches) == 1:
+        return branches[0](*args)
+    half = len(branches) // 2
+    return jax.lax.cond(
+        index < half, lambda *a: _switch(index, branches[:half], *a),
+        lambda *a: _switch(index - half, branches[half:], *a), *args)
+
+
+def _latent_kernel(len_ref, tab_ref, q_ref, hbm, o_ref, *refs, pages, block,
+                   mbs, n_slots, scale, v_lanes, p_terms):
+    # refs: a buffer a ring slot (three allocations), the semaphores, the
+    # ring's state (`_walk`), the context accumulator [nh, v_lanes] float32
+    bufs, (sem, ring, acc_ref) = refs[:RING], refs[RING:]
+    b = pl.program_id(0)
+    nh, lanes = q_ref.shape
+    rows = pages * block                 # rows (positions) of one chunk
+    piece = min(PIECE_PAGES, pages)
+    n_k = -(-pages // piece)             # pieces of a chunk
+    n_pages, issue, await_, begin = _walk(
+        len_ref, tab_ref, (hbm,), sem, ring, pages=pages, block=block,
+        mbs=mbs, n_slots=n_slots, dst=lambda i, r, idx: bufs[r].at[idx])
+    begin(b)
+
+    length = len_ref[b]
+    live = n_pages(b)
+    n_chunks = (live + pages - 1) // pages
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    def trip(at, k):
+        """A chunk in ring slot `at`, computed over its first `k` pieces:
+        both static, so that the copies started here (into another
+        allocation) may be scheduled under the products."""
+        buf, r = bufs[at], min(k * piece, pages) * block
+
+        def body(c, m, l):
+            ring[0] = (at + 1) % RING
+            left = length - c * rows             # live rows from here on
+            cnt = jnp.minimum(live - c * pages, pages)
+
+            # the pages this chunk did not fetch may hold anything, inf and
+            # nan too, and 0 * that is nan in the context product: those
+            # the last chunk in this ring slot fetched are zeroed, the ones
+            # past them were zeroed then
+            def zero(j, _):
+                buf[j] = jnp.zeros(buf.shape[1:], buf.dtype)
+
+            jax.lax.fori_loop(cnt, ring[3 + at], zero, None)
+            ring[3 + at] = cnt
+            await_(cnt, at)
+            # the page the length ends in: its stale tail is zeroed too
+            edge = jnp.clip((left - 1) // block, 0, pages - 1)
+            tail = edge * block + jax.lax.broadcasted_iota(
+                jnp.int32, (block, 1), 0)
+            v_edge = buf[edge]
+            buf[edge] = jnp.where(tail < left, v_edge, jnp.zeros_like(v_edge))
+            issue((at - 1) % RING)
+
+            x = buf[:r // block].reshape(r, lanes)
+            s = _dot_exact(q_ref[...], x, ((1,), (1,))) * scale  # [nh, r]
+            col = jax.lax.broadcasted_iota(jnp.int32, (nh, r), 1)
+            s = jnp.where(col < left, s, _NEG)
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)       # masked columns: exactly 0
+            alpha = jnp.exp(m - m_new)
+            v = buf[:r // block, :, :v_lanes].reshape(r, v_lanes)
+            acc_ref[...] = alpha * acc_ref[...] + _dot_exact(
+                p, v, ((1,), (0,)), p_terms)
+            return m_new, alpha * l + jnp.sum(p, axis=1, keepdims=True)
+        return body
+
+    branches = [trip(at, k) for at in range(RING) for k in range(1, n_k + 1)]
+
+    def chunk(c, carry):
+        k = jnp.clip((length - c * rows + piece * block - 1)
+                     // (piece * block), 1, n_k)
+        return _switch(ring[0] * n_k + k - 1, branches, c, *carry)
+
+    _, l = jax.lax.fori_loop(0, n_chunks, chunk,
+                             (jnp.full((nh, 1), _NEG, jnp.float32),
+                              jnp.zeros((nh, 1), jnp.float32)))
+    o_ref[...] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, pool_k, pool_v, table, lengths, *,
@@ -339,7 +463,8 @@ def paged_decode_attention(q, pool_k, pool_v, table, lengths, *,
     pages = min(pages_per_chunk, table.shape[1]) if pages_per_chunk \
         else chunk_pages(page_bytes, table.shape[1])
     note_attention_kernel("paged_kernel")
-    _GEOMETRY.update(kv_chunk_pages=pages, kv_page_bytes=page_bytes)
+    _GEOMETRY.update(kv_chunk_pages=pages, kv_page_bytes=page_bytes,
+                     kv_piece_pages=pages)
     return _attend(q, pool_k, pool_v, table, lengths, interpret=interpret,
                    pages=pages, n_kv=n_kv)
 
@@ -363,17 +488,20 @@ def latent_decode_attention(q, pool, table, lengths, *, rank, scale,
     lanes]`` that ``table`` / ``lengths`` say are live (as
     ``paged_decode_attention``), scores times ``scale``, the context over
     the rows' first ``rank`` lanes. Returns [B, 1, nh, rank] in ``q``'s
-    dtype. The walk is ``paged_decode``'s with one pool; a chunk's pages
-    are rounded up to a multiple of 8, so that the scores' columns fill
-    whole lane tiles (25 pages of 20 KB become 32: 0.415 ms against 24's
-    0.440 at the served shape)."""
+    dtype. The walk's copies are ``paged_decode``'s with one pool; a
+    chunk's pages are rounded up to a multiple of 8, so that the scores'
+    columns fill whole lane tiles (25 pages of 20 KB become 32: 0.415 ms
+    against 24's 0.440 at the served shape), and a slot's last chunk is
+    computed in pieces of ``PIECE_PAGES`` up to its live rows
+    (``_latent_kernel``)."""
     assert q.shape[1] == 1 and q.shape[3] == pool.shape[2] >= rank
     page_bytes = pool.shape[1] * pool.shape[2] * pool.dtype.itemsize
     pages = pages_per_chunk or min(
         -(-chunk_pages(page_bytes, table.shape[1]) // 8) * 8, MAX_PAGES)
     pages = min(pages, table.shape[1])
     note_attention_kernel("mla_decode")
-    _GEOMETRY.update(kv_chunk_pages=pages, kv_page_bytes=page_bytes)
+    _GEOMETRY.update(kv_chunk_pages=pages, kv_page_bytes=page_bytes,
+                     kv_piece_pages=min(PIECE_PAGES, pages))
     return _attend(q, pool, None, table, lengths, interpret=interpret,
                    pages=pages, n_kv=1, v_lanes=int(rank),
                    scale=float(scale), p_terms=LATENT_P_TERMS)
@@ -389,15 +517,23 @@ def _attend(q, pool_k, pool_v, table, lengths, *, interpret, pages, n_kv,
     """``pool_v`` None: the latent geometry, the values ``pool_k``'s first
     ``v_lanes`` lanes (the kernel ``mla_decode``)."""
     b, _, nh, hd = q.shape
-    nb, block = pool_k.shape[0], pool_k.shape[1] // n_kv
-    mbs = table.shape[1]
-    pools = (pool_k,) if pool_v is None else (pool_k, pool_v)
-    out_hd = v_lanes or hd
-    kernel = functools.partial(
-        _kernel, pages=pages, block=block, n_kv=n_kv, group=nh // n_kv,
-        mbs=mbs, n_slots=b, scale=scale or 1.0 / math.sqrt(hd),
-        v_lanes=v_lanes, p_terms=p_terms)
-    buf = pltpu.VMEM((RING, pages, block * n_kv, hd), pool_k.dtype)
+    block, mbs = pool_k.shape[1] // n_kv, table.shape[1]
+    state = [pltpu.SemaphoreType.DMA((RING, 1 if pool_v is None else 2)),
+             pltpu.SMEM((3 + RING,), jnp.int32)]
+    if pool_v is None:
+        pools, out_hd = (pool_k,), v_lanes
+        kernel = functools.partial(
+            _latent_kernel, pages=pages, block=block, mbs=mbs, n_slots=b,
+            scale=scale, v_lanes=v_lanes, p_terms=p_terms)
+        scratch = [pltpu.VMEM((pages, block, hd), pool_k.dtype)] * RING \
+            + state + [pltpu.VMEM((nh, v_lanes), jnp.float32)]
+    else:
+        pools, out_hd = (pool_k, pool_v), hd
+        kernel = functools.partial(
+            _kernel, pages=pages, block=block, n_kv=n_kv, group=nh // n_kv,
+            mbs=mbs, n_slots=b, scale=1.0 / math.sqrt(hd))
+        scratch = [pltpu.VMEM((RING, pages, block * n_kv, hd),
+                              pool_k.dtype)] * 2 + state
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -407,15 +543,13 @@ def _attend(q, pool_k, pool_v, table, lengths, *, interpret, pages, n_kv,
             + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
             out_specs=pl.BlockSpec((None, nh, out_hd),
                                    lambda i, *_: (i, 0, 0)),
-            scratch_shapes=[buf] * len(pools)
-            + [pltpu.SemaphoreType.DMA((RING, len(pools))),
-               pltpu.SMEM((3 + RING,), jnp.int32)]),
+            scratch_shapes=scratch),
         out_shape=jax.ShapeDtypeStruct((b, nh, out_hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             disable_bounds_checks=True),
         interpret=interpret,
-        name="mla_decode" if v_lanes else "paged_decode",
+        name="mla_decode" if pool_v is None else "paged_decode",
     )(lengths.astype(jnp.int32), table.reshape(-1).astype(jnp.int32),
       q.reshape(b, nh, hd), *pools)
     return out.reshape(b, 1, nh, out_hd)

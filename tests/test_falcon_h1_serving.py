@@ -120,7 +120,8 @@ def test_decode_call_spans_carry_the_kernels_geometry(tiny):
         eng.run()
         traced = paged_decode.kernel_geometry()
     calls = trace.spans(t0, time.perf_counter(), "engine/decode_call")
-    assert traced == {"kv_chunk_pages": 12, "kv_page_bytes": 8 * 1 * 16 * 4}
+    assert traced == {"kv_chunk_pages": 12, "kv_page_bytes": 8 * 1 * 16 * 4,
+                      "kv_piece_pages": 12}
     assert calls and all(c.attrs["path"] == "paged_kernel" for c in calls)
     for c in calls:
         assert {k: c.attrs[k] for k in traced} == traced

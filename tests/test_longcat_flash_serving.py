@@ -263,38 +263,63 @@ def gathered_view(q, pool, table, lengths, rank, scale):
                       precision="highest")[:, None]
 
 
+# lengths, pages a chunk (None: derived), query heads, table width, NaN in
+# every pool row past each slot's length
 CASES = {
-    "ragged": ([1, 5, 13, 28, 9], None),
-    "a_dead_slot_walks_one_page": ([0, 7, 0, 20, 3], None),
-    "at_a_pages_edge": ([4, 8, 12, 16, 28], None),
-    "at_a_chunks_edge": ([8, 16, 24, 9, 17], 2),
-    "chunks_of_three_pages": ([28, 1, 12, 13, 25], 3),
-    "one_page_a_chunk": ([28, 4, 5, 27, 1], 1),
+    "ragged": ([1, 5, 13, 28, 9], None, 6, 7, False),
+    "a_dead_slot_walks_one_page": ([0, 7, 0, 20, 3], None, 6, 7, False),
+    "at_a_pages_edge": ([4, 8, 12, 16, 28], None, 6, 7, False),
+    "at_a_chunks_edge": ([8, 16, 24, 9, 17], 2, 6, 7, False),
+    "chunks_of_three_pages": ([28, 1, 12, 13, 25], 3, 6, 7, False),
+    "one_page_a_chunk": ([28, 4, 5, 27, 1], 1, 6, 7, False),
+    # 4-position pages in chunks of 24 (96 rows) or 16 (64 rows): pieces of
+    # 8 pages (32 rows), the latent walk's unit of stopping at a slot's
+    # live rows
+    "128_heads_at_a_pieces_edge": ([31, 32, 33, 64, 65], None, 128, 24, True),
+    "64_heads_at_a_pieces_edge": ([31, 32, 33, 63, 96], None, 64, 24, True),
+    "128_heads_at_a_chunks_edge": ([63, 64, 65, 128, 192], 16, 128, 48,
+                                   True),
+    "64_heads_at_a_chunks_edge": ([64, 65, 127, 129, 191], 16, 64, 48,
+                                  True),
+    "one_live_row": ([1, 1, 96, 1, 33], None, 128, 24, True),
+    "a_dead_slot_among_pieces": ([0, 96, 0, 40, 1], None, 64, 24, True),
 }
 
 
 @pytest.mark.parametrize("which", sorted(CASES))
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_the_latent_kernel_is_the_gathered_view(which, dtype):
-    """4-position pages, 7 table entries a slot, 6 query heads on rows of
-    16 + 8 lanes in 128: lengths at a page's and a chunk's edge, a dead
-    slot (length 0 walks one page and its output is never read)."""
-    lengths, pages = CASES[which]
+    """4-position pages, query heads on rows of 16 + 8 lanes in 128: lengths
+    at a page's, a piece's and a chunk's edge, one live row, 6, 64 and 128
+    heads, a dead slot (length 0 walks one page and its output is never
+    read); where the case says so, every pool row past a slot's length
+    holds NaN, and none reaches the output."""
+    lengths, pages, nh, mbs, nan = CASES[which]
     rng = np.random.default_rng(len(which))
-    nb, bs, lanes, rank, nh, b, mbs = 40, 4, 128, 16, 6, 5, 7
+    nb, bs, lanes, rank, b = 5 * mbs + 5, 4, 128, 16, 5
     pool = np.zeros((nb, bs, lanes), np.float32)
     pool[..., :24] = rng.normal(size=(nb, bs, 24))
     q = np.zeros((b, 1, nh, lanes), np.float32)
     q[..., :24] = rng.normal(size=(b, 1, nh, 24))
-    pool, q = jnp.asarray(pool, dtype), jnp.asarray(q, dtype)
-    table = jnp.asarray(rng.permutation(nb - 1)[:b * mbs].reshape(b, mbs)
-                        + 1, jnp.int32)
+    table = rng.permutation(nb - 1)[:b * mbs].reshape(b, mbs) + 1
+    dirty = pool.copy()
+    if nan:
+        live = np.zeros((nb, bs), bool)
+        for row, n in zip(table, lengths):
+            for p in range(n):
+                live[row[p // bs], p % bs] = True
+        dirty[~live] = np.nan
+    q = jnp.asarray(q, dtype)
+    table = jnp.asarray(table, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
     got = paged_decode.latent_decode_attention(
-        q, pool, table, lengths, rank=rank, scale=0.3, interpret=True,
-        pages_per_chunk=pages)
+        q, jnp.asarray(dirty, dtype), table, lengths, rank=rank, scale=0.3,
+        interpret=True, pages_per_chunk=pages)
     assert got.shape == (b, 1, nh, rank) and got.dtype == q.dtype
-    want = gathered_view(q.astype(jnp.float32), pool.astype(jnp.float32),
+    geo = paged_decode.kernel_geometry()
+    assert geo["kv_piece_pages"] == min(8, geo["kv_chunk_pages"])
+    want = gathered_view(q.astype(jnp.float32),
+                         jnp.asarray(pool, dtype).astype(jnp.float32),
                          table, lengths, rank, 0.3)
     live = np.asarray(lengths) > 0
     err = np.abs(np.asarray(got, np.float32) - np.asarray(want))[live]
@@ -312,7 +337,8 @@ def test_the_latent_walk_rounds_its_chunk_up_to_eight_pages():
             *a, rank=512, scale=0.1, interpret=True),
             q, pool, table, jnp.ones((2,), jnp.int32))
         assert paged_decode.kernel_geometry() == {
-            "kv_chunk_pages": pages, "kv_page_bytes": 16 * 640 * 2}
+            "kv_chunk_pages": pages, "kv_page_bytes": 16 * 640 * 2,
+            "kv_piece_pages": 8}
 
 
 # --------------------------------------------------- spans and refusals

@@ -82,8 +82,10 @@ def derived_pages(args):
     geo = kernel_geometry()
     page_bytes = BS * args[1].shape[2] * args[1].shape[3] \
         * args[1].dtype.itemsize
-    assert geo == {"kv_page_bytes": page_bytes, "kv_chunk_pages":
-                   chunk_pages(page_bytes, args[3].shape[1])}
+    pages = chunk_pages(page_bytes, args[3].shape[1])
+    # the K/V walk computes a whole chunk at a time
+    assert geo == {"kv_page_bytes": page_bytes, "kv_chunk_pages": pages,
+                   "kv_piece_pages": pages}
     return geo["kv_chunk_pages"]
 
 
@@ -218,7 +220,38 @@ def test_a_chunk_is_sized_in_bytes(n_kv, hd, mbs, pages):
     jax.eval_shape(lambda *a: paged_decode_attention(
         *a, interpret=True, n_kv=n_kv), *shapes)
     assert kernel_geometry() == {"kv_chunk_pages": pages,
-                                 "kv_page_bytes": BS * n_kv * hd * 2}
+                                 "kv_page_bytes": BS * n_kv * hd * 2,
+                                 "kv_piece_pages": pages}
+
+
+@pytest.mark.parametrize("family", ["latent", "gpt"])
+def test_decode_call_spans_say_in_what_pieces_the_walk_computes(family):
+    """Every ``engine/decode_call`` span of an engine on a kernel carries
+    ``kv_piece_pages``: the latent walk stops at a slot's live rows in
+    pieces of 8 pages (half its chunk of 16 here: a table of 16 entries),
+    the K/V walk computes whole chunks."""
+    import time
+    from paddle_tpu import models
+    from paddle_tpu.monitor import trace
+    from paddle_tpu.serving import DecodeEngine
+    from paddle_tpu.kernels.pallas import paged_decode
+    if family == "latent":
+        model = models.LongCatFlashForCausalLM(models.longcat_flash_tiny())
+        path, chunk, piece = "mla_decode", 16, 8
+    else:
+        model = models.GPTForCausalLM(models.gpt_tiny())
+        path, chunk, piece = "paged_kernel", 16, 16
+    model.eval()
+    eng = DecodeEngine(model, max_slots=2, max_len=128, block_size=8,
+                       prefill_chunk=16)
+    t0 = time.perf_counter()
+    with paged_decode.force_interpret():
+        eng.submit(list(range(1, 12)), max_new_tokens=3)
+        eng.run()
+    calls = trace.spans(t0, time.perf_counter(), "engine/decode_call")
+    assert calls and {(c.attrs["path"], c.attrs["kv_chunk_pages"],
+                       c.attrs["kv_piece_pages"]) for c in calls} == {
+        (path, chunk, piece)}
 
 
 def test_off_the_tpu_the_model_keeps_the_gather_path():
@@ -353,7 +386,8 @@ def test_mosaic_compiles_the_derived_chunk_of_16_kb_pages(one_chip, nh, n_kv,
         ((nb, BS * n_kv, hd), jnp.bfloat16), ((b, mbs), jnp.int32),
         ((b,), jnp.int32))
     assert kernel_geometry() == {"kv_chunk_pages": 32,
-                                 "kv_page_bytes": 16384}
+                                 "kv_page_bytes": 16384,
+                                 "kv_piece_pages": 32}
     assert exe.as_text().count('custom_call_target="tpu_custom_call"') == 1
     assert exe.memory_analysis().temp_size_in_bytes < (1 << 20)
 
@@ -433,14 +467,15 @@ def test_mosaic_compiles_the_packed_flash_attention(one_chip, b, L, heads, d,
             == {"fused": 1, "split": 2}[form])
 
 
-def test_mosaic_compiles_the_latent_geometry(one_chip):
-    """LongCat-Flash's decode step: 64 query heads on ONE cached row of 512
-    + 64 lanes in 640, 128 slots, 20 KB pages in chunks of 32: one kernel
-    named `mla_decode`, one pool, the context 512 wide, no pool-sized
-    copy."""
+@pytest.mark.parametrize("nh", [64, 128], ids=["longcat", "deepseek"])
+def test_mosaic_compiles_the_latent_geometry(one_chip, nh):
+    """LongCat-Flash's and DeepSeek-V3's decode steps: 64 or 128 query heads
+    on ONE cached row of 512 + 64 lanes in 640, 128 slots, 20 KB pages in
+    chunks of 32 computed in pieces of 8: one kernel named `mla_decode`,
+    one pool, the context 512 wide, no pool-sized copy."""
     from paddle_tpu.kernels.pallas.paged_decode import (
         kernel_geometry, latent_decode_attention)
-    b, nh, lanes, rank, mbs, nb = 128, 64, 640, 512, 256, 16384
+    b, lanes, rank, mbs, nb = 128, 640, 512, 256, 16384
     exe = compile_for(
         one_chip, lambda q, pool, t, n: latent_decode_attention(
             q, pool, t, n, rank=rank, scale=192 ** -0.5),
@@ -451,7 +486,8 @@ def test_mosaic_compiles_the_latent_geometry(one_chip):
     assert "mla_decode" in text
     assert exe.memory_analysis().temp_size_in_bytes < (1 << 20)
     assert kernel_geometry() == {"kv_chunk_pages": 32,
-                                 "kv_page_bytes": BS * lanes * 2}
+                                 "kv_page_bytes": BS * lanes * 2,
+                                 "kv_piece_pages": 8}
 
 
 def test_mosaic_compiles_an_expert_walked_in_blocks(one_chip):

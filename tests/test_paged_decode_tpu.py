@@ -82,3 +82,56 @@ def test_the_kernel_reads_what_the_gathered_view_reads(b, nh, n_kv, hd, mbs,
     tol = 2.0 ** -8 if q_dtype == jnp.bfloat16 else 1e-5
     np.testing.assert_allclose(got, want, rtol=0,
                                atol=tol * max(float(np.abs(want).max()), 1.0))
+
+
+@pytest.mark.tpu
+@pytest.mark.parametrize("nh", [64, 128], ids=["longcat", "deepseek"])
+def test_the_latent_walk_reads_what_the_gathered_view_reads(nh):
+    """`mla_decode` at the two latent cells' decode shapes (128 slots, rows
+    of 512 + 64 lanes in 640, 20 KB pages in chunks of 32 computed in pieces
+    of 8): dead slots, one live row, lengths one under, at and one over a
+    piece's and a chunk's edge, the rest as a closed-loop cell leaves them;
+    every pool row past a slot's length holds NaN. The output is finite and
+    the float32 view's to bfloat16 rounding."""
+    from paddle_tpu.kernels.pallas import paged_decode as pd
+    b, lanes, rank, mbs, nb = 128, 640, 512, 256, 16384
+    rng = np.random.RandomState(nh)
+    lengths = np.clip(rng.normal(1140, 400, b), 1, mbs * BS).astype(np.int32)
+    lengths[:10] = [0, 1, 127, 128, 129, 511, 512, 513, 1024, mbs * BS]
+    perm = rng.permutation(np.arange(1, nb))
+    table, used = np.zeros((b, mbs), np.int32), 0
+    live = np.zeros((nb, BS), bool)
+    for i, n in enumerate(lengths):
+        k = max(1, -(-int(n) // BS))
+        table[i, :k] = perm[used:used + k]
+        for p in range(int(n)):
+            live[table[i, p // BS], p % BS] = True
+        used += k
+    pool = (rng.randn(nb, BS, lanes) * 0.5).astype(np.float32)
+    pool[..., 576:] = 0
+    q = (rng.randn(b, 1, nh, lanes) * 0.5).astype(np.float32)
+    q[..., 576:] = 0
+    dirty = pool.copy()
+    dirty[~live] = np.nan
+    q, clean, dirty = (jnp.asarray(x, jnp.bfloat16) for x in (q, pool, dirty))
+    table, n = jnp.asarray(table), jnp.asarray(lengths)
+    scale = 192 ** -0.5
+    assert pd.latent_mode(q, dirty, rank) == "mosaic"
+    got = jax.jit(lambda *a: pd.latent_decode_attention(
+        *a, rank=rank, scale=scale))(q, dirty, table, n)
+    assert pd.kernel_geometry()["kv_piece_pages"] == 8
+
+    def view(q, pool, table, n):
+        rows = pool[table].reshape(b, -1, lanes).astype(jnp.float32)
+        s = jnp.einsum("bhd,bmd->bhm", q[:, 0].astype(jnp.float32), rows,
+                       precision="highest") * scale
+        s = jnp.where(jnp.arange(rows.shape[1]) < n[:, None, None], s, -1e30)
+        return jnp.einsum("bhm,bmd->bhd", jax.nn.softmax(s, -1),
+                          rows[..., :rank], precision="highest")
+
+    want = np.asarray(jax.jit(view)(q, clean, table, n))
+    got = np.asarray(got, np.float32)[:, 0][lengths > 0]
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, want[lengths > 0], rtol=0,
+                               atol=2.0 ** -8 * max(float(np.abs(want).max()),
+                                                    1.0))

@@ -420,7 +420,7 @@ def test_decode_call_spans_say_which_walk_the_kernel_took(tiny):
     """The kernel sizes a chunk from its input (kernels/pallas/
     paged_decode.py::chunk_pages) and notes what it was traced with; every
     ``engine/decode_call`` span of an engine on the kernel carries it, a
-    gather engine's spans carry neither attribute."""
+    gather engine's spans carry none of its attributes."""
     import time
     from paddle_tpu.kernels.pallas import paged_decode
     from paddle_tpu.monitor import trace
@@ -435,7 +435,8 @@ def test_decode_call_spans_say_which_walk_the_kernel_took(tiny):
     assert eng.stats()["decode_attention"] == "paged_kernel" and calls
     # float32 pools, 2 heads of 16, blocks of 8: 1 KB a page; a chunk of
     # 512 KB would be 512 pages: the table's 6 entries bound it
-    assert traced == {"kv_chunk_pages": 6, "kv_page_bytes": 8 * 2 * 16 * 4}
+    assert traced == {"kv_chunk_pages": 6, "kv_page_bytes": 8 * 2 * 16 * 4,
+                      "kv_piece_pages": 6}
     for c in calls:
         assert {k: c.attrs[k] for k in traced} == traced
     t0 = time.perf_counter()
@@ -444,8 +445,9 @@ def test_decode_call_spans_say_which_walk_the_kernel_took(tiny):
     gather.submit(list(range(1, 12)), max_new_tokens=4)
     gather.run()
     calls = trace.spans(t0, time.perf_counter(), "engine/decode_call")
-    assert calls and all("kv_chunk_pages" not in c.attrs
-                         and "kv_page_bytes" not in c.attrs for c in calls)
+    assert calls and all(not {"kv_chunk_pages", "kv_page_bytes",
+                              "kv_piece_pages"} & set(c.attrs)
+                         for c in calls)
 
 
 # ----------------------------------------------------- satellite: pager unit
