@@ -40,6 +40,11 @@ node, so a chip that holds part of one group is reached only by the tokens
 that kept that group). A shared expert is gated by ``sigmoid(x w_s)``
 (Qwen3-Next) or, without ``shared_gated``, added whole (DeepSeek-V3).
 
+The experts are SwiGLU, or with ``poly`` (``moe_grouped.Poly``: Motif-3's
+``hidden_act`` ``poly_norm``) PolyNorm on the gate branch, each expert's
+and the shared expert's four numbers ``a1 a2 a3 b`` parameters of their
+own, ``experts_poly [count, 4]`` and ``shared_poly [4]``.
+
 ``collect_counters()`` is how a serving executable reads the step's routing:
 inside it every call adds three traced integers (assignments of valid
 tokens, those that fell on held experts, held experts with at least one),
@@ -53,6 +58,7 @@ import contextlib
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from .....nn import initializer
 from .....nn.layer import Layer
@@ -142,6 +148,17 @@ def _swiglu(x, gate_w, up_w, down_w):
     return jnp.dot(hid, down_w, precision=prec)
 
 
+def _poly_ffn(x, gate_w, up_w, down_w, coef, poly):
+    """``W_d(PolyNorm(W_g x) * W_u x)``, the hidden row in float32."""
+    prec = "highest" if x.dtype == jnp.float32 else None
+    f32 = jnp.float32
+    hid = moe_grouped.hidden(
+        jnp.dot(x, gate_w, precision=prec, preferred_element_type=f32),
+        jnp.dot(x, up_w, precision=prec, preferred_element_type=f32),
+        coef, poly)
+    return jnp.dot(hid.astype(x.dtype), down_w, precision=prec)
+
+
 class HeldExpertsMoE(Layer):
     """Router over ``n_routed`` experts (and ``n_zero`` zero-compute experts
     after them; ``scoring`` softmax or sigmoid; ``n_group`` groups of which
@@ -156,7 +173,8 @@ class HeldExpertsMoE(Layer):
                  n_zero: int = 0, choice_bias: bool = False,
                  scaling: float = 1.0, scoring: str = "softmax",
                  n_group: int = 1, topk_group: int = 1,
-                 shared_gated: bool = True, std: float = 0.02, dtype=None):
+                 shared_gated: bool = True, poly: "moe_grouped.Poly" = None,
+                 std: float = 0.02, dtype=None):
         super().__init__()
         count = n_routed if count is None else count
         if not (0 <= offset and offset + count <= n_routed and count >= 1):
@@ -174,6 +192,7 @@ class HeldExpertsMoE(Layer):
         self.n_zero, self.scaling = int(n_zero), float(scaling)
         self.scoring = str(scoring)
         self.n_group, self.topk_group = int(n_group), int(topk_group)
+        self.poly = poly
         normal = initializer.Normal(0.0, std)
 
         def mat(*shape):
@@ -189,11 +208,22 @@ class HeldExpertsMoE(Layer):
         self.experts_gate_proj = mat(count, h, i)
         self.experts_up_proj = mat(count, h, i)
         self.experts_down_proj = mat(count, i, h)
+
+        def poly_coef(*lead):       # a1 = a2 = a3 = 1/3, b = 0
+            start = np.broadcast_to(np.array([1, 1, 1, 0], np.float32) / 3,
+                                    lead + (4,))
+            return self.create_parameter(
+                lead + (4,), dtype=dtype,
+                default_initializer=initializer.Assign(start.copy()))
+        if poly is not None:
+            self.experts_poly = poly_coef(count)
         self.shared_width = int(shared_width)
         if shared_width:
             self.shared_gate_proj = mat(h, shared_width)
             self.shared_up_proj = mat(h, shared_width)
             self.shared_down_proj = mat(shared_width, h)
+            if poly is not None:
+                self.shared_poly = poly_coef()
             if shared_gated:
                 self.shared_expert_gate = mat(h, 1)
         self.shared_gated = bool(shared_width and shared_gated)
@@ -216,10 +246,12 @@ class HeldExpertsMoE(Layer):
                 None if self.gate_bias is None else self.gate_bias.value(),
                 self.scaling, self.scoring, self.n_group, self.topk_group)
         with jax.named_scope("moe_experts"):
+            poly = {} if self.poly is None else dict(
+                coef=self.experts_poly.value(), poly=self.poly)
             out, counts = moe_grouped.moe_grouped(
                 x, ids, weights, valid, self.experts_gate_proj.value(),
                 self.experts_up_proj.value(), self.experts_down_proj.value(),
-                self.offset)
+                self.offset, **poly)
         if self.n_zero:
             with jax.named_scope("zero_experts"):
                 to_zero = ids >= self.n_routed
@@ -242,9 +274,15 @@ class HeldExpertsMoE(Layer):
         if self.shared_width:
             with jax.named_scope("shared_expert"):
                 prec = "highest" if x.dtype == jnp.float32 else None
-                sh = _swiglu(x, self.shared_gate_proj.value(),
-                             self.shared_up_proj.value(),
-                             self.shared_down_proj.value())
+                if self.poly is None:
+                    sh = _swiglu(x, self.shared_gate_proj.value(),
+                                 self.shared_up_proj.value(),
+                                 self.shared_down_proj.value())
+                else:
+                    sh = _poly_ffn(x, self.shared_gate_proj.value(),
+                                   self.shared_up_proj.value(),
+                                   self.shared_down_proj.value(),
+                                   self.shared_poly.value(), self.poly)
                 if self.shared_gated:
                     g = jax.nn.sigmoid(jnp.dot(
                         x, self.shared_expert_gate.value(),

@@ -47,6 +47,20 @@ def pool_writes_traced(since: int = 0) -> list:
     return _POOL_WRITES[since:]
 
 
+# ... and the rows each traced ``mhc`` call mixed (``kernels/pallas/mhc.py``:
+# a hyper-connection boundary's rows, the kernel or its plain form), so that
+# a call span can say how many rows its executable mixes.
+_MHC_ROWS: list = []
+
+
+def note_mhc(rows: int) -> None:
+    _MHC_ROWS.append(int(rows))
+
+
+def mhc_rows_traced(since: int = 0) -> list:
+    return _MHC_ROWS[since:]
+
+
 def tpu_placement(x) -> bool:
     """True when `x` will execute on a real TPU. Must NOT observe the value:
     under deferred eager a .value() here would flush the pending graph at

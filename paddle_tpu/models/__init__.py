@@ -23,3 +23,5 @@ from .longcat_flash import (LongCatFlashConfig, LongCatFlashModel,  # noqa: F401
                             LongCatFlashForCausalLM, longcat_flash_tiny)
 from .deepseek_v3 import (DeepseekV3Config, DeepseekV3Model,  # noqa: F401
                           DeepseekV3ForCausalLM, deepseek_v3_tiny)
+from .motif3 import (Motif3Config, Motif3Model,  # noqa: F401
+                     Motif3ForCausalLM, motif3_tiny)

@@ -31,6 +31,20 @@ latent-attention sublayers: ``(latent_layer(..), latent_layer(..))``).
                                lays a row out in whole tiles whatever is
                                declared: 576 takes 640, the pad zero); what
                                is COUNTED a token stays ``rank + rope``
+  ``window_latent_layer(rank, rope, window)``
+                               a latent row of the last ``window``
+                               positions only (a sliding-window layer): a
+                               RING a slot, ``window / block_size`` pages
+                               of a ``[1 + max_slots * pages, block,
+                               lanes]`` pool behind a fixed table (slot
+                               ``s`` owns pages ``1 + s * pages ..``; block
+                               0 is trash, as in the paged pools), position
+                               ``p`` at ring row ``p mod window``. The layer
+                               is handed ``(pool, ring_table)`` like a
+                               latent entry; the ring is not the pager's,
+                               so a model with such an entry is served
+                               without the prefix cache (a ring cannot be
+                               rebuilt from shared blocks)
   ``state_layer(arrays)``      a fixed-size recurrent state per sequence,
                                ``arrays`` = ((shape, dtype), ...) for ONE
                                sequence: the engine keeps ``[max_slots, *shape]``
@@ -53,7 +67,8 @@ from collections import namedtuple
 import numpy as np
 
 CacheLayer = namedtuple("CacheLayer", ["kind", "n_kv_heads", "head_dim",
-                                       "arrays", "merged_rows"])
+                                       "arrays", "merged_rows", "window"],
+                        defaults=(0,))
 
 
 def kv_layer(n_kv_heads: int, head_dim: int,
@@ -65,6 +80,12 @@ def kv_layer(n_kv_heads: int, head_dim: int,
 def latent_layer(rank: int, rope: int) -> CacheLayer:
     """``head_dim`` is the row's counted width, ``rank + rope``."""
     return CacheLayer("latent", 1, int(rank) + int(rope), (), True)
+
+
+def window_latent_layer(rank: int, rope: int, window: int) -> CacheLayer:
+    """A latent row of the last ``window`` positions, in a ring a slot."""
+    return CacheLayer("window", 1, int(rank) + int(rope), (), True,
+                      int(window))
 
 
 def pool_lanes(width: int) -> int:
@@ -108,7 +129,12 @@ class ModelSpec(namedtuple("ModelSpec", [
 
     @property
     def latent_layers(self) -> list:
-        return [c for c in self.entries if c.kind == "latent"]
+        """Entries that cache latent rows: paged, or in a ring."""
+        return [c for c in self.entries if c.kind in ("latent", "window")]
+
+    @property
+    def window_layers(self) -> list:
+        return [c for c in self.entries if c.kind == "window"]
 
     @property
     def state_layers(self) -> list:

@@ -82,16 +82,31 @@ class _Weights(nn.Layer):
 
 class DenseFFN(_Weights):
     """SwiGLU ``W_d(silu(W_g v) * W_u v)`` of ``width``, the product of the
-    two halves in float32."""
+    two halves in float32; with ``poly`` (``moe_grouped.Poly``) PolyNorm
+    in place of SiLU, its four numbers the parameter ``poly_coef``."""
 
-    def __init__(self, cfg, width: int):
+    def __init__(self, cfg, width: int, poly=None):
         super().__init__(cfg)
         h = cfg.hidden_size
         self.gate_proj = self.mat(h, width)
         self.up_proj = self.mat(h, width)
         self.down_proj = self.mat(width, h)
+        self.poly = poly
+        if poly is not None:
+            self.poly_coef = self.create_parameter(
+                (4,), dtype=cfg.dtype, default_initializer=nn.initializer
+                .Assign(np.array([1, 1, 1, 0], np.float32) / 3))
 
     def apply(self, v):
+        if self.poly is not None:
+            from ..kernels.pallas.moe_grouped import hidden
+            with jax.named_scope("dense_ffn"):
+                hid = hidden(_dot(v, self.gate_proj.value())
+                             .astype(jnp.float32),
+                             _dot(v, self.up_proj.value())
+                             .astype(jnp.float32),
+                             self.poly_coef.value(), self.poly)
+                return _dot(hid.astype(v.dtype), self.down_proj.value())
         with jax.named_scope("dense_ffn"):
             hid = jax.nn.silu(_dot(v, self.gate_proj.value())
                               .astype(jnp.float32)) \
@@ -155,12 +170,15 @@ def rope_frequencies(rot, theta, scaling=None):
     ``mscale(mscale_all_dim) ** 2``, ``mscale(m) = 0.1 m ln(factor) + 1``.
     YaRN's factor ``mscale(mscale) / mscale(mscale_all_dim)`` on cos and
     sin is 1 in every configuration served (DeepSeek-V3: both 1), and
-    another is refused."""
+    another is refused. ``apply_yarn_scaling`` false (Motif-3) keeps the
+    frequencies and drops every factor: on cos and sin and on the softmax
+    scale."""
     if not scaling:
         return None, 1.0
     kind = scaling.get("type", scaling.get("rope_type"))
     if kind != "yarn":
         raise NotImplementedError(f"rope_scaling of type {kind!r}")
+    scaled = scaling.get("apply_yarn_scaling", True)
     factor = float(scaling["factor"])
     orig = float(scaling["original_max_position_embeddings"])
     theta = float(theta)
@@ -176,6 +194,8 @@ def rope_frequencies(rot, theta, scaling=None):
                    / max(high - low, 1e-3), 0.0, 1.0)
     plain = theta ** (-np.arange(0, rot, 2) / rot)
     inv = plain / factor * ramp + plain * (1.0 - ramp)
+    if not scaled:
+        return inv.astype(np.float32), 1.0
     all_dims = scaling.get("mscale_all_dim", 0)
     if _yarn_mscale(factor, scaling.get("mscale", 1)) \
             != _yarn_mscale(factor, all_dims):

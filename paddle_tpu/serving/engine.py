@@ -77,7 +77,8 @@ from ..models.gpt import (_lm_head_logits, _pick_token,
                           _resolve_decode_horizon, set_paged_kv_sharding)
 from ..distributed.reshard import snapshot as _snapshot
 from ..kernels.pallas import pool_write
-from ..kernels.pallas.util import attention_kernels_traced, pool_writes_traced
+from ..kernels.pallas.util import (attention_kernels_traced,
+                                   mhc_rows_traced, pool_writes_traced)
 from .guardrails import (HANG_ENV, DispatchWatchdog, EngineHangError,
                          FaultSchedule, InjectedFault)
 from .pager import TRASH_BLOCK, BlockPager, prefix_digest
@@ -89,7 +90,8 @@ __all__ = ["DecodeEngine", "Request", "generate_via_engine",
 
 
 # cache entries whose content is blocks behind the block table (the pager's
-# blocks, copy-on-write and the prefix cache apply); a "state" entry is rows
+# blocks, copy-on-write and the prefix cache apply); a "state" entry is rows,
+# a "window" entry a ring of pages a slot behind a fixed table
 _PAGED = ("kv", "latent")
 
 # terminal caller-supplied request ids remembered per engine for dedup
@@ -337,6 +339,9 @@ class DecodeEngine:
         # ... and layers that cache one latent row a position (blocks
         # behind the table like K/V: the pager serves them by mechanism)
         self._has_latent = bool(spec.latent_layers)
+        # ... of which some keep only their last positions, in a ring a
+        # slot (the pager's blocks do not back them)
+        self._has_ring = bool(spec.window_layers)
         self.quantize = quantize
         self.max_slots = int(max_slots)
         self.max_len = int(max_len)
@@ -383,11 +388,21 @@ class DecodeEngine:
             int(np.prod(p.shape)) if p.ndim else 1
             for _, p in model.named_parameters())
         self._cache_dtype = spec.head_weight.value().dtype
-        # K and V of one position, every kv entry: what a call span's
-        # ``kv_bytes`` counts per live context token
-        self._kv_bytes = jnp.dtype(self._cache_dtype).itemsize * (sum(
+        # what a call span's ``kv_bytes`` counts, entry by entry, for the
+        # rows each reads: K and V of every kv entry and the row of every
+        # paged latent entry for each position a slot holds; a window
+        # entry's row for each of its last ``window`` ({window: [entries,
+        # bytes of their rows]})
+        elem = jnp.dtype(self._cache_dtype).itemsize
+        self._kv_bytes = elem * (sum(
             2 * c.n_kv_heads * c.head_dim for c in spec.kv_layers)
-            + sum(c.head_dim for c in spec.latent_layers))
+            + sum(c.head_dim for c in spec.latent_layers
+                  if c.kind == "latent"))
+        self._ring_bytes: dict = {}
+        for c in spec.window_layers:
+            n_b = self._ring_bytes.setdefault(c.window, [0, 0])
+            n_b[0] += 1
+            n_b[1] += elem * c.head_dim
         # ---- tensor-parallel decode over the device mesh: with a "model"
         # axis of degree > 1 and a model riding it, the executables become
         # SPMD programs — KV pools shard on the head axis (hd fallback for
@@ -489,15 +504,19 @@ class DecodeEngine:
             return z if self._pool_sh is None \
                 else jax.device_put(z, self._pool_sh)
         # a kv entry: a K pool and a V pool; a latent entry: ONE pool of
-        # rows; a state entry: its arrays, a row a slot
+        # rows; a window entry: one pool of rings; a state entry: its
+        # arrays, a row a slot
+        self._ring_tables: dict = {}
         self._pools = spec.map_entries(
             lambda c: (_pool(c), _pool(c)) if c.kind == "kv"
             else (_pool(c),) if c.kind == "latent"
+            else (self._ring_pool(c),) if c.kind == "window"
             else self._state_rows(c))
-        # a prefix hit would skip tokens a recurrent state has to see
-        self._pager = BlockPager(self.kv_blocks, self.block_size,
-                                 self.max_slots, self._mbs,
-                                 prefix_cache=not self._has_state)
+        # a prefix hit would skip tokens a recurrent state has to see, and
+        # the rows a ring would need from the shared blocks
+        self._pager = BlockPager(
+            self.kv_blocks, self.block_size, self.max_slots, self._mbs,
+            prefix_cache=not (self._has_state or self._has_ring))
         # in-flight chunked prefills: slot -> _PrefillState
         self._prefilling: dict = {}
         self._admit_seq = itertools.count()   # eviction picks youngest
@@ -563,6 +582,9 @@ class DecodeEngine:
         # how each executable wrote its new rows into the pools: "kernel"
         # (kernels/pallas/pool_write.py) or "scatter" (XLA's), as traced
         self._kv_write: dict = {}
+        # rows each executable's hyper-connection boundaries mix
+        # (kernels/pallas/mhc.py), where it has any, as traced
+        self._mhc_rows: dict = {}
         self._verify_exe = None
         self._prefill_exes = {}
         # ---- the prepared step (_step_planned). The
@@ -589,7 +611,8 @@ class DecodeEngine:
         self._tok_len = self.max_slots + len(self._moe_names)
         # the decode step is told which slots are live (write_end) where a
         # layer keeps per-slot state, or counts the tokens it routes
-        self._tells_live = self._has_state or bool(self._moe_names)
+        self._tells_live = self._has_state or self._has_ring \
+            or bool(self._moe_names)
         self._slot_dev: dict = {}
         # how each step that ran an executable came by its plan, and why
         # the rebuilt ones lost theirs (stats()["plan"])
@@ -824,14 +847,37 @@ class DecodeEngine:
         return tuple(jnp.zeros((self.max_slots,) + shape, jnp.dtype(dtype))
                      for shape, dtype in layer.arrays)
 
+    def _ring_pool(self, layer):
+        """A window entry's rings: ``window / block_size`` pages a slot
+        after the trash block, and the fixed table that says which
+        (``self._ring_tables``, by pages a slot)."""
+        if layer.window % self.block_size:
+            raise ValueError(
+                f"a window of {layer.window} positions is no whole number "
+                f"of blocks of {self.block_size}")
+        pages = layer.window // self.block_size
+        self._ring_tables.setdefault(pages, np.arange(
+            1, 1 + self.max_slots * pages, dtype=np.int32).reshape(
+                self.max_slots, pages))
+        z = jnp.zeros((1 + self.max_slots * pages, self.block_size,
+                       pool_lanes(layer.head_dim)), self._cache_dtype)
+        return z if self._pool_sh is None \
+            else jax.device_put(z, self._pool_sh)
+
     def _layer_caches(self, pools, table, slot=None):
         """What each layer's cached forward is handed, entry by entry: a
         kv entry its pools and ``table`` (the rows of the call's slots), a
-        state entry its arrays' rows, ``slot``'s alone for a one-slot
-        call."""
+        window entry its rings and their fixed table's rows, a state entry
+        its arrays' rows, ``slot``'s alone for a one-slot call."""
         def hand(entry, cache):
             if entry.kind in _PAGED:
                 return tuple(cache) + (table,)
+            if entry.kind == "window":
+                ring = jnp.asarray(self._ring_tables[
+                    entry.window // self.block_size])
+                return tuple(cache) + (ring if slot is None else
+                                       jax.lax.dynamic_slice_in_dim(
+                                           ring, slot, 1, 0),)
             if slot is None:
                 return tuple(cache)
             return tuple(jax.lax.dynamic_slice_in_dim(a, slot, 1, 0)
@@ -842,7 +888,7 @@ class DecodeEngine:
         """The caches to keep after a call: what the backbone returned, a
         one-slot call's state rows written back at ``slot``."""
         def keep(entry, cache, n):
-            if entry.kind in _PAGED or slot is None:
+            if entry.kind != "state" or slot is None:
                 return tuple(n)
             return tuple(jax.lax.dynamic_update_slice_in_dim(
                 a, r.astype(a.dtype), slot, 0) for a, r in zip(cache, n))
@@ -933,6 +979,7 @@ class DecodeEngine:
         traced = len(attention_kernels_traced())
         state_traced = len(state_kernels_traced())
         writes = len(pool_writes_traced())
+        mixed = len(mhc_rows_traced())
         low = self._lower_in_eval(fn, args, self._pool_out_shardings())
         n_out = low.out_info[1].shape[0]
         if n_out != self._tok_len:
@@ -940,7 +987,9 @@ class DecodeEngine:
             # output is the next step's input, so trace at its length
             self._tok_len = n_out
             args = args[:3] + (self._dev(self._host_tok()),) + args[4:]
+            mixed = len(mhc_rows_traced())
             low = self._lower_in_eval(fn, args, self._pool_out_shardings())
+        self._mhc_rows["decode"] = sum(mhc_rows_traced(mixed))
         exe = low.compile()
         self._build_note(args[3])
         self._decode_exe = exe
@@ -1029,10 +1078,12 @@ class DecodeEngine:
         t0 = time.time()
         traced = len(attention_kernels_traced())
         writes = len(pool_writes_traced())
+        mixed = len(mhc_rows_traced())
         exe = self._compile_in_eval(fn, args,
                                     out_shardings=self._pool_out_shardings())
         self._prefill_exes[sc] = exe
         self._kv_write[sc] = self._write_path(writes)
+        self._mhc_rows[sc] = sum(mhc_rows_traced(mixed))
         self._prefill_attention[sc] = self._attention_path(traced)
         if self._prefill_attention[sc] == "key_walk":
             from ..models.hybrid import walk_geometry
@@ -1792,15 +1843,29 @@ class DecodeEngine:
 
     # ------------------------------------------------- paged scheduling
 
-    def _cache_attrs(self, slots: int, tokens: int) -> dict:
-        """What a call span says of the caches the call goes through: the
-        K/V bytes of the live context ``tokens`` it reads and, for a model
-        with state entries, the slots whose recurrent state it reads and
-        writes, and its bytes."""
-        attrs = dict(kv_bytes=int(tokens) * self._kv_bytes)
+    def _cache_attrs(self, contexts, mhc_rows=None) -> dict:
+        """What a call span says of the caches the call goes through, for
+        slots holding ``contexts`` positions: the bytes of the rows each
+        entry reads (a ring's last ``window`` a slot),
+        for a model with window entries the rows they read
+        (``window_rows``), for a model with state entries the slots whose
+        recurrent state it reads and writes and its bytes, and the rows
+        its hyper-connection boundaries mix (``mhc_rows``) where the
+        executable has any."""
+        contexts = np.asarray(contexts, np.int64).reshape(-1)
+        attrs = dict(kv_bytes=int(contexts.sum()) * self._kv_bytes)
+        if self._ring_bytes:
+            attrs["window_rows"] = 0
+            for window, (n, nb) in self._ring_bytes.items():
+                rows = int(np.minimum(contexts, window).sum())
+                attrs["kv_bytes"] += rows * nb
+                attrs["window_rows"] += rows * n
         if self._has_state:
-            attrs.update(state_slots=int(slots),
-                         state_bytes=int(slots) * self._state_bytes)
+            slots = len(contexts)
+            attrs.update(state_slots=slots,
+                         state_bytes=slots * self._state_bytes)
+        if mhc_rows:
+            attrs["mhc_rows"] = int(mhc_rows)
         return attrs
 
     def _kv_walked(self, sc: int, end: int) -> int:
@@ -2353,7 +2418,9 @@ class DecodeEngine:
                              path=self._prefill_attention[c.sc],
                              kv_write=self._kv_write[c.sc],
                              kv_walked=self._kv_walked(c.sc, c.end),
-                             **self._cache_attrs(1, c.end)) as c.span:
+                             **self._cache_attrs(
+                                 [c.end], self._mhc_rows.get(c.sc))) \
+                    as c.span:
                 self._pools, c.tok0, c.ok = self._prefill_exes[c.sc](
                     self._leaf_values(), self._pools, *c.args)
                 c.args = None
@@ -2547,8 +2614,8 @@ class DecodeEngine:
             attrs = dict(path=self._decode_attention,
                          kv_write=self._kv_write["decode"],
                          **self._decode_geometry,
-                         **self._cache_attrs(len(rows),
-                                             int((pos[mask] + 1).sum())))
+                         **self._cache_attrs(pos[mask] + 1,
+                                             self._mhc_rows.get("decode")))
             if self._has_state:
                 attrs["state_path"] = self._decode_state
             # the live KV blocks this step has to read; the gather path read
