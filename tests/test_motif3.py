@@ -306,14 +306,23 @@ def boundary(seed, t=21, n=4, c=128, dtype=jnp.float32):
     return x, y, post, res, weights
 
 
-@pytest.mark.parametrize("mix", [True, False], ids=["write_and_read",
-                                                    "first_read"])
-def test_the_mhc_kernel_is_its_plain_form(mix):
-    """The Pallas kernel through the interpreter (21 rows: a padded last
-    block of 16) against the jax.numpy form. Both are float32 with the
-    same products; what differs is the order of summation (1e-6 read),
-    and the projection's rounding to bf16 would move it by 1e-3."""
-    x, y, post, res, w = boundary(1)
+# (mix, rows, width): 21 rows are one padded grid step's worth, 150 and
+# 300 cross the tile (2 steps of 80, 3 of 112, ragged last), 1,024 columns
+# walk in two pieces
+@pytest.mark.parametrize("mix,t,c", [
+    (True, 21, 128), (False, 21, 128), (True, 150, 128), (False, 150, 128),
+    (True, 300, 128), (False, 300, 128), (True, 150, 256),
+    (False, 150, 256), (True, 150, 1024)],
+    ids=["write_and_read", "first_read", "write_and_read-150",
+         "first_read-150", "write_and_read-300", "first_read-300",
+         "write_and_read-150x256", "first_read-150x256",
+         "write_and_read-150x1024"])
+def test_the_mhc_kernel_is_its_plain_form(mix, t, c):
+    """The Pallas kernel through the interpreter against the jax.numpy
+    form. Both are float32 with the same products; what differs is the
+    order of summation (1e-6 read), and the projection's rounding to bf16
+    would move it by 1e-3."""
+    x, y, post, res, w = boundary(1, t, c=c)
     args = (x, y, post, res) if mix else (x, None, None, None)
     want = mhc.mhc_plain(*args, *w, eps=1e-5, clamp=1e6, iters=20)
     with mhc.force_interpret():
@@ -325,6 +334,32 @@ def test_the_mhc_kernel_is_its_plain_form(mix):
                         .astype(jnp.float32), *w[2:], eps=1e-5, clamp=1e6,
                         iters=20)
     assert float(jnp.abs(low[2] - want[2]).max()) > 1e-5
+
+
+@pytest.mark.parametrize("t,tokens,steps,pieces", [(128, 64, 2, 4),
+                                                   (512, 128, 4, 8)],
+                         ids=["decode", "chunk"])
+def test_a_trace_leaves_the_mhc_schedule_on_the_span_layer(t, tokens, steps,
+                                                           pieces):
+    """At Motif-3-Beta's widths (4 streams of 4,096) a decode step's 128
+    rows run as two tiles of 64, a chunk's 512 as four of 128 (a lane
+    width), each walking its streams in pieces of 64 K float32 values; one
+    ``mhc/schedule`` span a trace says so."""
+    import time
+    from paddle_tpu.monitor import trace
+    n, c, f32, bf = 4, 4096, jnp.float32, jnp.bfloat16
+    sds = jax.ShapeDtypeStruct
+    args = (sds((t, n, c), bf), sds((t, c), bf), sds((t, n), f32),
+            sds((t, n * n), f32), sds((n * c,), bf), sds((24, n * c), bf),
+            sds((3,), bf), sds((24,), bf))
+    mhc._fused.clear_cache()            # a cached trace records nothing
+    t0 = time.perf_counter()
+    jax.eval_shape(lambda *a: mhc._fused(*a, eps=1e-6, clamp=1e2, iters=20,
+                                         mix=True, interpret=True), *args)
+    got = trace.spans(t0, time.perf_counter(), "mhc/schedule")
+    assert [s.attrs for s in got] == [
+        {"T": t, "tokens": tokens, "grid_steps": steps,
+         "column_pieces": pieces}] == [mhc.schedule(t, n, c)]
 
 
 def test_h_res_is_doubly_stochastic_and_neither_identity_nor_uniform():
@@ -354,6 +389,18 @@ def test_the_clamp_bounds_the_streams():
     with mhc.force_interpret():
         got = mhc.mhc(x, 1e3 * y, post, res, *w, eps=1e-5, clamp=5.0)[0]
     assert float(jnp.abs(got - out).max()) < 1e-4
+
+
+def test_padded_rows_keep_the_tile_they_were_padded_to():
+    """``mhc`` pads a ragged count of rows to whole tiles of ``tile(t)``
+    and ``_fused`` derives its grid from the padded count: both must
+    agree, at least two tiles where there are two sublane tiles of rows,
+    none over a lane width."""
+    for t in range(1, 4097):
+        tb = mhc.tile(t)
+        padded = -(-t // tb) * tb
+        assert mhc.tile(padded) == tb and tb % 16 == 0 and tb <= 128
+        assert padded // tb >= min(2, -(-t // 16))
 
 
 # ------------------------------------ the PolyNorm grouped expert kernel

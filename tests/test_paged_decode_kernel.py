@@ -561,3 +561,23 @@ def test_mosaic_compiles_the_copy_on_write_of_48_pools(one_chip):
     assert text.count('custom_call_target="tpu_custom_call"') == 1
     assert " copy(" not in text and " gather(" not in text
     assert exe.memory_analysis().temp_size_in_bytes < (1 << 20)
+
+
+@pytest.mark.parametrize("t", [128, 512, 130],
+                         ids=["decode", "chunk", "ragged"])
+@pytest.mark.parametrize("mix", [True, False], ids=["write_and_read",
+                                                    "first_read"])
+def test_mosaic_compiles_the_hyper_connection_boundary(one_chip, t, mix):
+    """Motif-3-Beta's mHC boundary (4 streams of 4,096, bf16) at a decode
+    step's 128 rows, a chunk's 512 and a ragged 130: the tile of up to a
+    lane width of tokens, its blocks double-buffered and the walk's
+    float32 pieces, fit the kernel's fast memory."""
+    from paddle_tpu.kernels.pallas import mhc
+    n, c, bf, f32 = 4, 4096, jnp.bfloat16, jnp.float32
+    exe = compile_for(
+        one_chip, lambda *a: mhc._fused(*a, eps=1e-6, clamp=100.0, iters=20,
+                                        mix=mix, interpret=False),
+        ((t, n, c), bf), ((t, c), bf), ((t, n), f32), ((t, n * n), f32),
+        ((n * c,), bf), ((24, n * c), bf), ((3,), bf), ((24,), bf))
+    assert exe.as_text().count('custom_call_target="tpu_custom_call"') == 1
+    assert exe.memory_analysis().temp_size_in_bytes < (1 << 20)
