@@ -53,7 +53,16 @@ writes its rows at their positions first (before ``write_end``); then
   once many queries share the expansion). A chunk walks the rows its slot
   holds before ``write_end`` in key blocks, all heads a trip
   (``hybrid.walk_keys``), and never the rest of the table row; the full
-  forward, which has no table, takes its own rows whole, heads in blocks.
+  forward, which has no table, takes its own rows whole, groups of heads
+  in blocks.
+
+Both up-projections are read as they lie on the device. ``q = cq Wqb`` is
+held row-major and its heads split off the product's output;
+``Wkvb`` is held column-major by a serving engine (``serving_layouts``,
+laid out once where the engine is built), groups outermost, as the
+absorbed products, batched over the groups, read it; the expansion
+(``_expand``) contracts its ``rank`` lanes in either layout. Left to
+itself the compiler re-lays a whole weight of either kind every call.
 """
 from __future__ import annotations
 
@@ -61,6 +70,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from .cache_spec import latent_layer, window_latent_layer
 from .hybrid import (_dot, _positions, _Weights, rms_norm, rope,
@@ -69,6 +79,13 @@ from .hybrid import (_dot, _positions, _Weights, rms_norm, rope,
 __all__ = ["LatentAttention"]
 
 SCORE_BLOCK = 1 << 25         # score elements of one block of heads (f32)
+
+
+def _row_major(t):
+    """``t`` held row-major (its last axis minor), as a product's output
+    comes out of a weight that is."""
+    return with_layout_constraint(
+        t, Layout(major_to_minor=tuple(range(t.ndim))))
 
 
 class LatentAttention(_Weights):
@@ -126,11 +143,32 @@ class LatentAttention(_Weights):
             return window_latent_layer(self.rank, self.rot, self.window)
         return latent_layer(self.rank, self.rot)
 
+    def serving_layouts(self):
+        """The weights a serving engine holds in another layout than it is
+        handed, by parameter (major-to-minor axis order); the engine lays
+        each out once, where it is built. ``Wkvb`` is held column-major,
+        a group's ``[k_nope | v]`` columns of ``rank`` lanes each, groups
+        outermost: the absorbed decode products are batched over the
+        groups and read it as it lies (held row-major, every call re-lays
+        all of it). The expansion contracts its ``rank`` lanes, which it
+        reads in either layout."""
+        return {self.kv_b_proj: (1, 0)}
+
     def _kv_b(self):
         """``Wkvb`` as [rank, groups (= heads without grouping), nope |
         v]."""
         return self.kv_b_proj.value().reshape(self.rank, self.groups,
                                               self.nope + self.vd)
+
+    def _expand(self, c, dt):
+        """Each group's ``[k_nope | v]`` of latent rows ``c [B, M, rank]``
+        in ``dt`` (float32 accumulation): [B, M, groups, nope + vd]. One
+        product against ``Wkvb`` whole, which contracts its ``rank``
+        lanes in whatever layout it is held."""
+        kv = jnp.einsum("bmr,rn->bmn", c, self.kv_b_proj.value(),
+                        precision="highest" if dt == jnp.float32 else None,
+                        preferred_element_type=jnp.float32).astype(dt)
+        return kv.reshape(kv.shape[:2] + (self.groups, self.nope + self.vd))
 
     def _by_group(self, t):
         """[B, S, nh, d] -> [B, S, G, per, d] where heads share a group's
@@ -163,7 +201,10 @@ class LatentAttention(_Weights):
         cq = rms_norm(_dot(z, self.q_a_proj.value()),
                       self.q_a_layernorm.value().astype(f32) * self.q_scale,
                       self.eps, centred=False)
-        q = _dot(cq, self.q_b_proj.value())
+        # the heads are split from the product's rows as they come out:
+        # laid out heads first for the head-batched products downstream,
+        # the product would re-lay all of ``Wqb`` a call to make them so
+        q = _row_major(_dot(cq, self.q_b_proj.value()))
         gate = None
         if self.gated:
             at = self.nh * (self.nope + self.rot)
@@ -183,14 +224,13 @@ class LatentAttention(_Weights):
     def _expanded(self, q_nope, q_rope, rows, positions, keys=None):
         """Causal attention of the call's queries over ``rows [B, M,
         >= rank+rot]`` (position ``m`` at index ``m``, or ``keys [M]``;
-        one below 0 is no key), per-head keys and values expanded from the
-        rows' latent, heads in blocks; a window layer's band. Returns the
-        context [B, S, nh * vd]."""
+        one below 0 is no key), each group's keys and values expanded from
+        the rows' latent, the groups' heads in blocks; a window layer's
+        band. Returns the context [B, S, nh * vd]."""
         b, s, nh = q_nope.shape[:3]
         m, dt = rows.shape[1], q_nope.dtype
         prec = "highest" if dt == jnp.float32 else None
-        c, k_rope = rows[..., :self.rank], \
-            rows[..., self.rank:self.rank + self.rot]
+        f32 = jnp.float32
         kpos = jnp.arange(m)[None, None, None, :] if keys is None \
             else keys[None, None, None, :]
         qpos = positions[:, None, :, None]
@@ -199,36 +239,38 @@ class LatentAttention(_Weights):
             live &= kpos >= 0
         if self.window:
             live &= kpos > qpos - self.window
-        hb = max(1, min(nh, SCORE_BLOCK // max(s * m, 1)))
-        while nh % hb:
-            hb -= 1
+        if self.per > 1:                            # [B|1,1,1,S,M]
+            live = live[:, None]
+        g = self.groups
+        gb = max(1, min(g, SCORE_BLOCK // max(s * m * self.per, 1)))
+        while g % gb:
+            gb -= 1
+        h, k = self._h, self._k
+        k_rope = rows[..., self.rank:self.rank + self.rot]
 
         def block(at):
-            qn, qr, w = at          # [B,S,hb,nope] [B,S,hb,rot] [rank,hb,n+v]
-            kv = jnp.einsum("bmr,rhd->bmhd", c, w, precision=prec,
-                            preferred_element_type=jnp.float32).astype(dt)
-            sc = jnp.einsum("bqhd,bmhd->bhqm", qn, kv[..., :self.nope],
-                            precision=prec,
-                            preferred_element_type=jnp.float32) \
-                + jnp.einsum("bqhd,bmd->bhqm", qr, k_rope, precision=prec,
-                             preferred_element_type=jnp.float32)
+            qn, qr, kv = at     # [B,S,gb(,per),nope] [..,rot] [B,M,gb,n+v]
+            sc = jnp.einsum(f"bq{h}d,bm{k}d->b{h}qm", qn, kv[..., :self.nope],
+                            precision=prec, preferred_element_type=f32) \
+                + jnp.einsum(f"bq{h}d,bmd->b{h}qm", qr, k_rope,
+                             precision=prec, preferred_element_type=f32)
             probs = jax.nn.softmax(jnp.where(live, sc * self.scale, -1e30),
                                    axis=-1).astype(dt)
-            return jnp.einsum("bhqm,bmhd->bqhd", probs, kv[..., self.nope:],
-                              precision=prec,
-                              preferred_element_type=jnp.float32).astype(dt)
+            return jnp.einsum(f"b{h}qm,bm{k}d->bq{h}d", probs,
+                              kv[..., self.nope:], precision=prec,
+                              preferred_element_type=f32).astype(dt)
 
-        w = jnp.repeat(self._kv_b(), self.per, axis=1)     # a head's own
-        if hb == nh:
-            ctx = block((q_nope, q_rope, w))
+        at = (self._by_group(q_nope), self._by_group(q_rope),
+              self._expand(rows[..., :self.rank], dt))
+        if gb == g:
+            ctx = block(at)
         else:
-            def split(t, ax):   # the head axis as (blocks, hb), blocks first
+            def split(t):       # the group axis as (blocks, gb), blocks first
                 return jnp.moveaxis(t.reshape(
-                    t.shape[:ax] + (nh // hb, hb) + t.shape[ax + 1:]), ax, 0)
+                    t.shape[:2] + (g // gb, gb) + t.shape[3:]), 2, 0)
 
-            ctx = jax.lax.map(block, (split(q_nope, 2), split(q_rope, 2),
-                                      split(w, 1)))       # [n,B,S,hb,vd]
-            ctx = jnp.moveaxis(ctx, 0, 2)
+            ctx = jnp.moveaxis(jax.lax.map(block, tuple(map(split, at))),
+                               0, 2)                # [B,S,n,gb(,per),vd]
         return ctx.reshape(b, s, nh * self.vd)
 
     def _walked(self, q_nope, q_rope, pool, table, positions, end):
@@ -242,7 +284,6 @@ class LatentAttention(_Weights):
         dt = q_nope.dtype
         prec = "highest" if dt == jnp.float32 else None
         f32 = jnp.float32
-        w = self._kv_b()
         h, k = self._h, self._k
         q_nope, q_rope = self._by_group(q_nope), self._by_group(q_rope)
 
@@ -250,10 +291,8 @@ class LatentAttention(_Weights):
             with jax.named_scope("kv_gather"):
                 rows = jnp.take(pool, entries, axis=0, mode="clip").reshape(
                     b, -1, pool.shape[2])
-            kv = jnp.einsum(f"bmr,r{k}d->bm{k}d", rows[..., :self.rank], w,
-                            precision=prec,
-                            preferred_element_type=f32).astype(dt)
-            return kv, rows[..., self.rank:self.rank + self.rot]
+            return (self._expand(rows[..., :self.rank], dt),
+                    rows[..., self.rank:self.rank + self.rot])
 
         def score(held):
             kv, k_rope = held
@@ -271,9 +310,11 @@ class LatentAttention(_Weights):
 
         ctx = walk_keys(table, positions, end, pool.shape[1], nh * s, fetch,
                         score, value)                       # [B,nh,S,vd]
-        ctx = ctx.reshape(b, nh, s, self.vd)
-        return jnp.moveaxis(ctx, 1, 2).astype(dt).reshape(b, s,
-                                                          nh * self.vd)
+        # rounded where the walk leaves it, so that moving the heads behind
+        # the queries copies the context in dt (left to itself, the
+        # compiler moves the walk's float32 context: twice the bytes)
+        ctx = _row_major(ctx.reshape(b, nh, s, self.vd).astype(dt))
+        return jnp.moveaxis(ctx, 1, 2).reshape(b, s, nh * self.vd)
 
     def _absorbed(self, q_nope, q_rope, pool, table, last, lam=None):
         """The decode step: ``[q_nope Wkvb_k^T | q_rope]`` against the

@@ -168,6 +168,24 @@ def quantize_for_serving(model, skip: Sequence = ()):
     return swap_sublayers(model, swap)
 
 
+def lay_out_for_serving(model):
+    """Each weight a layer asks to be held in another layout (its
+    ``serving_layouts()``: {parameter: major-to-minor axis order}) laid
+    out so IN PLACE, once: the array replaces the one the parameter held,
+    so the device holds one copy, and every executable then compiles
+    against that layout. Names, shapes and values are unchanged."""
+    from jax.experimental.layout import Format, Layout
+    for layer in model.sublayers(include_self=True):
+        ask = getattr(layer, "serving_layouts", None)
+        for p, order in (ask() if ask is not None else {}).items():
+            a = p.value()
+            held = getattr(a.format, "layout", None)
+            if held is None or tuple(held.major_to_minor) != tuple(order):
+                p._data = jax.device_put(
+                    a, Format(Layout(major_to_minor=tuple(order)),
+                              a.sharding))
+
+
 class _PrefillState:
     """One slot's in-flight chunked prefill: which prompt positions are
     cached so far (shared-prefix coverage counts), which are covered by
@@ -330,6 +348,7 @@ class DecodeEngine:
                 f"({spec.max_pos})")
         if quantize == "int8":
             quantize_for_serving(model)
+        lay_out_for_serving(model)
         self.model = model
         self.spec = spec
         # layers that keep a fixed-size recurrent state per slot (beside or

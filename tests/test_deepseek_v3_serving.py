@@ -157,3 +157,59 @@ def test_a_shared_prefix_is_hit_and_a_preempted_request_served_alike(tiny):
     assert tight.stats()["paged"]["preemptions"] >= 1
     assert [r.tokens for r in reqs] == alone
     tight._pager.check_invariants()
+
+
+def test_the_weights_laid_out_for_serving_give_the_same_logits():
+    """The engine lays each ``Wkvb`` out groups first where it is built
+    (``serving_layouts``): a decode step over a filled cache, its logits
+    compiled against the weights as they were assigned and again after,
+    the kernel's walk under the interpreter and the gathered view alike,
+    gives the same bits; names, shapes and values stay."""
+    from paddle_tpu.kernels.pallas.util import attention_kernels_traced
+    from paddle_tpu.serving.engine import lay_out_for_serving
+    prog, _, _ = program()
+    leaves = [p for _, p in prog.named_parameters()]
+    rng = np.random.default_rng(7)
+    b, mbs, lanes = 3, 4, 128
+    pools = [jnp.asarray(rng.normal(size=(b * mbs + 1, BLOCK, lanes)),
+                         jnp.float32) for _ in prog.model.layers]
+    table = jnp.asarray(np.arange(1, b * mbs + 1, dtype=np.int32).reshape(
+        b, mbs))
+    pos = jnp.asarray([5, 17, 30], jnp.int32)
+    ids = jnp.asarray(rng.integers(0, 512, (b, 1)), jnp.int32)
+
+    def step(arrays, pools):
+        held = [p._data for p in leaves]
+        try:
+            for p, a in zip(leaves, arrays):
+                p._data = a
+            hidden, _ = prog.model(ids, kv_caches=[(p, table) for p in pools],
+                                   start_pos=pos, write_end=pos + 1)
+            return hidden.value()[:, -1] @ prog.lm_head.value()
+        finally:
+            for p, d in zip(leaves, held):
+                p._data = d
+
+    def logits():       # a fresh trace each: the kernel is chosen tracing
+        arrays = [p.value() for p in leaves]
+        out = [np.asarray(jax.jit(lambda a, q: step(a, q))(arrays, pools))]
+        mark = len(attention_kernels_traced())
+        with paged_decode.force_interpret():
+            out.append(np.asarray(
+                jax.jit(lambda a, q: step(a, q))(arrays, pools)))
+        assert "mla_decode" in attention_kernels_traced(mark)
+        return out
+
+    names = {n: (tuple(p.shape), np.asarray(p.value()))
+             for n, p in prog.named_parameters()}
+    before = logits()
+    lay_out_for_serving(prog)
+    wkvb = [blk.self_attn.kv_b_proj.value() for blk in prog.model.layers]
+    assert all(w.format.layout.major_to_minor == (1, 0) for w in wkvb)
+    after = logits()
+    for was, now in zip(before, after):
+        np.testing.assert_array_equal(was, now)
+    assert float(np.abs(before[0] - before[1]).max()) < 1e-5
+    for n, p in prog.named_parameters():
+        assert tuple(p.shape) == names[n][0]
+        np.testing.assert_array_equal(np.asarray(p.value()), names[n][1])

@@ -10,6 +10,7 @@ other serving kernels (`gdn.py`, `moe_grouped.py`) and the training
 attention's (`flash_pair.py`), kept in this ONE file because only one test
 process may load the TPU's compiler."""
 import math
+import re
 
 import numpy as np
 import jax
@@ -333,6 +334,120 @@ def test_merged_row_pools_reach_the_kernel_without_a_copy(one_chip):
     exe = compile_for(one_chip, paged_decode_attention, *small, *four_d,
                       *tail)
     assert exe.memory_analysis().temp_size_in_bytes > nb * BS * n_kv * hd * 2
+
+
+def relaid_parameters(text, params):
+    """The copies of the entry computation in ``text`` that re-lay an array
+    read from a parameter numbered below ``params``: a ``copy`` or
+    ``copy-start`` whose operand traces back to such a parameter through
+    bitcasts, slices and their joins, and whose result is laid out
+    otherwise than its operand (a move between memory spaces, which keeps
+    the layout, is no re-lay)."""
+    entry = text[text.index("\nENTRY"):]
+    defs = {}
+    for line in entry.splitlines()[1:]:
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.+?) ([a-z][\w-]*)\(([^)]*)\)",
+                     line)
+        if m:
+            defs[m.group(1)] = (m.group(2), m.group(3), line,
+                                re.findall(r"%([\w.-]+)", m.group(4)),
+                                m.group(4))
+    through = {"bitcast", "reshape", "slice", "slice-start", "slice-done",
+               "get-tuple-element", "copy-done"}
+
+    def weight(name, seen=()):
+        kind, op, line, operands, arg = defs.get(name, ("", "", "", [], ""))
+        if op == "parameter":
+            return int(arg) < params
+        if op in through or 'custom_call_target="ConcatBitcast"' in line:
+            return any(weight(o) for o in operands)
+        return False
+
+    def laid(shape):      # dims and layout, the memory space left out
+        return re.sub(r"S\(\d+\)", "", shape)
+
+    found = []
+    for name, (kind, op, line, operands, _) in defs.items():
+        if op not in ("copy", "copy-start") or not weight(operands[0]):
+            continue
+        if op == "copy":
+            src, dst = defs[operands[0]][0], kind
+        else:
+            dst, src = re.findall(r"\w+\[[\d,]*\]\{[^}]*\}", kind)[:2]
+        if laid(src) != laid(dst):
+            found.append(line.strip()[:160])
+    return found
+
+
+def latent_layer_call(one_chip, monkeypatch, nh, chunk):
+    """One real-width latent layer's decode step (128 slots) or chunk (512
+    rows of one slot), weights as arguments laid out as a serving engine
+    lays them, compiled for the described chip with the Mosaic kernels in
+    it."""
+    from paddle_tpu.kernels.pallas import paged_decode as pd, pool_write
+    from paddle_tpu.models.deepseek_v3 import YARN, deepseek_v3_tiny
+    from paddle_tpu.models.latent_attention import LatentAttention
+    from paddle_tpu.models.longcat_flash import longcat_flash_tiny
+    from paddle_tpu.nn.initializer import api
+    from paddle_tpu.serving.engine import lay_out_for_serving
+    from jax.experimental.layout import Format, Layout
+    widths = dict(num_attention_heads=nh, q_lora_rank=1536,
+                  kv_lora_rank=512, qk_nope_head_dim=128,
+                  qk_rope_head_dim=64, v_head_dim=128, dtype="bfloat16")
+    monkeypatch.setattr(api, "_host_sample",
+                        lambda sampler, shape, dtype: jnp.zeros(shape, dtype))
+    cfg = deepseek_v3_tiny(hidden_size=7168, rope_scaling=dict(
+        YARN, factor=40, original_max_position_embeddings=4096), **widths) \
+        if nh == 128 else longcat_flash_tiny(hidden_size=6144, **widths)
+    layer = LatentAttention(cfg)
+    lay_out_for_serving(layer)
+    params = [p for _, p in layer.named_parameters()]
+    for mod in (pd, pool_write):
+        monkeypatch.setattr(mod, "tpu_placement", lambda x: True)
+
+    def fn(ws, z, pool, table, pos, end):
+        held = [p._data for p in params]
+        try:
+            for p, w in zip(params, ws):
+                p._data = w
+            return layer.apply(z, (pool, table), pos, end)
+        finally:
+            for p, d in zip(params, held):
+                p._data = d
+
+    def sds(shape, dtype, sharding=one_chip):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    ws = [sds(p.shape, jnp.bfloat16, Format(Layout(
+        tuple(p.value().format.layout.major_to_minor)), one_chip))
+        for p in params]
+    b, s, cursor = (1, 512, ()) if chunk else (128, 1, (128,))
+    args = (ws, sds((b, s, cfg.hidden_size), jnp.bfloat16),
+            sds((16384, BS, 640), jnp.bfloat16), sds((b, 256), jnp.int32),
+            sds(cursor, jnp.int32), sds(cursor, jnp.int32))
+    cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        exe = jax.jit(fn, donate_argnums=(2,)).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cache)
+    return exe.as_text(), len(params)
+
+
+@pytest.mark.parametrize("call", ["decode", "chunk"])
+@pytest.mark.parametrize("nh", [128, 64], ids=["deepseek", "longcat"])
+def test_latent_attention_reads_its_weights_as_they_lie(one_chip,
+                                                        monkeypatch, nh,
+                                                        call):
+    """DeepSeek-V3's and LongCat-Flash's latent layer at the served widths:
+    neither the decode step nor a chunk re-lays a weight. Left to itself,
+    the compiler lays ``cq Wqb`` out heads first for the head-batched
+    products and copies all of ``Wqb`` for it, and the products batched
+    over the heads copy ``Wkvb`` held row-major, every call."""
+    text, params = latent_layer_call(one_chip, monkeypatch, nh,
+                                     call == "chunk")
+    assert "mla_decode" in text if call == "decode" else "while" in text
+    assert relaid_parameters(text, params) == []
 
 
 def test_mosaic_compiles_the_delta_rule_decode_step(one_chip):
